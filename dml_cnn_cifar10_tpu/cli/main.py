@@ -599,15 +599,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "agreement allgathers (single-process reacts "
                         "immediately)")
     p.add_argument("--compile_cache_dir", type=str, default=None,
-                   help="persistent compilation cache directory "
-                        "(compilecache/, docs/COMPILECACHE.md): compiled "
-                        "programs keyed by fingerprint persist here and "
-                        "warm restarts — supervisor recovery, elastic "
-                        "re-entry, serve warmup — skip the XLA recompile "
-                        "(jax's native persistent cache is armed under "
-                        "DIR/xla; executable deserialization is opt-in "
-                        "per backend via DML_COMPILECACHE_EXEC_BACKENDS). "
-                        "Fail-open; emits `compile` JSONL events")
+                   help="the repo's keyed compile store "
+                        "(compilecache/, docs/COMPILECACHE.md): every "
+                        "compile seam's lowered StableHLO, cost analysis "
+                        "and hit/miss telemetry persist here, keyed by "
+                        "fingerprint. It does not move jax's own "
+                        "persistent compilation cache, which gives the "
+                        "warm restart and lives where "
+                        "JAX_COMPILATION_CACHE_DIR says, else "
+                        "<repo>/.jax_cache (executable deserialization "
+                        "from this store is opt-in per backend via "
+                        "DML_COMPILECACHE_EXEC_BACKENDS). Fail-open; "
+                        "emits `compile` JSONL events")
     p.add_argument("--compile_cache_max_bytes", type=int,
                    default=2_000_000_000,
                    help="LRU size bound for --compile_cache_dir "
@@ -938,6 +941,24 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
             f"--fleet_min_replicas/--fleet_max_replicas must satisfy "
             f"1 <= min <= max, got {args.fleet_min_replicas}/"
             f"{args.fleet_max_replicas}")
+    if args.mode == "fleet" and args.fleet_max_replicas > 1:
+        from dml_cnn_cifar10_tpu.utils.platform import accelerator_expected
+        if accelerator_expected():
+            # One process for each chip: the controller starts one
+            # worker PROCESS per replica and every worker opens the
+            # accelerator; a TPU host's chips belong to the first
+            # process that does (docs/SERVING.md, "One process for each
+            # chip"). Refuse at flag parse instead of letting the
+            # second worker die or hang at backend start-up.
+            raise SystemExit(
+                f"--mode fleet with --fleet_max_replicas="
+                f"{args.fleet_max_replicas} cannot run on one "
+                f"accelerator host: each replica is a worker process, "
+                f"every worker opens the TPU, and a chip belongs to one "
+                f"process at a time (on a multi-chip host the first "
+                f"worker claims every chip). Use --fleet_max_replicas 1 "
+                f"here, or run replicas on separate hosts; replicas "
+                f"sharing a host is not built yet.")
     cfg.fleet.min_replicas = args.fleet_min_replicas
     cfg.fleet.max_replicas = args.fleet_max_replicas
     cfg.fleet.port = args.fleet_port
@@ -964,12 +985,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"[cli] ignoring unrecognized args: {unparsed}",
               file=sys.stderr)
 
-    # Before ANY jax backend use: the native persistent compilation
-    # cache (the warm start for backends where executable swapping is
-    # off — the default) is read at client creation; arming it later is
-    # a silent no-op.
+    # Before anything compiles: jax opens its persistent compilation
+    # cache (the warm start, executable swapping being off) once, at
+    # the first compile.
     from dml_cnn_cifar10_tpu.compilecache import arm_native_cache
-    arm_native_cache(args.compile_cache_dir)
+    arm_native_cache()
 
     if args.job_name == "ps":
         # The reference blocks a whole process on server.join()

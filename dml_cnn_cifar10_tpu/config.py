@@ -65,8 +65,8 @@ class DataConfig:
     # multi-host scale); --device_index_stream=false restores the host
     # numpy-PCG stream.
     device_index_stream: bool = True
-    # Use the native C++ record loader when the shared library is available;
-    # falls back to the pure-NumPy path otherwise.
+    # Use the native C++ record loader (runtime/librecordio.so, built on
+    # demand); a failed build or load raises rather than falling back.
     use_native_loader: bool = True
     # Synthetic mode generates CIFAR-format .bin files locally (same 3073-byte
     # record layout) for air-gapped testing/benchmarking.
@@ -189,8 +189,8 @@ class ModelConfig:
     # one-hot contractions — the all-MXU, ep-proven path whose dispatch
     # GSPMD compiles into the expert all-to-all) or "scatter"
     # ((expert, slot)-indexed scatter/gather — O(T·D) instead of the
-    # einsum pair's O(T²·f·D); measured 2.28x vit_moe step throughput
-    # at 16k tokens on one chip, BASELINE.md round 5). Identical
+    # einsum pair's O(T²·f·D); speed not measured on the current
+    # chip). Identical
     # semantics, numerically equivalent (pinned to ~1e-5 by
     # test_scatter_dispatch_matches_einsum — reduction orders differ,
     # so outputs are close, not bit-identical).

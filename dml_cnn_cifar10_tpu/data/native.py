@@ -47,8 +47,16 @@ def _build_library() -> None:
     lock_path = os.path.join(_RUNTIME_DIR, ".build.lock")
     with open(lock_path, "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
-        subprocess.run(["make", "-C", _RUNTIME_DIR], check=True,
-                       capture_output=True)
+        try:
+            subprocess.run(["make", "-C", _RUNTIME_DIR], check=True,
+                           capture_output=True, text=True)
+        except (OSError, subprocess.CalledProcessError) as e:
+            raise RuntimeError(
+                f"building {_LIB_PATH} with `make -C {_RUNTIME_DIR}` "
+                f"failed: {e}\n{getattr(e, 'stderr', '') or ''}\n"
+                f"Fix the toolchain, or run with --use_native_loader "
+                f"false (DataConfig.use_native_loader=False) to use the "
+                f"NumPy iterator.") from e
 
 
 def _needs_build() -> bool:

@@ -357,13 +357,13 @@ def input_pipeline(
     download.ensure_dataset(cfg)
     files = download.train_files(cfg) if train else download.test_files(cfg)
     if cfg.use_native_loader:
-        try:
-            from dml_cnn_cifar10_tpu.data import native
-            return native.NativeShuffleBatchIterator(
-                files, cfg, batch_size, train=train, seed=seed,
-                shard=shard, num_shards=num_shards)
-        except Exception:
-            pass  # library not built — NumPy reference path
+        # No quiet NumPy stand-in: runtime/librecordio.so is built on
+        # demand, and a failed build or load raises (data/native.py
+        # says how to switch the native loader off).
+        from dml_cnn_cifar10_tpu.data import native
+        return native.NativeShuffleBatchIterator(
+            files, cfg, batch_size, train=train, seed=seed,
+            shard=shard, num_shards=num_shards)
     return ShuffleBatchIterator(
         files, cfg, batch_size, train=train, seed=seed,
         shard=shard, num_shards=num_shards)

@@ -25,7 +25,8 @@ No reference counterpart (the reference model is the 5-layer CNN,
   BN's stats/normalize passes among the top byte movers; nf removes
   every activation-sized stats read/write. Different training semantics
   (the NFNet line of work shows the class reaches BN-level accuracy
-  with care); benched in BASELINE.md as the byte-reduction rung.
+  with care); the byte-reduction rung (tools/bench_resnet.py; not
+  measured on the current chip).
 """
 
 from __future__ import annotations
@@ -149,7 +150,7 @@ def init_params(key: jax.Array, cfg: ModelConfig, data: DataConfig,
 
     p: Params = {}
     if imagenet_stem and cfg.resnet_s2d:
-        # Space-to-depth stem (BASELINE.md round-4): 4x4/1 conv over the
+        # Space-to-depth stem: 4x4/1 conv over the
         # 2x2-folded input — same function class as 7x7/2 on the raw
         # image (zero-pad 7x7 to 8x8, fold into 4x4 x 4C), trained
         # directly in the folded parameterization as MLPerf does.

@@ -120,13 +120,17 @@ def _block(x: jax.Array, p: Params, heads: int, use_pallas: bool,
            moe_top_k: int = 1, causal: bool = False, window=None,
            moe_dispatch: str = "einsum"):
     """One transformer block → ``(x, aux)`` — ``aux`` is the MoE router
-    stats dict (ops/moe.py) for MoE blocks, scalar 0.0 for dense MLPs."""
+    stats dict (ops/moe.py) for MoE blocks, scalar 0.0 for dense MLPs.
+    ``mesh`` is the enclosing GSPMD program's mesh (none inside a
+    pipeline stage, which is already a ``shard_map``): a ``seq`` axis
+    >1 routes attention to the sequence-parallel kernels, otherwise it
+    tells the dispatch where to place the flash kernel."""
     b, s, dim = x.shape
     h = layer_norm(x, p["ln1"])
     qkv = L.dense(h, p["qkv"]["kernel"], p["qkv"]["bias"])
     qkv = qkv.reshape(b, s, heads, 3, dim // heads)  # heads-major
     q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
-    if mesh is not None:
+    if mesh is not None and mesh.shape.get("seq", 1) > 1:
         # Sequence-parallel path over the ``seq`` mesh axis. Two strategies
         # with the same sharded-activation contract:
         # - "ring": each device holds S/seq tokens, K/V shards walk the
@@ -147,7 +151,8 @@ def _block(x: jax.Array, p: Params, heads: int, use_pallas: bool,
             raise ValueError(f"unknown sp_mode {sp_mode!r}")
     else:
         o = attn.dispatch_attention(q, k, v, use_pallas=use_pallas,
-                                    causal=causal, window=window)
+                                    causal=causal, window=window,
+                                    mesh=mesh)
     x = x + L.dense(o.reshape(b, s, dim), p["proj"]["kernel"],
                     p["proj"]["bias"])
     h = layer_norm(x, p["ln2"])
@@ -223,8 +228,6 @@ def apply_with_aux(params: Params, images: jax.Array, cfg: ModelConfig,
         x = lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("data", "seq", None)))
 
-    attn_mesh = mesh if seq_parallel else None
-
     aux = jnp.zeros((), jnp.float32)
     if pipe_parallel:
         from dml_cnn_cifar10_tpu.parallel import pipeline
@@ -245,7 +248,7 @@ def apply_with_aux(params: Params, images: jax.Array, cfg: ModelConfig,
         def block_fn(h, bp):
             return _block(h, bp, cfg.vit_heads,
                           cfg.use_pallas_attention,
-                          cfg.moe_capacity_factor, mesh=attn_mesh,
+                          cfg.moe_capacity_factor, mesh=mesh,
                           sp_mode=cfg.sp_mode,
                           moe_top_k=cfg.moe_top_k,
                           causal=cfg.attn_causal, window=cfg.attn_window,

@@ -21,8 +21,14 @@ Two implementations with one contract::
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from dml_cnn_cifar10_tpu.ops import kernel_paths
+from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 
 NEG_INF = -1e30  # finite: exp(-inf - -inf) would NaN a fully-masked row
@@ -103,15 +109,44 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        scale: float | None = None,
                        causal: bool = False,
                        segment_ids: jax.Array | None = None,
-                       window: int | None = None) -> jax.Array:
+                       window: int | None = None,
+                       mesh=None) -> jax.Array:
     """Pick the attention impl: Pallas flash kernel when asked for and the
     sequence is long enough to benefit; XLA fused attention otherwise.
     Both paths differentiate (the flash path via its custom_vjp backward
-    kernels) and both honor ``causal``."""
-    seq = q.shape[1]
-    if use_pallas and seq >= 128:
-        from dml_cnn_cifar10_tpu.ops import flash_attention as fa
-        return fa.flash_attention(q, k, v, scale=scale, causal=causal,
-                                  segment_ids=segment_ids, window=window)
-    return xla_attention(q, k, v, scale=scale, causal=causal,
-                         segment_ids=segment_ids, window=window)
+    kernels) and both honor ``causal``.
+
+    ``mesh`` is the mesh of the enclosing GSPMD program, if any (callers
+    already inside a ``shard_map`` pass none). A compiled ``pallas_call``
+    cannot sit bare in a program partitioned over more than one device,
+    so there the flash call runs under a ``shard_map`` over the whole
+    mesh: batch over ``data``, heads over ``model`` — attention is
+    independent per (batch, head), so each device runs the kernel on
+    its own slice with no collective. A dim its axis does not divide is
+    replicated instead (and the printed path says so)."""
+    b, seq, h, _ = q.shape
+    if not (use_pallas and seq >= 128):
+        kernel_paths.note("attention", f"xla ({seq} tokens)")
+        return xla_attention(q, k, v, scale=scale, causal=causal,
+                             segment_ids=segment_ids, window=window)
+    from dml_cnn_cifar10_tpu.ops import flash_attention as fa
+
+    # interpret is passed, not left to resolve inside flash_attention's
+    # own jit: it is static there, so it keys that cache.
+    interpret = not platform_lib.on_tpu()
+    flash = functools.partial(fa.flash_attention, scale=scale,
+                              causal=causal, window=window,
+                              interpret=interpret)
+    path = f"flash{'-interpret' if interpret else ''} ({seq} tokens)"
+    if mesh is None or mesh.size == 1:
+        kernel_paths.note("attention", path)
+        return flash(q, k, v, segment_ids=segment_ids)
+    bax = "data" if b % mesh.shape["data"] == 0 else None
+    hax = "model" if h % mesh.shape["model"] == 0 else None
+    kernel_paths.note(
+        "attention", f"{path}/shard_map[batch/{bax}, heads/{hax}]")
+    qkv = P(bax, None, hax, None)
+    return jax.shard_map(
+        lambda q, k, v, seg: flash(q, k, v, segment_ids=seg),
+        mesh=mesh, in_specs=(qkv, qkv, qkv, P(bax, None)),
+        out_specs=qkv, check_vma=False)(q, k, v, segment_ids)

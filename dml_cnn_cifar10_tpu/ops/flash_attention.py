@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
 NEG_INF = -1e30  # not -inf: exp(-inf - -inf) would NaN the first block
 
 # ---------------------------------------------------------------------------
@@ -68,35 +70,32 @@ def _resolve(q, scale, block_q, block_k, interpret):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not platform_lib.on_tpu()
     s = q.shape[1]
-    # Auto block size (None): re-tuned on a v5e each round. Round 2 found
-    # 512 beats 128 from S>=2048; the round-3 sweep (with the backward
-    # kernels and fetch-free clamps in play) found 1024 beats 512 across
-    # the whole fwd+bwd training path — 1.64x at S=2048 (7.5 vs 12.3 ms),
-    # 1.28x at S=16384 (129.6 vs 165.6 ms), causal 107->76 ms — while
-    # 2048 exceeds the 16 MB scoped-VMEM limit. 1024 is taken only at
-    # head_dim <= 64 (the ladder's geometry; bigger heads double the
-    # block buffers and the fwd acc scratch, re-approaching the VMEM
-    # ceiling 2048 hit). 128 still wins below S=2048.
-    # Round-5 negative results on the W=1024-causal gap (7.48x measured
-    # vs the 8x round-3 target; 8.24x is the block-1024 granularity
-    # ceiling), trace-timed fwd+bwd at S=16384 [B=4,H=8,D=64] bf16 vs
-    # 15.39 ms for symmetric 1024 — do NOT retry without new geometry:
-    # - asymmetric folds: bq=512/bk=1024 16.40 ms, bq=1024/bk=512
-    #   19.86 ms, bq=bk=512 16.34 ms. The band-union FLOPs are identical
-    #   at every one of these granularities (the 1024-wide band spans
-    #   the same columns regardless of how blocks tile it), so finer
-    #   blocks only add grid ticks and narrower MXU dots.
-    # - in-tile K-half gating (two 512-wide sub-dots per 1024 tile, each
-    #   under pl.when on its half's band-liveness): 18.95 ms (+23%).
-    #   At W=block geometry the band crosses BOTH halves of nearly every
-    #   live block, so the split skips almost no work and pays the
-    #   doubled mask/softmax-update chain on every tick.
-    # 7.48x stands as the honest number: 91% of what block granularity
-    # admits, and every finer-granularity route measured is a loss.
-    d = q.shape[-1]
-    auto_block = (1024 if d <= 64 else 512) if s >= 2048 else 128
+    # Auto block size (None), chosen from what the call can see: the
+    # sequence length and the bytes in one head row. What Mosaic in
+    # libtpu 0.0.34 accepted on a TPU v5e inside the real ViT-Tiny train
+    # step, head dim 64, forward and both backward kernels (my chip
+    # runs, PR 21):
+    # - S < 2048: 128. S=257 (64-px crops) compiled and ran in f32, on
+    #   one chip and under the dispatch's shard_map on four.
+    # - S >= 2048, rows of <= 128 bytes (head dim 64 in bf16): 1024.
+    #   S=2117 (184-px crops) compiled and ran, full and causal W=1024.
+    # - S >= 2048, wider rows (head dim 64 in f32, head dim 128): 512.
+    #   S=2117 in f32 compiled and ran at 512, full and causal W=1024.
+    #   At 1024 the same f32 kernels compiled in a bare fwd+bwd call but
+    #   not inside the train step: XLA refused the dK/dV kernel with
+    #   "Ran out of memory in memory space vmem while allocating on
+    #   stack". That is the default 16 MiB scoped-VMEM limit, not the
+    #   chip: with vmem_limit_bytes=64 MiB the step compiled and ran.
+    #   (The [block, block] f32 score/probability intermediates do not
+    #   shrink with the input dtype; 2048 was not retried here.)
+    # Which of the accepted sizes is fastest, and the earlier findings
+    # that asymmetric folds (bq != bk) and in-tile K-half gating lose to
+    # symmetric blocks on the W=1024 causal band, were measured on an
+    # earlier chip only: not measured on the current one.
+    row_bytes = q.shape[-1] * q.dtype.itemsize
+    auto_block = (1024 if row_bytes <= 128 else 512) if s >= 2048 else 128
     block_q = auto_block if block_q is None else block_q
     block_k = auto_block if block_k is None else block_k
     return float(scale), block_q, block_k, interpret

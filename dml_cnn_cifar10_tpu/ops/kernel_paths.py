@@ -1,0 +1,37 @@
+"""Which implementation a traced program took, reported from the place
+that chose it.
+
+The fused optimizer update (``ops/optimizer.py``) and the attention
+dispatch (``ops/attention.py``) each pick between a compiled Pallas
+kernel and an XLA expression from what they can see at trace time: the
+platform, the mesh, the token count. A step builder
+(``parallel/step.py``) opens :func:`recording` around its step body;
+the choosers :func:`note` their pick; the builder prints the record
+once. The recorder is thread-local and only live inside ``recording``,
+so untraced callers pay a dict lookup and nothing is kept.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import threading
+
+_local = threading.local()
+
+
+@contextlib.contextmanager
+def recording():
+    """Collect the notes made while tracing the body → ``{kind: path}``."""
+    prev = getattr(_local, "rec", None)
+    _local.rec = rec = {}
+    try:
+        yield rec
+    finally:
+        _local.rec = prev
+
+
+def note(kind: str, path: str) -> None:
+    """Record that ``kind`` ("update" | "attention") compiled ``path``."""
+    rec = getattr(_local, "rec", None)
+    if rec is not None:
+        rec[kind] = path

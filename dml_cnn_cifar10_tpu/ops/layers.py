@@ -48,8 +48,8 @@ def max_pool(x: jax.Array, window: int = 3, stride: int = 2,
              padding: str = "SAME") -> jax.Array:
     """Max pool over NHWC spatial dims via ``lax.reduce_window``.
 
-    Backward is XLA's select-and-scatter. Round-3 note (BASELINE.md
-    ResNet-50 profile): that op is ~5% of the bf16 224² train step, and a
+    Backward is XLA's select-and-scatter. Round-3 note (a ResNet-50
+    profile on an earlier chip, not repeated on the current one): that op is ~5% of the bf16 224² train step, and a
     hand-written 9-shift compare-mask-pad VJP was implemented and
     MEASURED WORSE (-27% step time — the f32 grad accumulator makes 9
     full passes over the 112² activation grid, far more HBM traffic than
@@ -106,7 +106,7 @@ def batch_norm(
     regardless of compute dtype — bf16 batch stats lose too much
     precision — but the per-element normalize runs in ``x.dtype``
     (round 3: BN's epilogue is memory-bound and the f32 upcast doubled
-    its HBM traffic; see BASELINE.md's ResNet-50 profile). Output dtype
+    its HBM traffic). Output dtype
     == input dtype in train and eval.
     """
     axes = tuple(range(x.ndim - 1))

@@ -70,7 +70,7 @@ def moe_mlp(x: jax.Array, params: Params, capacity_factor: float,
     - ``"einsum"`` (default): [T,E,C] one-hot contractions — all-MXU,
       no scatter/gather, but O(T·E·C·D) flops; at capacity ≈ T/E·f the
       dispatch pair costs O(T²·f·D), dwarfing the expert MLPs at long T
-      (measured 6:1 at T=16k, D=192 — BASELINE.md round 5).
+      (the ratio is not measured on the current chip).
     - ``"scatter"``: tokens scatter-add into the [E,C,D] expert buffer
       by (expert, queue-slot) index and gather back — O(T·D) data
       movement, no quadratic term; rides XLA's TPU scatter/gather.

@@ -12,14 +12,19 @@ applies the whole update in ONE pass over the bytes:
 - **Pallas TPU kernel** (:func:`_pallas_leaf`): the leaf is flattened,
   padded to the f32 tile (8x128), and a grid of VMEM blocks computes
   ``m' = mu*m + (g + wd*p); p' = p - lr*m'`` reading p/g/m once and
-  writing p'/m' once. Engaged when the backend is TPU and the update is
-  not under a GSPMD-sharded (zero1) layout — a ``pallas_call`` is an
-  opaque custom call the partitioner cannot split, so sharded updates
-  keep the XLA expression form below (which GSPMD partitions and fuses
-  into one loop over the local shard — the same single-pass property).
-- **XLA fallback** (:func:`_xla_leaf`): the identical f32 elementwise
+  writing p'/m' once. Engaged when the backend is TPU and every operand
+  of the update is replicated. A ``pallas_call`` is an opaque custom
+  call the partitioner cannot split and, on a mesh of more than one
+  device, will not even place ("Mosaic kernels cannot be automatically
+  partitioned"), so there the whole update runs under a ``shard_map``
+  with replicated specs: every device applies the full update to its
+  own replica, which is what the replicated layout means anyway.
+- **XLA expression** (:func:`_xla_leaf`): the identical f32 elementwise
   expression, in the identical order, as one fused XLA loop — selected
-  on every non-TPU platform so CPU tier-1 runs the exact same math.
+  off TPU, so CPU tier-1 runs the exact same math, and for sharded
+  (zero1 / fsdp / tensor-parallel) update operands, where GSPMD
+  partitions it into one loop over the local shard (the same
+  single-pass property).
 
 Equivalence (PARITY.md "Update-path equivalence", pinned by
 ``tests/test_zero1.py``): the XLA fallback is BIT-IDENTICAL to the
@@ -41,6 +46,10 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from dml_cnn_cifar10_tpu.ops import kernel_paths
+from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 #: f32 VMEM tile: (sublanes, lanes). Leaves pad to a whole number of
 #: tiles; the grid walks blocks of ``_BLOCK_ROWS`` sublane rows.
@@ -52,8 +61,7 @@ _BLOCK_ROWS = 512  # 512 x 128 x 4 B = 256 KiB per ref; 5 refs < 2 MiB VMEM
 def _use_pallas(optimizer_sharding: str) -> bool:
     """Platform selection: the Pallas lowering only on a real TPU and
     only for the replicated (non-GSPMD-sharded) update layout."""
-    return (jax.default_backend() == "tpu"
-            and optimizer_sharding != "zero1")
+    return platform_lib.on_tpu() and optimizer_sharding != "zero1"
 
 
 def _xla_leaf(p, g, m, lr, momentum: float, weight_decay: float):
@@ -152,8 +160,8 @@ def fused_sgd_update(params: Any, grads: Any, momentum_tree: Optional[Any],
                      lr, momentum: float, weight_decay: float,
                      optimizer_sharding: str = "none",
                      use_pallas: Optional[bool] = None,
-                     interpret: Optional[bool] = None
-                     ) -> Tuple[Any, Optional[Any]]:
+                     interpret: Optional[bool] = None,
+                     mesh=None) -> Tuple[Any, Optional[Any]]:
     """``(new_params, new_momentum_tree)`` — the whole SGD update in one
     pass per leaf. ``momentum_tree=None`` means plain SGD (no trace kept).
 
@@ -161,15 +169,23 @@ def fused_sgd_update(params: Any, grads: Any, momentum_tree: Optional[Any],
     ``interpret=None`` resolves to interpreter mode off-TPU (the
     kernel-parity tests force ``use_pallas=True`` on CPU and run the
     interpreter). Only f32 leaves enter the kernel; anything else takes
-    the identical-math XLA expression.
+    the identical-math XLA expression. ``mesh`` is the mesh of the
+    enclosing GSPMD program, if any: with more than one device the
+    kernel path runs under a replicated ``shard_map`` over it (module
+    docstring). Callers already inside a ``shard_map`` pass none.
     """
     if use_pallas is None:
         use_pallas = _use_pallas(optimizer_sharding)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not platform_lib.on_tpu()
     lr = jnp.asarray(lr, jnp.float32)
+    wrap = use_pallas and mesh is not None and mesh.size > 1
+    kernel_paths.note(
+        "update", "xla" if not use_pallas else
+        ("pallas" + ("-interpret" if interpret else "")
+         + (f"/shard_map[{mesh.size} replicas]" if wrap else "")))
 
-    def one(p, g, m):
+    def one(p, g, m, lr):
         if (use_pallas and p.dtype == jnp.float32
                 and g.dtype == jnp.float32
                 and (m is None or m.dtype == jnp.float32)):
@@ -177,12 +193,18 @@ def fused_sgd_update(params: Any, grads: Any, momentum_tree: Optional[Any],
                                 interpret)
         return _xla_leaf(p, g, m, lr, momentum, weight_decay)
 
-    if momentum_tree is None:
-        return jax.tree.map(lambda p, g: one(p, g, None)[0],
-                            params, grads), None
-    out = jax.tree.map(one, params, grads, momentum_tree)
-    # Structural transpose (treedef-driven, like optim.py's adafactor
-    # unzip): params-of-pairs → pair-of-params-trees.
-    new_params, new_mom = jax.tree_util.tree_transpose(
-        jax.tree.structure(params), jax.tree.structure((0, 0)), out)
-    return new_params, new_mom
+    def update(params, grads, momentum_tree, lr):
+        if momentum_tree is None:
+            return jax.tree.map(lambda p, g: one(p, g, None, lr)[0],
+                                params, grads), None
+        out = jax.tree.map(lambda p, g, m: one(p, g, m, lr),
+                           params, grads, momentum_tree)
+        # Structural transpose (treedef-driven, like optim.py's adafactor
+        # unzip): params-of-pairs → pair-of-params-trees.
+        return jax.tree_util.tree_transpose(
+            jax.tree.structure(params), jax.tree.structure((0, 0)), out)
+
+    if wrap:
+        update = jax.shard_map(update, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False)
+    return update(params, grads, momentum_tree, lr)

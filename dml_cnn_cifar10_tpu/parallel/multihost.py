@@ -77,21 +77,6 @@ def initialize_from_hosts(worker_hosts: List[str], task_index: int) -> None:
     ))
 
 
-def _is_initialized() -> bool:
-    """Version-tolerant "has jax.distributed already initialized?".
-
-    ``jax.distributed.is_initialized`` only exists in newer jax; older
-    releases (the pinned 0.4.x included) expose the same fact as the
-    internal global state's live client. Neither probe touches the XLA
-    backend."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    from jax._src import distributed as _dist
-    state = getattr(_dist, "global_state", None)
-    return state is not None and getattr(state, "client", None) is not None
-
-
 def initialize(cfg: ParallelConfig) -> None:
     """Idempotent ``jax.distributed.initialize`` from config, with
     bounded retry + backoff around a slow-to-start coordinator."""
@@ -99,7 +84,7 @@ def initialize(cfg: ParallelConfig) -> None:
         return
     # NB: must not touch jax.process_count() here — it initializes the XLA
     # backend, after which jax.distributed.initialize refuses to run.
-    if _is_initialized():
+    if jax.distributed.is_initialized():
         return
     attempt = 0
     while True:

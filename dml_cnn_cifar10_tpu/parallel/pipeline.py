@@ -77,8 +77,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dml_cnn_cifar10_tpu.parallel.compat import shard_map
-
 SCHEDULES = ("1f1b", "1f1b_ring", "gpipe")
 
 
@@ -185,7 +183,7 @@ def _gpipe(x, stacked_params, block_fn, mesh, nstages, m):
         return lax.psum(out, "pipe")
 
     spec_x, spec_p = _specs(mesh, x, stacked_params)
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(spec_x, spec_p),
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(spec_x, spec_p),
                        out_specs=spec_x, check_vma=False)
     return fn(x, stacked_params)
 
@@ -471,9 +469,9 @@ def _one_f_one_b(x, stacked_params, block_fn, mesh, nstages, m,
         else _1f1b_backward_local,
         stage=stage, nstages=nstages, m=m)
 
-    fwd_sm = shard_map(fwd_local, mesh=mesh, in_specs=(spec_x, spec_p),
+    fwd_sm = jax.shard_map(fwd_local, mesh=mesh, in_specs=(spec_x, spec_p),
                            out_specs=spec_x, check_vma=False)
-    bwd_sm = shard_map(bwd_local, mesh=mesh,
+    bwd_sm = jax.shard_map(bwd_local, mesh=mesh,
                            in_specs=(spec_x, spec_p, spec_x),
                            out_specs=(spec_x, spec_p), check_vma=False)
 

@@ -51,8 +51,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dml_cnn_cifar10_tpu.parallel import compat
-from dml_cnn_cifar10_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -190,7 +188,7 @@ def _window_switch(src, my, causal, diag, left, right, skip):
 
 def _ring_fwd_scan(q, k, v, seg, my, axis_name, scale, use_pallas, causal,
                    window=None):
-    nsteps = compat.axis_size(axis_name)
+    nsteps = lax.axis_size(axis_name)
     b, sq, h, d = q.shape
     stats = _block_stats_pallas if use_pallas else _block_stats
     perm = _ring_perm(nsteps)
@@ -278,7 +276,7 @@ def _ring_core_bwd(axis_name, scale, use_pallas, causal, window, res, do):
     from dml_cnn_cifar10_tpu.ops import flash_attention as fa
 
     q, k, v, seg, my, out, lse = res
-    nsteps = compat.axis_size(axis_name)
+    nsteps = lax.axis_size(axis_name)
     delta = fa.attention_delta(out, do)               # [B,Sq,H] f32
     perm = _ring_perm(nsteps)
 
@@ -424,7 +422,7 @@ def sp_shard_map(local_fn, mesh: Mesh, axis_name: str, seq_len: int,
     if with_segments:
         in_specs += (P("data", axis_name),)
     in_specs += tuple(extra_in_specs)
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=in_specs,

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-from dml_cnn_cifar10_tpu.parallel import compat
 from dml_cnn_cifar10_tpu.ops import attention as attn
 from dml_cnn_cifar10_tpu.parallel.ring_attention import (
     sequence_sharding, sp_partition_spec, sp_shard_map)
@@ -59,7 +58,7 @@ def ulysses_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
     flag passes straight through. Differentiable end to end (all_to_all
     has a transpose rule; the flash path brings its custom_vjp).
     """
-    n = compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return attn.dispatch_attention(q, k, v, use_pallas=use_pallas,
                                        scale=scale, causal=causal,

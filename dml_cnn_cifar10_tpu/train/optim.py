@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from dml_cnn_cifar10_tpu.config import OptimConfig
+from dml_cnn_cifar10_tpu.ops import kernel_paths
 
 OptState = Dict[str, Any]
 
@@ -164,7 +165,7 @@ def _clipped(grads: Any, cfg: OptimConfig) -> Any:
 
 def sgd_update(
     grads: Any, state: OptState, params: Any, cfg: OptimConfig,
-    pallas_ok: Optional[bool] = None
+    pallas_ok: Optional[bool] = None, mesh=None
 ) -> Tuple[Any, OptState]:
     """One optimizer step; returns (new_params, new_state).
 
@@ -178,10 +179,14 @@ def sgd_update(
     math via the XLA expression): the step builders pass it when the
     update's operands are GSPMD-sharded (tp/fsdp/pipe state) — an
     opaque ``pallas_call`` there would force the partitioner to
-    materialize full replicas. ``None`` resolves by platform.
+    materialize full replicas. ``None`` resolves by platform. ``mesh``
+    is the enclosing GSPMD program's mesh (the GSPMD step builders pass
+    theirs; callers inside a ``shard_map`` pass none): on more than one
+    device the kernel runs under a replicated ``shard_map`` over it
+    (``ops/optimizer.py``).
     """
     new_params, new_state = _base_update(grads, state, params, cfg,
-                                         pallas_ok=pallas_ok)
+                                         pallas_ok=pallas_ok, mesh=mesh)
     if cfg.ema_decay:
         d = ema_decay_at(cfg, new_state["step"])
         new_state["ema"] = jax.tree.map(
@@ -192,9 +197,11 @@ def sgd_update(
 
 def _base_update(
     grads: Any, state: OptState, params: Any, cfg: OptimConfig,
-    pallas_ok: Optional[bool] = None
+    pallas_ok: Optional[bool] = None, mesh=None
 ) -> Tuple[Any, OptState]:
     step = state["step"]
+    if cfg.optimizer != "sgd" or not getattr(cfg, "fused_optimizer", True):
+        kernel_paths.note("update", f"xla ({cfg.optimizer} tree_map)")
     lr = learning_rate(cfg, step)
     grads = _clipped(grads, cfg)
 
@@ -307,15 +314,15 @@ def _base_update(
         # + apply in ONE pass over the param bytes — a Pallas TPU kernel,
         # or the identical (bit-equal, PARITY.md) f32 expression as one
         # fused XLA loop on other platforms / under GSPMD-sharded
-        # (zero1) layouts. --fused_optimizer false keeps the historical
-        # tree_map chain below.
+        # (zero1, fsdp, tensor-parallel) layouts. --fused_optimizer
+        # false keeps the historical tree_map chain below.
         from dml_cnn_cifar10_tpu.ops import optimizer as fused_lib
 
         new_params, mom = fused_lib.fused_sgd_update(
             params, grads, state.get("momentum") if cfg.momentum else None,
             lr, cfg.momentum, cfg.weight_decay,
             optimizer_sharding=getattr(cfg, "optimizer_sharding", "none"),
-            use_pallas=False if pallas_ok is False else None)
+            use_pallas=False if pallas_ok is False else None, mesh=mesh)
         if mom is not None:
             new_state["momentum"] = mom
         return new_params, new_state

@@ -185,8 +185,7 @@ class FlightRecorder:
                "platform": platform.platform(),
                "pid": os.getpid(),
                "env": {k: os.environ[k] for k in
-                       ("JAX_PLATFORMS", "XLA_FLAGS",
-                        "DML_FLEET_WORKER_PLATFORM")
+                       ("JAX_PLATFORMS", "XLA_FLAGS")
                        if k in os.environ}}
         try:
             import jax

@@ -70,20 +70,22 @@ def compiled_flops(jitted_fn, abstract_args) -> Optional[float]:
     (hundreds of ms to seconds for a real train step) even when the call
     path already compiled, so the driver runs this on a background
     thread, never inline in the step loop. None when the backend doesn't
-    report flops."""
-    cached = getattr(jitted_fn, "cached_flops", None)
-    if cached is not None:
-        try:
-            flops = cached(abstract_args)
-            if flops and flops > 0:
-                return float(flops)
-        except Exception:
-            pass
+    report flops. On a TPU a failure to lower, compile or analyse is a
+    real error and is raised; off it (the CPU backend's analysis has
+    another shape and the figure is never published) it is None."""
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
     try:
-        cost = jitted_fn.lower(*abstract_args).compile().cost_analysis()
-        flops = cost.get("flops", 0.0)
+        cached = getattr(jitted_fn, "cached_flops", None)
+        flops = cached(abstract_args) if cached is not None else None
+        if not (flops and flops > 0):
+            cost = jitted_fn.lower(
+                *abstract_args).compile().cost_analysis()
+            flops = cost.get("flops", 0.0)
         return float(flops) if flops and flops > 0 else None
     except Exception:
+        if platform_lib.on_tpu():
+            raise
         return None
 
 

@@ -1,15 +1,13 @@
 """Perf-regression gate (tools/bench_gate.py): the synthetic decision
-table, the real BENCH_r* trajectory acceptance (r05 must pass against
-r01-r05), and the regressions the gate exists to flag (10% throughput,
-3x compile_s, tail blowup)."""
+table, a recorded trajectory on disk (its last round must pass against
+all of them), and the regressions the gate exists to flag (10%
+throughput, 3x compile_s, tail blowup)."""
 
 import copy
 import json
-import os
 
 from tools import bench_gate
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _checks_by(checks, name):
@@ -57,19 +55,28 @@ def test_gate_flags_throughput_and_compile_regressions():
 
 
 # ---------------------------------------------------------------------------
-# the real trajectory: r05 vs r01-r05 (ISSUE-8 acceptance)
+# a recorded trajectory on disk (files, wrappers, the CLI entry point)
 # ---------------------------------------------------------------------------
 
-def test_r05_passes_the_recorded_trajectory(capsys):
-    rc = bench_gate.main([os.path.join(REPO, "BENCH_r05.json")])
+def _write_trajectory(tmp_path):
+    """Five rounds as the driver wraps them, built with ``_synth``."""
+    for n, ips in enumerate((990.0, 1010.0, 1000.0, 985.0, 1005.0), 1):
+        (tmp_path / f"round_{n:02d}.json").write_text(json.dumps(
+            {"n": n, "rc": 0, "parsed": bench_gate._synth(ips)}))
+    return str(tmp_path / "round_*.json"), str(tmp_path / "round_05.json")
+
+
+def test_last_round_passes_the_recorded_trajectory(tmp_path, capsys):
+    baselines, last = _write_trajectory(tmp_path)
+    rc = bench_gate.main([last, "--baselines", baselines])
     out = capsys.readouterr().out
     assert rc == 0, out
     assert "PASS" in out and "REGRESSION" not in out
 
 
-def test_synthetic_10pct_regression_of_r05_fails(tmp_path, capsys):
-    report = bench_gate.load_report(os.path.join(REPO,
-                                                 "BENCH_r05.json"))
+def test_synthetic_10pct_regression_of_last_round_fails(tmp_path, capsys):
+    baselines, last = _write_trajectory(tmp_path)
+    report = bench_gate.load_report(last)
     slow = copy.deepcopy(report)
     slow["value"] *= 0.9
     for row in bench_gate.ROW_KEYS:
@@ -79,7 +86,8 @@ def test_synthetic_10pct_regression_of_r05_fails(tmp_path, capsys):
                 slow[row]["mfu"] *= 0.9
     cand = tmp_path / "cand.json"
     cand.write_text(json.dumps(slow))
-    rc = bench_gate.main([str(cand), "--format", "json"])
+    rc = bench_gate.main([str(cand), "--baselines", baselines,
+                          "--format", "json"])
     doc = json.loads(capsys.readouterr().out)
     assert rc == 1 and doc["pass"] is False
     bad = [c for c in doc["checks"] if not c["ok"]]
@@ -87,9 +95,10 @@ def test_synthetic_10pct_regression_of_r05_fails(tmp_path, capsys):
 
 
 def test_load_report_shapes(tmp_path):
-    # BENCH_r wrapper and raw bench stdout both load to the same doc.
-    wrapped = bench_gate.load_report(os.path.join(REPO,
-                                                  "BENCH_r05.json"))
+    # Driver wrapper and raw bench stdout both load to the same doc.
+    _, last = _write_trajectory(tmp_path)
+    wrapped = bench_gate.load_report(last)
+    assert wrapped == bench_gate._synth(1005.0)
     raw = tmp_path / "raw.json"
     raw.write_text(json.dumps(wrapped))
     assert bench_gate.load_report(str(raw)) == wrapped

@@ -459,6 +459,69 @@ def test_cli_fleet_flags_plumb_into_config():
              "--fleet_max_replicas", "2"])[0])
 
 
+def test_controller_router_and_http_loadgen_never_start_a_backend(tmp_path):
+    """A chip belongs to one process at a time, so the fleet's parent
+    (controller + router threads) and the HTTP load generator must stay
+    off JAX's backends: the workers are the only processes that may open
+    the chip. Proven by running them under a platform that does not
+    exist — any backend initialization would raise."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = f"""
+import os, sys
+from dml_cnn_cifar10_tpu.config import TrainConfig
+from dml_cnn_cifar10_tpu.fleet.controller import FleetController
+from tools import loadgen
+cfg = TrainConfig(log_dir=r"{tmp_path}/logs")
+cfg.fleet.dir = r"{tmp_path}/fleet"
+cfg.fleet.min_replicas = 1
+cfg.fleet.port = 0
+c = FleetController(cfg)
+port = c.start()
+try:
+    c.tick()
+    loadgen.main(["--target", f"http://127.0.0.1:{{port}}",
+                  "--duration_s", "0.3", "--concurrency", "2",
+                  "--report", r"{tmp_path}/report.json"])
+finally:
+    c.shutdown()
+import jax
+try:
+    jax.devices()
+except RuntimeError:
+    print("NO_BACKEND_WAS_EVER_STARTED")
+"""
+    env = dict(os.environ, JAX_PLATFORMS="no_such_platform",
+               PYTHONPATH=repo)
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "NO_BACKEND_WAS_EVER_STARTED" in out.stdout
+
+
+def test_cli_refuses_several_replicas_on_one_accelerator_host(monkeypatch):
+    """One process for each chip: every fleet worker is a process that
+    opens the accelerator, so on a TPU host a second replica could only
+    die or hang at backend start-up. The CLI says so at flag parse
+    (the CPU simulation above keeps its many workers)."""
+    from dml_cnn_cifar10_tpu.cli.main import (build_parser,
+                                              config_from_args)
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
+    monkeypatch.setattr(platform_lib, "accelerator_expected", lambda: True)
+
+    def parse(*extra):
+        return config_from_args(build_parser().parse_known_args(
+            ["--mode", "fleet", *extra])[0])
+
+    with pytest.raises(SystemExit, match=r"one\s+process at a time"):
+        parse("--fleet_max_replicas", "2")
+    assert parse("--fleet_min_replicas", "1", "--fleet_max_replicas",
+                 "1").fleet.max_replicas == 1
+
+
 # ---------------------------------------------------------------------------
 # acceptance smoke: 2 workers + router; worker kill, then hot-swap —
 # zero failed client requests throughout, outputs pinned to --mode serve

@@ -141,7 +141,7 @@ def test_initialize_retries_slow_coordinator(monkeypatch):
         if calls["n"] < 3:
             raise RuntimeError("connection refused")
 
-    monkeypatch.setattr(multihost, "_is_initialized", lambda: False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
     monkeypatch.setattr(jax.distributed, "initialize", flaky_init)
     monkeypatch.setattr(multihost.time, "sleep", sleeps.append)
     multihost.initialize(cfg)

@@ -189,3 +189,22 @@ def test_stale_abi_fails_loudly(tmp_path, monkeypatch):
     monkeypatch.setattr(native, "_needs_build", lambda: False)
     with pytest.raises(RuntimeError, match="ABI v1 != expected"):
         native.load_library()
+
+
+def test_failed_build_is_reported_not_swallowed(data_cfg, tmp_path,
+                                                monkeypatch):
+    """With ``use_native_loader`` on, a library that cannot be built must
+    stop the pipeline with the build's own error — not hand back the
+    NumPy iterator as if nothing happened."""
+    import dataclasses
+
+    (tmp_path / "Makefile").write_text(
+        "all:\n\t@echo 'recordio.cc: no such toolchain' >&2; exit 1\n")
+    monkeypatch.setattr(native, "_RUNTIME_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_LIB_PATH",
+                        str(tmp_path / "librecordio.so"))
+    monkeypatch.setattr(native, "_lib", None)
+    cfg = dataclasses.replace(data_cfg, use_native_loader=True)
+    with pytest.raises(RuntimeError, match="no such toolchain") as e:
+        pipe.input_pipeline(cfg, 16, train=True)
+    assert "--use_native_loader false" in str(e.value)

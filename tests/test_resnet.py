@@ -185,8 +185,7 @@ def test_s2d_stem_folded_kernel_equivalence():
     as the 7x7/2 stem when the 7x7 kernel is folded into the 4x4x(4C)
     parameterization (zero-pad to 8x8; ws[m,n,(a,b,c)] = w8[2m+a,2n+b,c]
     with the XLA SAME pad lo=2 mapping to folded pad (1,2)) — the MLPerf
-    transform is a re-parameterization, not a different model
-    (BASELINE.md round-4)."""
+    transform is a re-parameterization, not a different model."""
     from dml_cnn_cifar10_tpu.models import resnet
 
     cfg7 = ModelConfig(name="resnet50", logit_relu=False)

@@ -1,0 +1,147 @@
+"""The training steps lower for the TPU on one device and on four.
+
+A compiled ``pallas_call`` cannot sit bare in a program GSPMD partitions
+over more than one device: jax refuses to lower it ("Mosaic kernels
+cannot be automatically partitioned"). The CPU mesh never sees that —
+off TPU the fused update is an XLA expression and flash attention runs
+in the interpreter — so this cross-lowers the chip branch instead:
+``utils.platform.on_tpu`` forced true, then
+``.trace(...).lower(lowering_platforms=("tpu",))`` on the virtual mesh.
+Lowering is all a CPU can check; whether Mosaic compiles the kernels is
+``chip_smoke.py``'s job.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dml_cnn_cifar10_tpu.config import (DataConfig, ModelConfig, OptimConfig,
+                                        ParallelConfig)
+from dml_cnn_cifar10_tpu.models.registry import get_model
+from dml_cnn_cifar10_tpu.parallel import mesh as mesh_lib
+from dml_cnn_cifar10_tpu.parallel import step as step_lib
+from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+from dml_cnn_cifar10_tpu.utils.profiling import abstractify
+
+BATCH = 8
+
+
+@pytest.fixture
+def chip_branch(monkeypatch):
+    """Every kernel chooser reads the one switch; flip it."""
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: True)
+
+
+def _mesh(n):
+    return mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:n])
+
+
+def _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh, **layout):
+    sh = step_lib.train_state_shardings(mesh, model_def, model_cfg,
+                                        data_cfg, optim_cfg, **layout)
+    state = jax.eval_shape(
+        lambda k: step_lib.init_train_state(k, model_def, model_cfg,
+                                            data_cfg, optim_cfg),
+        jax.random.key(0))
+    return sh, jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        state, sh)
+
+
+def _tpu_text(jitted, *abs_args):
+    return jitted.trace(*abs_args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def _batch_abs(data_cfg, lead=()):
+    return (jax.ShapeDtypeStruct(
+        (*lead, BATCH, data_cfg.crop_height, data_cfg.crop_width,
+         data_cfg.num_channels), jnp.float32),
+        jax.ShapeDtypeStruct((*lead, BATCH), jnp.int32))
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_cnn_step_keeps_the_fused_update_kernel(chip_branch, ndev, capsys):
+    model_def = get_model("cnn")
+    model_cfg, data_cfg = ModelConfig(), DataConfig()
+    optim_cfg = OptimConfig(momentum=0.9)
+    mesh = _mesh(ndev)
+    sh, state = _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh)
+    step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
+                                    state_sharding=sh)
+    text = _tpu_text(step, state, *_batch_abs(data_cfg))
+    # One kernel per parameter leaf, on one device and on four.
+    assert text.count("tpu_custom_call") == len(jax.tree.leaves(
+        state.params))
+    said = capsys.readouterr().out
+    want = "update=pallas" + (f"/shard_map[{ndev} replicas]"
+                              if ndev > 1 else "")
+    assert f"[step] train_step on {ndev} device(s): {want} " in said
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_resident_chunk_keeps_the_fused_update_kernel(chip_branch, ndev):
+    model_def = get_model("cnn")
+    model_cfg, data_cfg = ModelConfig(), DataConfig()
+    optim_cfg = OptimConfig()
+    mesh = _mesh(ndev)
+    sh, state = _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh)
+    repl = mesh_lib.replicated(mesh)
+    ds_images = jax.device_put(
+        jnp.zeros((64, data_cfg.image_height, data_cfg.image_width,
+                   data_cfg.num_channels), jnp.uint8), repl)
+    ds_labels = jax.device_put(jnp.zeros((64,), jnp.int32), repl)
+    chunk = step_lib.make_train_chunk_resident(
+        model_def, model_cfg, optim_cfg, mesh, ds_images, ds_labels,
+        state_sharding=sh, data_cfg=data_cfg,
+        index_stream=(0, BATCH, 2))
+    # The builder returns the jitted chunk with the dataset bound.
+    text = _tpu_text(chunk.func, *abstractify((ds_images, ds_labels)),
+                     state)
+    assert text.count("tpu_custom_call") == len(jax.tree.leaves(
+        state.params))
+
+
+def test_sharded_update_operands_keep_the_xla_expression(chip_branch,
+                                                         capsys):
+    """zero1: the moments are data-sharded, the rule keeps the XLA
+    expression (no kernel to place), and the builder says so."""
+    model_def = get_model("cnn")
+    model_cfg, data_cfg = ModelConfig(), DataConfig()
+    optim_cfg = OptimConfig(momentum=0.9, optimizer_sharding="zero1")
+    mesh = _mesh(4)
+    sh, state = _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh,
+                           zero1=True)
+    step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
+                                    state_sharding=sh)
+    text = _tpu_text(step, state, *_batch_abs(data_cfg))
+    assert "tpu_custom_call" not in text
+    assert "update=xla " in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+@pytest.mark.parametrize("attn", [{}, {"attn_causal": True,
+                                       "attn_window": 128}])
+def test_vit_step_keeps_flash_and_update_kernels(chip_branch, ndev, attn,
+                                                 capsys):
+    """64-pixel crops at patch 4 are 256 patch tokens (+cls): past the
+    128-token threshold, so attention is the flash kernels, forward and
+    both backward passes."""
+    model_def = get_model("vit_tiny")
+    model_cfg = ModelConfig(name="vit_tiny", vit_depth=2, **attn)
+    data_cfg = DataConfig(image_height=64, image_width=64, crop_height=64,
+                          crop_width=64)
+    optim_cfg = OptimConfig(momentum=0.9)
+    mesh = _mesh(ndev)
+    sh, state = _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh)
+    step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
+                                    state_sharding=sh)
+    text = _tpu_text(step, state, *_batch_abs(data_cfg))
+    # The depth-scanned block holds the flash forward (lse-emitting) and
+    # the dQ and dK/dV kernels once each; the update is one per leaf.
+    assert text.count("tpu_custom_call") == 3 + len(jax.tree.leaves(
+        state.params))
+    said = capsys.readouterr().out
+    assert "attention=flash (257 tokens)" in said
+    if ndev > 1:
+        assert "shard_map[batch/data, heads/model]" in said

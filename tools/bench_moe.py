@@ -61,13 +61,13 @@ def bench_train(experts: int, steps: int, batch: int, capacity: float,
     state = step_lib.init_train_state(jax.random.key(0), model_def,
                                       model_cfg, data_cfg, optim_cfg, mesh,
                                       state_sharding=sh)
-    # Compile cache under bench.py's dir convention: the FLOPs probe
-    # below is served from the cached entry instead of a second AOT
-    # compile on re-runs.
-    from bench import _bench_cache_dir
+    # Keyed compile store under bench.py's dir convention: the FLOPs
+    # probe below is served from the cached entry instead of a second
+    # AOT compile on re-runs.
+    from bench import _bench_cache_dir, device_peaks
     from dml_cnn_cifar10_tpu.compilecache import CompileCache
-    cache = (CompileCache(_bench_cache_dir())
-             if _bench_cache_dir() else None)
+    cache = CompileCache(_bench_cache_dir())
+    peak = device_peaks(jax.devices()[0].device_kind)["tflops"]
     train = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
                                      state_sharding=sh,
                                      compile_cache=cache)
@@ -100,7 +100,8 @@ def bench_train(experts: int, steps: int, batch: int, capacity: float,
         "capacity_factor": capacity,
         "images_per_sec": round(img_s, 1),
         "tflops_per_sec": round(tf, 2) if tf else None,
-        "mfu_vs_197": round(tf / 197.0, 4) if tf else None,
+        "peak_tflops": peak,
+        "mfu": round(tf / peak, 4) if tf else None,
     }
 
 
@@ -141,10 +142,8 @@ def drop_table(experts_list, capacities, tokens=8192, dim=192):
 
 
 def main():
-    # Before any jax backend use (see compilecache.arm_native_cache).
-    from bench import _bench_cache_dir
-    from dml_cnn_cifar10_tpu.compilecache import arm_native_cache
-    arm_native_cache(_bench_cache_dir() or None)
+    from bench import _bench_cache_dir, device_stamp
+    _bench_cache_dir()  # arms jax's cache before anything compiles
     ap = argparse.ArgumentParser()
     ap.add_argument("--experts", type=int, nargs="+", default=[2, 4])
     ap.add_argument("--steps", type=int, default=300)
@@ -154,13 +153,14 @@ def main():
     ap.add_argument("--dispatch", type=str, nargs="+",
                     default=["einsum", "scatter"])
     args = ap.parse_args()
+    stamp = device_stamp()  # fails off TPU / on an unknown device_kind
 
     if not args.skip_train:
         for e in args.experts:
             for disp in args.dispatch:
                 row = bench_train(e, args.steps, args.batch, args.capacity,
                                   dispatch=disp)
-                print("train:", row, flush=True)
+                print("train:", {**row, **stamp}, flush=True)
 
     print("\ndrop-rate vs capacity factor (fresh router, unit-normal "
           "tokens):")

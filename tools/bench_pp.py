@@ -2,17 +2,12 @@
 """Pipeline-parallel vs data-parallel benchmark (round-1 #7; round-3 1F1B).
 
 Times the full ViT training step at a fixed global batch over several
-mesh layouts on the 8-virtual-device CPU mesh (the only multi-device
-substrate on this box — one real TPU chip cannot host a pipe axis), and
-reads the compiled step's TEMP-ALLOCATION bytes from XLA's memory
-analysis — the live-activation footprint the 1F1B schedule exists to cap.
-
-CPU timings are a schedule-overhead proxy, not TPU absolute numbers:
-they expose the bubble compute (skipped by 1F1B, burned by GPipe) and
-the ppermute/psum traffic, which is what the layout decision rides on.
-The memory column is geometry, not timing, so it transfers to TPU
-directly: GPipe-autodiff's saved scan carries grow O(M); 1F1B's ring
-buffer is O(P), flat in M.
+mesh layouts of one multi-chip TPU host (a device count divisible by 4;
+fails off TPU — a time comes only from a chip run), and reads the
+compiled step's TEMP-ALLOCATION bytes from XLA's memory analysis — the
+live-activation footprint the 1F1B schedule exists to cap:
+GPipe-autodiff's saved scan carries grow O(M); 1F1B's ring buffer is
+O(P), flat in M.
 
 Usage: python tools/bench_pp.py [--steps 8] [--batch 32] [--depth 8]
 Prints one markdown table.
@@ -21,11 +16,12 @@ Prints one markdown table.
 from __future__ import annotations
 
 import argparse
+import os
+import sys
 import time
 
-from dml_cnn_cifar10_tpu.utils.platform import force_cpu
-
-force_cpu(virtual_devices=8)
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -86,25 +82,33 @@ def main():
                         "recompute FLOPs O(dim^2))")
     args = p.parse_args()
 
+    from bench import device_stamp
+    stamp = device_stamp()  # fails off TPU / on an unknown device_kind
+    n = stamp["device_count"]
+    if n % 4:
+        raise SystemExit(f"bench_pp needs a device count divisible by 4 "
+                         f"for its pp=4 layouts, got {n}")
+
     base = dict(name="vit_tiny", pool="mean", logit_relu=False,
                 vit_depth=args.depth, vit_dim=args.dim, vit_heads=2,
                 patch_size=4,
                 use_pallas_attention=False)
-    dp2pp4 = ParallelConfig(data_axis=2, pipe_axis=4)
+    pp4 = ParallelConfig(data_axis=n // 4, pipe_axis=4)
+    d4 = f"dp={n // 4} x pp=4"
     layouts = [
-        ("dp=8", ParallelConfig(data_axis=8), ModelConfig(**base)),
-        ("dp=4 x pp=2 1f1b (M=P)",
-         ParallelConfig(data_axis=4, pipe_axis=2), ModelConfig(**base)),
-        ("dp=2 x pp=4 gpipe (M=P)", dp2pp4,
+        (f"dp={n}", ParallelConfig(data_axis=n), ModelConfig(**base)),
+        (f"dp={n // 2} x pp=2 1f1b (M=P)",
+         ParallelConfig(data_axis=n // 2, pipe_axis=2), ModelConfig(**base)),
+        (f"{d4} gpipe (M=P)", pp4,
          ModelConfig(**base, pipe_schedule="gpipe")),
-        ("dp=2 x pp=4 1f1b-rec (M=P)", dp2pp4, ModelConfig(**base)),
-        ("dp=2 x pp=4 1f1b-ring (M=P)", dp2pp4,
+        (f"{d4} 1f1b-rec (M=P)", pp4, ModelConfig(**base)),
+        (f"{d4} 1f1b-ring (M=P)", pp4,
          ModelConfig(**base, pipe_schedule="1f1b_ring")),
-        ("dp=2 x pp=4 gpipe (M=4P)", dp2pp4,
+        (f"{d4} gpipe (M=4P)", pp4,
          ModelConfig(**base, pipe_schedule="gpipe", pipe_microbatches=16)),
-        ("dp=2 x pp=4 1f1b-rec (M=4P)", dp2pp4,
+        (f"{d4} 1f1b-rec (M=4P)", pp4,
          ModelConfig(**base, pipe_microbatches=16)),
-        ("dp=2 x pp=4 1f1b-ring (M=4P)", dp2pp4,
+        (f"{d4} 1f1b-ring (M=4P)", pp4,
          ModelConfig(**base, pipe_schedule="1f1b_ring",
                      pipe_microbatches=16)),
     ]
@@ -112,8 +116,8 @@ def main():
             for n, pc, mc in layouts]
     ref = rows[0][1]
     print(f"\nViT depth={args.depth} dim={args.dim} global batch={args.batch}, "
-          f"{args.steps} timed steps, 8 virtual CPU devices\n")
-    print("| layout | step ms | images/sec | temp MiB | vs dp=8 | "
+          f"{args.steps} timed steps, {stamp}\n")
+    print(f"| layout | step ms | images/sec | temp MiB | vs dp={n} | "
           "final loss |")
     print("|---|---|---|---|---|---|")
     for name, ms, ips, temp, loss in rows:

@@ -8,7 +8,7 @@ that moves fewer bytes. This benchmark measures that lever:
 ``--resnet_norm nf`` (scaled weight standardization + SkipInit,
 models/resnet.py) against the BN baseline on identical geometry.
 
-Method matches the ladder rows (BASELINE.md): synthetic ImageNet-shaped
+Method: synthetic ImageNet-shaped
 uint8 records resident in HBM, in-scan device decode, K-step chunk,
 bf16 compute, 3 timed repetitions with min/median/max.
 
@@ -53,14 +53,12 @@ def measure(norm: str, batch: int, k: int, chunks: int, reps: int,
     optim_cfg = OptimConfig(learning_rate=0.1)
     model_def = get_model(name)
 
-    # Persistent compile cache, shared with bench.py's dir convention:
-    # re-runs skip recompiles where the platform allows and the FLOPs
-    # probe below reads the entry's cost analysis instead of paying a
-    # second AOT compile.
+    # Keyed compile store, shared with bench.py's dir convention: the
+    # FLOPs probe below reads the entry's cost analysis instead of
+    # paying a second AOT compile.
     from bench import _bench_cache_dir
     from dml_cnn_cifar10_tpu.compilecache import CompileCache
-    cache = (CompileCache(_bench_cache_dir())
-             if _bench_cache_dir() else None)
+    cache = CompileCache(_bench_cache_dir())
 
     sh = step_lib.train_state_shardings(mesh, model_def, model_cfg,
                                         data_cfg, optim_cfg)
@@ -114,22 +112,17 @@ def measure(norm: str, batch: int, k: int, chunks: int, reps: int,
     if flops:
         tflops = flops * (med / batch) / 1e12
         row["tflops_per_sec"] = round(tflops, 2)
-        # Peak from the chip the bench actually ran on (bench.py's
-        # device-kind lookup, BENCH_PEAK_TFLOPS overridable) — not a
-        # hardcoded v5e constant.
-        from bench import _peak_tflops
-        peak = _peak_tflops(jax.devices()[0].device_kind)
-        if peak:
-            row["peak_tflops"] = peak
-            row["mfu"] = round(tflops / peak, 4)
+        # Peak from the chip the bench actually ran on (bench.PEAKS).
+        from bench import device_peaks
+        peak = device_peaks(jax.devices()[0].device_kind)["tflops"]
+        row["peak_tflops"] = peak
+        row["mfu"] = round(tflops / peak, 4)
     return row
 
 
 def main():
-    # Before any jax backend use (see compilecache.arm_native_cache).
-    from bench import _bench_cache_dir
-    from dml_cnn_cifar10_tpu.compilecache import arm_native_cache
-    arm_native_cache(_bench_cache_dir() or None)
+    from bench import _bench_cache_dir, device_stamp
+    _bench_cache_dir()  # arms jax's cache before anything compiles
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--k", type=int, default=20)
@@ -137,9 +130,10 @@ def main():
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--norms", type=str, nargs="+", default=["bn", "nf"])
     args = ap.parse_args()
+    stamp = device_stamp()  # fails off TPU / on an unknown device_kind
     for norm in args.norms:
         row = measure(norm, args.batch, args.k, args.chunks, args.reps)
-        print(json.dumps(row), flush=True)
+        print(json.dumps({**row, **stamp}), flush=True)
 
 
 if __name__ == "__main__":

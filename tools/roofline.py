@@ -11,10 +11,12 @@ for: whether the remaining conv+BN fusions sit against the bandwidth
 roof rather than the MXU roof.
 
 Usage:
-    python tools/roofline.py /path/to/*.xplane.pb [--peak-tflops 197]
-        [--peak-gbps 819] [--top 25]
+    python tools/roofline.py /path/to/*.xplane.pb
+        --device-kind "TPU v5 lite" [--top 25]
 
-v5e defaults: 197 bf16 TFLOP/s, 819 GB/s HBM.
+The peaks come from the one table, ``bench.PEAKS``, keyed by the
+``device_kind`` of the chip that took the trace; an unknown kind is an
+error.
 """
 
 from __future__ import annotations
@@ -22,7 +24,11 @@ from __future__ import annotations
 import argparse
 import glob
 import json
+import os
 import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def load_ops(pb_path):
@@ -63,22 +69,27 @@ def load_ops(pb_path):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("xplane", help="xplane.pb path (or glob)")
-    ap.add_argument("--peak-tflops", type=float, default=197.0)
-    ap.add_argument("--peak-gbps", type=float, default=819.0)
+    ap.add_argument("--device-kind", required=True,
+                    help="jax.devices()[0].device_kind of the chip that "
+                         "took the trace (a key of bench.PEAKS)")
     ap.add_argument("--top", type=int, default=25)
     args = ap.parse_args()
+    from bench import device_peaks
+    peaks = device_peaks(args.device_kind)
+    peak_tflops, peak_gbps = peaks["tflops"], peaks["hbm_gbps"]
 
     paths = sorted(glob.glob(args.xplane))
     if not paths:
         sys.exit(f"no xplane matches {args.xplane}")
     ops = load_ops(paths[0])
     total_ps = sum(o["time_ps"] for o in ops)
-    ridge = args.peak_tflops * 1e12 / (args.peak_gbps * 1e9)  # flops/byte
+    ridge = peak_tflops * 1e12 / (peak_gbps * 1e9)  # flops/byte
 
     ops.sort(key=lambda o: -o["time_ps"])
     print(f"total device op time: {total_ps / 1e9:.2f} ms; ridge "
           f"intensity {ridge:.0f} flops/byte "
-          f"({args.peak_tflops:.0f} TF/s / {args.peak_gbps:.0f} GB/s)\n")
+          f"({peak_tflops:.0f} TF/s / {peak_gbps:.0f} GB/s, "
+          f"{args.device_kind})\n")
     print("| % time | op | TF/s | GB/s | flops/byte | bound | % of roof |")
     print("|---|---|---|---|---|---|---|")
     for o in ops[:args.top]:
@@ -90,9 +101,9 @@ def main():
         inten = o["flops"] / o["hbm_bytes"] if o["hbm_bytes"] else float(
             "inf")
         if inten >= ridge:
-            bound, roof = "compute", tf / args.peak_tflops
+            bound, roof = "compute", tf / peak_tflops
         else:
-            bound, roof = "bandwidth", gb / args.peak_gbps
+            bound, roof = "bandwidth", gb / peak_gbps
         name = o["name"][:48]
         print(f"| {o['time_ps'] / total_ps * 100:5.1f} | {name} | "
               f"{tf:6.1f} | {gb:6.0f} | {inten:8.1f} | {bound} | "
