@@ -77,29 +77,31 @@ def _positions_to_rows(seed: int, j0: jax.Array, count: int,
     ``perm_e`` is the epoch-``e`` pseudo-permutation of ``[0, n)``."""
     if n <= 0:
         raise ValueError(f"need a positive dataset size, got {n}")
-    bits = max(2, (n - 1).bit_length())
-    bits += bits % 2                      # balanced halves
-    half_bits = bits // 2
-    domain = jnp.uint32(1 << bits)
+    with jax.named_scope("index"):
+        bits = max(2, (n - 1).bit_length())
+        bits += bits % 2                      # balanced halves
+        half_bits = bits // 2
+        domain = jnp.uint32(1 << bits)
 
-    j = jnp.uint32(j0) + jnp.arange(count, dtype=jnp.uint32)
-    epoch = j // jnp.uint32(n)
-    pos = j % jnp.uint32(n)
-    key = _mix(jnp.uint32(seed) * _C0 ^ epoch * _C1)
-    out = _feistel(pos, key, half_bits)
+        j = jnp.uint32(j0) + jnp.arange(count, dtype=jnp.uint32)
+        epoch = j // jnp.uint32(n)
+        pos = j % jnp.uint32(n)
+        key = _mix(jnp.uint32(seed) * _C0 ^ epoch * _C1)
+        out = _feistel(pos, key, half_bits)
 
-    # Cycle walking: values that landed in [n, 2^bits) re-walk until they
-    # fall inside [0, n). The domain is < 4n, so each walk escapes with
-    # probability > 3/4; the loop converges in a couple of iterations.
-    def cond(o):
-        return jnp.any(o >= jnp.uint32(n))
+        # Cycle walking: values that landed in [n, 2^bits) re-walk until
+        # they fall inside [0, n). The domain is < 4n, so each walk escapes
+        # with probability > 3/4; the loop converges in a couple of
+        # iterations.
+        def cond(o):
+            return jnp.any(o >= jnp.uint32(n))
 
-    def walk(o):
-        return jnp.where(o >= jnp.uint32(n), _feistel(o, key, half_bits)
-                         % domain, o)
+        def walk(o):
+            return jnp.where(o >= jnp.uint32(n), _feistel(o, key, half_bits)
+                             % domain, o)
 
-    out = jax.lax.while_loop(cond, walk, out)
-    return out.astype(jnp.int32)
+        out = jax.lax.while_loop(cond, walk, out)
+        return out.astype(jnp.int32)
 
 
 def check_supported_range(total_steps: int, batch: int) -> None:

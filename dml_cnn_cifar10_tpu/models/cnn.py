@@ -67,17 +67,29 @@ def apply(params: Params, images: jax.Array, cfg: ModelConfig,
     x = images.astype(cdt)
     p = jax.tree.map(lambda a: a.astype(cdt), params)
 
-    x = jax.nn.relu(L.conv2d(x, p["conv1"]["kernel"]) + p["conv1"]["bias"])
-    x = L.max_pool(x)
-    x = jax.nn.relu(L.conv2d(x, p["conv2"]["kernel"]) + p["conv2"]["bias"])
-    x = L.max_pool(x)
+    # One named scope a layer (bias and ReLU with their layer): metadata
+    # only, it is what utils/devprof.scope_map reads a compiled
+    # instruction's layer from.
+    with jax.named_scope("conv1"):
+        x = jax.nn.relu(L.conv2d(x, p["conv1"]["kernel"])
+                        + p["conv1"]["bias"])
+    with jax.named_scope("pool1"):
+        x = L.max_pool(x)
+    with jax.named_scope("conv2"):
+        x = jax.nn.relu(L.conv2d(x, p["conv2"]["kernel"])
+                        + p["conv2"]["bias"])
+    with jax.named_scope("pool2"):
+        x = L.max_pool(x)
     x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(L.dense(x, p["full1"]["kernel"], p["full1"]["bias"]))
-    x = jax.nn.relu(L.dense(x, p["full2"]["kernel"], p["full2"]["bias"]))
-    logits = L.dense(x, p["full3"]["kernel"], p["full3"]["bias"])
-    if cfg.logit_relu:  # faithful: reference ReLUs its logits (:145)
-        logits = jax.nn.relu(logits)
-    return logits.astype(jnp.float32)
+    with jax.named_scope("fc1"):
+        x = jax.nn.relu(L.dense(x, p["full1"]["kernel"], p["full1"]["bias"]))
+    with jax.named_scope("fc2"):
+        x = jax.nn.relu(L.dense(x, p["full2"]["kernel"], p["full2"]["bias"]))
+    with jax.named_scope("logits"):
+        logits = L.dense(x, p["full3"]["kernel"], p["full3"]["bias"])
+        if cfg.logit_relu:  # faithful: reference ReLUs its logits (:145)
+            logits = jax.nn.relu(logits)
+        return logits.astype(jnp.float32)
 
 
 # Shared implementation: models.param_count

@@ -204,41 +204,66 @@ def _bn(x, p, s, cfg: ModelConfig, train: bool, axis_name):
                         axis_name)
 
 
+# Named scopes (metadata only; utils/devprof.scope_map reads a compiled
+# instruction's layer from them): a convolution is ``conv<k>``, its batch
+# norm with the ReLU that follows ``bn<k>``, the projection and its batch
+# norm ``shortcut/conv`` and ``shortcut/bn``, the residual sum with its ReLU
+# ``add``.
+
+def _conv(name, x, w, stride=1):
+    with jax.named_scope(name):
+        return L.conv2d(x, w, stride=stride)
+
+
+def _bn_act(name, x, p, s, cfg, train, axis_name, relu=True):
+    with jax.named_scope(name):
+        x, ns = _bn(x, p, s, cfg, train, axis_name)
+        return (jax.nn.relu(x) if relu else x), ns
+
+
+def _shortcut(x, p, s, ns, stride, cfg, train, axis_name):
+    if "proj" not in p:
+        return x
+    with jax.named_scope("shortcut"):
+        x = _conv("conv", x, p["proj"], stride)
+        x, ns["proj_bn"] = _bn_act("bn", x, p["proj_bn"], s["proj_bn"], cfg,
+                                   train, axis_name, relu=False)
+    ns["proj"] = None
+    return x
+
+
+def _add_act(x, h):
+    with jax.named_scope("add"):
+        return jax.nn.relu(x + h)
+
+
 def _basic_block(x, p, s, stride, cfg, train, axis_name):
     ns: State = {}
-    h = L.conv2d(x, p["conv1"], stride=stride)
-    h, ns["bn1"] = _bn(h, p["bn1"], s["bn1"], cfg, train, axis_name)
-    h = jax.nn.relu(h)
-    h = L.conv2d(h, p["conv2"])
-    h, ns["bn2"] = _bn(h, p["bn2"], s["bn2"], cfg, train, axis_name)
-    if "proj" in p:
-        x = L.conv2d(x, p["proj"], stride=stride)
-        x, ns["proj_bn"] = _bn(x, p["proj_bn"], s["proj_bn"], cfg, train,
-                               axis_name)
+    h = _conv("conv1", x, p["conv1"], stride)
+    h, ns["bn1"] = _bn_act("bn1", h, p["bn1"], s["bn1"], cfg, train,
+                           axis_name)
+    h = _conv("conv2", h, p["conv2"])
+    h, ns["bn2"] = _bn_act("bn2", h, p["bn2"], s["bn2"], cfg, train,
+                           axis_name, relu=False)
+    x = _shortcut(x, p, s, ns, stride, cfg, train, axis_name)
     ns["conv1"] = ns["conv2"] = None
-    if "proj" in p:
-        ns["proj"] = None
-    return jax.nn.relu(x + h), ns
+    return _add_act(x, h), ns
 
 
 def _bottleneck_block(x, p, s, stride, cfg, train, axis_name):
     ns: State = {}
-    h = L.conv2d(x, p["conv1"])
-    h, ns["bn1"] = _bn(h, p["bn1"], s["bn1"], cfg, train, axis_name)
-    h = jax.nn.relu(h)
-    h = L.conv2d(h, p["conv2"], stride=stride)
-    h, ns["bn2"] = _bn(h, p["bn2"], s["bn2"], cfg, train, axis_name)
-    h = jax.nn.relu(h)
-    h = L.conv2d(h, p["conv3"])
-    h, ns["bn3"] = _bn(h, p["bn3"], s["bn3"], cfg, train, axis_name)
-    if "proj" in p:
-        x = L.conv2d(x, p["proj"], stride=stride)
-        x, ns["proj_bn"] = _bn(x, p["proj_bn"], s["proj_bn"], cfg, train,
-                               axis_name)
+    h = _conv("conv1", x, p["conv1"])
+    h, ns["bn1"] = _bn_act("bn1", h, p["bn1"], s["bn1"], cfg, train,
+                           axis_name)
+    h = _conv("conv2", h, p["conv2"], stride)
+    h, ns["bn2"] = _bn_act("bn2", h, p["bn2"], s["bn2"], cfg, train,
+                           axis_name)
+    h = _conv("conv3", h, p["conv3"])
+    h, ns["bn3"] = _bn_act("bn3", h, p["bn3"], s["bn3"], cfg, train,
+                           axis_name, relu=False)
+    x = _shortcut(x, p, s, ns, stride, cfg, train, axis_name)
     ns["conv1"] = ns["conv2"] = ns["conv3"] = None
-    if "proj" in p:
-        ns["proj"] = None
-    return jax.nn.relu(x + h), ns
+    return _add_act(x, h), ns
 
 
 def _ws_conv(w, gain, eps: float = 1e-4):
@@ -313,35 +338,40 @@ def apply(params: Params, state: State, images: jax.Array, cfg: ModelConfig,
     # Mirror init_state's structure exactly: a treedef change between step 1
     # and step 2 would silently retrigger compilation.
     new_state: State = {"fc": {"kernel": None, "bias": None}}
-    stem_w = (_ws_conv(p["stem"]["conv"], p["stem"]["g"]) if nf
-              else p["stem"]["conv"])
-    if s2d_stem:
-        # Space-to-depth: [B,2h,2w,C] -> [B,h,w,4C] (2x2 phases into
-        # channels), then the stride-1 4x4 conv with explicit padding
-        # (1,2): the 7x7/2 SAME conv (XLA pad lo=2) reads raw rows
-        # 2i-2..2i+4 for output i, which fold to rows i-1..i+2 — a 7x7
-        # kernel embeds as ws[m,n,(a,b,c)] = w7[2m+a-... w8[2m+a] with
-        # w8[0:7]=w7, w8[7]=0 (tests/test_resnet.py pins the fold).
-        b_, hh, ww, c_ = x.shape
-        x = x.reshape(b_, hh // 2, 2, ww // 2, 2, c_)
-        x = jnp.transpose(x, (0, 1, 3, 2, 4, 5)).reshape(
-            b_, hh // 2, ww // 2, 4 * c_)
-        x = lax.conv_general_dilated(
-            x, stem_w, window_strides=(1, 1),
-            padding=((1, 2), (1, 2)),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    else:
-        x = L.conv2d(x, stem_w, stride=2 if imagenet_stem else 1)
-    if nf:
-        x = x + p["stem"]["c"]
-        new_state["stem"] = {"conv": None, "g": None, "c": None}
-    else:
-        x, stem_bn = _bn(x, p["stem"]["bn"], state["stem"]["bn"], cfg,
-                         train, axis_name)
-        new_state["stem"] = {"conv": None, "bn": stem_bn}
-    x = jax.nn.relu(x)
-    if imagenet_stem or s2d_stem:
-        x = L.max_pool(x, window=3, stride=2)
+    with jax.named_scope("stem"):
+        with jax.named_scope("conv"):
+            stem_w = (_ws_conv(p["stem"]["conv"], p["stem"]["g"]) if nf
+                      else p["stem"]["conv"])
+            if s2d_stem:
+                # Space-to-depth: [B,2h,2w,C] -> [B,h,w,4C] (2x2 phases
+                # into channels), then the stride-1 4x4 conv with explicit
+                # padding (1,2): the 7x7/2 SAME conv (XLA pad lo=2) reads
+                # raw rows 2i-2..2i+4 for output i, which fold to rows
+                # i-1..i+2 — a 7x7 kernel embeds as ws[m,n,(a,b,c)] =
+                # w7[2m+a-... w8[2m+a] with w8[0:7]=w7, w8[7]=0
+                # (tests/test_resnet.py pins the fold).
+                b_, hh, ww, c_ = x.shape
+                x = x.reshape(b_, hh // 2, 2, ww // 2, 2, c_)
+                x = jnp.transpose(x, (0, 1, 3, 2, 4, 5)).reshape(
+                    b_, hh // 2, ww // 2, 4 * c_)
+                x = lax.conv_general_dilated(
+                    x, stem_w, window_strides=(1, 1),
+                    padding=((1, 2), (1, 2)),
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            else:
+                x = L.conv2d(x, stem_w, stride=2 if imagenet_stem else 1)
+        with jax.named_scope("bn"):
+            if nf:
+                x = x + p["stem"]["c"]
+                new_state["stem"] = {"conv": None, "g": None, "c": None}
+            else:
+                x, stem_bn = _bn(x, p["stem"]["bn"], state["stem"]["bn"],
+                                 cfg, train, axis_name)
+                new_state["stem"] = {"conv": None, "bn": stem_bn}
+            x = jax.nn.relu(x)
+        if imagenet_stem or s2d_stem:
+            with jax.named_scope("pool"):
+                x = L.max_pool(x, window=3, stride=2)
 
     for si in range(1, 5):
         key = f"stage{si}"
@@ -350,18 +380,23 @@ def apply(params: Params, state: State, images: jax.Array, cfg: ModelConfig,
         stage_state = []
         for bi, bp in enumerate(p[key]):
             stride = 2 if (bi == 0 and si > 1) else 1
-            x, bs = block(x, bp, state[key][bi], stride, cfg, train,
-                          axis_name)
+            with jax.named_scope(f"{key}/block{bi}"):
+                x, bs = block(x, bp, state[key][bi], stride, cfg, train,
+                              axis_name)
             stage_state.append(bs)
         new_state[key] = stage_state
 
-    x = jnp.mean(x, axis=(1, 2))  # global average pool
-    logits = L.dense(x, p["fc"]["kernel"], p["fc"]["bias"])
-    if cfg.logit_relu:
-        # Faithful-mode switch shared with the reference CNN
-        # (cifar10cnn.py:145); fixed_config turns it off.
-        logits = jax.nn.relu(logits)
-    return logits.astype(jnp.float32), new_state
+    with jax.named_scope("head"):
+        with jax.named_scope("pool"):
+            x = jnp.mean(x, axis=(1, 2))  # global average pool
+        with jax.named_scope("fc"):
+            logits = L.dense(x, p["fc"]["kernel"], p["fc"]["bias"])
+            if cfg.logit_relu:
+                # Faithful-mode switch shared with the reference CNN
+                # (cifar10cnn.py:145); fixed_config turns it off.
+                logits = jax.nn.relu(logits)
+            logits = logits.astype(jnp.float32)
+    return logits, new_state
 
 
 # Shared implementation: models.param_count

@@ -40,22 +40,23 @@ def device_preprocess(images_u8: jax.Array, cfg: DataConfig,
         raise ValueError(
             "random crop/flip/brightness/contrast on device need a PRNG "
             "key; pass key= or use the host pipeline")
-    x = images_u8.astype(jnp.float32)
-    if cfg.augmented:
-        kc, kf, kb, kn = jax.random.split(key, 4)
-    if cfg.random_crop:
-        # Flip folds into the crop's column-selection matmul for free.
-        x = _random_crop(x, cfg, kc,
-                         flip_key=kf if cfg.random_flip else None)
-    else:
-        x = _center_crop(x, cfg)
-        if cfg.random_flip:
-            x = _random_flip(x, kf)
-    if cfg.random_brightness:
-        x = _random_brightness(x, cfg.random_brightness, kb)
-    if cfg.random_contrast:
-        x = _random_contrast(x, cfg.random_contrast, kn)
-    return _normalize(x, cfg)
+    with jax.named_scope("decode"):
+        x = images_u8.astype(jnp.float32)
+        if cfg.augmented:
+            kc, kf, kb, kn = jax.random.split(key, 4)
+        if cfg.random_crop:
+            # Flip folds into the crop's column-selection matmul for free.
+            x = _random_crop(x, cfg, kc,
+                             flip_key=kf if cfg.random_flip else None)
+        else:
+            x = _center_crop(x, cfg)
+            if cfg.random_flip:
+                x = _random_flip(x, kf)
+        if cfg.random_brightness:
+            x = _random_brightness(x, cfg.random_brightness, kb)
+        if cfg.random_contrast:
+            x = _random_contrast(x, cfg.random_contrast, kn)
+        return _normalize(x, cfg)
 
 
 def _center_crop(x: jax.Array, cfg: DataConfig) -> jax.Array:

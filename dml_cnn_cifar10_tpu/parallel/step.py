@@ -609,7 +609,9 @@ def _chunk_body(loss_fn, optim_cfg: OptimConfig,
         # One source of truth for both size regimes: per-(seed, step) key
         # so draws are distinct and deterministic wherever decode runs.
         if augmented:
-            key = jax.random.fold_in(jax.random.key(data_cfg.seed), step)
+            with jax.named_scope("decode"):   # the key is the decode's
+                key = jax.random.fold_in(jax.random.key(data_cfg.seed),
+                                         step)
             return device_preprocess(imgs, data_cfg, key)
         return device_preprocess(imgs, data_cfg)
 
@@ -790,10 +792,12 @@ def make_train_chunk_resident(
             idx = device_stream.chunk_shuffle_indices(
                 seed, state.step, global_batch, k, n)
             idx = lax.with_sharding_constraint(idx, idx_sh2)
-            images = ds_images[idx]
+            with jax.named_scope("gather"):
+                images = ds_images[idx]
+                labels = ds_labels[idx]
             if spatial:
                 images = lax.with_sharding_constraint(images, gathered_sh)
-            return body(state, images, ds_labels[idx])
+            return body(state, images, labels)
 
         jitted_dev = _cached(jax.jit(
             chunk_dev,
@@ -825,10 +829,12 @@ def make_train_chunk_resident(
         # Conv models on a seq>1 mesh pin the gathered chunk to the
         # spatial (H-over-seq) layout so the resident path partitions
         # activations the same way the host-fed paths do.
-        images = dataset_images[idx]
+        with jax.named_scope("gather"):
+            images = dataset_images[idx]
+            labels = dataset_labels[idx]
         if spatial:
             images = lax.with_sharding_constraint(images, gathered_sh)
-        return body(state, images, dataset_labels[idx])
+        return body(state, images, labels)
 
     idx_sh = mesh_lib.batch_sharding(mesh, 2, leading_dims=1)
     jitted = _cached(jax.jit(
@@ -992,11 +998,13 @@ def make_batch_eval_resident(
     data_cfg: DataConfig,
     state_sharding: Optional[TrainState] = None,
     compile_cache=None,
+    scope: str = "train_acc",
 ):
     """Single-batch accuracy against an HBM-resident dataset:
     ``fn(state, idx [B] int32) -> accuracy`` (device scalar). The
     index-fed mirror of ``make_eval_step`` for the boundary metrics —
-    ~0.5 KB host→device instead of a decoded image batch."""
+    ~0.5 KB host→device instead of a decoded image batch. ``scope`` is
+    the named scope the whole pass runs under (metadata only)."""
     from dml_cnn_cifar10_tpu.ops.preprocess import device_preprocess
 
     logits_fn = _eval_logits_fn(model_def, model_cfg, mesh)
@@ -1006,12 +1014,15 @@ def make_batch_eval_resident(
     gathered_sh = mesh_lib.batch_sharding(mesh, 4, spatial=spatial)
 
     def ev(dataset_images, dataset_labels, state: TrainState, idx):
-        images = dataset_images[idx]
-        if spatial:
-            images = lax.with_sharding_constraint(images, gathered_sh)
-        images = device_preprocess(images, eval_cfg)
-        labels = dataset_labels[idx]
-        return metrics_lib.batch_accuracy(logits_fn(state, images), labels)
+        with jax.named_scope(scope):
+            with jax.named_scope("gather"):
+                images = dataset_images[idx]
+                labels = dataset_labels[idx]
+            if spatial:
+                images = lax.with_sharding_constraint(images, gathered_sh)
+            images = device_preprocess(images, eval_cfg)
+            return metrics_lib.batch_accuracy(logits_fn(state, images),
+                                              labels)
 
     repl = mesh_lib.replicated(mesh)
     state_sh = state_sharding if state_sharding is not None else repl
@@ -1025,7 +1036,17 @@ def make_batch_eval_resident(
         compile_cache, "eval_batch_resident",
         mesh_context(mesh, compute_dtype=model_cfg.compute_dtype,
                      model=model_cfg.name))
-    return functools.partial(jitted, dataset_images, dataset_labels)
+    fn = functools.partial(jitted, dataset_images, dataset_labels)
+
+    def lower(*abs_args):
+        # AOT lowering through the partial, as the resident chunk offers
+        # it: the telemetry probe reads this program's scope map.
+        from dml_cnn_cifar10_tpu.utils.profiling import abstractify
+        return jitted.lower(*abstractify((dataset_images,
+                                          dataset_labels)), *abs_args)
+
+    fn.lower = lower
+    return fn
 
 
 def _eval_data_cfg(data_cfg: DataConfig) -> DataConfig:

@@ -19,8 +19,10 @@ Faithful-mode details mirrored deliberately:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import sys
+import threading
 import time
 from typing import Optional
 
@@ -48,7 +50,9 @@ from dml_cnn_cifar10_tpu.utils.logging import MetricsLogger
 from dml_cnn_cifar10_tpu.utils.preemption import PreemptionGuard
 from dml_cnn_cifar10_tpu.utils.profiling import (DrainMeter, abstractify,
                                                  compiled_flops,
+                                                 compiled_with_flops,
                                                  correct_stack_flops,
+                                                 metadata_keyed_compiles,
                                                  profile_trace)
 
 
@@ -237,6 +241,29 @@ class Trainer:
         if tracer is not None and tracer.enabled:
             tracer.add_secs("compile", ev.get("compile_s") or 0.0)
 
+    def _register_scope_maps(self, compiled, state_abs, out_dir,
+                             step: int) -> None:
+        """Instruction-to-layer maps (utils/devprof.scope_map) of the
+        dispatch this fit runs, from the executable the FLOP probe just
+        compiled, and of the boundary's resident accuracy program where
+        there is one (a forward pass: its own, small compile). Telemetry
+        only, on the probe thread, fail-open: a map that cannot be built
+        costs a warning, never the run."""
+        try:
+            if compiled is not None and hasattr(compiled, "as_text"):
+                devprof_lib.register_scope_map(
+                    compiled, out_dir, logger=self.logger, step=step)
+            acc = self._resident_acc_eval
+            if acc is not None and hasattr(acc, "lower"):
+                idx = jax.ShapeDtypeStruct((self.cfg.batch_size,), jnp.int32,
+                                           sharding=self._idx1_sharding)
+                with metadata_keyed_compiles():
+                    acc_compiled = acc.lower(state_abs, idx).compile()
+                devprof_lib.register_scope_map(
+                    acc_compiled, out_dir, logger=self.logger, step=step)
+        except Exception as e:
+            print(f"[devprof] scope map not built: {e!r}", file=sys.stderr)
+
     def init_or_restore(self) -> step_lib.TrainState:
         key = jax.random.key(self.cfg.seed)
         sharding = self.state_sharding if self.state_sharding is not None \
@@ -357,6 +384,26 @@ class Trainer:
 
     def fit(self, total_steps: Optional[int] = None,
             state: Optional[step_lib.TrainState] = None) -> TrainResult:
+        # Host telemetry (utils/telemetry.py): ONE span stream over the
+        # whole of fit — set-up, the loop, the FLOP-probe thread, the
+        # garbage collector, teardown — emitted at the existing metrics
+        # boundaries with zero extra device fetches. Disabled, a span is
+        # a shared no-op context manager and nothing else exists.
+        tracer = telemetry_lib.SpanTracer(enabled=self.cfg.telemetry)
+        self._tracer = tracer  # exposed for tests/diagnostics
+        tracer.watch_gc()
+        with contextlib.ExitStack() as setup:
+            # `_fit` closes the stack where set-up ends; here it only
+            # closes the span of a set-up that raised.
+            setup.enter_context(tracer.span("fit_setup"))
+            try:
+                return self._fit(tracer, setup, total_steps, state)
+            finally:
+                # Spans that finish from here on (the probe thread may
+                # outlive a short fit) are logged by whoever finishes them.
+                tracer.close(self.logger)
+
+    def _fit(self, tracer, setup, total_steps, state) -> TrainResult:
         cfg = self.cfg
         total_steps = total_steps or cfg.total_steps
         state = state if state is not None else self.init_or_restore()
@@ -372,87 +419,88 @@ class Trainer:
                 f"{total_steps}, resume {start_step}) must be a multiple "
                 f"of steps_per_dispatch={self.steps_per_dispatch}")
 
-        num_shards = jax.process_count()
-        shard = jax.process_index()
-        per_process_batch = cfg.batch_size // num_shards
-        # Resident-eval fns are fit-scoped: reset so a prior fit's
-        # closures (bound to THAT run's iterators and HBM-pinned splits)
-        # can't leak into this one or into standalone evaluate() calls.
-        self._resident_full_eval = None
-        self._resident_test_eval = None
-        self._resident_acc_eval = None
-        self._resident_idx = None
-        train_data_cfg = cfg.data
-        if (self.steps_per_dispatch > 1 and cfg.resident_data
-                and cfg.data.use_native_loader):
-            # The HBM-resident path needs the index view only the
-            # in-memory permutation iterator provides; the native C++
-            # stream would silently force the ~90x-slower host-fed chunk
-            # path. Resident wins: build the train iterator non-native.
-            train_data_cfg = dataclasses.replace(cfg.data,
-                                                 use_native_loader=False)
-        train_it = pipe.input_pipeline(
-            train_data_cfg, per_process_batch, train=True,
-            seed=cfg.seed + shard, shard=shard, num_shards=num_shards)
-        # Full-split byte size, computed PROCESS-UNIFORMLY: per-shard
-        # nbytes differ when records don't divide evenly, and any
-        # size-gated decision below must come out identical on every
-        # process or the SPMD programs diverge and the job deadlocks.
-        def full_split_bytes(it):
-            per_record = int(np.prod(it.images.shape[1:])) \
-                * it.images.dtype.itemsize
-            return it.total_records * per_record
-
-        if (train_data_cfg is not cfg.data
-                and full_split_bytes(train_it)
-                > cfg.resident_data_max_bytes):
-            # Dataset turned out to exceed the HBM-resident cap: losing
-            # the native loader AND the resident path would be strictly
-            # worse than doing nothing, so rebuild the native stream.
+        with tracer.span("build_iterators"):
+            num_shards = jax.process_count()
+            shard = jax.process_index()
+            per_process_batch = cfg.batch_size // num_shards
+            # Resident-eval fns are fit-scoped: reset so a prior fit's
+            # closures (bound to THAT run's iterators and HBM-pinned splits)
+            # can't leak into this one or into standalone evaluate() calls.
+            self._resident_full_eval = None
+            self._resident_test_eval = None
+            self._resident_acc_eval = None
+            self._resident_idx = None
             train_data_cfg = cfg.data
+            if (self.steps_per_dispatch > 1 and cfg.resident_data
+                    and cfg.data.use_native_loader):
+                # The HBM-resident path needs the index view only the
+                # in-memory permutation iterator provides; the native C++
+                # stream would silently force the ~90x-slower host-fed chunk
+                # path. Resident wins: build the train iterator non-native.
+                train_data_cfg = dataclasses.replace(cfg.data,
+                                                     use_native_loader=False)
             train_it = pipe.input_pipeline(
                 train_data_cfg, per_process_batch, train=True,
                 seed=cfg.seed + shard, shard=shard, num_shards=num_shards)
-        test_it = pipe.input_pipeline(
-            train_data_cfg, per_process_batch, train=False,
-            seed=cfg.seed + shard, shard=shard, num_shards=num_shards)
-        # Fresh-batch train accuracy (cifar10cnn.py:235) — an independent
-        # stream over the same decoded arrays (no second decode).
-        acc_it = train_it.clone(seed=cfg.seed + 7 + shard)
-        k = self.steps_per_dispatch
-        # The resident cap is judged on the FULL split — multi-host
-        # replicates the whole dataset into every process's HBM (the
-        # host ships only per-process index slices).
-        resident = (k > 1 and cfg.resident_data
-                    and getattr(train_it, "supports_index_stream", False)
+            # Full-split byte size, computed PROCESS-UNIFORMLY: per-shard
+            # nbytes differ when records don't divide evenly, and any
+            # size-gated decision below must come out identical on every
+            # process or the SPMD programs diverge and the job deadlocks.
+            def full_split_bytes(it):
+                per_record = int(np.prod(it.images.shape[1:])) \
+                    * it.images.dtype.itemsize
+                return it.total_records * per_record
+
+            if (train_data_cfg is not cfg.data
                     and full_split_bytes(train_it)
-                    <= cfg.resident_data_max_bytes)
-        # Exact-resume data order: fast-forward the fresh streams to the
-        # cumulative consumption recorded at the checkpoint being
-        # resumed, so interrupted+resumed training is bit-identical to
-        # an uninterrupted run (the reference's MTS restart replays the
-        # stream from scratch — a documented improvement). Must happen
-        # BEFORE the prefetch threads start drawing. Augmentation draws
-        # are replayed only on paths whose ``_finish`` makes them: the
-        # per-step train stream (k==1) and the host-fed acc stream.
-        # Scope: params + stream position are exact at ANY resume step;
-        # the metric/eval CADENCE is keyed to the LOCAL step (reference
-        # parity, cifar10cnn.py:232), so resuming at a step that is not
-        # a cadence multiple (possible only via wall-clock or preemption
-        # saves) shifts WHEN eval batches are drawn relative to the
-        # uninterrupted run.
-        base_counts = {"train": 0, "acc": 0, "test": 0}
-        exact_ok = all(getattr(it, "supports_skip", False)
-                       for it in (train_it, acc_it, test_it))
-        if start_step > 0 and exact_ok:
-            prior = ckpt_lib.load_data_state(cfg.log_dir, start_step)
-            if prior:
-                base_counts.update(
-                    {name: int(prior.get(name, 0)) for name in base_counts})
-                train_it.skip_batches(base_counts["train"], aug=(k == 1))
-                acc_it.skip_batches(base_counts["acc"], aug=not resident)
-                test_it.skip_batches(base_counts["test"])
-        consumed = {"acc": 0, "test": 0}
+                    > cfg.resident_data_max_bytes):
+                # Dataset turned out to exceed the HBM-resident cap: losing
+                # the native loader AND the resident path would be strictly
+                # worse than doing nothing, so rebuild the native stream.
+                train_data_cfg = cfg.data
+                train_it = pipe.input_pipeline(
+                    train_data_cfg, per_process_batch, train=True,
+                    seed=cfg.seed + shard, shard=shard, num_shards=num_shards)
+            test_it = pipe.input_pipeline(
+                train_data_cfg, per_process_batch, train=False,
+                seed=cfg.seed + shard, shard=shard, num_shards=num_shards)
+            # Fresh-batch train accuracy (cifar10cnn.py:235) — an independent
+            # stream over the same decoded arrays (no second decode).
+            acc_it = train_it.clone(seed=cfg.seed + 7 + shard)
+            k = self.steps_per_dispatch
+            # The resident cap is judged on the FULL split — multi-host
+            # replicates the whole dataset into every process's HBM (the
+            # host ships only per-process index slices).
+            resident = (k > 1 and cfg.resident_data
+                        and getattr(train_it, "supports_index_stream", False)
+                        and full_split_bytes(train_it)
+                        <= cfg.resident_data_max_bytes)
+            # Exact-resume data order: fast-forward the fresh streams to the
+            # cumulative consumption recorded at the checkpoint being
+            # resumed, so interrupted+resumed training is bit-identical to
+            # an uninterrupted run (the reference's MTS restart replays the
+            # stream from scratch — a documented improvement). Must happen
+            # BEFORE the prefetch threads start drawing. Augmentation draws
+            # are replayed only on paths whose ``_finish`` makes them: the
+            # per-step train stream (k==1) and the host-fed acc stream.
+            # Scope: params + stream position are exact at ANY resume step;
+            # the metric/eval CADENCE is keyed to the LOCAL step (reference
+            # parity, cifar10cnn.py:232), so resuming at a step that is not
+            # a cadence multiple (possible only via wall-clock or preemption
+            # saves) shifts WHEN eval batches are drawn relative to the
+            # uninterrupted run.
+            base_counts = {"train": 0, "acc": 0, "test": 0}
+            exact_ok = all(getattr(it, "supports_skip", False)
+                           for it in (train_it, acc_it, test_it))
+            if start_step > 0 and exact_ok:
+                prior = ckpt_lib.load_data_state(cfg.log_dir, start_step)
+                if prior:
+                    base_counts.update({name: int(prior.get(name, 0))
+                                        for name in base_counts})
+                    train_it.skip_batches(base_counts["train"], aug=(k == 1))
+                    acc_it.skip_batches(base_counts["acc"], aug=not resident)
+                    test_it.skip_batches(base_counts["test"])
+            consumed = {"acc": 0, "test": 0}
         if resident:
             # HBM-resident data path: dataset lives on device, the host
             # ships only shuffled index arrays; gather+decode+K steps are
@@ -463,97 +511,103 @@ class Trainer:
             # local row i is full-split row shard + i*num_shards) and
             # contributes its slice of the global [K, B] index array —
             # the same ~16x win over host-fed chunks as single-host.
-            repl = mesh_lib.replicated(self.mesh)
-            host_imgs, host_lbls = _full_split_arrays(
-                train_it, lambda: pipe.input_pipeline(
-                    train_data_cfg, per_process_batch, train=True,
-                    seed=cfg.seed))
-            ds_images = mesh_lib.place_local(repl, host_imgs)
-            ds_labels = mesh_lib.place_local(repl,
-                                             host_lbls.astype(np.int32))
-
-            def to_global(idx):
-                if num_shards > 1:
-                    return (shard + idx * num_shards).astype(np.int32)
-                return idx
-
-            # Device-generated index stream: the training dispatch takes
-            # ONLY the donated state — no host index generation, no H2D
-            # upload, and exact resume is free (the stream position is
-            # state.step). Requires the global row space: the full split
-            # is replicated in HBM, and the stateless stream emits GLOBAL
-            # rows directly (identical on every process by purity).
-            dev_stream = cfg.data.device_index_stream
-            if dev_stream:
-                # uint32 position domain — refuse runs that would wrap
-                # (data/device_stream.py module docstring).
-                from dml_cnn_cifar10_tpu.data import device_stream
-                device_stream.check_supported_range(cfg.total_steps,
-                                                    cfg.batch_size)
-            chunk_fn = step_lib.make_train_chunk_resident(
-                self.model_def, cfg.model, cfg.optim, self.mesh,
-                ds_images, ds_labels,
-                state_sharding=self.state_sharding, data_cfg=cfg.data,
-                index_stream=((cfg.data.seed, cfg.batch_size, k)
-                              if dev_stream else None),
-                health_metrics=cfg.health_metrics,
-                compile_cache=self.compile_cache,
-                rules=self.partition_rules)
-            idx_sh = mesh_lib.batch_sharding(self.mesh, 2, leading_dims=1)
-            # Eval also goes resident: boundary train-accuracy is index-fed
-            # from the in-HBM train split, test eval is one dispatch over
-            # the in-HBM test split — each boundary costs ONE host↔device
-            # round trip instead of a decoded-batch H2D + per-batch
-            # fetches.
-            self._idx1_sharding = mesh_lib.batch_sharding(self.mesh, 1)
-            self._resident_idx = lambda a: mesh_lib.place_local(
-                self._idx1_sharding, to_global(a))
-            self._resident_acc_eval = step_lib.make_batch_eval_resident(
-                self.model_def, cfg.model, self.mesh, ds_images, ds_labels,
-                cfg.data, state_sharding=self.state_sharding,
-                compile_cache=self.compile_cache)
-            if cfg.eval_full_test_set:
-                # Multi-host included (round 3): each process contributes
-                # its padded strided shard as its slice of the global
-                # [M, B, ...] arrays; the scan's replicated output is the
-                # GLOBAL correct count — one dispatch + one fetch per
-                # eval on every process (the host-fed fallback cost M
-                # per-batch H2D uploads per eval).
-                self._resident_full_eval = step_lib.make_eval_resident(
-                    self.model_def, cfg.model, self.mesh,
-                    test_it.images, test_it.labels, cfg.data,
-                    state_sharding=self.state_sharding,
-                    batch_size=per_process_batch,
-                    num_shards=num_shards,
-                    total_records=test_it.total_records,
-                    expected_batches=test_it.num_padded_sweep_batches(),
-                    compile_cache=self.compile_cache)
-            else:
-                t_imgs, t_lbls = _full_split_arrays(
-                    test_it, lambda: pipe.input_pipeline(
-                        train_data_cfg, per_process_batch, train=False,
+            with tracer.span("place_resident"):
+                repl = mesh_lib.replicated(self.mesh)
+                host_imgs, host_lbls = _full_split_arrays(
+                    train_it, lambda: pipe.input_pipeline(
+                        train_data_cfg, per_process_batch, train=True,
                         seed=cfg.seed))
-                t_images = mesh_lib.place_local(repl, t_imgs)
-                t_labels = mesh_lib.place_local(repl,
-                                                t_lbls.astype(np.int32))
-                self._resident_test_eval = step_lib.make_batch_eval_resident(
-                    self.model_def, cfg.model, self.mesh, t_images,
-                    t_labels, cfg.data, state_sharding=self.state_sharding,
+                ds_images = mesh_lib.place_local(repl, host_imgs)
+                ds_labels = mesh_lib.place_local(repl,
+                                                 host_lbls.astype(np.int32))
+
+            with tracer.span("build_step"):
+                def to_global(idx):
+                    if num_shards > 1:
+                        return (shard + idx * num_shards).astype(np.int32)
+                    return idx
+
+                # Device-generated index stream: the training dispatch takes
+                # ONLY the donated state — no host index generation, no H2D
+                # upload, and exact resume is free (the stream position is
+                # state.step). Requires the global row space: the full split
+                # is replicated in HBM, and the stateless stream emits GLOBAL
+                # rows directly (identical on every process by purity).
+                dev_stream = cfg.data.device_index_stream
+                if dev_stream:
+                    # uint32 position domain — refuse runs that would wrap
+                    # (data/device_stream.py module docstring).
+                    from dml_cnn_cifar10_tpu.data import device_stream
+                    device_stream.check_supported_range(cfg.total_steps,
+                                                        cfg.batch_size)
+                chunk_fn = step_lib.make_train_chunk_resident(
+                    self.model_def, cfg.model, cfg.optim, self.mesh,
+                    ds_images, ds_labels,
+                    state_sharding=self.state_sharding, data_cfg=cfg.data,
+                    index_stream=((cfg.data.seed, cfg.batch_size, k)
+                                  if dev_stream else None),
+                    health_metrics=cfg.health_metrics,
+                    compile_cache=self.compile_cache,
+                    rules=self.partition_rules)
+                idx_sh = mesh_lib.batch_sharding(self.mesh, 2, leading_dims=1)
+                # Eval also goes resident: boundary train-accuracy is
+                # index-fed from the in-HBM train split, test eval is one
+                # dispatch over the in-HBM test split — each boundary costs
+                # ONE host↔device round trip instead of a decoded-batch H2D
+                # + per-batch fetches.
+                self._idx1_sharding = mesh_lib.batch_sharding(self.mesh, 1)
+                self._resident_idx = lambda a: mesh_lib.place_local(
+                    self._idx1_sharding, to_global(a))
+                self._resident_acc_eval = step_lib.make_batch_eval_resident(
+                    self.model_def, cfg.model, self.mesh, ds_images, ds_labels,
+                    cfg.data, state_sharding=self.state_sharding,
                     compile_cache=self.compile_cache)
+                if cfg.eval_full_test_set:
+                    # Multi-host included (round 3): each process contributes
+                    # its padded strided shard as its slice of the global
+                    # [M, B, ...] arrays; the scan's replicated output is the
+                    # GLOBAL correct count — one dispatch + one fetch per
+                    # eval on every process (the host-fed fallback cost M
+                    # per-batch H2D uploads per eval).
+                    self._resident_full_eval = step_lib.make_eval_resident(
+                        self.model_def, cfg.model, self.mesh,
+                        test_it.images, test_it.labels, cfg.data,
+                        state_sharding=self.state_sharding,
+                        batch_size=per_process_batch,
+                        num_shards=num_shards,
+                        total_records=test_it.total_records,
+                        expected_batches=test_it.num_padded_sweep_batches(),
+                        compile_cache=self.compile_cache)
+                else:
+                    t_imgs, t_lbls = _full_split_arrays(
+                        test_it, lambda: pipe.input_pipeline(
+                            train_data_cfg, per_process_batch, train=False,
+                            seed=cfg.seed))
+                    t_images = mesh_lib.place_local(repl, t_imgs)
+                    t_labels = mesh_lib.place_local(repl,
+                                                    t_lbls.astype(np.int32))
+                    self._resident_test_eval = \
+                        step_lib.make_batch_eval_resident(
+                            self.model_def, cfg.model, self.mesh, t_images,
+                            t_labels, cfg.data,
+                            state_sharding=self.state_sharding,
+                            compile_cache=self.compile_cache,
+                            scope="test_eval")
 
-            if dev_stream:
-                def produce():
-                    # The chunk generates its own indices in-graph; a
-                    # dispatch has no data arguments at all.
-                    return ()
-            else:
-                def produce():
-                    local = train_it.next_index_chunk(k)
-                    return (mesh_lib.place_local(idx_sh, to_global(local)),)
+                if dev_stream:
+                    def produce():
+                        # The chunk generates its own indices in-graph; a
+                        # dispatch has no data arguments at all.
+                        return ()
+                else:
+                    def produce():
+                        local = train_it.next_index_chunk(k)
+                        return (mesh_lib.place_local(idx_sh,
+                                                     to_global(local)),)
 
-            prefetch = pipe.PrefetchIterator(
-                iter(produce, None), depth=cfg.data.prefetch, place=None)
-            step_fn = chunk_fn
+                prefetch = pipe.PrefetchIterator(
+                    iter(produce, None), depth=cfg.data.prefetch, place=None)
+                step_fn = chunk_fn
         elif k > 1:
             # Host-fed chunked path (multi-host, or dataset too big for
             # HBM): the host gathers raw uint8 bytes; decode/augment runs
@@ -573,12 +627,10 @@ class Trainer:
                 train_it, depth=cfg.data.prefetch, place=self._placed)
             step_fn = self.train_step
 
-        # Host-loop telemetry (utils/telemetry.py): span tracing, goodput
-        # accounting, HBM snapshots — all emitted at the existing metrics
-        # boundaries with zero extra device fetches. Disabled spans reduce
-        # to a shared no-op context manager.
-        tracer = telemetry_lib.SpanTracer(enabled=cfg.telemetry)
-        self._tracer = tracer  # exposed for tests/diagnostics
+        # Set-up ends here, and the goodput clock starts: data, step and
+        # resident arrays are built; what follows is the loop's own.
+        setup.close()
+        tracer.start()
         # Device-time attribution (utils/devprof.py): the always-on
         # step-time estimator rides the existing fused boundary fetch
         # (two clock reads, zero device traffic — the parity test pins
@@ -866,113 +918,135 @@ class Trainer:
                         meter.mark(global_step)
                         dev_est.mark(global_step)
                         run_t0 = time.perf_counter()
-                        import threading
+                        # Where a profiler capture of this run goes, the
+                        # instruction-to-layer maps go beside it.
+                        scopemap_dir = devwin.out_dir if devwin is not None \
+                            else cfg.profile_dir
 
-                        def _probe(fn=step_fn, abs_args=step_abs):
-                            f = compiled_flops(fn, abs_args) or 0.0
-                            if f and k > 1:
-                                # Verify, don't assume, that this backend
-                                # counts the K-step scan body ONCE: probe
-                                # the scan-free per-step fn too; a
-                                # chunk/step flops ratio near K means the
-                                # scan was unrolled or counted
-                                # per-iteration — scale back by K.
-                                d = cfg.data
-                                img = jax.ShapeDtypeStruct(
-                                    (cfg.batch_size, d.crop_height,
-                                     d.crop_width, d.num_channels),
-                                    jnp.float32)
-                                lab = jax.ShapeDtypeStruct(
-                                    (cfg.batch_size,), jnp.int32)
-                                f1 = compiled_flops(
-                                    self.train_step,
-                                    (abs_args[0], img, lab)) or 0.0
-                                if f1 and f >= (1 + k) / 2 * f1:
-                                    flops_cell["assume"] = "per_iteration"
-                                    f = f / k
-                                elif f1:
-                                    flops_cell["assume"] = "scan_once"
-                            # Models that scan their LAYER stack (ViT)
-                            # also get their scan body counted once —
-                            # ~1/depth of the real FLOPs (round-2
-                            # verdict weak #4). The model's stack_probe
-                            # measures one block standalone: bf_counted
-                            # (as the step runs it — Pallas attention is
-                            # an opaque custom call counted as 0) and
-                            # bf_true (dense-equivalent, fully counted);
-                            # correct_stack_flops swaps counted for true
-                            # at full depth. Only on pure-data-parallel
-                            # meshes: under seq/model/pipe partitioning
-                            # the unsharded block probe doesn't match
-                            # the per-chip share, so the figure stays
-                            # uncorrected and is LABELED as such. The
-                            # block probe runs at the PER-CHIP
-                            # microbatch (batch / grad_accum / data
-                            # axis) to match f's per-device accounting.
-                            sp = getattr(self.model_def, "stack_probe",
-                                         None)
-                            if f and sp is not None:
-                                mesh_shape = dict(self.mesh.shape) \
-                                    if self.mesh is not None else {}
-                                ndata = mesh_shape.get("data", 1)
-                                pure_dp = all(
-                                    v == 1 for a, v in mesh_shape.items()
-                                    if a != "data")
-                                if not pure_dp:
-                                    flops_cell["stack"] = (
-                                        "uncorrected_model_parallel")
-                                else:
-                                    micro = max(1, cfg.batch_size // max(
-                                        1, cfg.optim.grad_accum) // ndata)
-                                    try:
-                                        depth, bfc, bft = sp(
-                                            cfg.model, cfg.data, micro)
-                                    except Exception:
-                                        depth, bfc, bft = 0, None, None
-                                    f, flops_cell["stack"] = \
-                                        correct_stack_flops(f, depth,
-                                                            bfc, bft)
-                                    if flops_cell["stack"] == \
-                                            "probe_failed":
-                                        # Don't publish a known ~1/depth
-                                        # undercount as TFLOP/s.
-                                        f = 0.0
-                            flops_cell["flops"] = f
+                        def _probe(fn=step_fn, abs_args=step_abs,
+                                   step0=global_step):
+                            with tracer.span("flops_probe"):
+                                with tracer.span("probe_compile_dispatch"):
+                                    f, compiled = compiled_with_flops(
+                                        fn, abs_args,
+                                        exact_metadata=tracer.enabled)
+                                    f = f or 0.0
+                                if f and k > 1:
+                                    # Verify, don't assume, that this backend
+                                    # counts the K-step scan body ONCE: probe
+                                    # the scan-free per-step fn too; a
+                                    # chunk/step flops ratio near K means the
+                                    # scan was unrolled or counted
+                                    # per-iteration — scale back by K.
+                                    d = cfg.data
+                                    img = jax.ShapeDtypeStruct(
+                                        (cfg.batch_size, d.crop_height,
+                                         d.crop_width, d.num_channels),
+                                        jnp.float32)
+                                    lab = jax.ShapeDtypeStruct(
+                                        (cfg.batch_size,), jnp.int32)
+                                    with tracer.span("probe_compile_step"):
+                                        f1 = compiled_flops(
+                                            self.train_step,
+                                            (abs_args[0], img, lab)) or 0.0
+                                    if f1 and f >= (1 + k) / 2 * f1:
+                                        flops_cell["assume"] = "per_iteration"
+                                        f = f / k
+                                    elif f1:
+                                        flops_cell["assume"] = "scan_once"
+                                # Models that scan their LAYER stack (ViT)
+                                # also get their scan body counted once —
+                                # ~1/depth of the real FLOPs (round-2
+                                # verdict weak #4). The model's stack_probe
+                                # measures one block standalone: bf_counted
+                                # (as the step runs it — Pallas attention is
+                                # an opaque custom call counted as 0) and
+                                # bf_true (dense-equivalent, fully counted);
+                                # correct_stack_flops swaps counted for true
+                                # at full depth. Only on pure-data-parallel
+                                # meshes: under seq/model/pipe partitioning
+                                # the unsharded block probe doesn't match
+                                # the per-chip share, so the figure stays
+                                # uncorrected and is LABELED as such. The
+                                # block probe runs at the PER-CHIP
+                                # microbatch (batch / grad_accum / data
+                                # axis) to match f's per-device accounting.
+                                sp = getattr(self.model_def, "stack_probe",
+                                             None)
+                                if f and sp is not None:
+                                    mesh_shape = dict(self.mesh.shape) \
+                                        if self.mesh is not None else {}
+                                    ndata = mesh_shape.get("data", 1)
+                                    pure_dp = all(
+                                        v == 1 for a, v in mesh_shape.items()
+                                        if a != "data")
+                                    if not pure_dp:
+                                        flops_cell["stack"] = (
+                                            "uncorrected_model_parallel")
+                                    else:
+                                        micro = max(1, cfg.batch_size // max(
+                                            1, cfg.optim.grad_accum) // ndata)
+                                        try:
+                                            depth, bfc, bft = sp(
+                                                cfg.model, cfg.data, micro)
+                                        except Exception:
+                                            depth, bfc, bft = 0, None, None
+                                        f, flops_cell["stack"] = \
+                                            correct_stack_flops(f, depth,
+                                                                bfc, bft)
+                                        if flops_cell["stack"] == \
+                                                "probe_failed":
+                                            # Don't publish a known ~1/depth
+                                            # undercount as TFLOP/s.
+                                            f = 0.0
+                                if tracer.enabled:
+                                    # From the compile just made (no third
+                                    # one), BEFORE the figure is posted:
+                                    # whoever waits for the probe finds the
+                                    # maps built.
+                                    with tracer.span("probe_scope_map"):
+                                        self._register_scope_maps(
+                                            compiled, abs_args[0],
+                                            scopemap_dir, step0)
+                                flops_cell["flops"] = f
 
-                        probe_thread = threading.Thread(target=_probe,
-                                                        daemon=True)
+                        probe_thread = threading.Thread(
+                            target=_probe, daemon=True, name="flops-probe")
                         probe_thread.start()
                     last_metrics = metrics
                     global_step += k
 
                     if (i + k) % cfg.output_every == 0:
-                        # Fresh-batch train accuracy (cifar10cnn.py:235), then
-                        # ONE fused device->host fetch for loss+accuracy.
-                        if self._resident_acc_eval is not None:
-                            aidx = self._resident_idx(
-                                acc_it.next_index_chunk(1)[0])
-                            acc_arr = self._resident_acc_eval(state, aidx)
-                        else:
-                            acc_arr = self.eval_step(
-                                state, *self._placed(next(acc_it)))["accuracy"]
-                        consumed["acc"] += 1
-                        # Router health for MoE models (ops/moe.py stats
-                        # via parallel/step.py) and the optional
-                        # training-health scalars (grad/param norms,
-                        # update ratio — health_metrics=True) ride the
-                        # SAME fused fetch as loss/accuracy: everything
-                        # concatenates into one 1-D f32 array -> one
-                        # device->host round trip per boundary (a second
-                        # fetch would drain the device queue twice).
-                        fused_keys = sorted(
-                            mk for mk in metrics
-                            if mk.startswith(("moe_", "health_")))
-                        parts = [jnp.reshape(metrics["loss"], (1,)),
-                                 jnp.reshape(
-                                     jnp.asarray(acc_arr, jnp.float32),
-                                     (1,))]
-                        parts += [jnp.reshape(metrics[mk], (-1,)).astype(
-                                      jnp.float32) for mk in fused_keys]
+                        with tracer.span("boundary_acc_dispatch"):
+                            # Fresh-batch train accuracy
+                            # (cifar10cnn.py:235), then ONE fused
+                            # device->host fetch for loss+accuracy.
+                            if self._resident_acc_eval is not None:
+                                aidx = self._resident_idx(
+                                    acc_it.next_index_chunk(1)[0])
+                                acc_arr = self._resident_acc_eval(state, aidx)
+                            else:
+                                acc_arr = self.eval_step(
+                                    state, *self._placed(next(acc_it))
+                                )["accuracy"]
+                            consumed["acc"] += 1
+                            # Router health for MoE models (ops/moe.py stats
+                            # via parallel/step.py) and the optional
+                            # training-health scalars (grad/param norms,
+                            # update ratio — health_metrics=True) ride the
+                            # SAME fused fetch as loss/accuracy: everything
+                            # concatenates into one 1-D f32 array -> one
+                            # device->host round trip per boundary (a second
+                            # fetch would drain the device queue twice).
+                            fused_keys = sorted(
+                                mk for mk in metrics
+                                if mk.startswith(("moe_", "health_")))
+                            parts = [jnp.reshape(metrics["loss"], (1,)),
+                                     jnp.reshape(
+                                         jnp.asarray(acc_arr, jnp.float32),
+                                         (1,))]
+                            parts += [jnp.reshape(metrics[mk], (-1,)).astype(
+                                          jnp.float32) for mk in fused_keys]
                         # The fused fetch is a true drain: the host blocks
                         # on device compute, so the span is device-busy
                         # time — traced, but counted as productive. The
@@ -983,78 +1057,80 @@ class Trainer:
                             fused = jax.device_get(
                                 jnp.concatenate(parts))
                         t_drain1 = time.perf_counter()
-                        device_step_ms, drain_wait_ms = dev_est.boundary(
-                            global_step, t_drain0, t_drain1)
-                        rate = meter.rate(global_step)
-                        drained = True
-                        loss, acc = float(fused[0]), float(fused[1])
-                        train_loss.append(loss)
-                        perf = {}
-                        off = 2
-                        for mk in fused_keys:
-                            nleaf = int(np.prod(metrics[mk].shape)) \
-                                if metrics[mk].shape else 1
-                            mv = fused[off:off + nleaf]
-                            off += nleaf
-                            perf[mk] = (round(float(mv[0]), 5)
-                                        if nleaf == 1
-                                        else [round(float(x), 5)
-                                              for x in mv])
-                        flops_probe = flops_cell.get("flops")
-                        if flops_probe and rate > 0:
-                            # steps/sec x flops/step. XLA cost analysis
-                            # reports the PER-DEVICE share of the
-                            # partitioned program (already per-chip, no
-                            # device_count divide). Whether it counted
-                            # the K-step scan body once was VERIFIED by
-                            # the probe's chunk-vs-step cross-check
-                            # (flops_scan in the metrics records which
-                            # case held); grad-accum microbatches scale
-                            # back in. Models that scan their layer
-                            # stack (ViT) are corrected to full depth
-                            # via stack_probe (flops_stack label);
-                            # exact for the CNN.
-                            tf = (flops_probe
-                                  * max(1, cfg.optim.grad_accum)
-                                  * (rate / cfg.batch_size) / 1e12)
-                            perf["tflops_per_sec_per_chip"] = round(tf, 3)
-                            if cfg.peak_tflops:
-                                perf["mfu"] = round(
-                                    tf / cfg.peak_tflops, 4)
-                        if "assume" in flops_cell:
-                            # Logged once, OUTSIDE the rate guard (like
-                            # flops_stack below): a 0-rate boundary must
-                            # defer the TFLOP/s figure, not silently
-                            # swallow the scan-accounting label.
-                            perf["flops_scan"] = flops_cell.pop("assume")
-                        if "stack" in flops_cell:
-                            # Logged once, OUTSIDE the flops>0 guard: the
-                            # layer-stack accounting case
-                            # (scan_once_x<depth> = corrected;
-                            # probe_failed = TFLOP/s withheld;
-                            # uncorrected_model_parallel = raw figure,
-                            # trust accordingly).
-                            perf["flops_stack"] = flops_cell.pop("stack")
-                        self.logger.train_print(global_step, i + k - 1, acc)
-                        # optimizer_ms: per-step device time inside the
-                        # step's named_scope("optimizer"), measured by
-                        # the last --profile_at_steps window (null until
-                        # one completes) — the kernel/sharding win is
-                        # attributed, not inferred.
-                        self.logger.log("train", step=global_step, loss=loss,
-                                        train_accuracy=acc,
-                                        images_per_sec=rate,
-                                        lr=_current_lr(cfg, global_step),
-                                        device_step_ms=device_step_ms,
-                                        drain_wait_ms=drain_wait_ms,
-                                        optimizer_ms=(
-                                            devwin.optimizer_step_ms
-                                            if devwin is not None
-                                            else None),
-                                        **perf)
-                        telemetry_lib.flush_boundary(tracer, self.logger,
-                                                     global_step,
-                                                     alerts=self.alerts)
+                        with tracer.span("boundary_log"):
+                            device_step_ms, drain_wait_ms = dev_est.boundary(
+                                global_step, t_drain0, t_drain1)
+                            rate = meter.rate(global_step)
+                            drained = True
+                            loss, acc = float(fused[0]), float(fused[1])
+                            train_loss.append(loss)
+                            perf = {}
+                            off = 2
+                            for mk in fused_keys:
+                                nleaf = int(np.prod(metrics[mk].shape)) \
+                                    if metrics[mk].shape else 1
+                                mv = fused[off:off + nleaf]
+                                off += nleaf
+                                perf[mk] = (round(float(mv[0]), 5)
+                                            if nleaf == 1
+                                            else [round(float(x), 5)
+                                                  for x in mv])
+                            flops_probe = flops_cell.get("flops")
+                            if flops_probe and rate > 0:
+                                # steps/sec x flops/step. XLA cost analysis
+                                # reports the PER-DEVICE share of the
+                                # partitioned program (already per-chip, no
+                                # device_count divide). Whether it counted
+                                # the K-step scan body once was VERIFIED by
+                                # the probe's chunk-vs-step cross-check
+                                # (flops_scan in the metrics records which
+                                # case held); grad-accum microbatches scale
+                                # back in. Models that scan their layer
+                                # stack (ViT) are corrected to full depth
+                                # via stack_probe (flops_stack label);
+                                # exact for the CNN.
+                                tf = (flops_probe
+                                      * max(1, cfg.optim.grad_accum)
+                                      * (rate / cfg.batch_size) / 1e12)
+                                perf["tflops_per_sec_per_chip"] = round(tf, 3)
+                                if cfg.peak_tflops:
+                                    perf["mfu"] = round(
+                                        tf / cfg.peak_tflops, 4)
+                            if "assume" in flops_cell:
+                                # Logged once, OUTSIDE the rate guard (like
+                                # flops_stack below): a 0-rate boundary must
+                                # defer the TFLOP/s figure, not silently
+                                # swallow the scan-accounting label.
+                                perf["flops_scan"] = flops_cell.pop("assume")
+                            if "stack" in flops_cell:
+                                # Logged once, OUTSIDE the flops>0 guard: the
+                                # layer-stack accounting case
+                                # (scan_once_x<depth> = corrected;
+                                # probe_failed = TFLOP/s withheld;
+                                # uncorrected_model_parallel = raw figure,
+                                # trust accordingly).
+                                perf["flops_stack"] = flops_cell.pop("stack")
+                            self.logger.train_print(global_step, i + k - 1,
+                                                    acc)
+                            # optimizer_ms: per-step device time inside the
+                            # step's named_scope("optimizer"), measured by
+                            # the last --profile_at_steps window (null until
+                            # one completes) — the kernel/sharding win is
+                            # attributed, not inferred.
+                            self.logger.log("train", step=global_step,
+                                            loss=loss, train_accuracy=acc,
+                                            images_per_sec=rate,
+                                            lr=_current_lr(cfg, global_step),
+                                            device_step_ms=device_step_ms,
+                                            drain_wait_ms=drain_wait_ms,
+                                            optimizer_ms=(
+                                                devwin.optimizer_step_ms
+                                                if devwin is not None
+                                                else None),
+                                            **perf)
+                            telemetry_lib.flush_boundary(tracer, self.logger,
+                                                         global_step,
+                                                         alerts=self.alerts)
                         if cfg.check_numerics:
                             # Loss is a replicated metric, so every
                             # process takes the same branch on the same
@@ -1188,19 +1264,20 @@ class Trainer:
             # tensorboardX's daemon writer dies unflushed at interpreter
             # exit — an OOM/NaN abort is exactly when the last scalars
             # matter.
-            ckpt_mgr.close()
-            prefetch.close()
-            # A capture window the run ended (or crashed) inside still
-            # stops, parses, and emits its devtime records — like the
-            # Chrome trace below, the runs that die mid-window are
-            # exactly the ones worth attributing.
-            if devwin is not None:
-                devwin.close(global_step)
-            # A supervisor-owned monitor must keep its threads (and
-            # epoch/world state) across fit attempts; only a monitor
-            # this Trainer built for itself dies with the fit.
-            if self._owns_cluster and self.cluster is not None:
-                self.cluster.close()
+            with tracer.span("fit_teardown"):
+                ckpt_mgr.close()
+                prefetch.close()
+                # A capture window the run ended (or crashed) inside still
+                # stops, parses, and emits its devtime records — like the
+                # Chrome trace below, the runs that die mid-window are
+                # exactly the ones worth attributing.
+                if devwin is not None:
+                    devwin.close(global_step)
+                # A supervisor-owned monitor must keep its threads (and
+                # epoch/world state) across fit attempts; only a monitor
+                # this Trainer built for itself dies with the fit.
+                if self._owns_cluster and self.cluster is not None:
+                    self.cluster.close()
             # The Chrome trace exports from the finally block so a
             # crashed/preempted run still leaves its host-loop timeline —
             # exactly the runs worth opening in Perfetto.

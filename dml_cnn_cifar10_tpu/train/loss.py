@@ -19,10 +19,11 @@ def softmax_cross_entropy(logits: jax.Array, labels: jax.Array,
     ``label_smoothing`` ε mixes the one-hot target with uniform:
     ``(1-ε)·onehot + ε/K`` (the ladder-config regularizer; 0 = parity).
     """
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32),
-                               axis=-1)[:, 0]
-    if label_smoothing:
-        uniform = -jnp.mean(logp, axis=-1)  # ε/K on every class
-        nll = (1.0 - label_smoothing) * nll + label_smoothing * uniform
-    return jnp.mean(nll)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32),
+                                   axis=-1)[:, 0]
+        if label_smoothing:
+            uniform = -jnp.mean(logp, axis=-1)  # ε/K on every class
+            nll = (1.0 - label_smoothing) * nll + label_smoothing * uniform
+        return jnp.mean(nll)

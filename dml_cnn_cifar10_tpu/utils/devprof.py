@@ -47,7 +47,7 @@ import os
 import re
 import sys
 import time
-from typing import List, Optional
+from typing import List, NamedTuple, Optional, Tuple
 
 #: Device-time buckets, in report order.
 DEVTIME_BUCKETS = ("compute", "collective", "infeed")
@@ -337,3 +337,310 @@ class DeviceStepEstimator:
         if steps <= 0:
             return None, drain_ms
         return round((drain_end - mark_t) / steps * 1e3, 4), drain_ms
+
+
+# ---------------------------------------------------------------------------
+# Instruction -> layer: the map from a compiled executable's optimized HLO
+# ---------------------------------------------------------------------------
+#
+# A device trace names an event after its HLO instruction (`fusion.393`,
+# `select-and-scatter.23`) and carries no name-scope path. The executable
+# does: every instruction's `metadata={op_name=...}` holds the path of
+# `jax.named_scope`s it was traced under, with the autodiff pass around
+# each component (`.../fwd_bwd/transpose(jvp(conv1))/conv_general_dilated`).
+# `scope_map` reads that once per compiled program; the join with a trace
+# is by instruction name (`benchmark/lib/scopes.py`, or by hand with the
+# `scopemap_<module>.json` written beside a capture).
+
+#: Layer kinds, by the LAST scope component that a row knows.
+LAYER_KINDS = (
+    ("conv", re.compile(r"^(conv\d*|shortcut)$")),
+    ("pool", re.compile(r"^pool\d*$")),
+    ("norm_act", re.compile(r"^(bn\d*|add)$")),
+    ("dense", re.compile(r"^(fc\d*|logits|loss)$")),
+    ("decode", re.compile(r"^(decode|index|gather)$")),
+    ("optimizer", re.compile(r"^optimizer$")),
+)
+PASSES = ("forward", "backward", "update", "other")
+
+_HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_HLO_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)")
+_HLO_CALLED_LIST = re.compile(
+    r"\b(branch_computations|called_computations)=\{([^}]*)\}")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_TRANSFORM = re.compile(r"^(jvp|transpose|vmap|remat|checkpoint|"
+                        r"custom_jvp|custom_vjp|shard_map)\((.*)\)$")
+_CALL = re.compile(r"^(jit|pjit|closed_call|core_call|custom_jvp_call|"
+                   r"custom_vjp_call)(\(.*\))?$")
+_PLUMBING = frozenset(("while", "body", "cond", "body_fun", "cond_fun",
+                       "branch", "scan", "checkpoint"))
+#: Instructions that move or name data and never decide what a fusion
+#: costs: left out when a fusion's layers are counted.
+_NO_WORK = frozenset((
+    "parameter", "constant", "broadcast", "bitcast", "tuple",
+    "get-tuple-element", "iota", "reshape", "copy", "convert", "transpose"))
+
+
+class ScopeEntry(NamedTuple):
+    """Where one instruction of an executable came from."""
+
+    scope: str       # the named scopes, outermost first, joined by "/"
+    kind: str        # a row of LAYER_KINDS, or "none"
+    pass_: str       # one of PASSES
+    mixed: bool      # a fusion whose instructions come from several layers
+    in_loop: bool    # an instruction of a `while` body or condition
+    inherited: bool = False   # an unnamed copy, named after its consumer
+
+
+def _split_path(op_name: str) -> List[str]:
+    """``a/jvp(b/c)/d`` -> ``[a, jvp(b/c), d]``: slashes at depth 0."""
+    parts, depth, cur = [], 0, []
+    for ch in op_name:
+        if ch == "/" and depth == 0:
+            parts.append("".join(cur))
+            cur = []
+            continue
+        depth += ch == "("
+        depth -= ch == ")"
+        cur.append(ch)
+    parts.append("".join(cur))
+    return [p for p in parts if p]
+
+
+def parse_op_name(op_name: str):
+    """An instruction's ``op_name`` -> ``(scope, kind, pass)``."""
+    # XLA joins the names of instructions it merged with ";".
+    comps = _split_path(op_name.split(";", 1)[0])
+    backward = any("transpose(" in c for c in comps)
+    relu = any("jit(relu)" in c for c in comps)
+    if comps and not _TRANSFORM.match(comps[-1]):
+        comps = comps[:-1]       # the primitive's own name
+    scopes: List[str] = []
+    for c in comps:
+        m = _TRANSFORM.match(c)
+        while m:
+            c = m.group(2)
+            m = _TRANSFORM.match(c)
+        for part in _split_path(c):
+            if not (_CALL.match(part) or part in _PLUMBING):
+                scopes.append(part)
+    kind = "none"
+    for part in reversed(scopes):
+        kind = next((k for k, pat in LAYER_KINDS if pat.match(part)), "none")
+        if kind != "none":
+            break
+    if kind == "none" and relu:
+        kind = "norm_act"        # a ReLU under no layer's scope
+    if backward:
+        pass_ = "backward"
+    elif "fwd_bwd" in scopes:
+        pass_ = "forward"
+    elif "optimizer" in scopes:
+        pass_ = "update"
+    else:
+        pass_ = "other"
+    return "/".join(scopes), kind, pass_
+
+
+class _Instr(NamedTuple):
+    name: str
+    opcode: str
+    root: bool
+    op_name: str
+    called: Tuple[Tuple[str, str], ...]    # (attribute, computation)
+    refs: Tuple[str, ...]                  # every %name the line mentions
+
+
+def _parse_hlo(text: str):
+    """``(module name, entry computation, {computation: [_Instr]})``."""
+    module, entry, comps, cur = "", None, {}, None
+    for line in text.splitlines():
+        if cur is None:
+            if line.startswith("HloModule "):
+                module = line.split()[1].rstrip(",")
+                continue
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                cur = comps.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        rest = m.group(3)
+        op = _HLO_OPCODE.search(" " + rest)
+        name = _HLO_OP_NAME.search(rest)
+        called = [(a, c) for a, c in _HLO_CALLED.findall(rest)]
+        for attr, names in _HLO_CALLED_LIST.findall(rest):
+            called += [(attr, c.strip().lstrip("%"))
+                       for c in names.split(",") if c.strip()]
+        cur.append(_Instr(m.group(2), op.group(1) if op else "",
+                          bool(m.group(1)), name.group(1) if name else "",
+                          tuple(called), tuple(_HLO_REF.findall(rest))))
+    return module, entry, comps
+
+
+#: Copies the compiler puts in (a prefetch into the fast memory space, a
+#: layout change) carry no metadata; their time belongs to the layer that
+#: consumes them. What such a copy may be followed through to its user:
+_COPIES = frozenset(("copy", "copy-start", "copy-done", "slice-start",
+                     "slice-done"))
+_THROUGH = _COPIES | frozenset(("bitcast", "reshape", "tuple",
+                                "get-tuple-element", "custom-call"))
+
+#: Attributes whose computation runs as instructions of its own (events
+#: of the trace), against `calls=` of a fusion and the scalar `to_apply`
+#: of a reduce, which run inside their instruction.
+_RUNS = {"while": ("body", "condition"), "call": ("to_apply",),
+         "conditional": ("true_computation", "false_computation",
+                         "branch_computations"),
+         "async-start": ("calls", "called_computations")}
+
+
+def scope_map_of_text(text: str):
+    """``(module name, {instruction name: ScopeEntry})`` for every
+    instruction that can be an event of a trace's ``XLA Ops`` line: those
+    of the entry computation, of ``while`` bodies and conditions, of called
+    computations and of conditional branches. A fusion goes by its own
+    metadata and, where that names no layer, by its root's (then by the
+    working instruction nearest the root that names one); one whose
+    working instructions come from more than one layer is ``mixed``. A
+    copy without metadata (the compiler's prefetches and layout changes)
+    takes the layer of the instruction that consumes it."""
+    module, entry, comps = _parse_hlo(text)
+
+    def layers_in(comp: str, found: list) -> None:
+        """``(scope, kind, pass)`` of the working instructions of a fused
+        computation that name a layer, nested fusions included, in the
+        order of the text (operands before their users)."""
+        for ins in comps.get(comp, ()):
+            if ins.opcode == "fusion":
+                for attr, c in ins.called:
+                    if attr == "calls":
+                        layers_in(c, found)
+            elif ins.opcode not in _NO_WORK and ins.op_name:
+                parsed = parse_op_name(ins.op_name)
+                if parsed[1] != "none":
+                    found.append(parsed)
+
+    def root_op_name(comp: str) -> str:
+        for ins in comps.get(comp, ()):
+            if ins.root:
+                if ins.op_name or ins.opcode != "fusion":
+                    return ins.op_name
+                return next((root_op_name(c) for a, c in ins.called
+                             if a == "calls"), "")
+        return ""
+
+    def inherit(comp: str) -> None:
+        """An unnamed copy takes the layer of what consumes it, through
+        other unnamed movers, a few hops down the same computation."""
+        users: dict = {}
+        opcode = {}
+        for ins in comps.get(comp, ()):
+            opcode[ins.name] = ins.opcode
+            for ref in ins.refs:
+                users.setdefault(ref, []).append(ins.name)
+        for ins in comps.get(comp, ()):
+            if ins.opcode not in _COPIES or out[ins.name].kind != "none":
+                continue
+            front, seen = [ins.name], {ins.name}
+            for _ in range(4):
+                nxt = [u for n in front for u in users.get(n, ())
+                       if u in out and u not in seen]
+                named = [u for u in nxt if out[u].kind != "none"]
+                if named:
+                    e = out[named[0]]
+                    out[ins.name] = e._replace(mixed=False, inherited=True)
+                    break
+                seen.update(nxt)
+                front = [u for u in nxt if opcode.get(u) in _THROUGH]
+                if not front:
+                    break
+
+    out: dict = {}
+    todo, done = [(entry, False)], set()
+    while todo:
+        comp, in_loop = todo.pop()
+        if comp is None or comp in done:
+            continue
+        done.add(comp)
+        for ins in comps.get(comp, ()):
+            scope, kind, pass_ = parse_op_name(ins.op_name)
+            mixed = False
+            if ins.opcode == "fusion":
+                fused = [c for a, c in ins.called if a == "calls"]
+                if kind == "none" and fused:
+                    scope, kind, pass_ = parse_op_name(
+                        root_op_name(fused[0]) or ins.op_name)
+                found: list = []
+                for c in fused:
+                    layers_in(c, found)
+                if kind == "none" and found:
+                    # a fusion the compiler made (a packed ReLU mask) whose
+                    # root carries no name: the layer nearest the root
+                    scope, kind, pass_ = found[-1]
+                mixed = len({f[0] for f in found}) > 1
+            out[ins.name] = ScopeEntry(scope, kind, pass_, mixed, in_loop)
+            for attr, c in ins.called:
+                if attr in _RUNS.get(ins.opcode, ()):
+                    todo.append((c, in_loop or ins.opcode == "while"))
+        inherit(comp)
+    return module, out
+
+
+def scope_map(compiled) -> dict:
+    """``{instruction name: ScopeEntry}`` of a compiled executable
+    (``jax.stages.Compiled``, or anything with ``as_text()``)."""
+    return scope_map_of_text(compiled.as_text())[1]
+
+
+_SCOPE_MAPS: dict = {}
+
+
+def scope_maps() -> dict:
+    """The maps this process has built, by HLO module name
+    (``jit_chunk_dev``, ``jit_ev``): what a trace of this process is
+    joined with."""
+    return dict(_SCOPE_MAPS)
+
+
+def clear_scope_maps() -> None:
+    _SCOPE_MAPS.clear()
+
+
+def register_scope_map(compiled, out_dir: Optional[str] = None,
+                       logger=None, step: int = 0) -> Optional[str]:
+    """Build the map of ``compiled``, keep it under its module's name,
+    write ``scopemap_<module>.json`` under ``out_dir`` (beside a profiler
+    capture) where one is given, and announce it with one ``scopemap``
+    record. Returns the module's name."""
+    module, entries = scope_map_of_text(compiled.as_text())
+    if not entries:
+        return None
+    _SCOPE_MAPS[module] = entries
+    path = None
+    if out_dir:
+        os.makedirs(out_dir, exist_ok=True)
+        path = os.path.join(out_dir, f"scopemap_{module}.json")
+        with open(path, "w") as f:
+            json.dump({"module": module, "instructions": {
+                name: {"scope": e.scope, "kind": e.kind, "pass": e.pass_,
+                       "mixed": e.mixed, "in_loop": e.in_loop,
+                       "inherited": e.inherited}
+                for name, e in entries.items()}}, f)
+    if logger is not None:
+        logger.log("scopemap", step=step, module=module,
+                   instructions=len(entries),
+                   mapped=sum(e.kind != "none" for e in entries.values()),
+                   mixed=sum(e.mixed for e in entries.values()), path=path)
+    return module

@@ -369,6 +369,16 @@ def _observe_record(kind: str, f: dict, reg: MetricsRegistry) -> None:
         reg.gauge("dml_goodput_total_seconds",
                   "Wall-clock seconds since the tracer epoch"
                   ).set(f.get("total_s"))
+    elif kind == "span":
+        # Where the host's time goes, live: every finished span of the
+        # telemetry stream (utils/telemetry.py), by name.
+        name = str(f.get("name"))
+        reg.counter("dml_span_seconds_total",
+                    "Seconds spent in finished host spans, by span name",
+                    labelnames=("name",)
+                    ).inc(f.get("dur_s") or 0.0, name=name)
+        reg.counter("dml_spans_total", "Finished host spans, by span name",
+                    labelnames=("name",)).inc(1, name=name)
     elif kind == "hbm":
         if f.get("available"):
             reg.gauge("dml_hbm_bytes_in_use",

@@ -59,34 +59,73 @@ def abstractify(tree):
                                                         None)), tree)
 
 
-def compiled_flops(jitted_fn, abstract_args) -> Optional[float]:
-    """FLOPs of one dispatch from XLA's cost analysis.
+@contextlib.contextmanager
+def metadata_keyed_compiles(on: bool = True):
+    """Within it, this thread's compiles look jax's persistent cache up
+    under a key that includes the program's metadata. By default jax
+    strips it from the key, so an executable loaded from the cache may
+    carry the name scopes of whoever compiled it first (an older
+    checkout's, which had none): fine to run, wrong to read
+    ``utils/devprof.scope_map`` from. Thread-local; a jax without the
+    option compiles as ever."""
+    state = None
+    if on:
+        try:
+            from jax._src import config as jax_config
+            state = getattr(jax_config,
+                            "compilation_cache_include_metadata_in_key",
+                            None)
+        except ImportError:
+            pass
+    if state is None:
+        yield
+        return
+    with state(True):
+        yield
+
+
+def compiled_with_flops(jitted_fn, abstract_args,
+                        exact_metadata: bool = False):
+    """``(flops, compiled)`` of one dispatch: XLA's cost-analysis FLOPs
+    and the compiled object they were read from (``None`` each where
+    there is none), so that a caller who also wants the executable's text
+    (``utils/devprof.scope_map``) does not compile a second time; such a
+    caller asks for ``exact_metadata`` (:func:`metadata_keyed_compiles`).
 
     A cache-wrapped function (``compilecache.CachedFunction``, or the
     resident-chunk partial's shim) serves the figure from the persistent
     compile cache — the already-obtained executable's analysis or the
-    entry's recorded one — with NO recompile. The bare AOT fallback
-    ``lower().compile()`` keeps its own executable cache and recompiles
-    (hundreds of ms to seconds for a real train step) even when the call
-    path already compiled, so the driver runs this on a background
-    thread, never inline in the step loop. None when the backend doesn't
-    report flops. On a TPU a failure to lower, compile or analyse is a
-    real error and is raised; off it (the CPU backend's analysis has
-    another shape and the figure is never published) it is None."""
+    entry's recorded one — with NO recompile, and hands on the executable
+    it obtained. The bare AOT fallback ``lower().compile()`` keeps its own
+    executable cache and recompiles (hundreds of ms to seconds for a real
+    train step) even when the call path already compiled, so the driver
+    runs this on a background thread, never inline in the step loop. On
+    a TPU a failure to lower, compile or analyse is a real error and is
+    raised; off it (the CPU backend's analysis has another shape and the
+    figure is never published) it is ``(None, None)``."""
     from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
     try:
         cached = getattr(jitted_fn, "cached_flops", None)
         flops = cached(abstract_args) if cached is not None else None
-        if not (flops and flops > 0):
-            cost = jitted_fn.lower(
-                *abstract_args).compile().cost_analysis()
-            flops = cost.get("flops", 0.0)
-        return float(flops) if flops and flops > 0 else None
+        if flops and flops > 0:
+            holder = getattr(jitted_fn, "cached", None) or jitted_fn
+            compiled = getattr(holder, "compiled", None)
+        else:
+            with metadata_keyed_compiles(exact_metadata):
+                compiled = jitted_fn.lower(*abstract_args).compile()
+            flops = compiled.cost_analysis().get("flops", 0.0)
+        return (float(flops) if flops and flops > 0 else None), compiled
     except Exception:
         if platform_lib.on_tpu():
             raise
-        return None
+        return None, None
+
+
+def compiled_flops(jitted_fn, abstract_args) -> Optional[float]:
+    """FLOPs of one dispatch from XLA's cost analysis; None when the
+    backend doesn't report flops (:func:`compiled_with_flops`)."""
+    return compiled_with_flops(jitted_fn, abstract_args)[0]
 
 
 def correct_stack_flops(f: float, depth: int, bf_counted: Optional[float],

@@ -6,20 +6,28 @@ boundary; this layer records WHERE THE WALL-CLOCK WENT and WHETHER THE RUN
 IS HEALTHY — the two questions a long multi-host job must answer without a
 profiler attached. Three coordinated pieces:
 
-- :class:`SpanTracer`: a ring-buffered context-manager tracer the driver
-  wraps around its host-loop phases (compile/first-dispatch, data wait,
-  dispatch enqueue, boundary drain, eval, checkpoint, preemption
-  allgather). Near-zero overhead when disabled — ``span()`` returns a
-  shared no-op context manager, no allocation, no clock read. Finished
-  spans export two ways: JSONL ``span`` records through the existing
-  ``MetricsLogger`` (:func:`flush_boundary`) and a Chrome trace-event file
-  (:meth:`SpanTracer.export_chrome_trace`) loadable in Perfetto alongside
-  the XLA trace from ``--profile_dir``.
+- :class:`SpanTracer`: a ring-buffered context-manager tracer, ONE span
+  stream over the whole of ``Trainer.fit``: set-up (``fit_setup`` and its
+  children), the loop's phases (compile/first-dispatch, data wait,
+  dispatch enqueue, the boundary's accuracy dispatch / drain / logging,
+  eval, checkpoint, preemption allgather), the FLOP-probe thread, the
+  collections of Python's garbage collector that can stall a loop
+  (``gc_gen<n>``), teardown.
+  Depth is kept per thread; a record names its thread where that is not
+  the loop's. Near-zero overhead when disabled — ``span()`` returns a
+  shared no-op context manager, no allocation, no clock read, no
+  profiler annotation, no collector hook. Finished spans export three
+  ways: JSONL ``span`` records through the existing ``MetricsLogger``
+  (:func:`flush_boundary`; microsecond resolution), a
+  ``jax.profiler.TraceAnnotation`` while the span is open (the host
+  phases appear in any profiler capture, on the profiler's clock), and
+  a Chrome trace-event file (:meth:`SpanTracer.export_chrome_trace`).
 - Goodput accounting: top-level spans carry a category
   (``compile`` / ``data`` / ``eval`` / ``checkpoint`` / ``sync``);
-  :meth:`SpanTracer.goodput` reports the fraction of wall-clock since the
-  tracer epoch spent in each, with productive training as the remainder —
-  so the categories sum to 1.0 by construction. Host-loop caveat: on the
+  :meth:`SpanTracer.goodput` reports the fraction of wall-clock since
+  :meth:`SpanTracer.start` (the loop's entry) spent in each, with
+  productive training as the remainder — so the categories sum to 1.0 by
+  construction. Host-loop caveat: on the
   async-dispatch paths a host-side data wait can overlap device compute,
   so ``data_frac`` is an upper bound on true device starvation.
 - :func:`hbm_stats`: per-process device-memory snapshot via
@@ -38,14 +46,22 @@ computed here — they are compiled into the step (``parallel/step.py``,
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
+import threading
 import time
 from typing import Optional
 
 # Category order pins the goodput report layout (train first, then the
 # overheads in rough size order for a typical run).
 GOODPUT_CATEGORIES = ("compile", "data", "eval", "checkpoint", "sync")
+
+# A collection of generation 0 or 1 shorter than this leaves no span: a
+# fit makes a thousand of them (90 us each) while it traces, compiles and
+# parses, and the boundary that flushed their records held the device idle
+# for 12 ms (PERF.md, PR 24). A full collection always leaves one.
+GC_SPAN_MIN_S = 1e-3
 
 
 class _NullSpan:
@@ -63,8 +79,17 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _trace_annotation(name: str):
+    """The profiler's own host event for a span: in any capture
+    (``--profile_dir``, ``--profile_at_steps``, a harness's) the phase is
+    an event of the host plane on the profiler's clock, above the device
+    operations it caused. A flag check when no capture is running."""
+    import jax
+    return jax.profiler.TraceAnnotation(name)
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "t0")
+    __slots__ = ("_tracer", "name", "cat", "t0", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: Optional[str]):
         self._tracer = tracer
@@ -72,20 +97,23 @@ class _Span:
         self.cat = cat
 
     def __enter__(self):
+        self._annotation = _trace_annotation(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
-        self._tracer._depth += 1
+        self._tracer._local.depth = self._tracer._depth + 1
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         tr = self._tracer
-        tr._depth -= 1
-        tr._record(self.name, self.cat, self.t0, t1 - self.t0, tr._depth)
+        depth = tr._local.depth = tr._depth - 1
+        self._annotation.__exit__(*exc)
+        tr._record(self.name, self.cat, self.t0, t1 - self.t0, depth)
         return False
 
 
 class SpanTracer:
-    """Ring-buffered host-loop span tracer + goodput aggregator.
+    """Ring-buffered host span tracer + goodput aggregator.
 
     ``with tracer.span("eval", cat="eval"): ...`` records one finished
     span. Only DEPTH-0 spans with a category count toward goodput —
@@ -95,25 +123,44 @@ class SpanTracer:
     Chrome export; ``drain()`` hands out (and forgets) the spans finished
     since the last drain so boundary flushes are incremental. Overflow is
     counted (``dropped``), never silent.
+
+    One tracer covers a whole ``fit``, its background threads included:
+    depth is kept per thread (a span of the FLOP-probe thread never shifts
+    the loop's nesting), and a record names its thread where that is not
+    the one the tracer was made on. Two clocks: span starts are relative
+    to ``_epoch`` (the tracer's creation, so set-up is inside it), the
+    goodput clock runs from :meth:`start` (where set-up ends).
     """
 
     def __init__(self, enabled: bool = True, max_spans: int = 65536):
         self.enabled = enabled
         self.max_spans = max_spans
         self.dropped = 0
-        self._depth = 0
-        # (name, cat, start_s, dur_s, depth) tuples; _ring feeds the
-        # Chrome export, _pending feeds the incremental JSONL flush.
+        self._local = threading.local()
+        self._home = threading.get_ident()
+        # (name, cat, start_s, dur_s, depth, thread) tuples; _ring feeds
+        # the Chrome export, _pending feeds the incremental JSONL flush.
         self._ring = collections.deque(maxlen=max_spans)
         self._pending = collections.deque(maxlen=max_spans)
         self._cat_secs = dict.fromkeys(GOODPUT_CATEGORIES, 0.0)
-        self._epoch = time.perf_counter()
+        self._epoch = self._goodput_epoch = time.perf_counter()
         self._wall_epoch = time.time()
+        # (logger, step) once the owning fit has made its last flush:
+        # a span that finishes later is logged by whoever finishes it.
+        self._sink = None
+        self.flushed_step = 0    # the step of the newest boundary flush
+
+    @property
+    def _depth(self) -> int:
+        """Open spans on the calling thread."""
+        return getattr(self._local, "depth", 0)
 
     def start(self) -> None:
-        """Reset the goodput epoch (call at loop entry, pre-compile)."""
-        self._epoch = time.perf_counter()
-        self._wall_epoch = time.time()
+        """Start the goodput clock and its attributed seconds anew (call
+        at loop entry, pre-compile). Span starts stay relative to the
+        tracer's creation."""
+        self._goodput_epoch = time.perf_counter()
+        self._cat_secs = dict.fromkeys(GOODPUT_CATEGORIES, 0.0)
 
     def span(self, name: str, cat: Optional[str] = None):
         if not self.enabled:
@@ -124,11 +171,17 @@ class SpanTracer:
         if len(self._ring) == self.max_spans \
                 or len(self._pending) == self.max_spans:
             self.dropped += 1
-        rec = (name, cat, t0 - self._epoch, dur, depth)
+        thread = None if threading.get_ident() == self._home \
+            else threading.current_thread().name
+        rec = (name, cat, t0 - self._epoch, dur, depth, thread)
         self._ring.append(rec)
-        self._pending.append(rec)
         if depth == 0 and cat is not None:
             self._cat_secs[cat] = self._cat_secs.get(cat, 0.0) + dur
+        sink = self._sink
+        if sink is None:
+            self._pending.append(rec)
+        else:
+            _log_span(sink[0], sink[1], rec)
 
     def add_secs(self, cat: str, secs: float) -> None:
         """Attribute externally-measured seconds to a goodput category
@@ -144,14 +197,52 @@ class SpanTracer:
             return
         self._cat_secs[cat] = self._cat_secs.get(cat, 0.0) + secs
 
+    def watch_gc(self) -> None:
+        """Record one span ``gc_gen<n>`` per collection of Python's
+        garbage collector (every full one; of generations 0 and 1 those
+        of :data:`GC_SPAN_MIN_S` or more), start to stop, on the thread
+        it runs on and at that thread's depth, until :meth:`close`.
+        Nothing is registered on a disabled tracer."""
+        if self.enabled and self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._local.gc_t0 = time.perf_counter()
+            return
+        t0 = getattr(self._local, "gc_t0", None)
+        if t0 is not None:
+            self._local.gc_t0 = None
+            dur = time.perf_counter() - t0
+            gen = info.get("generation")
+            if gen == 2 or dur >= GC_SPAN_MIN_S:
+                self._record(f"gc_gen{gen}", None, t0, dur, self._depth)
+
+    def close(self, logger=None) -> None:
+        """The owning ``fit`` is over: stop watching the collector, log
+        what finished since its last flush (under that flush's step), and
+        from here on let whoever finishes a span log it (the probe thread
+        outlives a short fit)."""
+        if not self.enabled:
+            return
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        if logger is not None:
+            self._sink = (logger, self.flushed_step)
+            for rec in self.drain():
+                _log_span(logger, self.flushed_step, rec)
+
     def drain(self) -> list:
         """Spans finished since the last drain (and forget them)."""
-        out = list(self._pending)
-        self._pending.clear()
-        return out
+        out = []
+        while True:
+            try:
+                out.append(self._pending.popleft())
+            except IndexError:
+                return out
 
     def goodput(self, now: Optional[float] = None) -> dict:
-        """Cumulative goodput breakdown since the epoch.
+        """Cumulative goodput breakdown since :meth:`start`.
 
         ``{total_s, train_frac, <cat>_frac...}`` — ``train_frac`` is the
         unattributed remainder (dispatch enqueue, boundary drain, host
@@ -159,7 +250,7 @@ class SpanTracer:
         executing training steps), so the fractions sum to 1.0 exactly.
         """
         total = max((now if now is not None else time.perf_counter())
-                    - self._epoch, 1e-9)
+                    - self._goodput_epoch, 1e-9)
         out = {"total_s": round(total, 4)}
         attributed = 0.0
         for cat in sorted(self._cat_secs):
@@ -174,20 +265,34 @@ class SpanTracer:
 
         Load in Perfetto (ui.perfetto.dev) or chrome://tracing — ``ts``
         is microseconds since the tracer epoch, so the host-loop lane
-        lines up with an XLA trace captured over the same run.
+        lines up with an XLA trace captured over the same run. A lane
+        (``tid``) is a depth of the loop's thread; another thread's
+        spans are left out (they reach a profiler capture as
+        ``TraceAnnotation``s).
         """
         events = [{"name": name, "ph": "X",
                    "ts": round(start * 1e6, 1),
                    "dur": round(dur * 1e6, 1),
                    "pid": pid, "tid": depth,
                    **({"cat": cat} if cat else {})}
-                  for name, cat, start, dur, depth in self._ring]
+                  for name, cat, start, dur, depth, thread in self._ring
+                  if thread is None]
         doc = {"traceEvents": events, "displayTimeUnit": "ms",
                "otherData": {"epoch_unix_s": round(self._wall_epoch, 3),
                              "dropped_spans": self.dropped}}
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         with open(path, "w") as f:
             json.dump(doc, f)
+
+
+def _log_span(logger, step: int, rec) -> None:
+    """One ``span`` record, times to the microsecond: the idle gaps they
+    are joined with are microseconds long."""
+    name, cat, start, dur, depth, thread = rec
+    logger.log("span", step=step, name=name, start_s=round(start, 6),
+               dur_s=round(dur, 6), depth=depth,
+               **({"cat": cat} if cat else {}),
+               **({"thread": thread} if thread else {}))
 
 
 def percentile(values, q: float):
@@ -268,10 +373,9 @@ def flush_boundary(tracer: SpanTracer, logger, step: int,
     already flushes. The engine may run even when the tracer is off —
     `train`/`fault` records still flow without ``--telemetry``."""
     if tracer.enabled:
-        for name, cat, start, dur, depth in tracer.drain():
-            logger.log("span", step=step, name=name,
-                       start_s=round(start, 4), dur_s=round(dur, 4),
-                       depth=depth, **({"cat": cat} if cat else {}))
+        tracer.flushed_step = step
+        for rec in tracer.drain():
+            _log_span(logger, step, rec)
         gp = tracer.goodput()
         if tracer.dropped:
             gp["dropped_spans"] = tracer.dropped

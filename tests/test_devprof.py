@@ -210,3 +210,260 @@ def test_profile_window_fail_open(tmp_path, capsys, monkeypatch):
     win.maybe_stop(5, drained=True)
     assert win.state == "done"
     assert "no parseable trace" in capsys.readouterr().err
+
+
+# ---------------------------------------------------------------------------
+# instruction -> layer: scope_map
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(chunk)/while/body/closed_call/fwd_bwd/jvp(conv1)/"
+     "conv_general_dilated", ("fwd_bwd/conv1", "conv", "forward")),
+    ("jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(conv1))/"
+     "conv_general_dilated", ("fwd_bwd/conv1", "conv", "backward")),
+    ("jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(pool1))/"
+     "select_and_scatter", ("fwd_bwd/pool1", "pool", "backward")),
+    ("jit(chunk)/while/body/closed_call/optimizer/sub",
+     ("optimizer", "optimizer", "update")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(stage3)/"
+     "jvp(block2)/jvp(conv2)/conv_general_dilated",
+     ("fwd_bwd/stage3/block2/conv2", "conv", "forward")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/"
+     "transpose(jvp(stage1/block0))/transpose(jvp(shortcut))/"
+     "transpose(jvp(bn))/mul",
+     ("fwd_bwd/stage1/block0/shortcut/bn", "norm_act", "backward")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(stage1/block0)/"
+     "jvp(shortcut)/add_any", ("fwd_bwd/stage1/block0/shortcut", "conv",
+                               "forward")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(stem)/jvp(bn)/"
+     "jit(relu)/max", ("fwd_bwd/stem/bn", "norm_act", "forward")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(head)/jvp(fc)/"
+     "dot_general", ("fwd_bwd/head/fc", "dense", "forward")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(head)/jvp(pool)/"
+     "reduce_sum", ("fwd_bwd/head/pool", "pool", "forward")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(loss)/"
+     "jit(log_softmax)/reduce_max", ("fwd_bwd/loss", "dense", "forward")),
+    ("jit(chunk_dev)/while/body/decode/_random_crop/nkw,nrwc->nrkc/"
+     "dot_general", ("decode/_random_crop/nkw,nrwc->nrkc", "decode",
+                     "other")),
+    ("jit(chunk_dev)/index/while/body/xor", ("index", "decode", "other")),
+    ("jit(chunk_dev)/gather/gather", ("gather", "decode", "other")),
+    ("jit(ev)/train_acc/conv1/conv_general_dilated",
+     ("train_acc/conv1", "conv", "other")),
+    # a ReLU under no layer's scope; an addition that is a primitive,
+    # not the `add` scope; plumbing; nothing at all
+    ("jit(chunk)/while/body/closed_call/fwd_bwd/jvp(jit(relu))/max",
+     ("fwd_bwd", "norm_act", "forward")),
+    ("jit(chunk)/while/body/closed_call/fwd_bwd/add",
+     ("fwd_bwd", "none", "forward")),
+    ("jit(chunk)/while/body/dynamic_slice", ("", "none", "other")),
+    ("", ("", "none", "other")),
+    # XLA joins the names of merged instructions with ";": the first
+    ("jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(loss))/mul;"
+     "fwd_bwd/transpose(jvp(loss))/broadcast_in_dim",
+     ("fwd_bwd/loss", "dense", "backward")),
+])
+def test_parse_op_name(op_name, want):
+    assert devprof.parse_op_name(op_name) == want
+
+
+_HLO = """HloModule jit_chunk, is_scheduled=true
+
+%fused_relu (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  %c0 = f32[] constant(0), metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(loss))/jit(log_softmax)"}
+  %b0 = f32[8]{0} broadcast(%c0), dimensions={}
+  ROOT %max.1 = f32[8]{0} maximum(%p0, %b0), metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(conv1)/jit(relu)/max"}
+}
+
+%fused_pool (p0.1: f32[8]) -> f32[4] {
+  %p0.1 = f32[8]{0} parameter(0)
+  %relu_fusion.1 = f32[8]{0} fusion(%p0.1), kind=kLoop, calls=%fused_relu, metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(conv1)/jit(relu)/max"}
+  ROOT %rw.1 = f32[4]{0} reduce-window(%relu_fusion.1), to_apply=%region_max, metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(pool1)/reduce_window_max"}
+}
+
+%region_max (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %m = f32[] maximum(%a, %b), metadata={op_name="reduce_window_max"}
+}
+
+%fused_mask (p0.2: f32[8]) -> u32[1] {
+  %p0.2 = f32[8]{0} parameter(0)
+  %gt.1 = pred[8]{0} compare(%p0.2, %p0.2), direction=GT, metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(conv1)/gt"}
+  %cv.1 = u32[8]{0} convert(%gt.1)
+  ROOT %red.1 = u32[1]{0} reduce(%cv.1), to_apply=%region_max
+}
+
+%body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  %gte.1 = f32[8]{0} get-tuple-element(%t), index=1
+  %copy-start.9 = (f32[8]{0}, f32[8]{0:S(1)}, u32[]) copy-start(%gte.1)
+  %copy-done.9 = f32[8]{0:S(1)} copy-done(%copy-start.9)
+  %copy.3 = f32[8]{0} copy(%gte.1)
+  %conv.1 = f32[8]{0} convolution(%copy-done.9, %gte.1), dim_labels=b0f_0io->b0f, metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(conv1)/conv_general_dilated" stack_frame_id=8}
+  %fusion.7 = f32[4]{0} fusion(%conv.1), kind=kOutput, calls=%fused_pool, metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(pool1)/reduce_window_max"}
+  %mask_fusion.2 = u32[1]{0} fusion(%conv.1), kind=kLoop, calls=%fused_mask
+  %sas.3 = f32[8]{0} select-and-scatter(%conv.1, %fusion.7), select=%region_max, scatter=%region_max, metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(pool1))/select_and_scatter"}
+  %optimizer.5 = f32[8]{0} custom-call(%sas.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(chunk)/while/body/closed_call/optimizer/pallas_call"}
+  %call.1 = f32[8]{0} call(%optimizer.5), to_apply=%called
+  ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%copy.3, %call.1)
+}
+
+%called (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT %neg.1 = f32[8]{0} negate(%x), metadata={op_name="jit(chunk)/while/body/closed_call/fwd_bwd/jvp(loss)/neg"}
+}
+
+%cond (t.1: (s32[], f32[8])) -> pred[] {
+  %t.1 = (s32[], f32[8]{0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+%never_called (y: f32[8]) -> f32[8] {
+  ROOT %y = f32[8]{0} parameter(0)
+}
+
+ENTRY %main (arg: f32[8]) -> f32[8] {
+  %arg = f32[8]{0} parameter(0)
+  %fusion.7.entry = f32[8]{0} copy(%arg), metadata={op_name="jit(chunk)/index/xor"}
+  %tuple.0 = (s32[], f32[8]{0}) tuple(%arg, %fusion.7.entry)
+  %while.1 = (s32[], f32[8]{0}) while(%tuple.0), condition=%cond, body=%body, metadata={op_name="jit(chunk)/while"}
+  ROOT %gte.2 = f32[8]{0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_scope_map_of_text_walks_what_runs_and_flags_mixed_fusions():
+    module, m = devprof.scope_map_of_text(_HLO)
+    assert module == "jit_chunk"
+    # every instruction of the entry, the while's body and condition and
+    # the called computation; none of a fused computation, a scalar
+    # region or a computation nothing runs
+    assert set(m) == {
+        "arg", "fusion.7.entry", "tuple.0", "while.1", "gte.2",
+        "t", "gte.1", "copy-start.9", "copy-done.9", "copy.3", "conv.1",
+        "fusion.7", "mask_fusion.2", "sas.3",
+        "optimizer.5", "call.1", "tuple.1", "x", "neg.1", "t.1", "lt.1"}
+    def five(name):          # the entry without its `inherited` flag
+        return m[name][:5]
+
+    assert five("conv.1") == ("fwd_bwd/conv1", "conv", "forward", False, True)
+    assert five("sas.3") == ("fwd_bwd/pool1", "pool", "backward", False, True)
+    assert m["optimizer.5"][:3] == ("optimizer", "optimizer", "update")
+    # conv1's ReLU (a nested fusion) under pool1's window: the root's
+    # layer, flagged; the shared constant of another layer does not count
+    assert five("fusion.7") == ("fwd_bwd/pool1", "pool", "forward", True, True)
+    # no metadata on the fusion or its root: the working instruction
+    assert five("mask_fusion.2") == ("fwd_bwd/conv1", "conv", "forward",
+                                  False, True)
+    # a copy the compiler put in carries no name: a prefetch is its
+    # consumer's, one that only the loop's carry takes is nobody's
+    assert m["copy-start.9"] == m["copy-done.9"] \
+        == m["conv.1"]._replace(inherited=True)
+    assert not m["conv.1"].inherited and not m["copy.3"].inherited
+    assert five("copy.3") == ("", "none", "other", False, True)
+    # a called computation inside the loop is in the loop
+    assert five("neg.1") == ("fwd_bwd/loss", "dense", "forward", False, True)
+    assert five("fusion.7.entry") == ("index", "decode", "other", False, False)
+    assert m["while.1"].kind == "none" and not m["while.1"].in_loop
+    assert m["lt.1"].in_loop
+
+
+def _small_chunk():
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def loss_fn(p, x, y):
+        with jax.named_scope("conv1"):
+            x = jax.nn.relu(lax.conv_general_dilated(
+                x, p["c"], (1, 1), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["cb"])
+        with jax.named_scope("pool1"):
+            x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1),
+                                  (1, 2, 2, 1), "SAME")
+        with jax.named_scope("fc1"):
+            logits = x.reshape(x.shape[0], -1) @ p["w"] + p["b"]
+        with jax.named_scope("loss"):
+            return -jnp.mean(jnp.sum(jax.nn.log_softmax(logits)
+                                     * jax.nn.one_hot(y, 10), -1))
+
+    def step(p, batch):
+        with jax.named_scope("fwd_bwd"):
+            loss, g = jax.value_and_grad(loss_fn)(p, *batch)
+        with jax.named_scope("optimizer"):
+            p = jax.tree.map(lambda a, b: a - 0.1 * b, p, g)
+        return p, loss
+
+    def chunk(p, xs, ys):
+        return lax.scan(step, p, (xs, ys))
+
+    f32 = jnp.float32
+    p = {"c": jax.ShapeDtypeStruct((3, 3, 3, 8), f32),
+         "cb": jax.ShapeDtypeStruct((8,), f32),
+         "w": jax.ShapeDtypeStruct((4 * 4 * 8, 10), f32),
+         "b": jax.ShapeDtypeStruct((10,), f32)}
+    xs = jax.ShapeDtypeStruct((3, 4, 8, 8, 3), f32)
+    ys = jax.ShapeDtypeStruct((3, 4), jnp.int32)
+    return jax.jit(chunk).lower(p, xs, ys).compile()
+
+
+def test_scope_map_of_a_compiled_scan_of_steps():
+    compiled = _small_chunk()
+    m = devprof.scope_map(compiled)
+    found = {(e.scope, e.kind, e.pass_) for e in m.values() if e.in_loop}
+    assert ("fwd_bwd/conv1", "conv", "forward") in found
+    assert ("fwd_bwd/conv1", "conv", "backward") in found
+    assert ("fwd_bwd/pool1", "pool", "backward") in found
+    assert ("optimizer", "optimizer", "update") in found
+    assert any(kind == "dense" for _, kind, _ in found)
+    # every instruction of the computations that run is accounted for:
+    # the text's own count of them
+    _, entry, comps = devprof._parse_hlo(compiled.as_text())
+    runs, todo = set(), [entry]
+    while todo:
+        c = todo.pop()
+        if c in runs:
+            continue
+        runs.add(c)
+        for ins in comps[c]:
+            todo += [t for a, t in ins.called
+                     if a in devprof._RUNS.get(ins.opcode, ())]
+    assert len(runs) >= 3         # entry, the scan's body and condition
+    assert set(m) == {ins.name for c in runs for ins in comps[c]}
+    assert sum(e.kind != "none" for e in m.values()) >= 8
+
+
+def test_register_scope_map_keeps_writes_and_announces(tmp_path):
+    devprof.clear_scope_maps()
+    seen = []
+
+    class Log:
+        def log(self, kind, **fields):
+            seen.append((kind, fields))
+
+    class Compiled:
+        def as_text(self):
+            return _HLO
+
+    out = str(tmp_path / "prof")
+    assert devprof.register_scope_map(Compiled(), out, logger=Log(),
+                                      step=40) == "jit_chunk"
+    assert set(devprof.scope_maps()) == {"jit_chunk"}
+    ((kind, rec),) = seen
+    assert kind == "scopemap" and rec["step"] == 40
+    assert (rec["module"], rec["instructions"], rec["mixed"]) \
+        == ("jit_chunk", 21, 1)
+    assert rec["mapped"] == 9
+    assert rec["path"] == os.path.join(out, "scopemap_jit_chunk.json")
+    with open(rec["path"]) as f:
+        doc = json.load(f)
+    assert doc["instructions"]["sas.3"] == {
+        "scope": "fwd_bwd/pool1", "kind": "pool", "pass": "backward",
+        "mixed": False, "in_loop": True, "inherited": False}
+    # no capture directory: kept and announced, nothing written
+    devprof.register_scope_map(Compiled(), None, logger=Log(), step=41)
+    assert seen[-1][1]["path"] is None
+    devprof.clear_scope_maps()
+    assert devprof.scope_maps() == {}

@@ -99,3 +99,41 @@ def test_bfloat16_compute_path():
     assert logits.dtype == jnp.float32             # outputs upcast for loss
     ref = cnn.apply(params, x, ModelConfig())
     np.testing.assert_allclose(logits, ref, rtol=0.1, atol=2.0)
+
+
+def _scopes_off(monkeypatch):
+    import contextlib
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+
+
+CNN_SCOPES = ("conv1", "pool1", "conv2", "pool2", "fc1", "fc2", "logits")
+
+
+def test_lowered_text_holds_every_layer_scope():
+    """Each layer of the model is under its own ``jax.named_scope``: the
+    names reach the lowered program's locations, which is where
+    ``utils/devprof.scope_map`` reads a compiled instruction's layer."""
+    from dml_cnn_cifar10_tpu.utils import devprof
+
+    cfg, data = ModelConfig(logit_relu=False), DataConfig()
+    params = cnn.init_params(jax.random.key(0), cfg, data)
+    x = jnp.zeros((2, data.crop_height, data.crop_width, 3), jnp.float32)
+    text = jax.jit(lambda p, x: cnn.apply(p, x, cfg)).lower(
+        params, x).as_text(debug_info=True)
+    for scope in CNN_SCOPES:
+        assert f"/{scope}/" in text or f"/{scope}\"" in text, scope
+        # and the table of kinds knows each of them
+        assert devprof.parse_op_name(f"jit(f)/{scope}/op")[1] != "none"
+
+
+def test_logits_are_bit_equal_without_the_scopes(monkeypatch):
+    """Scopes are metadata: no numeric effect."""
+    cfg, data = ModelConfig(logit_relu=False), DataConfig()
+    params = cnn.init_params(jax.random.key(0), cfg, data)
+    x = jnp.asarray(np.random.default_rng(0).normal(
+        size=(4, data.crop_height, data.crop_width, 3)), jnp.float32)
+    with_scopes = np.asarray(cnn.apply(params, x, cfg))
+    _scopes_off(monkeypatch)
+    without = np.asarray(cnn.apply(params, x, cfg))
+    assert np.array_equal(with_scopes, without)

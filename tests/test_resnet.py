@@ -273,3 +273,51 @@ def test_nf_weight_standardization_properties():
     var = np.asarray(jnp.var(ws, axis=(0, 1, 2)))
     fan_in = 3 * 3 * 16
     np.testing.assert_allclose(var, 1.5**2 / fan_in, rtol=1e-3)
+
+
+def test_lowered_text_holds_every_layer_scope():
+    """Stem, every block's convolutions, batch norms, shortcut and sum,
+    and the head are each under a ``jax.named_scope`` (tracing only: the
+    ImageNet stem at 72 px, bottlenecks)."""
+    from dml_cnn_cifar10_tpu.utils import devprof
+
+    cfg = ModelConfig(name="resnet50", num_classes=10, logit_relu=False)
+    data = DataConfig(crop_height=72, crop_width=72)
+    params = jax.eval_shape(
+        lambda k: resnet.init_params(k, cfg, data, depth=50),
+        jax.random.key(0))
+    state = jax.eval_shape(resnet.init_state, params)
+    x = jax.ShapeDtypeStruct((2, 72, 72, 3), jnp.float32)
+    text = jax.jit(lambda p, s, x: resnet.apply(p, s, x, cfg, train=True)
+                   ).lower(params, state, x).as_text(debug_info=True)
+    want = ["stem/conv", "stem/bn", "stem/pool", "head/pool", "head/fc"]
+    for si, n in enumerate((3, 4, 6, 3), start=1):
+        for bi in range(n):
+            want += [f"stage{si}/block{bi}/{leaf}" for leaf in (
+                "conv1", "bn1", "conv2", "bn2", "conv3", "bn3", "add")]
+        want += [f"stage{si}/block0/shortcut/conv",
+                 f"stage{si}/block0/shortcut/bn"]
+    for scope in want:
+        assert f"/{scope}/" in text, scope
+        assert devprof.parse_op_name(f"jit(f)/{scope}/op")[1] != "none"
+    assert "stage1/block1/shortcut" not in text
+    kinds = {devprof.parse_op_name(f"jit(f)/{s}/op")[1] for s in want}
+    assert kinds == {"conv", "norm_act", "pool", "dense"}
+
+
+def test_logits_are_bit_equal_without_the_scopes(monkeypatch):
+    """Scopes are metadata: no numeric effect, on logits or BN state."""
+    import contextlib
+
+    cfg, data = _cfgs()
+    params = resnet.init_params(jax.random.key(0), cfg, data, depth=18)
+    state = resnet.init_state(params)
+    images, _ = _batch(np.random.default_rng(0), n=2, hw=16)
+    run = jax.jit(lambda p, s, x: resnet.apply(p, s, x, cfg, train=True))
+    with_scopes = run(params, state, jnp.asarray(images))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    run = jax.jit(lambda p, s, x: resnet.apply(p, s, x, cfg, train=True))
+    without = run(params, state, jnp.asarray(images))
+    assert all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(with_scopes), jax.tree.leaves(without)))

@@ -37,8 +37,9 @@ def test_span_nesting_and_drain(monkeypatch):
     spans = tr.drain()
     # Inner finishes first; depth recorded at entry.
     assert [(s[0], s[4]) for s in spans] == [("inner", 1), ("outer", 0)]
-    name, cat, start, dur, depth = spans[1]
+    name, cat, start, dur, depth, thread = spans[1]
     assert cat == "eval" and start == 0.0 and dur == pytest.approx(2.0)
+    assert thread is None      # the tracer's own thread is not named
     # drain() forgets — a second drain is empty; the ring retains.
     assert tr.drain() == []
     assert len(tr._ring) == 2
@@ -346,3 +347,352 @@ def test_check_jsonl_schema_catches_violations(tmp_path):
     p.write_text(json.dumps(good) + "\n")
     assert lint.check_file(str(p), strict=True) == []
     assert lint.main(["--strict", str(p)]) == 0
+
+
+# ---------------------------------------------------------------------------
+# One span stream over the whole of fit (threads, the collector, set-up)
+# ---------------------------------------------------------------------------
+
+def test_a_second_threads_spans_leave_the_loops_depths_alone():
+    import threading
+
+    tr = SpanTracer(enabled=True)
+    inside = threading.Event()
+    done = threading.Event()
+
+    def other():
+        with tr.span("flops_probe"):
+            with tr.span("probe_compile_dispatch"):
+                inside.set()
+                done.wait(5)
+
+    t = threading.Thread(target=other, name="flops-probe")
+    with tr.span("outer"):
+        t.start()
+        assert inside.wait(5)
+        # two spans are open on the other thread: this thread's depth is
+        # still its own
+        with tr.span("inner"):
+            assert tr._depth == 2
+        done.set()
+        t.join(5)
+    assert tr._depth == 0
+    recs = {r[0]: r for r in tr.drain()}
+    assert (recs["inner"][4], recs["outer"][4]) == (1, 0)
+    assert (recs["flops_probe"][4], recs["probe_compile_dispatch"][4]) \
+        == (0, 1)
+    assert recs["flops_probe"][5] == "flops-probe"
+    assert recs["outer"][5] is None and recs["inner"][5] is None
+
+
+def test_span_records_keep_microseconds_and_name_their_thread(monkeypatch):
+    import threading
+
+    clock = _FakeClock()
+    monkeypatch.setattr(time, "perf_counter", clock)
+    seen = []
+
+    class Log:
+        def log(self, kind, **fields):
+            seen.append((kind, fields))
+
+    tr = SpanTracer(enabled=True)
+    clock.t = 0.001234
+    with tr.span("boundary_drain"):
+        clock.t += 0.000085
+    t = threading.Thread(target=lambda: tr.span("late").__enter__()
+                         .__exit__(None, None, None), name="worker-7")
+    t.start()
+    t.join()
+    flush_boundary(tr, Log(), step=3)
+    spans = [f for k, f in seen if k == "span"]
+    assert spans[0]["start_s"] == 0.001234 and spans[0]["dur_s"] == 0.000085
+    assert "thread" not in spans[0] and spans[1]["thread"] == "worker-7"
+    assert tr.flushed_step == 3
+
+
+def test_goodput_of_a_fixed_run_is_what_it_was(monkeypatch):
+    """The goodput clock starts at ``start()`` (where set-up ends), not
+    at the tracer's creation: set-up spans, uncategorized loop spans, the
+    probe's and the collector's change no fraction."""
+    clock = _FakeClock()
+    monkeypatch.setattr(time, "perf_counter", clock)
+    tr = SpanTracer(enabled=True)
+    with tr.span("fit_setup"):
+        with tr.span("build_iterators"):
+            clock.t += 7.0
+    tr.add_secs("compile", 3.0)      # attributed before the loop: dropped
+    tr.start()
+    with tr.span("compile_first_dispatch", cat="compile"):
+        clock.t += 2.0
+    with tr.span("data_wait", cat="data"):
+        clock.t += 1.0
+    with tr.span("boundary_acc_dispatch"):
+        clock.t += 0.5
+    with tr.span("boundary_drain"):
+        tr._on_gc("start", {"generation": 2})
+        clock.t += 5.0
+        tr._on_gc("stop", {"generation": 2})
+    with tr.span("boundary_log"):
+        clock.t += 0.5
+    with tr.span("checkpoint", cat="checkpoint"):
+        clock.t += 1.0
+    assert tr.goodput() == {
+        "total_s": 10.0, "checkpoint_frac": 0.1, "compile_frac": 0.2,
+        "data_frac": 0.1, "eval_frac": 0.0, "sync_frac": 0.0,
+        "train_frac": 0.6}
+    recs = {r[0]: r for r in tr.drain()}
+    # span starts stay on the tracer's own clock, set-up included
+    assert recs["fit_setup"][2] == 0.0
+    assert recs["compile_first_dispatch"][2] == pytest.approx(7.0)
+    # the collection is a span inside the drain, one level down
+    assert recs["gc_gen2"][3] == pytest.approx(5.0)
+    assert recs["gc_gen2"][4] == 1 and recs["boundary_drain"][4] == 0
+
+
+def test_gc_hook_is_registered_only_while_an_enabled_tracer_watches():
+    import gc
+
+    before = list(gc.callbacks)
+    off = SpanTracer(enabled=False)
+    off.watch_gc()
+    assert gc.callbacks == before
+    on = SpanTracer(enabled=True)
+    on.watch_gc()
+    on.watch_gc()
+    assert len(gc.callbacks) == len(before) + 1
+    gc.collect()
+    assert any(r[0] == "gc_gen2" for r in on.drain())
+    on.close()
+    assert gc.callbacks == before
+
+
+def test_short_young_collections_leave_no_span(monkeypatch):
+    """A fit makes a thousand collections of generation 0 in its set-up;
+    only those long enough to stall a loop, and every full one, are
+    spans."""
+    clock = _FakeClock()
+    monkeypatch.setattr(time, "perf_counter", clock)
+    tr = SpanTracer(enabled=True)
+    for gen, dur in ((0, 9e-5), (1, 5e-4), (0, 2e-3), (1, 1e-3), (2, 1e-5)):
+        tr._on_gc("start", {"generation": gen})
+        clock.t += dur
+        tr._on_gc("stop", {"generation": gen})
+    assert [(r[0], round(r[3], 6)) for r in tr.drain()] == [
+        ("gc_gen0", 2e-3), ("gc_gen1", 1e-3), ("gc_gen2", 1e-5)]
+
+
+def test_registry_counts_span_seconds_and_spans_by_name():
+    from dml_cnn_cifar10_tpu.utils import metrics_registry as mr
+
+    reg = mr.MetricsRegistry()
+    for dur in (0.25, 0.5):
+        mr.observe_record("span", {"step": 1, "name": "fit_setup",
+                                   "start_s": 0.0, "dur_s": dur,
+                                   "depth": 0}, registry=reg)
+    mr.observe_record("span", {"step": 1, "name": "checkpoint",
+                               "start_s": 1.0, "dur_s": 2.0, "depth": 0,
+                               "cat": "checkpoint"}, registry=reg)
+    secs = reg.get("dml_span_seconds_total").values()
+    count = reg.get("dml_spans_total").values()
+    assert secs == {("fit_setup",): 0.75, ("checkpoint",): 2.0}
+    assert count == {("fit_setup",): 2.0, ("checkpoint",): 1.0}
+    text = reg.render()
+    assert 'dml_span_seconds_total{name="fit_setup"} 0.75' in text
+    assert mr.parse_prometheus_text(text)["dml_spans_total"]["samples"][
+        (("name", "checkpoint"),)] == 1.0
+
+
+def _resident_fit(data_cfg, tmp_path, sub, telemetry, total_steps=8,
+                  profile_dir=None):
+    import threading
+
+    from dml_cnn_cifar10_tpu.train.loop import Trainer
+
+    cfg = tiny_train_cfg(data_cfg, str(tmp_path / sub),
+                         total_steps=total_steps, output_every=4,
+                         eval_every=8, checkpoint_every=8)
+    cfg.steps_per_dispatch = 2
+    cfg.telemetry = telemetry
+    cfg.profile_dir = profile_dir
+    cfg.metrics_jsonl = os.path.join(str(tmp_path / sub), "m.jsonl")
+    before = set(threading.enumerate())
+    trainer = Trainer(cfg)
+    result = trainer.fit()
+    for t in set(threading.enumerate()) - before:
+        t.join(timeout=300)
+    trainer.logger.close()
+    with open(cfg.metrics_jsonl) as f:
+        return trainer, result, [json.loads(line) for line in f]
+
+
+def test_fit_spans_cover_setup_probe_boundary_teardown_and_gc(
+        data_cfg, tmp_path, monkeypatch):
+    import gc
+
+    from dml_cnn_cifar10_tpu.utils import devprof
+    from dml_cnn_cifar10_tpu.utils import telemetry as telemetry_lib
+
+    devprof.clear_scope_maps()
+    real_record = SpanTracer._record
+    collected = []
+
+    def record_and_collect(self, name, cat, t0, dur, depth):
+        # a full collection inside the loop, while its spans are open
+        if name == "dispatch" and not collected:
+            collected.append(gc.collect())
+        return real_record(self, name, cat, t0, dur, depth)
+
+    monkeypatch.setattr(SpanTracer, "_record", record_and_collect)
+    callbacks_before = list(gc.callbacks)
+    trainer, result, recs = _resident_fit(data_cfg, tmp_path, "on", True)
+    assert result.final_step == 8
+    assert gc.callbacks == callbacks_before       # the hook is gone again
+    spans = [r for r in recs if r["kind"] == "span"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert {"fit_setup", "build_iterators", "place_resident", "build_step",
+            "compile_first_dispatch", "dispatch", "data_wait",
+            "boundary_acc_dispatch", "boundary_drain", "boundary_log",
+            "eval", "checkpoint", "fit_teardown", "flops_probe",
+            "probe_compile_dispatch", "probe_scope_map"} <= set(by_name)
+    assert any(n.startswith("gc_gen") for n in by_name)
+    # set-up's children lie inside it, one level down; the boundary's
+    # three are siblings at the loop's depth, in order
+    (setup,) = by_name["fit_setup"]
+    assert setup["depth"] == 0 and setup["start_s"] < 0.01
+    for child in ("build_iterators", "place_resident", "build_step"):
+        (c,) = by_name[child]
+        assert c["depth"] == 1 and c["start_s"] >= setup["start_s"]
+        assert c["start_s"] + c["dur_s"] \
+            <= setup["start_s"] + setup["dur_s"] + 1e-5
+    acc, drain, log = (by_name[n][0] for n in (
+        "boundary_acc_dispatch", "boundary_drain", "boundary_log"))
+    assert acc["depth"] == drain["depth"] == log["depth"] == 0
+    assert acc["start_s"] + acc["dur_s"] <= drain["start_s"] + 1e-5
+    assert drain["start_s"] + drain["dur_s"] <= log["start_s"] + 1e-5
+    # the probe's spans name their thread and keep their own depths
+    (probe,) = by_name["flops_probe"]
+    assert probe["thread"] == "flops-probe" and probe["depth"] == 0
+    assert by_name["probe_scope_map"][0]["depth"] == 1
+    assert "thread" not in drain
+    # every start is on the tracer's clock: epoch + start_s is the
+    # span's perf_counter start (what benchmark/lib/driver.py reads)
+    assert all(isinstance(s["start_s"], float) and s["start_s"] >= 0
+               for s in spans)
+    # goodput keeps its keys, and its clock starts after set-up
+    final = [r for r in recs if r["kind"] == "goodput" and r.get("final")]
+    assert set(final[-1]) == {
+        "kind", "t", "task", "step", "total_s", "train_frac", "final",
+        *(f"{c}_frac" for c in GOODPUT_CATEGORIES)}
+    end = max(s["start_s"] + s["dur_s"] for s in spans
+              if s["name"] == "checkpoint")
+    assert final[-1]["total_s"] <= end - setup["dur_s"] + 0.5
+    # the scope maps: one record a program, the maps kept by module
+    maps = [r for r in recs if r["kind"] == "scopemap"]
+    assert {m["module"] for m in maps} == set(devprof.scope_maps())
+    assert len(maps) == 2 and all(m["path"] is None for m in maps)
+    assert all(0 < m["mapped"] <= m["instructions"] for m in maps)
+    from tools import check_jsonl_schema
+    assert check_jsonl_schema.check_lines(
+        [json.dumps(r) for r in recs], strict=True) == []
+
+
+def test_telemetry_off_builds_and_registers_nothing(data_cfg, tmp_path,
+                                                    monkeypatch):
+    import gc
+
+    from dml_cnn_cifar10_tpu.utils import devprof
+    from dml_cnn_cifar10_tpu.utils import telemetry as telemetry_lib
+
+    devprof.clear_scope_maps()
+    annotations = []
+    monkeypatch.setattr(telemetry_lib, "_trace_annotation",
+                        lambda name: annotations.append(name))
+    appended = []
+    real_callbacks = gc.callbacks
+
+    class Watched(list):
+        def append(self, cb):
+            appended.append(cb)
+            super().append(cb)
+
+    monkeypatch.setattr(gc, "callbacks", Watched(real_callbacks))
+    trainer, result, recs = _resident_fit(data_cfg, tmp_path, "off", False)
+    assert result.final_step == 8
+    assert not appended, "no gc callback without telemetry"
+    assert not annotations, "no TraceAnnotation without telemetry"
+    assert devprof.scope_maps() == {}, "no scope map without telemetry"
+    kinds = {r["kind"] for r in recs}
+    assert not kinds & {"span", "scopemap", "goodput", "hbm"}
+    assert {"train", "eval", "done"} <= kinds
+    assert trainer._tracer.span("x") is trainer._tracer.span("y")
+
+
+def test_a_one_dispatch_fit_still_logs_its_probe_span(data_cfg, tmp_path):
+    """The warm-up fit of one dispatch makes its last flush before its
+    probe thread ends: the span is logged by whoever finishes it."""
+    trainer, result, recs = _resident_fit(data_cfg, tmp_path, "one", True,
+                                          total_steps=2)
+    assert result.final_step == 2
+    names = [r["name"] for r in recs if r["kind"] == "span"]
+    assert names.count("flops_probe") == 1
+    assert "fit_teardown" in names and "fit_setup" in names
+    # fit_teardown ends after the final flush by construction: it is in
+    # the stream all the same, after the final goodput record
+    final_at = max(i for i, r in enumerate(recs)
+                   if r["kind"] == "goodput" and r.get("final"))
+    teardown_at = next(i for i, r in enumerate(recs) if r["kind"] == "span"
+                       and r["name"] == "fit_teardown")
+    assert teardown_at > final_at
+
+
+def test_report_names_the_span_that_holds_a_slow_intervals_excess(tmp_path):
+    """tools/telemetry_report.py, "slowest boundary interval": the
+    interval's length against the median and the spans inside it."""
+    from tools import telemetry_report
+
+    recs = []
+
+    def span(name, start, dur, step, depth=0, **kw):
+        recs.append({"kind": "span", "t": start + dur, "task": 0,
+                     "step": step, "name": name, "start_s": start,
+                     "dur_s": dur, "depth": depth, **kw})
+
+    # a warm-up fit with one boundary, then a fit of six intervals of
+    # 3 s; in the fifth a full collection inside the drain adds 1.5 s
+    span("boundary_drain", 5.0, 1.0, 6)
+    t = 0.0
+    span("fit_setup", 0.0, 2.0, 0)
+    t = 2.0
+    for i in range(7):
+        stall = 1.5 if i == 5 else 0.0
+        span("boundary_log", t, 0.01, 30 * i)
+        span("dispatch", t + 0.01, 0.002, 30 * i)
+        span("boundary_acc_dispatch", t + 0.02, 0.003, 30 * (i + 1))
+        if stall:
+            span("gc_gen2", t + 1.0, stall, 30 * (i + 1), depth=1)
+        span("boundary_drain", t + 0.023, 2.977 + stall, 30 * (i + 1))
+        t += 3.0 + stall
+    path = tmp_path / "m.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in recs))
+    slow = telemetry_report.summarize_json(str(path))["slowest_boundary"]
+    assert slow["intervals"] == 6 and slow["step"] == 180
+    assert slow["secs"] == pytest.approx(4.5)
+    assert slow["median_secs"] == pytest.approx(3.0)
+    assert slow["ratio"] == pytest.approx(1.5)
+    top = slow["spans"][0]
+    assert top["name"] in ("gc_gen2", "boundary_drain")
+    by_name = {r["name"]: r for r in slow["spans"]}
+    assert by_name["gc_gen2"]["excess_secs"] == pytest.approx(1.5)
+    assert by_name["boundary_drain"]["excess_secs"] == pytest.approx(1.5)
+    assert by_name["dispatch"]["excess_secs"] == pytest.approx(0.0)
+    text = telemetry_report.summarize(str(path))
+    assert "slowest boundary interval: 4.5000 s ending at step 180" in text
+    assert "gc_gen2" in text and "x1.5" in text
+    # too few intervals: no section
+    few = tmp_path / "few.jsonl"
+    few.write_text("".join(json.dumps(r) + "\n" for r in recs[:8]))
+    assert "slowest_boundary" not in telemetry_report.summarize_json(
+        str(few))

@@ -40,6 +40,8 @@ KIND_KEYS = {
     "train": ("step", "loss", "train_accuracy", "images_per_sec", "lr",
               "device_step_ms", "drain_wait_ms", "optimizer_ms"),
     "eval": ("step", "test_accuracy"),
+    # `cat` rides categorized spans, `thread` those of another thread
+    # than the loop's (the FLOP probe, a collection on a worker thread).
     "span": ("step", "name", "start_s", "dur_s", "depth"),
     "goodput": ("step", "total_s", "train_frac", "compile_frac",
                 "data_frac", "eval_frac", "checkpoint_frac", "sync_frac"),
@@ -135,6 +137,12 @@ KIND_KEYS = {
     # total `optimizer_ms` (the weight-update tail), the lane's wall
     # window, and the top-k op table as a nested list of
     # {name, bucket, dur_ms, calls, frac}.
+    # Instruction-to-layer map of one compiled program
+    # (utils/devprof.scope_map), announced once per telemetry fit and
+    # program; `path` is null where no profiler capture directory
+    # exists to write scopemap_<module>.json beside.
+    "scopemap": ("step", "module", "instructions", "mapped", "mixed",
+                 "path"),
     "devtime": ("step", "device", "total_ms", "compute_ms",
                 "collective_ms", "infeed_ms", "optimizer_ms",
                 "window_ms", "top_ops"),

@@ -84,6 +84,68 @@ def _goodput_from_spans(records: List[dict]) -> Optional[dict]:
     return out
 
 
+def _slowest_boundary(records: List[dict]) -> Optional[dict]:
+    """The longest boundary interval of a telemetry stream against the
+    median one, and the spans inside it against what they take in a
+    median interval: which span holds the excess of a stalled interval
+    (a ``gc_gen2``, a ``dispatch`` that blocked, ``boundary_log``, or
+    ``boundary_drain`` itself — then the host was waiting and the cause
+    lies below the program). An interval runs from the end of one
+    ``boundary_drain`` to the end of the next, on the tracer's clock; a
+    span belongs to the interval it ends in. None with fewer than three
+    intervals. A stream of several ``fit``s (their clocks restart) is
+    read fit by fit."""
+    spans = [r for r in records if r.get("kind") == "span"
+             and isinstance(r.get("start_s"), (int, float))
+             and isinstance(r.get("dur_s"), (int, float))]
+    # Spans are recorded as they finish, so on one tracer's clock their
+    # ends never go back: an end that does is a new fit's (its clock
+    # restarted).
+    fits: List[List[dict]] = [[]]
+    newest = None
+    for r in spans:
+        end = r["start_s"] + r["dur_s"]
+        if newest is not None and end < newest - 0.5:
+            fits.append([])
+            newest = None
+        newest = end if newest is None else max(newest, end)
+        fits[-1].append(r)
+    intervals = []
+    for fit in fits:
+        ends = [(r["start_s"] + r["dur_s"], r.get("step")) for r in fit
+                if r.get("name") == "boundary_drain"
+                and not r.get("thread")]
+        for (t0, _), (t1, step) in zip(ends, ends[1:]):
+            inside: dict = {}
+            for r in fit:
+                end = r["start_s"] + r["dur_s"]
+                if t0 < end <= t1:
+                    inside[r["name"]] = inside.get(r["name"], 0.0) \
+                        + r["dur_s"]
+            intervals.append({"step": step, "secs": t1 - t0,
+                              "spans": inside})
+    if len(intervals) < 3:
+        return None
+    median = percentile([iv["secs"] for iv in intervals], 50)
+    worst = max(intervals, key=lambda iv: iv["secs"])
+    names = set(worst["spans"])
+    rows = []
+    for name in names:
+        typical = percentile([iv["spans"].get(name, 0.0)
+                              for iv in intervals], 50)
+        rows.append({"name": name,
+                     "secs": round(worst["spans"][name], 6),
+                     "median_secs": round(typical, 6),
+                     "excess_secs": round(worst["spans"][name] - typical,
+                                          6)})
+    rows.sort(key=lambda r: -r["excess_secs"])
+    return {"intervals": len(intervals), "step": worst["step"],
+            "secs": round(worst["secs"], 6),
+            "median_secs": round(median, 6),
+            "ratio": round(worst["secs"] / median, 4) if median else None,
+            "spans": rows}
+
+
 def _device_split(trains: List[dict]) -> Optional[dict]:
     """Boundary-estimator aggregate over the train rows: p50
     ``device_step_ms`` / ``drain_wait_ms`` and the implied device-busy
@@ -513,6 +575,18 @@ def summarize_records(records: List[dict], header: str) -> str:
                          f"the ring buffer]")
     else:
         lines.append("  no goodput/span records (run without --telemetry)")
+    slow = _slowest_boundary(records)
+    if slow:
+        lines.append(
+            f"  slowest boundary interval: {slow['secs']:.4f} s ending at "
+            f"step {slow['step']} (median {slow['median_secs']:.4f} s over "
+            f"{slow['intervals']} intervals, x{slow['ratio']})")
+        lines.append(f"    {'span':<24} {'in it':>10} {'median':>10} "
+                     f"{'excess':>10}")
+        for row in slow["spans"][:8]:
+            lines.append(f"    {row['name']:<24} {row['secs']:>9.4f}s "
+                         f"{row['median_secs']:>9.4f}s "
+                         f"{row['excess_secs']:>+9.4f}s")
 
     health = [r for r in trains if "health_grad_norm" in r]
     if health:
@@ -1047,6 +1121,9 @@ def summarize_json(path: str) -> dict:
     if gp:
         out["goodput"] = {k: v for k, v in gp.items()
                           if k not in ("kind", "t", "task")}
+    slow = _slowest_boundary(records)
+    if slow:
+        out["slowest_boundary"] = slow
     compiles = [r for r in records if r.get("kind") == "compile"]
     if compiles:
         misses = [r for r in compiles if not r.get("hit")]
