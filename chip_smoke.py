@@ -30,8 +30,11 @@ not where it should be, on a ``--metrics_jsonl`` stream that does not
 pass ``tools/check_jsonl_schema.py --strict``, and — the no-fallback
 check — if the lowered training step it ran (read back from the keyed
 compile store ``--compile_cache_dir`` fills) does not hold one Mosaic
-``tpu_custom_call`` per parameter leaf: the fused optimizer update went
-to the XLA expression or to interpret mode without saying so. On more
+``tpu_custom_call`` per parameter leaf, and four more where the batch of
+a device fills the lanes (the two pools' forward and backward kernels,
+``ops/relu_pool.py``: on one chip, not on four at this batch): the fused
+optimizer update or the pools went to the XLA expression or to
+interpret mode without saying so. On more
 than one chip the same count must sit inside a manual computation (the
 replicated ``shard_map`` of ``ops/optimizer.py``), the step's mesh must
 give the ``data`` axis every device, and every device must hold a shard
@@ -140,7 +143,14 @@ def compile_seconds(records: list, phase_name: str) -> float:
 def check_lowered_step(store: str, phase_name: str, n_devices: int,
                        n_leaves: int) -> None:
     """The no-fallback check, on the StableHLO of the step that ran."""
+    import jax.numpy as jnp
+
     from dml_cnn_cifar10_tpu.compilecache import CompileCache
+    from dml_cnn_cifar10_tpu.ops import relu_pool
+
+    # conv1's activation on one device, as the pools' chooser sees it
+    pools = 4 * relu_pool.fits_kernels((BATCH // n_devices, 24, 24, 64),
+                                       jnp.float32)
 
     text = None
     for key, meta in CompileCache(store).entries():
@@ -151,9 +161,10 @@ def check_lowered_step(store: str, phase_name: str, n_devices: int,
         raise SmokeFailure(f"the keyed compile store holds no lowered "
                            f"{phase_name} (cache machinery failed open?)")
     kernels = text.count("tpu_custom_call")
-    check(kernels == n_leaves,
+    check(kernels == n_leaves + pools,
           f"{phase_name}: {kernels} Mosaic tpu_custom_call in the lowered "
-          f"step, one per parameter leaf ({n_leaves})")
+          f"step, one per parameter leaf ({n_leaves}) and {pools} of the "
+          f"pools")
     if n_devices > 1:
         check(f'"data"={n_devices}' in text,
               f"{phase_name}: the step's mesh gives the data axis all "

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from dml_cnn_cifar10_tpu.config import DataConfig, ModelConfig
 from dml_cnn_cifar10_tpu.ops import layers as L
+from dml_cnn_cifar10_tpu.ops.relu_pool import bias_relu_max_pool
 
 Params = Dict[str, Any]
 
@@ -56,30 +57,32 @@ def init_params(key: jax.Array, cfg: ModelConfig, data: DataConfig) -> Params:
 
 
 def apply(params: Params, images: jax.Array, cfg: ModelConfig,
-          train: bool = True) -> jax.Array:
+          train: bool = True, mesh=None) -> jax.Array:
     """Forward pass: NHWC images → logits [B, num_classes].
 
     ``train`` is accepted for registry uniformity (this model has no
-    BatchNorm/dropout, ``cifar10cnn.py:94-147``).
+    BatchNorm/dropout, ``cifar10cnn.py:94-147``). ``mesh`` is the mesh of
+    the enclosing GSPMD program, if any: the pools' kernels need it to
+    place themselves (``ops/relu_pool.py``).
     """
     del train
     cdt = jnp.dtype(cfg.compute_dtype)
     x = images.astype(cdt)
     p = jax.tree.map(lambda a: a.astype(cdt), params)
 
-    # One named scope a layer (bias and ReLU with their layer): metadata
-    # only, it is what utils/devprof.scope_map reads a compiled
-    # instruction's layer from.
+    # One named scope a layer: metadata only, it is what
+    # utils/devprof.scope_map reads a compiled instruction's layer from.
+    # A convolution's bias and ReLU are with the pool behind it
+    # (bias_relu_max_pool shares one backward pass between the three), a
+    # dense layer's with it.
     with jax.named_scope("conv1"):
-        x = jax.nn.relu(L.conv2d(x, p["conv1"]["kernel"])
-                        + p["conv1"]["bias"])
+        x = L.conv2d(x, p["conv1"]["kernel"])
     with jax.named_scope("pool1"):
-        x = L.max_pool(x)
+        x = bias_relu_max_pool(x, p["conv1"]["bias"], mesh)
     with jax.named_scope("conv2"):
-        x = jax.nn.relu(L.conv2d(x, p["conv2"]["kernel"])
-                        + p["conv2"]["bias"])
+        x = L.conv2d(x, p["conv2"]["kernel"])
     with jax.named_scope("pool2"):
-        x = L.max_pool(x)
+        x = bias_relu_max_pool(x, p["conv2"]["bias"], mesh)
     x = x.reshape(x.shape[0], -1)
     with jax.named_scope("fc1"):
         x = jax.nn.relu(L.dense(x, p["full1"]["kernel"], p["full1"]["bias"]))

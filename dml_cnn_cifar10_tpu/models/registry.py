@@ -12,8 +12,10 @@ class ModelDef(NamedTuple):
                             #           -> (logits, new_state)
     init_state: Callable    # (params) -> mutable state pytree ({} if none)
     has_state: bool
-    # apply accepts a ``mesh=`` kwarg and uses it for sequence-parallel
-    # (ring-attention) routing when the mesh's ``seq`` axis is >1.
+    # apply accepts a ``mesh=`` kwarg: the ViTs route sequence-parallel
+    # (ring) attention by it when the mesh's ``seq`` axis is >1, and a
+    # Pallas kernel in a GSPMD program needs it to place itself (flash
+    # attention, the CNN's pools).
     wants_mesh: bool = False
     # apply returns ``(logits, aux_loss)``; the step adds
     # ``model_cfg.moe_aux_coef * aux_loss`` to the training loss.
@@ -33,7 +35,7 @@ class ModelDef(NamedTuple):
 def _cnn() -> ModelDef:
     from dml_cnn_cifar10_tpu.models import cnn
     return ModelDef(cnn.init_params, cnn.apply, lambda p: {}, False,
-                    spatial=True)
+                    wants_mesh=True, spatial=True)
 
 
 def _resnet(depth: int) -> Callable[[], ModelDef]:

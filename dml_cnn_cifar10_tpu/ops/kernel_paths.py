@@ -1,10 +1,11 @@
 """Which implementation a traced program took, reported from the place
 that chose it.
 
-The fused optimizer update (``ops/optimizer.py``) and the attention
-dispatch (``ops/attention.py``) each pick between a compiled Pallas
-kernel and an XLA expression from what they can see at trace time: the
-platform, the mesh, the token count. A step builder
+The fused optimizer update (``ops/optimizer.py``), the attention
+dispatch (``ops/attention.py``) and the CNN's bias + ReLU + pool
+(``ops/relu_pool.py``) each pick between a compiled Pallas kernel and
+an XLA expression from what they can see at trace time: the platform,
+the mesh, the token count, the shape. A step builder
 (``parallel/step.py``) opens :func:`recording` around its step body;
 the choosers :func:`note` their pick; the builder prints the record
 once. The recorder is thread-local and only live inside ``recording``,
@@ -31,7 +32,8 @@ def recording():
 
 
 def note(kind: str, path: str) -> None:
-    """Record that ``kind`` ("update" | "attention") compiled ``path``."""
+    """Record that ``kind`` ("update" | "attention" | "pool") compiled
+    ``path``."""
     rec = getattr(_local, "rec", None)
     if rec is not None:
         rec[kind] = path

@@ -48,13 +48,30 @@ def max_pool(x: jax.Array, window: int = 3, stride: int = 2,
              padding: str = "SAME") -> jax.Array:
     """Max pool over NHWC spatial dims via ``lax.reduce_window``.
 
-    Backward is XLA's select-and-scatter. Round-3 note (a ResNet-50
-    profile on an earlier chip, not repeated on the current one): that op is ~5% of the bf16 224² train step, and a
-    hand-written 9-shift compare-mask-pad VJP was implemented and
-    MEASURED WORSE (-27% step time — the f32 grad accumulator makes 9
-    full passes over the 112² activation grid, far more HBM traffic than
-    the generic scatter). The default stays; the experiment is recorded
-    so it isn't retried blind.
+    Backward is XLA's select-and-scatter, which reads the activation
+    again to find each window's argmax. What has been tried in its place:
+
+    - Round 3 (a ResNet-50 profile on an earlier chip): a 9-shift
+      compare-mask-pad VJP, nine float32 accumulation passes over the
+      112x112 grid with the activation still read for the compares.
+      MEASURED WORSE (-27% step time).
+    - PR 25, on the v5e, for the CNN's ``conv -> bias -> ReLU -> pool``
+      pairs: keep each window's winning tap (int8) in the forward pass,
+      fold the ReLU in (its mask is ``pooled > 0`` at the argmax) and
+      gather the gradient from tensors at the output's resolution. As XLA
+      expressions (nine strided slices or a variadic ``reduce_window`` for
+      the tap; stack-and-reshape, interior pads or broadcasts for the
+      interleave) every variant MEASURED WORSE than select-and-scatter
+      (63-106 ms a step against 46.8). As two Pallas kernels it is
+      ``ops/relu_pool.py``, 31.6 ms a step, and what ``models/cnn.py``
+      calls. PERF.md, Findings, PR 25 has the numbers.
+    - PR 25, the ResNet-50 stem's pool (bfloat16, batch 256, 112x112)
+      through those kernels: 2,513.9 img/s/chip against 2,523.5 with this
+      function, ``step.device_ms`` 101.73 against 101.35. The
+      select-and-scatter (1.5 ms a step) leaves and more comes back;
+      where was not taken apart (at batch 256 the kernels' batch-in-the-
+      lanes view need not be that model's layout). The stem keeps this
+      function.
     """
     return lax.reduce_window(
         x,

@@ -465,7 +465,8 @@ def _pallas_veto(state_sharding: Optional[TrainState]):
 
 
 def _announced(fn, phase: str, mesh: Optional[Mesh]):
-    """``fn``, saying once which update and attention path it compiled.
+    """``fn``, saying once which update, attention and pool path it
+    compiled.
 
     The choice is made where the shapes are known, inside the trace
     (``ops/kernel_paths.py``), so the line prints when the step is
@@ -480,7 +481,8 @@ def _announced(fn, phase: str, mesh: Optional[Mesh]):
             out = fn(*args)
         line = (f"[step] {phase} on {mesh.size if mesh is not None else 1}"
                 f" device(s): update={rec.get('update', 'none')} "
-                f"attention={rec.get('attention', 'none')}")
+                f"attention={rec.get('attention', 'none')} "
+                f"pool={rec.get('pool', 'none')}")
         if line not in said:
             said.add(line)
             print(line, flush=True)

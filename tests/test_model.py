@@ -78,6 +78,123 @@ def test_max_pool_matches_reference_semantics():
     assert float(out[0, 1, 1, 0]) == 15.0
 
 
+def _pool_input(shape, dtype, ties):
+    rng = np.random.default_rng(shape[1] * 31 + shape[2])
+    z, bias = rng.normal(size=shape), rng.normal(size=shape[-1])
+    if ties:
+        # A grid of halves: every window holds ties, at zero (where the
+        # ReLU's mask decides), above it and below it.
+        z, bias = np.round(z * 2) / 2, np.round(bias * 2) / 2
+    return jnp.asarray(z, dtype), jnp.asarray(bias, dtype)
+
+
+def _plain_pool(z, bias):
+    return L.max_pool(jax.nn.relu(z + bias))
+
+
+# (H = W, a block's byte budget): whole images in one block, and images
+# cut into row blocks that read a halo row from their neighbour.
+POOL_SIZES = [(24, None), (12, None), (32, 10_000), (112, 10_000)]
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["random", "ties"])
+@pytest.mark.parametrize("size,block_bytes", POOL_SIZES,
+                         ids=[f"{s}" for s, _ in POOL_SIZES])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_pool_kernels_match_max_pool_of_relu(dtype, size, block_bytes, ties,
+                                             monkeypatch):
+    """The kernels (in the Pallas interpreter) against ``jax.vjp`` of
+    ``max_pool(relu(z + bias))``: forward bit-equal, gradients equal up to
+    the order in which the (at most four) windows that share an input
+    position, and the windows of a channel, are summed."""
+    from dml_cnn_cifar10_tpu.ops import relu_pool
+
+    if block_bytes:
+        monkeypatch.setattr(relu_pool, "_BLOCK_BYTES", block_bytes)
+    shape = (1, size, size, 2) if size > 32 else (2, size, size, 4)
+    rows = relu_pool._blocks(size // 2, size, shape[3], shape[0],
+                             jnp.dtype(dtype).itemsize)[0]
+    assert (rows < size // 2) == bool(block_bytes)
+    z, bias = _pool_input(shape, dtype, ties)
+    want, ref_vjp = jax.vjp(_plain_pool, z, bias)
+    got, vjp = jax.vjp(
+        lambda z, b: relu_pool.fused_bias_relu_max_pool(z, b, True), z, bias)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    ct = jnp.asarray(np.random.default_rng(1).normal(size=want.shape), dtype)
+    eps = float(jnp.finfo(dtype).eps)
+    (dz, dbias), (dz_want, dbias_want) = [
+        [np.asarray(t, np.float32) for t in f(ct)] for f in (vjp, ref_vjp)]
+    assert np.abs(dz_want).max() > 1.0          # the comparison is not empty
+    np.testing.assert_allclose(dz, dz_want, rtol=0,
+                               atol=4 * eps * np.abs(dz_want).max())
+    # a sum over every window of a channel: a rounding a term at most
+    terms = np.abs(np.asarray(ct, np.float32)).sum(axis=(0, 1, 2)).max()
+    np.testing.assert_allclose(dbias, dbias_want, rtol=0, atol=eps * terms)
+    # What the forward pass leaves for the backward pass: the winning tap
+    # and the pooled output (and the bias, for its dtype).
+    carried = sorted((str(l.dtype), l.size) for l in jax.tree.leaves(vjp))
+    assert carried == sorted([("int8", want.size),
+                              (str(jnp.dtype(dtype)), want.size),
+                              (str(jnp.dtype(dtype)), bias.size)])
+
+
+def test_pool_kernels_over_data_sum_the_bias_gradient():
+    """On a mesh the kernels run a device, each on its own images; the
+    bias is replicated, so its gradient is the sum over the devices."""
+    from dml_cnn_cifar10_tpu.config import ParallelConfig
+    from dml_cnn_cifar10_tpu.ops import relu_pool
+    from dml_cnn_cifar10_tpu.parallel import mesh as mesh_lib
+
+    mesh = mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:4])
+    z, bias = _pool_input((8, 12, 12, 4), jnp.float32, True)
+    want, ref_vjp = jax.vjp(_plain_pool, z, bias)
+    got, vjp = jax.vjp(jax.jit(relu_pool.over_data(mesh, interpret=True)),
+                       z, bias)
+    np.testing.assert_array_equal(got, want)
+    assert got.sharding.spec[0] == "data"
+    for a, b in zip(vjp(want), ref_vjp(want)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,fits", [
+    ((128, 24, 24, 64), True), ((256, 12, 12, 32), True),
+    ((128, 7, 24, 64), False),      # odd H
+    ((128, 24, 9, 64), False),      # odd W
+    ((8, 24, 24, 64), False),       # a batch that leaves lanes empty
+    ((128, 24, 24, 3), False),      # channels short of an int8 tile
+])
+def test_pool_path_is_chosen_by_shape(shape, fits, monkeypatch):
+    """On a TPU the shapes the kernels were written for take them; every
+    other shape, and every shape off TPU, runs ``max_pool(relu(z +
+    bias))`` itself, value and gradient."""
+    from dml_cnn_cifar10_tpu.ops import kernel_paths, relu_pool
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
+    def path_taken():
+        with kernel_paths.recording() as rec:
+            jax.eval_shape(
+                lambda z, b: relu_pool.bias_relu_max_pool(z, b),
+                jax.ShapeDtypeStruct(shape, jnp.float32),
+                jax.ShapeDtypeStruct(shape[-1:], jnp.float32))
+        return rec
+
+    assert relu_pool.fits_kernels(shape, jnp.float32) == fits
+    assert not relu_pool.fits_kernels(shape, jnp.int32)
+    assert path_taken() == {"pool": "xla"}          # the CPU
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: True)
+    assert path_taken() == {"pool": "pallas" if fits else "xla"}
+    if not fits:
+        z, bias = _pool_input((2, *shape[1:3], 4), jnp.float32, True)
+        want, ref_vjp = jax.vjp(_plain_pool, z, bias)
+        got, vjp = jax.vjp(relu_pool.bias_relu_max_pool, z, bias)
+        np.testing.assert_array_equal(got, want)
+        for a, b in zip(vjp(want), ref_vjp(want)):
+            np.testing.assert_array_equal(a, b)
+
+
 def test_conv2d_matches_manual_nhwc():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(1, 5, 5, 2)).astype(np.float32))

@@ -53,11 +53,11 @@ def _tpu_text(jitted, *abs_args):
         lowering_platforms=("tpu",)).as_text()
 
 
-def _batch_abs(data_cfg, lead=()):
+def _batch_abs(data_cfg, lead=(), batch=BATCH):
     return (jax.ShapeDtypeStruct(
-        (*lead, BATCH, data_cfg.crop_height, data_cfg.crop_width,
+        (*lead, batch, data_cfg.crop_height, data_cfg.crop_width,
          data_cfg.num_channels), jnp.float32),
-        jax.ShapeDtypeStruct((*lead, BATCH), jnp.int32))
+        jax.ShapeDtypeStruct((*lead, batch), jnp.int32))
 
 
 @pytest.mark.parametrize("ndev", [1, 4])
@@ -100,6 +100,55 @@ def test_resident_chunk_keeps_the_fused_update_kernel(chip_branch, ndev):
                      state)
     assert text.count("tpu_custom_call") == len(jax.tree.leaves(
         state.params))
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_cnn_step_pools_are_the_kernels(chip_branch, ndev, capsys):
+    """At a batch that fills the lanes of every device, each of the CNN's
+    bias + ReLU + pool pairs is two kernels (forward, backward) beside
+    the update's one a leaf, on four devices under a ``shard_map`` over
+    ``data``; no select-and-scatter is left in the step."""
+    model_def = get_model("cnn")
+    model_cfg, data_cfg = ModelConfig(), DataConfig()
+    optim_cfg = OptimConfig()
+    mesh = _mesh(ndev)
+    sh, state = _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh)
+    step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
+                                    state_sharding=sh)
+    text = _tpu_text(step, state, *_batch_abs(data_cfg, batch=128 * ndev))
+    assert "select_and_scatter" not in text
+    assert text.count("tpu_custom_call") == 4 + len(jax.tree.leaves(
+        state.params))
+    want = "pallas" + (f"/shard_map[batch/data x{ndev}]" if ndev > 1 else "")
+    assert f" pool={want}\n" in capsys.readouterr().out
+    # The same builder at a batch that leaves lanes empty keeps XLA's pool.
+    step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
+                                    state_sharding=sh)
+    text = _tpu_text(step, state, *_batch_abs(data_cfg, batch=8 * ndev))
+    assert "select_and_scatter" in text
+    assert " pool=xla\n" in capsys.readouterr().out
+
+
+def test_cnn_pools_carry_no_activation_to_the_backward_pass(chip_branch):
+    """What the CNN's forward pass keeps for its backward pass at 24x24:
+    of the pools the winning tap (int8) and the pooled output, both at the
+    pool's output size, and nothing of the size of a convolution's
+    activation (XLA's ReLU and max-pool keep it, twice)."""
+    from dml_cnn_cifar10_tpu.models import cnn
+
+    cfg, data_cfg, batch = ModelConfig(logit_relu=False), DataConfig(), 128
+    params = jax.eval_shape(lambda k: cnn.init_params(k, cfg, data_cfg),
+                            jax.random.key(0))
+    images = _batch_abs(data_cfg, batch=batch)[0]
+    _, vjp = jax.eval_shape(
+        lambda p, x: jax.vjp(lambda p: cnn.apply(p, x, cfg), p),
+        params, images)
+    carried = sorted((str(l.dtype), l.shape) for l in jax.tree.leaves(vjp)
+                     if l.ndim == 4 and l.size >= batch * 6 * 6 * 64)
+    assert carried == sorted([
+        ("int8", (12, 12, 64, batch)), ("float32", (12, 12, 64, batch)),
+        ("float32", (batch, 12, 12, 64)),          # conv2's input
+        ("int8", (6, 6, 64, batch)), ("float32", (6, 6, 64, batch))])
 
 
 def test_sharded_update_operands_keep_the_xla_expression(chip_branch,
