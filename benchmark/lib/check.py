@@ -23,6 +23,9 @@ of limits:
   rounding that points anywhere (a perturbation at right angles to a
   vector does not lengthen it), so it cannot tell one precision from the
   next; the norm of the difference moves in the first order.
+- every other tree of the optimizer's state that both sides hold reads
+  the same four numbers under its own name: for AdamW's ``nu``, ``nu``,
+  ``nu_mid``, ``nu_diff``, ``nu_diff_mid``.
 
 A leaf whose first gradient in the reference is under a thousandth of the
 median leaf's is left out of the parameters' and the momentum's numbers:
@@ -82,6 +85,12 @@ def diff_norms(prog, ref, start=None, keep=None) -> Dict[str, float]:
             / max(size[k], median, 1e-300) for k in names}
 
 
+def _worst_and_mid(out: Dict[str, float], name: str,
+                   per_leaf: Dict[str, float]) -> None:
+    out[name] = max(per_leaf.values())
+    out[name + "_mid"] = float(np.median(list(per_leaf.values())))
+
+
 def compare(first, start_params, start_state, ref) -> Dict[str, float]:
     """``first`` is the program's ``FirstDispatch`` (host arrays), ``ref``
     the reference's ``ChunkResult`` from the same start."""
@@ -90,32 +99,29 @@ def compare(first, start_params, start_state, ref) -> Dict[str, float]:
         ref_loss = float(np.asarray(ref.losses)[-1])
         out["loss"] = abs(first.loss - ref_loss) / max(abs(ref_loss), 1e-300)
 
-    grad = _norms(ref.first_grad)
+    grad = {k: float(v) for k, v in _leaves(ref.first_grad_norms)}
     floor = 1e-3 * float(np.median(list(grad.values())))
     moved = {k for k, g in grad.items() if g >= floor}
-    gaps = norm_gaps(_norms(first.params, start_params),
-                     _norms(ref.params, start_params), keep=moved)
-    out["dparam"] = max(gaps.values())
-    out["dparam_mid"] = float(np.median(list(gaps.values())))
-    diffs = diff_norms(first.params, ref.params, start_params, keep=moved)
-    out["ddiff"] = max(diffs.values())
-    out["ddiff_mid"] = float(np.median(list(diffs.values())))
+    _worst_and_mid(out, "dparam", norm_gaps(
+        _norms(first.params, start_params), _norms(ref.params, start_params),
+        keep=moved))
+    _worst_and_mid(out, "ddiff", diff_norms(first.params, ref.params,
+                                            start_params, keep=moved))
     if _leaves(ref.model_state):
-        gaps = norm_gaps(_norms(first.model_state, start_state),
-                         _norms(ref.model_state, start_state))
-        out["dstate"] = max(gaps.values())
-        out["dstate_mid"] = float(np.median(list(gaps.values())))
-        diffs = diff_norms(first.model_state, ref.model_state, start_state)
-        out["sdiff"] = max(diffs.values())
-        out["sdiff_mid"] = float(np.median(list(diffs.values())))
-    if ref.momentum is not None:
-        gaps = norm_gaps(_norms(first.momentum), _norms(ref.momentum),
-                         keep=moved)
-        out["moment"] = max(gaps.values())
-        out["moment_mid"] = float(np.median(list(gaps.values())))
-        diffs = diff_norms(first.momentum, ref.momentum, keep=moved)
-        out["mdiff"] = max(diffs.values())
-        out["mdiff_mid"] = float(np.median(list(diffs.values())))
+        _worst_and_mid(out, "dstate", norm_gaps(
+            _norms(first.model_state, start_state),
+            _norms(ref.model_state, start_state)))
+        _worst_and_mid(out, "sdiff", diff_norms(
+            first.model_state, ref.model_state, start_state))
+    for name in sorted(set(ref.opt) & set(first.opt)):
+        gap, diff = ("moment", "mdiff") if name == "momentum" \
+            else (name, name + "_diff")
+        theirs, mine = _norms(first.opt[name]), _norms(ref.opt[name])
+        # a tree shaped like the parameters leaves out the leaves they do
+        keep = moved if set(mine) == set(grad) else None
+        _worst_and_mid(out, gap, norm_gaps(theirs, mine, keep=keep))
+        _worst_and_mid(out, diff, diff_norms(first.opt[name], ref.opt[name],
+                                             keep=keep))
     return out
 
 

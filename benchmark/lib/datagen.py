@@ -12,13 +12,16 @@ matrix or kernel gets normal values of variance 1/(2 fan-in) (half the
 He-normal deviation: with it the paper's CNN trains from the first step
 at the cells' learning rates, where He-normal diverges), a ``scale``
 values around 1, every other vector small values around 0, so that no
-leaf's gradient vanishes at the start.
+leaf's gradient vanishes at the start. The fan-in of a matrix or kernel is
+the product of all its dimensions but the last, unless the configuration's
+module states another for the leaf (``fan_in(path, shape)``: an
+embedding's ``[V, D]``, an expert-major ``[E, D, H]``).
 """
 
 from __future__ import annotations
 
 import os
-from typing import Any, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +58,12 @@ def write_record_files(paths, images: np.ndarray, labels: np.ndarray,
             f.write(recs[i * per:(i + 1) * per].tobytes())
 
 
-def make_params(seed: int, abstract_params: Any, sharding=None) -> Any:
+def make_params(seed: int, abstract_params: Any, sharding=None,
+                fan_in: Optional[Callable] = None) -> Any:
     """Fill ``abstract_params`` (a tree of shapes) from the seed, on the
-    device, in one jitted call."""
+    device, in one jitted call. ``fan_in(path, shape)`` is the
+    configuration's own word on a leaf (``path`` as ``keystr`` prints
+    it), or None for the rule above."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(abstract_params)
 
     def build(key):
@@ -67,8 +73,10 @@ def make_params(seed: int, abstract_params: Any, sharding=None) -> Any:
             name = str(getattr(path[-1], "key", getattr(path[-1], "idx", "")))
             noise = jax.random.normal(k, leaf.shape, jnp.float32)
             if leaf.ndim >= 2:
-                fan_in = int(np.prod(leaf.shape[:-1]))
-                v = noise * np.sqrt(0.5 / fan_in)
+                fan = fan_in and fan_in(jax.tree_util.keystr(path),
+                                        leaf.shape)
+                v = noise * np.sqrt(
+                    0.5 / (fan or int(np.prod(leaf.shape[:-1]))))
             elif name == "scale":
                 v = 1.0 + 0.1 * noise
             else:

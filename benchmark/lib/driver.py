@@ -17,7 +17,7 @@ import contextlib
 import sys
 import threading
 import time
-from typing import Any, List, NamedTuple, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -147,7 +147,14 @@ class FirstDispatch(NamedTuple):
     loss: Optional[float]      # None where the dispatch ended on no boundary
     params: Any
     model_state: Any
-    momentum: Any
+    opt: Dict[str, Any]        # every tree of the optimizer's state, by name
+
+
+def in_the_programs_place(ref) -> FirstDispatch:
+    """A reference's ``ChunkResult`` where the program's first dispatch
+    goes: a control, a planted fault."""
+    return FirstDispatch(float(ref.losses[-1]), ref.params, ref.model_state,
+                         ref.opt)
 
 
 def _to_host(tree):
@@ -199,7 +206,8 @@ def start_program(flags: dict, devices, make_params, telemetry: bool = False,
         loss=float(warm.train_loss[0]) if warm.train_loss else None,
         params=_to_host(warm.state.params),
         model_state=_to_host(warm.state.model_state),
-        momentum=_to_host(warm.state.opt.get("momentum")))
+        opt={name: _to_host(tree) for name, tree in warm.state.opt.items()
+             if name != "step"})
     # the warm-up's background work (the program's FLOP probe compiles the
     # step a second time on a thread) must not run into the window
     for t in set(threading.enumerate()) - threads_before:

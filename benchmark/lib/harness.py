@@ -16,9 +16,6 @@ import numpy as np
 from benchmark.lib import cells, check, datagen, driver, peaks, reference
 from benchmark.lib import validate, xplane
 
-TEST_RECORDS = 512          # the program's default size of its test split
-WHOLE_CHUNK_DECODE_BYTES = 1 << 30
-
 
 def cell_flags(cell: cells.Cell, overrides: Optional[dict] = None) -> dict:
     """The program's flags as the cell's two files state them.
@@ -52,50 +49,53 @@ def program_flags(cell: cells.Cell, work: str,
 
 def hyper_of(cell: cells.Cell, overrides: Optional[dict] = None
              ) -> reference.Hyper:
-    """What the reference has to know of the feed and the update, from the
-    cell's own files."""
-    c, flags = cell.config, cell_flags(cell, overrides)
-    k, batch = flags["steps_per_dispatch"], flags["batch_size"]
-    side = max(c["image_size"], c["crop_size"])
-    decoded = k * batch * side * side * c["num_channels"] * 4
+    """What the reference of any task has to know of the stream and the
+    dispatch, from the cell's flags."""
+    flags = cell_flags(cell, overrides)
     return reference.Hyper(
-        seed=flags["seed"], batch=batch, steps=k,
-        records=flags["synthetic_train_records"], crop=c["crop_size"],
-        random_crop=c["decode"]["random_crop"],
-        random_flip=c["decode"]["random_flip"],
-        normalize=c["decode"]["normalize"],
-        learning_rate=flags["learning_rate"],
-        warmup_steps=flags.get("warmup_steps", 0),
-        momentum=flags.get("momentum", 0.0),
-        weight_decay=flags.get("weight_decay", 0.0),
-        decode_whole_chunk=decoded <= WHOLE_CHUNK_DECODE_BYTES)
+        seed=flags["seed"], batch=flags["batch_size"],
+        steps=flags["steps_per_dispatch"],
+        records=flags["synthetic_train_records"])
 
 
-def write_records(cell: cells.Cell, seed: int, flags: dict):
-    """The train and test splits, where the program will look for them."""
+def task_of(cell: cells.Cell, overrides: Optional[dict] = None
+            ) -> reference.Task:
+    """The records, the feed, the loss and the update as the
+    configuration's module states them (``task(spec, flags)``), or those of
+    an image classifier under SGD, read from the configuration's file."""
+    flags = cell_flags(cell, overrides)
+    stated = getattr(cell.reference, "task", None)
+    if stated is not None:
+        return stated(cell.config, flags)
+    return reference.image_task(
+        reference.image_hyper(cell.config, flags),
+        cell.reference.make_forward(cell.config))
+
+
+def make_params(cell: cells.Cell, seed: int, abstract, sharding=None):
+    """The starting weights, with the fan-ins the configuration states."""
+    return datagen.make_params(seed, abstract, sharding,
+                               getattr(cell.reference, "fan_in", None))
+
+
+def write_records(cell: cells.Cell, task: reference.Task, seed: int,
+                  flags: dict):
+    """The task's records, where the program will look for them; gives
+    back the training split."""
     from dml_cnn_cifar10_tpu.data import download
-    c = cell.config
     data_cfg = driver.build_train_config(flags).data
-    made = []
-    for s, n, paths in (
-            (seed, flags["synthetic_train_records"],
-             download.train_files(data_cfg)),
-            (seed + 1, TEST_RECORDS, download.test_files(data_cfg))):
-        images, labels = datagen.make_records(
-            s, n, c["num_classes"], c["image_size"], c["image_size"],
-            c["num_channels"])
-        datagen.write_record_files(paths, images, labels, c["num_classes"])
-        made.append((images, labels))
-    return made[0]
+    return task.write_records(
+        seed, flags["synthetic_train_records"],
+        {"train": download.train_files(data_cfg),
+         "test": download.test_files(data_cfg)})
 
 
-def reference_chunk(cell: cells.Cell, hyper: reference.Hyper, seed: int,
-                    devices, like_params, images, labels,
-                    numerics: Optional[str] = None):
+def reference_chunk(cell: cells.Cell, task: reference.Task,
+                    hyper: reference.Hyper, seed: int, devices, like_params,
+                    records, numerics: Optional[str] = None):
     """The reference's K steps from the seed's weights, on ``devices``,
     in the numerics the configuration states (or a control's)."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     mesh = Mesh(np.asarray(devices), ("batch",))
@@ -106,12 +106,10 @@ def reference_chunk(cell: cells.Cell, hyper: reference.Hyper, seed: int,
 
     abstract = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), like_params)
-    params = datagen.make_params(seed, abstract, repl)
+    params = make_params(cell, seed, abstract, repl)
     state = jax.device_put(cell.reference.init_model_state(params), repl)
     result = reference.run_chunk(
-        cell.reference.make_forward(cell.config), hyper, params, state,
-        jax.device_put(images, repl), jax.device_put(jnp.asarray(labels),
-                                                     repl),
+        task, hyper, params, state, jax.device_put(records, repl),
         numerics=numerics or cell.config["reference_numerics"],
         batch_sharding=batch_sharding if len(devices) > 1 else None)
     return jax.device_get(params), jax.device_get(state), \
@@ -133,13 +131,14 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
                             dir=os.path.join(root, ".bench_work"))
     try:
         flags = program_flags(cell, work)
+        task, hyper = task_of(cell), hyper_of(cell)
         lap("imports")
-        images, labels = write_records(cell, seed, flags)
+        records = write_records(cell, task, seed, flags)
         lap("records")
         program = driver.start_program(
             flags, devices,
-            lambda abstract, sharding: datagen.make_params(seed, abstract,
-                                                           sharding),
+            lambda abstract, sharding: make_params(cell, seed, abstract,
+                                                   sharding),
             telemetry=traced, fault=fault)
         lap("first_dispatch")
         w = driver.measure_window(
@@ -151,9 +150,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         first = program.first
         del program
         gc.collect()   # the program's state is freed before the reference
-        hyper = hyper_of(cell)
         start_params, start_state, ref = reference_chunk(
-            cell, hyper, seed, devices, first.params, images, labels)
+            cell, task, hyper, seed, devices, first.params, records)
         numbers = check.compare(first, start_params, start_state, ref)
         lap("reference")
         trace = xplane.load(w.trace_dir, driver.TRACE_MARKER) \
@@ -172,11 +170,15 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     out = {"correct": correct, "attempted": steps,
            "failed": hyper.steps * sum(1 for x in losses
                                        if not np.isfinite(x))}
-    ctx = {"window_s": w.t1 - w.t0, "steps": steps, "images": steps * batch,
-           "chips": cell.chips, "peak": peak, "spans": w.spans,
-           "trace": trace, "setup_s": w.t0 - t_process_start,
-           "flops_per_image":
-               cell.reference.train_flops_per_image(cell.config)}
+    # one example is one row of the batch: an image, or a sequence of the
+    # traffic's length; the older two names are what the readers know
+    examples = steps * batch
+    flops = cell.reference.train_flops_per_image(cell.config)
+    ctx = {"window_s": w.t1 - w.t0, "steps": steps, "examples": examples,
+           "images": examples, "chips": cell.chips, "peak": peak,
+           "spans": w.spans, "trace": trace,
+           "setup_s": w.t0 - t_process_start, "flops_per_example": flops,
+           "flops_per_image": flops}
     wanted = cell.per_layer if traced else cell.end_to_end
     metrics, silent = {}, []
     for m in wanted:

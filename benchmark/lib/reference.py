@@ -2,10 +2,14 @@
 straightforward jax.numpy, float32 at the highest matmul precision.
 
 Nothing here imports the program. What the program's first dispatch does
-to the state is re-derived from the published description of each piece:
+to the state is re-derived from the published description of each piece.
+Every configuration shares the row of the dataset each batch position
+reads (a cycle-walking Feistel permutation per epoch, written out again
+below in NumPy) and the K steps around it (:func:`make_chunk`). What a
+run is given, how a batch is made of it, the loss and the update are the
+configuration's :class:`Task`. One that states none is an image
+classifier (:func:`image_task`):
 
-- the row of the dataset each batch position reads (a cycle-walking
-  Feistel permutation per epoch, written out again below in NumPy);
 - decode: cast, per-image random crop window and mirror (the draws are
   ``jax.random`` calls on ``fold_in(key(seed), step)``), per-image
   standardisation;
@@ -23,12 +27,15 @@ below what the configuration states.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from benchmark.lib import datagen
 
 # --- the shuffled index stream ------------------------------------------------
 
@@ -163,7 +170,8 @@ def _rounded_store(r: Callable) -> Callable:
 
 
 class Numerics:
-    """``conv`` and ``dense`` as the reference or a control forms them;
+    """``conv``, ``dense`` and ``einsum`` as the reference or a control
+    forms them;
     every product is taken at HIGHEST precision from what the mode leaves
     of its operands.
 
@@ -209,6 +217,13 @@ class Numerics:
             return jnp.dot(a, b, precision=lax.Precision.HIGHEST)
         return self.store(self._product(product)(x, w))
 
+    def einsum(self, spec: str, a, b):
+        """A batched product of two operands, formed like ``dense``:
+        attention's two products, a per-expert product."""
+        def product(x, y):
+            return jnp.einsum(spec, x, y, precision=lax.Precision.HIGHEST)
+        return self.store(self._product(product)(a, b))
+
 
 def max_pool_3x3_s2(x):
     return lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1),
@@ -223,12 +238,173 @@ def softmax_cross_entropy(logits, labels):
 # --- the K steps --------------------------------------------------------------
 
 class Hyper(NamedTuple):
-    """What the cell's flags say about the feed and the update."""
+    """What every task shares: the shuffled stream's seed and size, and
+    the dispatch's batch, length and first step."""
 
     seed: int
     batch: int
     steps: int                 # K: steps in one dispatch
     records: int
+    step0: int = 0
+
+
+class Task(NamedTuple):
+    """What a configuration states of its training, beside its sizes: the
+    four things that differ between an image classifier and any other
+    model. A configuration's module gives one from ``task(spec, flags)``;
+    one that states none gets :func:`image_task`.
+
+    ``write_records(seed, n, paths) -> records``: what a run is given,
+    made from the seed (``n`` training records; ``paths`` is where the
+    program looks, ``{"train": [...], "test": [...]}``), written there,
+    and returned as a tree of host arrays with ``n`` leading rows.
+    ``feed(records, rows [B], key, step) -> batch``: any tree with ``B``
+    leading rows, from the records on the device.
+    ``loss(nm, params, model_state, batch) -> (loss, new_model_state)``.
+    ``init_opt(params) -> {name: tree}`` and ``update(params, opt, grads,
+    step) -> (params, opt)``: the optimizer's state, each tree under the
+    name the program's own state gives it, and one step of it.
+    ``fault(name) -> Task``: this task with one fault planted in the
+    reference (``FAULTS``), to read what the comparison makes of it.
+    """
+
+    write_records: Callable
+    feed: Callable
+    loss: Callable
+    init_opt: Callable
+    update: Callable
+    fault: Callable
+    whole_chunk: bool = False  # one feed for the K*B rows of a dispatch
+    grad_blocks: int = 1       # the gradient taken in this many blocks of
+    #                            the batch, one after the other, and
+    #                            averaged: a reference at a chip-filling
+    #                            size fits beside its optimizer's state
+
+
+FAULTS = ("half_batch",    # half of the batch left out of the loss's mean
+          "no_exchange")   # one chip's quarter of the rows, nothing exchanged
+
+
+class ChunkResult(NamedTuple):
+    params: Any
+    model_state: Any
+    opt: Dict[str, Any]        # the optimizer's trees, by name
+    losses: jax.Array          # [K]
+    first_grad_norms: Any      # per leaf: the norm of step 0's gradient as
+    #                            the optimizer got it
+
+
+def _norm(x):
+    return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
+
+
+def _value_and_grad(loss_fn: Callable, blocks: int) -> Callable:
+    """``(params, model_state, batch) -> ((loss, new_model_state), grads)``;
+    in ``blocks`` > 1 the batch goes through in equal blocks, the model's
+    state from one to the next, and loss and gradient are their means."""
+    grad = jax.value_and_grad(loss_fn, has_aux=True)
+    if blocks == 1:
+        return grad
+
+    def blocked(p, ms, batch):
+        split = jax.tree.map(
+            lambda x: x.reshape(blocks, x.shape[0] // blocks, *x.shape[1:]),
+            batch)
+
+        def one_block(carry, block):
+            ms, loss, g = carry
+            (block_loss, ms), gg = grad(p, ms, block)
+            return (ms, loss + block_loss, jax.tree.map(jnp.add, g, gg)), None
+
+        (ms, loss, g), _ = lax.scan(
+            one_block, (ms, jnp.float32(0.0), jax.tree.map(jnp.zeros_like, p)),
+            split)
+        return (loss / blocks, ms), jax.tree.map(lambda x: x / blocks, g)
+
+    return blocked
+
+
+def make_chunk(task: Task, hyper: Hyper, numerics: str = "float32",
+               batch_sharding=None) -> Callable:
+    """The jitted ``(params, model_state, records, idx [K,B], data_key) ->
+    ChunkResult``: K steps of training from step ``hyper.step0``. Trace it
+    under ``jax.default_matmul_precision("highest")``.
+
+    ``batch_sharding`` (a ``NamedSharding`` over the batch dimension) lets
+    a four-chip cell's reference use all four chips; the arithmetic is the
+    same.
+    """
+    nm = Numerics(numerics)
+    k, b, step0 = hyper.steps, hyper.batch, hyper.step0
+    if b % task.grad_blocks:
+        raise ValueError(f"a batch of {b} does not divide into "
+                         f"{task.grad_blocks} blocks")
+
+    def constrain(x):
+        if batch_sharding is None:
+            return x
+        return lax.with_sharding_constraint(x, batch_sharding(x.ndim))
+
+    grad = _value_and_grad(functools.partial(task.loss, nm),
+                           task.grad_blocks)
+
+    @jax.jit
+    def chunk(p, ms, records, idx, data_key):
+        def one_step(carry, xs):
+            p, ms, opt, first = carry
+            i, rows, batch = xs
+            if batch is None:
+                batch = task.feed(records, constrain(rows), data_key,
+                                  step0 + i)
+            batch = jax.tree.map(constrain, batch)
+            (loss, new_ms), g = grad(p, ms, batch)
+            # in a branch of its own: a reduction that the compiler may
+            # fuse into the gradient's producers changes their round-off
+            first = lax.cond(i == 0, lambda: jax.tree.map(_norm, g),
+                             lambda: first)
+            p, opt = task.update(p, opt, g, step0 + i)
+            return (p, new_ms, opt, first), loss
+
+        first = jax.tree.map(lambda x: jnp.float32(0.0), p)
+        whole = None
+        if task.whole_chunk:
+            flat = task.feed(records, idx.reshape(-1), data_key, step0)
+            whole = jax.tree.map(lambda x: x.reshape(k, b, *x.shape[1:]),
+                                 flat)
+        (p, ms, opt, first), losses = lax.scan(
+            one_step, (p, ms, task.init_opt(p), first),
+            (jnp.arange(k), idx, whole))
+        return ChunkResult(p, ms, opt, losses, first)
+
+    return chunk
+
+
+def run_chunk(task: Task, hyper: Hyper, params, model_state, records,
+              numerics: str = "float32", batch_sharding=None) -> ChunkResult:
+    """K steps of training from ``(params, model_state)`` at step
+    ``hyper.step0`` (see :func:`make_chunk`)."""
+    chunk = make_chunk(task, hyper, numerics, batch_sharding)
+    idx = stream_rows(hyper.seed, hyper.step0 * hyper.batch,
+                      hyper.steps * hyper.batch, hyper.records
+                      ).reshape(hyper.steps, hyper.batch)
+    with jax.default_matmul_precision("highest"):
+        return chunk(params, model_state, records, jnp.asarray(idx),
+                     jax.random.key(hyper.seed))
+
+
+# --- the default task: an image classifier under SGD --------------------------
+
+TEST_RECORDS = 512          # the program's default size of its test split
+WHOLE_CHUNK_DECODE_BYTES = 1 << 30
+
+
+class ImageHyper(NamedTuple):
+    """What the default task reads from the configuration's file (the
+    records' shape, the decode) and from the cell's flags (the update)."""
+
+    classes: int
+    image_size: int
+    channels: int
     crop: int
     random_crop: bool
     random_flip: bool
@@ -238,104 +414,85 @@ class Hyper(NamedTuple):
     momentum: float
     weight_decay: float
     decode_whole_chunk: bool   # one key for K*B images, else one per step
-    # faults, planted to read what the comparison makes of them:
-    batch_keep: Optional[int] = None   # the loss's mean over these rows only
-    rows_seen: Optional[int] = None    # the step sees these rows only (one
-    #                                    chip's share, nothing exchanged)
+    fault: Optional[str] = None   # one of FAULTS, planted
 
 
-class ChunkResult(NamedTuple):
-    params: Any
-    model_state: Any
-    momentum: Any
-    losses: jax.Array          # [K]
-    first_grad: Any            # gradient of step 0 as the optimizer got it
+def image_hyper(spec: dict, flags: dict) -> ImageHyper:
+    side = max(spec["image_size"], spec["crop_size"])
+    decoded = flags["steps_per_dispatch"] * flags["batch_size"] \
+        * side * side * spec["num_channels"] * 4
+    return ImageHyper(
+        classes=spec["num_classes"], image_size=spec["image_size"],
+        channels=spec["num_channels"], crop=spec["crop_size"],
+        random_crop=spec["decode"]["random_crop"],
+        random_flip=spec["decode"]["random_flip"],
+        normalize=spec["decode"]["normalize"],
+        learning_rate=flags["learning_rate"],
+        warmup_steps=flags.get("warmup_steps", 0),
+        momentum=flags.get("momentum", 0.0),
+        weight_decay=flags.get("weight_decay", 0.0),
+        decode_whole_chunk=decoded <= WHOLE_CHUNK_DECODE_BYTES)
 
 
-def make_chunk(forward: Callable, hyper: Hyper, numerics: str = "float32",
-               batch_sharding=None, step0: int = 0) -> Callable:
-    """The jitted ``(params, model_state, images_u8, labels, idx [K,B],
-    data_key) -> ChunkResult``: K steps of training from step ``step0``.
-    Trace it under ``jax.default_matmul_precision("highest")``.
+def image_task(ih: ImageHyper, forward: Callable) -> Task:
+    """uint8 images with a label each, a crop, a mirror and a
+    normalisation, the mean softmax cross-entropy over rows, SGD with
+    coupled weight decay, a momentum trace (under the name ``momentum``)
+    and a linear warm-up of the learning rate.
 
     ``forward(nm, params, model_state, images) -> (logits, new_model_state)``
-    is the configuration's plain forward pass. ``batch_sharding`` (a
-    ``NamedSharding`` over the batch dimension) lets a four-chip cell's
-    reference use all four chips; the arithmetic is the same.
+    is the configuration's plain forward pass.
     """
-    nm = Numerics(numerics)
-    k, b = hyper.steps, hyper.batch
+    if ih.fault is not None and ih.fault not in FAULTS:
+        raise ValueError(f"unknown fault {ih.fault!r}")
 
-    def constrain(x):
-        if batch_sharding is None:
-            return x
-        return lax.with_sharding_constraint(x, batch_sharding(x.ndim))
+    def write_records(seed, n, paths):
+        made = []
+        for s, count, files in ((seed, n, paths["train"]),
+                                (seed + 1, TEST_RECORDS, paths["test"])):
+            images, labels = datagen.make_records(
+                s, count, ih.classes, ih.image_size, ih.image_size,
+                ih.channels)
+            datagen.write_record_files(files, images, labels, ih.classes)
+            made.append((images, labels))
+        return made[0]
 
-    def loss_fn(p, ms, x, y):
-        if hyper.rows_seen is not None:
-            x, y = x[:hyper.rows_seen], y[:hyper.rows_seen]
+    def feed(records, rows, key, step):
+        images, labels = records
+        x = decode(images[rows], jax.random.fold_in(key, step), ih.crop,
+                   ih.crop, ih.random_crop, ih.random_flip, ih.normalize)
+        return x, labels[rows]
+
+    def loss(nm, p, ms, batch):
+        x, y = batch
+        if ih.fault == "no_exchange":
+            seen = x.shape[0] // 4
+            x, y = x[:seen], y[:seen]
         logits, new_ms = forward(nm, p, ms, x)
-        keep = hyper.batch_keep
-        if keep is not None:
+        if ih.fault == "half_batch":
+            keep = logits.shape[0] // 2
             logits, y = logits[:keep], y[:keep]
         return softmax_cross_entropy(logits.astype(jnp.float32), y), new_ms
 
-    @jax.jit
-    def chunk(p, ms, imgs, lbls, idx, data_key):
-        def one_step(carry, xs):
-            p, ms, mom, first = carry
-            i, rows, x = xs
-            y = constrain(lbls[rows])
-            if x is None:
-                key = jax.random.fold_in(data_key, step0 + i)
-                x = decode(constrain(imgs[rows]), key, hyper.crop,
-                           hyper.crop, hyper.random_crop, hyper.random_flip,
-                           hyper.normalize)
-            x = constrain(x)
-            (loss, new_ms), g = jax.value_and_grad(loss_fn, has_aux=True)(
-                p, ms, x, y)
-            first = jax.tree.map(lambda f, gg: jnp.where(i == 0, gg, f),
-                                 first, g)
-            if hyper.weight_decay:
-                g = jax.tree.map(lambda gg, pp: gg + hyper.weight_decay * pp,
-                                 g, p)
-            if mom is not None:
-                mom = jax.tree.map(lambda m, gg: hyper.momentum * m + gg,
-                                   mom, g)
-                g = mom
-            lr = jnp.float32(hyper.learning_rate)
-            if hyper.warmup_steps:
-                step = (step0 + i).astype(jnp.float32)
-                lr = lr * jnp.clip((step + 1.0) / hyper.warmup_steps,
-                                   0.0, 1.0)
-            p = jax.tree.map(lambda pp, gg: pp - lr * gg, p, g)
-            return (p, new_ms, mom, first), loss
+    def init_opt(p):
+        return {"momentum": jax.tree.map(jnp.zeros_like, p)} \
+            if ih.momentum else {}
 
-        mom = jax.tree.map(jnp.zeros_like, p) if hyper.momentum else None
-        first = jax.tree.map(jnp.zeros_like, p)
-        whole = None
-        if hyper.decode_whole_chunk:
-            key = jax.random.fold_in(data_key, step0)
-            flat = decode(imgs[idx.reshape(-1)], key, hyper.crop, hyper.crop,
-                          hyper.random_crop, hyper.random_flip,
-                          hyper.normalize)
-            whole = flat.reshape(k, b, *flat.shape[1:])
-        (p, ms, mom, first), losses = lax.scan(
-            one_step, (p, ms, mom, first), (jnp.arange(k), idx, whole))
-        return ChunkResult(p, ms, mom, losses, first)
+    def update(p, opt, g, step):
+        if ih.weight_decay:
+            g = jax.tree.map(lambda gg, pp: gg + ih.weight_decay * pp, g, p)
+        if ih.momentum:
+            g = jax.tree.map(lambda m, gg: ih.momentum * m + gg,
+                             opt["momentum"], g)
+            opt = {"momentum": g}
+        lr = jnp.float32(ih.learning_rate)
+        if ih.warmup_steps:
+            lr = lr * jnp.clip(
+                (jnp.asarray(step).astype(jnp.float32) + 1.0)
+                / ih.warmup_steps, 0.0, 1.0)
+        return jax.tree.map(lambda pp, gg: pp - lr * gg, p, g), opt
 
-    return chunk
-
-
-def run_chunk(forward: Callable, hyper: Hyper, params, model_state,
-              images_u8, labels, numerics: str = "float32",
-              batch_sharding=None, step0: int = 0) -> ChunkResult:
-    """K steps of training from ``(params, model_state)`` at step ``step0``
-    (see :func:`make_chunk`)."""
-    chunk = make_chunk(forward, hyper, numerics, batch_sharding, step0)
-    idx = stream_rows(hyper.seed, step0 * hyper.batch,
-                      hyper.steps * hyper.batch, hyper.records
-                      ).reshape(hyper.steps, hyper.batch)
-    with jax.default_matmul_precision("highest"):
-        return chunk(params, model_state, images_u8, labels,
-                     jnp.asarray(idx), jax.random.key(hyper.seed))
+    return Task(write_records, feed, loss, init_opt, update,
+                fault=lambda name: image_task(ih._replace(fault=name),
+                                              forward),
+                whole_chunk=ih.decode_whole_chunk)

@@ -38,7 +38,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control", default=None)
-    ap.add_argument("--faults", default="")
+    ap.add_argument("--faults", default="",
+                    help="as the configuration's task plants them")
     ap.add_argument("--program_control", default=None)
     ap.add_argument("--reference", default=None)
     ap.add_argument("--set", default="", dest="overrides")
@@ -47,7 +48,7 @@ def main(argv=None) -> int:
                     help="directory of BENCHMARK.json (tests)")
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
-    from benchmark.lib import cells, check, datagen, driver, harness, peaks
+    from benchmark.lib import cells, check, driver, harness, peaks
 
     def parse(pairs):
         return {k: json.loads(v) if v[:1] in "-0123456789tf[{\"" else v
@@ -70,24 +71,21 @@ def main(argv=None) -> int:
             out.write(line + "\n")
             out.flush()
 
-    def as_first(ref):
-        return driver.FirstDispatch(float(ref.losses[-1]), ref.params,
-                                    ref.model_state, ref.momentum)
-
     for seed in [int(s) for s in args.seeds.split(",")]:
         os.makedirs(os.path.join(args.root, ".bench_work"), exist_ok=True)
         work = tempfile.mkdtemp(prefix="calibrate.",
                                 dir=os.path.join(args.root, ".bench_work"))
         try:
             flags = harness.program_flags(cell, work, overrides)
-            images, labels = harness.write_records(cell, seed, flags)
+            task = harness.task_of(cell, overrides)
+            records = harness.write_records(cell, task, seed, flags)
             hyper = harness.hyper_of(cell, overrides)
 
             def run_program(flags):
                 t = time.perf_counter()
                 program = driver.start_program(
                     flags, devices,
-                    lambda a, sh: datagen.make_params(seed, a, sh))
+                    lambda a, sh: harness.make_params(cell, seed, a, sh))
                 first = program.first
                 del program
                 gc.collect()
@@ -97,7 +95,7 @@ def main(argv=None) -> int:
             like = first.params
             t = time.perf_counter()
             p0, s0, ref = harness.reference_chunk(
-                cell, hyper, seed, devices, like, images, labels,
+                cell, task, hyper, seed, devices, like, records,
                 numerics=args.reference)
             t_ref = time.perf_counter() - t
             emit(seed, "reference_losses",
@@ -116,22 +114,20 @@ def main(argv=None) -> int:
             if args.control:
                 t = time.perf_counter()
                 _, _, c = harness.reference_chunk(
-                    cell, hyper, seed, devices, like, images, labels,
+                    cell, task, hyper, seed, devices, like, records,
                     numerics=args.control)
                 emit(seed, "control_" + args.control,
-                     check.compare(as_first(c), p0, s0, ref),
+                     check.compare(driver.in_the_programs_place(c), p0, s0,
+                                   ref),
                      time.perf_counter() - t)
             for fault in [f for f in args.faults.split(",") if f]:
-                broken = {
-                    "half_batch": dict(batch_keep=hyper.batch // 2),
-                    "no_exchange": dict(rows_seen=hyper.batch // 4),
-                }[fault]
                 t = time.perf_counter()
                 _, _, c = harness.reference_chunk(
-                    cell, hyper._replace(**broken), seed, devices, like,
-                    images, labels)
+                    cell, task.fault(fault), hyper, seed, devices, like,
+                    records)
                 emit(seed, "fault_" + fault,
-                     check.compare(as_first(c), p0, s0, ref),
+                     check.compare(driver.in_the_programs_place(c), p0, s0,
+                                   ref),
                      time.perf_counter() - t)
         finally:
             shutil.rmtree(work, ignore_errors=True)

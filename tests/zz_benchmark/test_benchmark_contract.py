@@ -8,7 +8,7 @@ import re
 import pytest
 
 import bench_roots
-from benchmark.lib import cells, harness, driver
+from benchmark.lib import cells, harness, driver, reference
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -105,23 +105,31 @@ def test_the_reference_reads_the_feed_as_the_programs_flags_state_it(
     cell = cells.load_cell(root, workload)
     cfg = driver.build_train_config(harness.program_flags(cell,
                                                           str(tmp_path)))
+    # what every task shares, and what the default task (an image
+    # classifier under SGD: neither configuration states another) reads
     hyper = harness.hyper_of(cell)
-    assert (hyper.random_crop, hyper.random_flip, hyper.normalize) == (
+    assert not hasattr(cell.reference, "task")
+    image = reference.image_hyper(cell.config, harness.cell_flags(cell))
+    assert harness.task_of(cell).whole_chunk == image.decode_whole_chunk
+    assert (image.random_crop, image.random_flip, image.normalize) == (
         cfg.data.random_crop, cfg.data.random_flip, cfg.data.normalize)
-    assert (hyper.crop, hyper.records) == (
+    assert (image.crop, hyper.records) == (
         cfg.data.crop_height, cfg.data.synthetic_train_records)
-    assert cfg.data.image_height == cell.config["image_size"]
-    assert (hyper.learning_rate, hyper.momentum, hyper.weight_decay) == (
+    assert cfg.data.image_height == cell.config["image_size"] \
+        == image.image_size
+    assert (image.learning_rate, image.momentum, image.weight_decay) == (
         cfg.optim.learning_rate, cfg.optim.momentum, cfg.optim.weight_decay)
-    # the reference knows a constant rate under a linear warm-up, plain SGD
+    # the default task knows a constant rate under a linear warm-up, plain SGD
     assert (cfg.optim.schedule, cfg.optim.optimizer) == ("constant", "sgd")
-    assert hyper.warmup_steps == cfg.optim.warmup_steps
+    assert image.warmup_steps == cfg.optim.warmup_steps
     assert not cfg.optim.label_smoothing and not cfg.optim.grad_clip_norm
     assert cfg.optim.grad_accum == 1 and not cfg.optim.ema_decay
-    assert (hyper.batch, hyper.steps, hyper.seed) == (
-        cfg.batch_size, cfg.steps_per_dispatch, cfg.data.seed)
+    assert (hyper.batch, hyper.steps, hyper.seed, hyper.step0) == (
+        cfg.batch_size, cfg.steps_per_dispatch, cfg.data.seed, 0)
     assert cfg.output_every % cfg.steps_per_dispatch == 0
     assert cfg.eval_every > cfg.total_steps < cfg.checkpoint_every
     assert cfg.model.compute_dtype == cell.config["compute_dtype"]
-    assert not cfg.model.logit_relu and not hyper.decode_whole_chunk
-    assert cfg.model.num_classes == cell.config["num_classes"]
+    assert not cfg.model.logit_relu and not image.decode_whole_chunk
+    assert cfg.model.num_classes == cell.config["num_classes"] \
+        == image.classes
+    assert cfg.data.num_channels == image.channels and image.fault is None

@@ -95,8 +95,9 @@ def test_the_stream_is_the_programs(n, step0, batch, k):
 def test_warm_up_ramps_the_rate_as_the_program_does():
     """One parameter whose gradient stays near 1/2: the change is half
     the sum of the rates."""
-    hyper = reference.Hyper(
-        seed=1, batch=4, steps=3, records=8, crop=4, random_crop=False,
+    hyper = reference.Hyper(seed=1, batch=4, steps=3, records=8)
+    image = reference.ImageHyper(
+        classes=2, image_size=4, channels=3, crop=4, random_crop=False,
         random_flip=False, normalize="none", learning_rate=0.5,
         warmup_steps=4, momentum=0.0, weight_decay=0.0,
         decode_whole_chunk=True)
@@ -109,15 +110,15 @@ def test_warm_up_ramps_the_rate_as_the_program_does():
 
     images = jnp.zeros((8, 4, 4, 3), jnp.uint8)
     labels = jnp.ones((8,), jnp.int32)
-    out = reference.run_chunk(forward, hyper, {"b": jnp.float32(0.0)}, {},
-                              images, labels)
+    out = reference.run_chunk(reference.image_task(image, forward), hyper,
+                              {"b": jnp.float32(0.0)}, {}, (images, labels))
     # d loss / d b = softmax(b, 0)[0] - [label == 0] = 0.5 at b = 0, and
     # stays near it; the rates are 0.5 * (1, 2, 3) / 4
     rates = 0.5 * np.array([1, 2, 3]) / 4
     assert float(out.params["b"]) == pytest.approx(-0.5 * rates.sum(),
                                                    rel=0.2)
-    first = float(out.first_grad["b"])
-    assert first == pytest.approx(0.5)
+    first = float(out.first_grad_norms["b"])
+    assert first == pytest.approx(0.5) and out.opt == {}
 
 
 def test_a_gap_of_norms_is_blind_to_a_turn_and_the_difference_is_not():

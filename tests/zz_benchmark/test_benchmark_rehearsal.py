@@ -203,8 +203,8 @@ def test_the_control_comes_out_not_correct(tiny_root, no_chip_needed,
     below float32, at the test's size."""
     cell = cells.load_cell(tiny_root, "tiny1")
     flags = harness.program_flags(cell, str(tmp_path))
-    images, labels = harness.write_records(cell, SEED, flags)
-    hyper = harness.hyper_of(cell)
+    task, hyper = harness.task_of(cell), harness.hyper_of(cell)
+    records = harness.write_records(cell, task, SEED, flags)
     like = jax.eval_shape(
         lambda: {n: {"kernel": jnp.zeros(s), "bias": jnp.zeros(s[-1])}
                  for n, s in (("conv1", (5, 5, 3, 64)),
@@ -212,15 +212,32 @@ def test_the_control_comes_out_not_correct(tiny_root, no_chip_needed,
                               ("full1", (2304, 384)), ("full2", (384, 192)),
                               ("full3", (192, 10)))})
     devices = jax.devices()[:1]
-    p0, s0, ref = harness.reference_chunk(cell, hyper, SEED, devices, like,
-                                          images, labels)
-    _, _, low = harness.reference_chunk(cell, hyper, SEED, devices, like,
-                                        images, labels, numerics=numerics)
-    as_program = driver.FirstDispatch(float(low.losses[-1]), low.params,
-                                      low.model_state, low.momentum)
-    same = driver.FirstDispatch(float(ref.losses[-1]), ref.params,
-                                ref.model_state, ref.momentum)
+    p0, s0, ref = harness.reference_chunk(cell, task, hyper, SEED, devices,
+                                          like, records)
+    _, _, low = harness.reference_chunk(cell, task, hyper, SEED, devices,
+                                        like, records, numerics=numerics)
+    as_program = driver.in_the_programs_place(low)
+    same = driver.in_the_programs_place(ref)
     assert check.verdict(check.compare(same, p0, s0, ref), LIMITS)[0]
     correct, compared = check.verdict(
         check.compare(as_program, p0, s0, ref), LIMITS)
     assert correct is False, compared
+
+
+def test_the_tool_that_reads_limits_runs_on_the_seam(tiny_root,
+                                                     no_chip_needed, capfd):
+    """``benchmark/tools/calibrate.py`` at the test's size: the program's
+    sound run, the control and both faults as the task plants them."""
+    from benchmark.tools import calibrate
+    assert calibrate.main([
+        "--workload", "tiny1", "--seeds", str(SEED), "--control", "bfloat16",
+        "--faults", "half_batch,no_exchange", "--root", tiny_root]) == 0
+    out, _ = capfd.readouterr()
+    read = {r["what"]: r["numbers"] for r in map(json.loads,
+                                                 out.strip().splitlines())}
+    assert set(read) == {"reference_losses", "program", "control_bfloat16",
+                         "fault_half_batch", "fault_no_exchange"}
+    assert check.verdict(read["program"], LIMITS)[0]
+    for what in ("control_bfloat16", "fault_half_batch",
+                 "fault_no_exchange"):
+        assert not check.verdict(read[what], LIMITS)[0], (what, read[what])
