@@ -1,0 +1,11 @@
+"""Device time of a step in instructions of layer kind ``attention``, both
+passes and what the backward pass computes a second time: the trace's
+events joined by instruction name with the program's instruction-to-layer
+map. Nothing where the program built no map, or has no instruction of the
+kind."""
+
+from benchmark.lib import scopes
+
+
+def read(ctx):
+    return scopes.kind_ms_per_step(ctx, "attention")

@@ -22,7 +22,13 @@ the records are generated from the seed, there is no network):
     resume from the step-400 checkpoint;
 (c) 20 steps of the per-step path (``--steps_per_dispatch 1``, the CLI
     default), which feeds batches from the host through the native
-    record loader.
+    record loader;
+(d) 20 steps of the looped decoder over tokens (``--model looped_decoder
+    --dataset tokens_synth``) at a small size with the published head size
+    (2 layers run 4 times, 2 heads of 128, 256 tokens, AdamW, a layer
+    recomputed in the backward pass): the loss must be finite and fall,
+    and the lowered step must hold the flash-attention kernels (forward,
+    dq and dk/dv).
 
 It fails on a non-finite loss, on a training accuracy that did not rise
 on the separable synthetic set, on a step counter or checkpoint that is
@@ -140,17 +146,9 @@ def compile_seconds(records: list, phase_name: str) -> float:
     return events[0]["compile_s"]
 
 
-def check_lowered_step(store: str, phase_name: str, n_devices: int,
-                       n_leaves: int) -> None:
-    """The no-fallback check, on the StableHLO of the step that ran."""
-    import jax.numpy as jnp
-
+def lowered_text(store: str, phase_name: str) -> str:
+    """The StableHLO of the step that ran, from the keyed compile store."""
     from dml_cnn_cifar10_tpu.compilecache import CompileCache
-    from dml_cnn_cifar10_tpu.ops import relu_pool
-
-    # conv1's activation on one device, as the pools' chooser sees it
-    pools = 4 * relu_pool.fits_kernels((BATCH // n_devices, 24, 24, 64),
-                                       jnp.float32)
 
     text = None
     for key, meta in CompileCache(store).entries():
@@ -160,6 +158,21 @@ def check_lowered_step(store: str, phase_name: str, n_devices: int,
     if text is None:
         raise SmokeFailure(f"the keyed compile store holds no lowered "
                            f"{phase_name} (cache machinery failed open?)")
+    return text
+
+
+def check_lowered_step(store: str, phase_name: str, n_devices: int,
+                       n_leaves: int) -> None:
+    """The no-fallback check, on the StableHLO of the step that ran."""
+    import jax.numpy as jnp
+
+    from dml_cnn_cifar10_tpu.ops import relu_pool
+
+    # conv1's activation on one device, as the pools' chooser sees it
+    pools = 4 * relu_pool.fits_kernels((BATCH // n_devices, 24, 24, 64),
+                                       jnp.float32)
+
+    text = lowered_text(store, phase_name)
     kernels = text.count("tpu_custom_call")
     check(kernels == n_leaves + pools,
           f"{phase_name}: {kernels} Mosaic tpu_custom_call in the lowered "
@@ -173,6 +186,43 @@ def check_lowered_step(store: str, phase_name: str, n_devices: int,
               or "SPMDFullToShardShape" in text,
               f"{phase_name}: the kernels sit in a manual computation "
               f"(replicated shard_map)")
+
+
+DECODER_SIZES = {
+    "hidden_size": 256, "num_attention_heads": 2, "num_key_value_heads": 2,
+    "head_dim": 128, "intermediate_size": 512, "num_hidden_layers": 2,
+    "vocab_size": 512, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
+    "total_ut_steps": 4, "exit_entropy_beta": 0.1}
+
+
+def smoke_decoder(work: str) -> dict:
+    """(d): the looped decoder through the same CLI, resident token rows,
+    two steps a dispatch."""
+    sizes = os.path.join(work, "decoder_sizes.json")
+    with open(sizes, "w") as f:
+        json.dump(DECODER_SIZES, f)
+    # after COMMON: the later flag wins
+    d = run_cli("d_decoder", work, "logs_decoder",
+                ["--model", "looped_decoder", "--model_config_file", sizes,
+                 "--dataset", "tokens_synth", "--sequence_length", "256",
+                 "--batch_size", "8", "--synthetic_train_records", "256",
+                 "--compute_dtype", "bfloat16", "--remat", "true",
+                 "--optimizer", "adamw", "--learning_rate", "0.003",
+                 "--adam_b2", "0.95", "--weight_decay", "0.1",
+                 "--schedule", "constant", "--steps_per_dispatch", "2",
+                 "--total_steps", "20", "--output_every", "4",
+                 "--eval_every", "20", "--checkpoint_every", "20"])
+    check_training("d", d, 4, 4, 20)
+    losses = [r["loss"] for r in of_kind(d, "train")]
+    check(losses[-1] < losses[0],
+          f"d: the decoder's loss fell over 20 steps ({losses})")
+    kernels = lowered_text(os.path.join(work, "keyed_d_decoder"),
+                           "train_chunk_resident").count("tpu_custom_call")
+    check(kernels >= 3,
+          f"d: {kernels} Mosaic tpu_custom_call in the decoder's lowered "
+          f"step (at least the forward, the dq and the dk/dv kernel; "
+          f"identical calls may share one lowered function)")
+    return {"decoder_losses": losses}
 
 
 def check_batch_placement(n_devices: int) -> None:
@@ -247,7 +297,8 @@ def smoke(work: str, n_devices: int) -> dict:
                        "train_step", n_devices, n_leaves)
 
     check_batch_placement(n_devices)
-    return {"compile_s_first_dispatch": compile_s,
+    return {**smoke_decoder(work),
+            "compile_s_first_dispatch": compile_s,
             "compile_s_per_step": compile_seconds(c, "train_step"),
             "loss_step_400": loss_400, "loss_step_600": loss_600,
             "per_step_loss_step_20": loss_20,

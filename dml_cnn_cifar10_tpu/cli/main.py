@@ -55,13 +55,26 @@ def build_parser() -> argparse.ArgumentParser:
     # --- framework flags ---
     p.add_argument("--model", type=str, default="cnn",
                    choices=["cnn", "resnet18", "resnet50", "vit_tiny",
-                            "vit_moe"])
+                            "vit_moe", "looped_decoder"],
+                   help="looped_decoder: a causal decoder over tokens whose "
+                        "layers run several times on the same weights "
+                        "(needs --dataset tokens_synth)")
+    p.add_argument("--model_config_file", type=str, default=None,
+                   help="sizes of a model that reads them from a file in "
+                        "the shape of a published config.json "
+                        "(looped_decoder; default: its small built-in "
+                        "sizes)")
     p.add_argument("--dataset", type=str, default="cifar10",
                    choices=["cifar10", "cifar100", "synthetic",
-                            "imagenet_synth"],
+                            "imagenet_synth", "tokens_synth"],
                    help="imagenet_synth: generated ImageNet-shaped shards "
                         "(256x256, 1000 classes, wide 2-byte labels) — the "
-                        "ResNet-50 ladder rung on an air-gapped box")
+                        "ResNet-50 ladder rung on an air-gapped box; "
+                        "tokens_synth: generated rows of --sequence_length "
+                        "+ 1 int32 token ids over the model's vocabulary")
+    p.add_argument("--sequence_length", type=int, default=128,
+                   help="tokens of a sequence (token datasets): a record "
+                        "holds one more, inputs [:-1] and targets [1:]")
     p.add_argument("--image_size", type=int, default=None,
                    help="stored square image side (default: 32, or 256 "
                         "for imagenet_synth)")
@@ -390,6 +403,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "choice for large models)")
     p.add_argument("--momentum", type=float, default=0.0,
                    help="SGD momentum (reference uses plain SGD)")
+    p.add_argument("--adam_b1", type=float, default=0.9)
+    p.add_argument("--adam_b2", type=float, default=0.999)
+    p.add_argument("--adam_eps", type=float, default=1e-8)
     p.add_argument("--weight_decay", type=float, default=0.0)
     p.add_argument("--label_smoothing", type=float, default=0.0)
     p.add_argument("--random_brightness", type=float, default=0.0,
@@ -774,6 +790,26 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
         cfg.data.synthetic_train_records = args.synthetic_train_records
     cfg.model.name = args.model
     cfg.model.compute_dtype = args.compute_dtype
+    cfg.model.config_file = args.model_config_file
+    cfg.data.sequence_length = args.sequence_length
+    if (args.dataset == "tokens_synth") != (args.model == "looped_decoder"):
+        raise SystemExit(
+            f"--dataset tokens_synth and --model looped_decoder go "
+            f"together (got {args.dataset} with {args.model}): a model "
+            f"over tokens reads token rows and nothing else does")
+    if args.model == "looped_decoder" and args.mode not in ("train", "eval"):
+        raise SystemExit(
+            f"--model looped_decoder trains and evaluates; --mode "
+            f"{args.mode} takes an image classifier (the serving stack "
+            f"has no token requests and no KV cache)")
+    if args.dataset == "tokens_synth":
+        # the generated ids cover the model's whole vocabulary
+        from dml_cnn_cifar10_tpu.models import looped_decoder
+        cfg.data.num_classes = cfg.model.num_classes = \
+            looped_decoder.sizes(cfg.model)["vocab_size"]
+    cfg.optim.adam_b1 = args.adam_b1
+    cfg.optim.adam_b2 = args.adam_b2
+    cfg.optim.adam_eps = args.adam_eps
     cfg.optim.learning_rate = args.learning_rate
     cfg.optim.grad_accum = args.grad_accum
     cfg.optim.optimizer = args.optimizer

@@ -26,7 +26,8 @@ from typing import Optional, Tuple
 class DataConfig:
     """Input pipeline config. Reference: ``cifar10cnn.py:9-27,34-91``."""
 
-    dataset: str = "cifar10"              # cifar10 | cifar100 | synthetic
+    # cifar10 | cifar100 | synthetic | imagenet_synth | tokens_synth
+    dataset: str = "cifar10"
     data_dir: str = "cifar10data"         # reference constant (cifar10cnn.py:26)
     image_height: int = 32                # cifar10cnn.py:15
     image_width: int = 32                 # cifar10cnn.py:16
@@ -72,12 +73,23 @@ class DataConfig:
     # record layout) for air-gapped testing/benchmarking.
     synthetic_train_records: int = 2048
     synthetic_test_records: int = 512
+    # Token datasets (``tokens_synth``): a record is ``sequence_length + 1``
+    # little-endian int32 token ids below ``num_classes`` (the vocabulary,
+    # which the CLI takes from the model's sizes): inputs ``[:-1]``,
+    # next-token targets ``[1:]``. No label, no decode: on the device a
+    # batch is a gather of rows, and the shift is the model's.
+    sequence_length: int = 128
 
     # Every randomized-augmentation field and its "off" value — the one
     # list ``augmented`` and ``without_augmentation`` both derive from, so
     # a new augmentation knob cannot drift between them.
     _AUG_OFF = (("random_crop", False), ("random_flip", False),
                 ("random_brightness", 0.0), ("random_contrast", 0.0))
+
+    @property
+    def tokens(self) -> bool:
+        """True for a dataset of token rows (no image, no label)."""
+        return self.dataset == "tokens_synth"
 
     @property
     def augmented(self) -> bool:
@@ -106,6 +118,10 @@ class ModelConfig:
 
     name: str = "cnn"                     # cnn | resnet18 | resnet50 | vit_tiny
     num_classes: int = 10
+    # A file of sizes in the shape of a published ``config.json``, for a
+    # model that reads one (models/looped_decoder.py) in place of a flat
+    # family of fields here.
+    config_file: Optional[str] = None
     # Reference applies ReLU to the final logits (cifar10cnn.py:145). Faithful
     # mode keeps it; fixed mode emits raw logits.
     logit_relu: bool = True

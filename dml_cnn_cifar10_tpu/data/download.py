@@ -54,6 +54,9 @@ CIFAR100_FOLDER = "cifar-100-binary"
 # (wide_label below). ImageNet itself has no binary-record distribution and
 # this box has no egress; the shards are always generated synthetically.
 IMAGENET_SYNTH_FOLDER = "imagenet-synth-bin"
+# Token rows for a model over tokens: each record ``sequence_length + 1``
+# little-endian int32 ids, no label. Always generated: no corpus is here.
+TOKENS_SYNTH_FOLDER = "tokens-synth-bin"
 
 
 def _progress(url: str):
@@ -173,6 +176,9 @@ def train_files(cfg: DataConfig) -> List[str]:
     if cfg.dataset == "imagenet_synth":
         base = os.path.join(cfg.data_dir, IMAGENET_SYNTH_FOLDER)
         return [os.path.join(base, f"train_{i}.bin") for i in range(1, 5)]
+    if cfg.dataset == "tokens_synth":
+        base = os.path.join(cfg.data_dir, TOKENS_SYNTH_FOLDER)
+        return [os.path.join(base, f"train_{i}.bin") for i in range(1, 5)]
     raise ValueError(f"unknown dataset {cfg.dataset!r}")
 
 
@@ -184,6 +190,8 @@ def test_files(cfg: DataConfig) -> List[str]:
         return [os.path.join(cfg.data_dir, CIFAR100_FOLDER, "test.bin")]
     if cfg.dataset == "imagenet_synth":
         return [os.path.join(cfg.data_dir, IMAGENET_SYNTH_FOLDER, "val.bin")]
+    if cfg.dataset == "tokens_synth":
+        return [os.path.join(cfg.data_dir, TOKENS_SYNTH_FOLDER, "val.bin")]
     raise ValueError(f"unknown dataset {cfg.dataset!r}")
 
 
@@ -260,6 +268,36 @@ def generate_synthetic_dataset(cfg: DataConfig, seed: int = 0) -> None:
         write(path, cfg.synthetic_test_records)
 
 
+def generate_token_dataset(cfg: DataConfig, seed: int = 0) -> None:
+    """Write the ``tokens_synth`` shards: rows of ``sequence_length + 1``
+    little-endian int32 ids. Each row counts on from its own start by its
+    own stride over the ``num_classes`` ids of the vocabulary, one token in
+    ten replaced by noise, so that a model has something to learn and every
+    row differs. A shard that already has the requested size is kept (the
+    benchmark writes its own rows there first)."""
+    rng = np.random.default_rng(seed)
+    width, vocab = cfg.sequence_length + 1, cfg.num_classes
+
+    def write(path: str, n: int) -> None:
+        if os.path.isfile(path) and os.path.getsize(path) == n * width * 4:
+            return
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        start = rng.integers(0, vocab, size=(n, 1))
+        stride = rng.integers(1, 8, size=(n, 1))
+        tokens = (start + stride * np.arange(width)[None, :]) % vocab
+        noise = rng.integers(0, vocab, size=tokens.shape)
+        tokens = np.where(rng.random(tokens.shape) < 0.1, noise, tokens)
+        tmp = path + ".tmp"
+        tokens.astype("<i4").tofile(tmp)
+        os.replace(tmp, path)
+
+    per_shard = max(1, cfg.synthetic_train_records // len(train_files(cfg)))
+    for path in train_files(cfg):
+        write(path, per_shard)
+    for path in test_files(cfg):
+        write(path, cfg.synthetic_test_records)
+
+
 def ensure_dataset(cfg: DataConfig) -> None:
     """Make sure the binary shards exist: download, or synthesize offline.
 
@@ -267,6 +305,9 @@ def ensure_dataset(cfg: DataConfig) -> None:
     ``synthetic`` mode (or when the download fails — e.g. an air-gapped host)
     it falls back to :func:`generate_synthetic_dataset`.
     """
+    if cfg.tokens:
+        generate_token_dataset(cfg, seed=cfg.seed)
+        return
     if cfg.dataset in ("synthetic", "imagenet_synth"):
         # imagenet_synth is generate-only: ImageNet has no fixed-length
         # binary distribution to download; the rung's record framing is

@@ -41,7 +41,14 @@ class DataPipelineError(RuntimeError):
 
 
 def _load_split(files: List[str], cfg: DataConfig):
-    """Decode all shards once, as uint8 HWC (cast happens per batch)."""
+    """Decode all shards once, as uint8 HWC (cast happens per batch). A
+    token dataset's rows come as they are, ``[N, S+1]`` int32, in the
+    images' place, with a label column of zeros that nothing reads."""
+    if cfg.tokens:
+        rows = np.concatenate([
+            np.fromfile(path, dtype="<i4").reshape(
+                -1, cfg.sequence_length + 1) for path in files])
+        return rows.astype(np.int32), np.zeros(len(rows), np.int32)
     nlb = download.label_bytes(cfg)
     record_bytes = cfg.record_bytes + (nlb - 1)
     label_offset = nlb - 1  # CIFAR-100: fine label is the 2nd byte
@@ -126,6 +133,8 @@ class ShuffleBatchIterator:
     def _finish(self, images: np.ndarray) -> np.ndarray:
         """uint8 [N,H,W,C] → cropped/augmented/normalized float32 batch."""
         cfg = self.cfg
+        if cfg.tokens:
+            return images         # token rows: nothing to crop or scale
         images = images.astype(np.float32)
         if self.train and cfg.random_crop:
             images = rec.random_crop(images, cfg.crop_height, cfg.crop_width,
@@ -356,7 +365,8 @@ def input_pipeline(
     """
     download.ensure_dataset(cfg)
     files = download.train_files(cfg) if train else download.test_files(cfg)
-    if cfg.use_native_loader:
+    if cfg.use_native_loader and not cfg.tokens:
+        # (the native loader reads image records only)
         # No quiet NumPy stand-in: runtime/librecordio.so is built on
         # demand, and a failed build or load raises (data/native.py
         # says how to switch the native loader off).

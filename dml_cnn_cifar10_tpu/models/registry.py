@@ -30,6 +30,20 @@ class ModelDef(NamedTuple):
     # bf_counted, bf_true) — gives the loop the per-block numbers to
     # correct the TFLOP/s metric (vit.block_flops_probe).
     stack_probe: Callable | None = None
+    # A model that is no image classifier states its own loss:
+    # ``loss(params, batch, model_cfg, train, mesh=None) -> (loss, stats)``
+    # with ``stats["accuracy"]`` in place of the argmax over logits that
+    # nothing holds (parallel/step.py asks for it before ``apply``, which
+    # such a model leaves None). Its batch is what ``batch_shape(model_cfg,
+    # data_cfg, batch) -> ShapeDtypeStruct`` says, of ``batch_ndim``
+    # dimensions (an image batch has 4), and ``step_flops(model_cfg,
+    # data_cfg, batch)`` counts a training step's operations from the
+    # shapes where XLA's cost analysis cannot (loops counted once, kernels
+    # not at all).
+    loss: Callable | None = None
+    batch_shape: Callable | None = None
+    batch_ndim: int = 4
+    step_flops: Callable | None = None
 
 
 def _cnn() -> ModelDef:
@@ -80,12 +94,20 @@ def _vit_moe() -> ModelDef:
                     stack_probe=vit.block_flops_probe)
 
 
+def _looped_decoder() -> ModelDef:
+    from dml_cnn_cifar10_tpu.models import looped_decoder as m
+    return ModelDef(m.init_params, None, lambda p: {}, False,
+                    wants_mesh=True, loss=m.loss, batch_shape=m.batch_shape,
+                    batch_ndim=2, step_flops=m.step_flops)
+
+
 MODELS = {
     "cnn": _cnn,
     "resnet18": _resnet(18),
     "resnet50": _resnet(50),
     "vit_tiny": _vit,
     "vit_moe": _vit_moe,
+    "looped_decoder": _looped_decoder,
 }
 
 

@@ -612,28 +612,31 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
     # dimension_semantics is ever added here, the b*h axis must NOT be
     # marked 'parallel' unless _zero_all becomes per-row (round-4
     # advisor).
-    if folded:
-        res = pl.pallas_call(
-            functools.partial(kernel, **kw),
-            out_shape=out_shape,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(b * h, sched.shape[1]),
+    # The kernels' instructions take the scopes' names in a device trace:
+    # `flash_fwd.<n>`, `flash_bwd_dq.<n>`, `flash_bwd_dkv.<n>`.
+    with jax.named_scope("flash_fwd"):
+        if folded:
+            res = pl.pallas_call(
+                functools.partial(kernel, **kw),
+                out_shape=out_shape,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(b * h, sched.shape[1]),
+                    in_specs=in_specs,
+                    out_specs=out_specs,
+                    scratch_shapes=scratch),
+                interpret=interpret,
+            )(jnp.asarray(sched), *inputs)
+        else:
+            res = pl.pallas_call(
+                functools.partial(kernel, **kw),
+                out_shape=out_shape,
+                grid=(b * h, nq, nk),
                 in_specs=in_specs,
                 out_specs=out_specs,
-                scratch_shapes=scratch),
-            interpret=interpret,
-        )(jnp.asarray(sched), *inputs)
-    else:
-        res = pl.pallas_call(
-            functools.partial(kernel, **kw),
-            out_shape=out_shape,
-            grid=(b * h, nq, nk),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(*inputs)
+                scratch_shapes=scratch,
+                interpret=interpret,
+            )(*inputs)
 
     if mode == "out":
         return _from_bh(res, b, s, h)
@@ -849,28 +852,29 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
 
     dq_scratch = [pltpu.VMEM((bq, d), jnp.float32)]
     dq_shape = jax.ShapeDtypeStruct(qb.shape, dq_dt)
-    if folded:
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, **kw),
-            out_shape=dq_shape,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(b * h, sched_q.shape[1]),
+    with jax.named_scope("flash_bwd_dq"):
+        if folded:
+            dq = pl.pallas_call(
+                functools.partial(_flash_bwd_dq_kernel, **kw),
+                out_shape=dq_shape,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(b * h, sched_q.shape[1]),
+                    in_specs=in_specs,
+                    out_specs=q_spec_i,
+                    scratch_shapes=dq_scratch),
+                interpret=interpret,
+            )(jnp.asarray(sched_q), *inputs)
+        else:
+            dq = pl.pallas_call(
+                functools.partial(_flash_bwd_dq_kernel, **kw),
+                out_shape=dq_shape,
+                grid=(b * h, nq, nk),
                 in_specs=in_specs,
                 out_specs=q_spec_i,
-                scratch_shapes=dq_scratch),
-            interpret=interpret,
-        )(jnp.asarray(sched_q), *inputs)
-    else:
-        dq = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, **kw),
-            out_shape=dq_shape,
-            grid=(b * h, nq, nk),
-            in_specs=in_specs,
-            out_specs=q_spec_i,
-            scratch_shapes=dq_scratch,
-            interpret=interpret,
-        )(*inputs)
+                scratch_shapes=dq_scratch,
+                interpret=interpret,
+            )(*inputs)
 
     # dK/dV pass: k-major — outer/inner = (k block j, q block i).
     qi2, kj2, qi2_seg, kj2_seg = _index_maps(folded, h, q_major=False)
@@ -887,30 +891,31 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                   jax.ShapeDtypeStruct(vb.shape, dv_dt)]
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
                    pltpu.VMEM((bk, d), jnp.float32)]
-    if folded:
-        sched_k = _fold_schedule(nq, nk, bq, bk, causal, window, "k",
-                                 kv_start=kv_start)
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, **kw),
-            out_shape=dkv_shapes,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(b * h, sched_k.shape[1]),
+    with jax.named_scope("flash_bwd_dkv"):
+        if folded:
+            sched_k = _fold_schedule(nq, nk, bq, bk, causal, window, "k",
+                                     kv_start=kv_start)
+            dk, dv = pl.pallas_call(
+                functools.partial(_flash_bwd_dkv_kernel, **kw),
+                out_shape=dkv_shapes,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=(b * h, sched_k.shape[1]),
+                    in_specs=in_specs2,
+                    out_specs=[kv_spec, kv_spec],
+                    scratch_shapes=dkv_scratch),
+                interpret=interpret,
+            )(jnp.asarray(sched_k), *inputs)
+        else:
+            dk, dv = pl.pallas_call(
+                functools.partial(_flash_bwd_dkv_kernel, **kw),
+                out_shape=dkv_shapes,
+                grid=(b * h, nk, nq),
                 in_specs=in_specs2,
                 out_specs=[kv_spec, kv_spec],
-                scratch_shapes=dkv_scratch),
-            interpret=interpret,
-        )(jnp.asarray(sched_k), *inputs)
-    else:
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_dkv_kernel, **kw),
-            out_shape=dkv_shapes,
-            grid=(b * h, nk, nq),
-            in_specs=in_specs2,
-            out_specs=[kv_spec, kv_spec],
-            scratch_shapes=dkv_scratch,
-            interpret=interpret,
-        )(*inputs)
+                scratch_shapes=dkv_scratch,
+                interpret=interpret,
+            )(*inputs)
 
     return (_from_bh(dq, b, s, h), _from_bh(dk, b, kv_len, h),
             _from_bh(dv, b, kv_len, h))

@@ -14,6 +14,7 @@ Parity notes (all against ``/root/reference/cifar10cnn.py``):
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -172,3 +173,64 @@ def pooled_hw(h: int, w: int, n_pools: int, window: int = 3,
         h = -(-h // stride)
         w = -(-w // stride)
     return h, w
+
+
+# --- decoder primitives (models/looped_decoder.py) ---------------------------
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the last dim, float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps) \
+        * scale
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on ``x [B, S, H, Dh]``, rotate-half over the whole
+    head dimension: the pair ``(x[i], x[i + Dh/2])`` turns by ``position *
+    theta ** (-2 i / Dh)``, positions ``0..S-1``. float32."""
+    s, dh = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-np.arange(0, dh, 2, dtype=np.float64) / dh)
+    angle = jnp.asarray(np.arange(s)[:, None] * inv_freq[None, :],
+                        jnp.float32)
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None, :]
+    x = x.astype(jnp.float32)
+    half = jnp.concatenate([-x[..., dh // 2:], x[..., :dh // 2]], -1)
+    return x * cos + half * sin
+
+
+def low_dot(x, w, dtype):
+    """``x @ w`` of operands rounded to ``dtype``, summed in float32."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def mixed_matmul(x: jax.Array, w: jax.Array, dtype) -> jax.Array:
+    """``x [..., K] @ w [K, N]`` (``x`` float32) as a matrix unit forms it
+    from float32 operands: the operands rounded to ``dtype``, the sum and
+    the result in float32, and the same in both backward products, each from the
+    cotangent rounded to ``dtype`` and the other rounded operand. Written
+    out (not left to autodiff) so that ``dw`` is summed and handed on in
+    float32 whatever ``dtype`` is, and the operand kept for the backward
+    pass is the rounded one."""
+    return low_dot(x, w, dtype)
+
+
+def _mixed_matmul_fwd(x, w, dtype):
+    x_low = x.astype(dtype)
+    return low_dot(x_low, w, dtype), (x_low, w)
+
+
+def _mixed_matmul_bwd(dtype, res, g):
+    x_low, w = res
+    g_low = g.astype(dtype)
+    dx = jnp.dot(g_low, w.astype(dtype).T,
+                 preferred_element_type=jnp.float32)
+    k = x_low.shape[-1]
+    dw = jnp.dot(x_low.reshape(-1, k).T, g_low.reshape(-1, g.shape[-1]),
+                 preferred_element_type=jnp.float32)
+    return dx, dw.astype(w.dtype)
+
+
+mixed_matmul.defvjp(_mixed_matmul_fwd, _mixed_matmul_bwd)

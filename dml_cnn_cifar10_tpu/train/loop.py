@@ -357,7 +357,9 @@ class Trainer:
         host-fed sweep uses fixed-shape padded batches (pad label -1 ⇒ 0
         correct) so every process issues the same number of collective
         eval steps — correct under any process/shard layout."""
-        if self.cfg.eval_full_test_set:
+        # (a model that states its own loss has no per-row count to sum
+        # over a sweep: its evaluation is one batch's accuracy over tokens)
+        if self.cfg.eval_full_test_set and self.model_def.loss is None:
             if self._resident_full_eval is not None:
                 fn, total = self._resident_full_eval
                 return int(jax.device_get(fn(state))) / max(total, 1)
@@ -558,11 +560,17 @@ class Trainer:
                 self._idx1_sharding = mesh_lib.batch_sharding(self.mesh, 1)
                 self._resident_idx = lambda a: mesh_lib.place_local(
                     self._idx1_sharding, to_global(a))
-                self._resident_acc_eval = step_lib.make_batch_eval_resident(
-                    self.model_def, cfg.model, self.mesh, ds_images, ds_labels,
-                    cfg.data, state_sharding=self.state_sharding,
-                    compile_cache=self.compile_cache)
-                if cfg.eval_full_test_set:
+                own_loss = self.model_def.loss is not None
+                if not own_loss:
+                    # (a model that states its own loss counts what it got
+                    # right inside the step: no second forward pass)
+                    self._resident_acc_eval = \
+                        step_lib.make_batch_eval_resident(
+                            self.model_def, cfg.model, self.mesh, ds_images,
+                            ds_labels, cfg.data,
+                            state_sharding=self.state_sharding,
+                            compile_cache=self.compile_cache)
+                if cfg.eval_full_test_set and not own_loss:
                     # Multi-host included (round 3): each process contributes
                     # its padded strided shard as its slice of the global
                     # [M, B, ...] arrays; the scan's replicated output is the
@@ -931,7 +939,16 @@ class Trainer:
                                         fn, abs_args,
                                         exact_metadata=tracer.enabled)
                                     f = f or 0.0
-                                if f and k > 1:
+                                analytic = self.model_def.step_flops
+                                if f and analytic is not None:
+                                    # Counted from the shapes: XLA counts a
+                                    # loop's body once and a kernel not at
+                                    # all (models/registry.py).
+                                    f = analytic(
+                                        cfg.model, cfg.data, cfg.batch_size
+                                        // self.mesh.shape.get("data", 1))
+                                    flops_cell["stack"] = "from_shapes"
+                                elif f and k > 1:
                                     # Verify, don't assume, that this backend
                                     # counts the K-step scan body ONCE: probe
                                     # the scan-free per-step fn too; a
@@ -1021,7 +1038,10 @@ class Trainer:
                             # Fresh-batch train accuracy
                             # (cifar10cnn.py:235), then ONE fused
                             # device->host fetch for loss+accuracy.
-                            if self._resident_acc_eval is not None:
+                            if self.model_def.loss is not None:
+                                # the step's own count of the last batch
+                                acc_arr = metrics["accuracy"]
+                            elif self._resident_acc_eval is not None:
                                 aidx = self._resident_idx(
                                     acc_it.next_index_chunk(1)[0])
                                 acc_arr = self._resident_acc_eval(state, aidx)

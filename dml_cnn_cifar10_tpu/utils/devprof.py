@@ -360,6 +360,15 @@ LAYER_KINDS = (
     ("dense", re.compile(r"^(fc\d*|logits|loss)$")),
     ("decode", re.compile(r"^(decode|index|gather)$")),
     ("optimizer", re.compile(r"^optimizer$")),
+    # a decoder over tokens (models/looped_decoder.py): the innermost
+    # scope that names a kind decides, so `attn/qkv` is attention and
+    # `exit/norm` is a norm
+    ("attention", re.compile(r"^attn$")),
+    ("mlp", re.compile(r"^mlp$")),
+    ("norm", re.compile(r"^(attn_norm|attn_post_norm|mlp_norm|"
+                        r"mlp_post_norm|norm)$")),
+    ("embed", re.compile(r"^embed$")),
+    ("exit_head", re.compile(r"^(exit|head|gate)$")),
 )
 PASSES = ("forward", "backward", "update", "other")
 
