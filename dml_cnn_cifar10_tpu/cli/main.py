@@ -295,7 +295,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--remat", type="bool", default=False,
                    help="recompute block activations in the backward pass "
                         "(ViT transformer blocks / ResNet residual "
-                        "blocks; activation memory O(1) in depth)")
+                        "blocks; activation memory O(1) in depth). The "
+                        "looped decoder recomputes a layer but its flash "
+                        "attention kernel, whose output and log-sum-exp "
+                        "it keeps")
     p.add_argument("--pipe_axis", type=int, default=1,
                    help="pipeline-parallel mesh degree (stages; schedule "
                         "per --pipe_schedule)")

@@ -149,7 +149,10 @@ class ModelConfig:
     # backward pass instead of storing them (jax.checkpoint around the
     # ViT transformer block / ResNet residual block). Trades ~1 extra
     # forward of FLOPs for activation memory that stays O(1) in depth —
-    # the standard long-context / deep-stack memory lever on TPU.
+    # the standard long-context / deep-stack memory lever on TPU. The
+    # looped decoder recomputes a layer but its flash attention kernel:
+    # the kernel's output and log-sum-exp are kept beside the layer's
+    # input (models/looped_decoder.py, KEPT).
     remat: bool = False
     # Sequence-parallel attention strategy when the mesh's ``seq`` axis >1:
     # "ring" walks K/V shards around the ring (no head-count constraint,

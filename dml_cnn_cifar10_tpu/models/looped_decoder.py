@@ -31,9 +31,11 @@ How the loop is built: the passes are one ``lax.scan`` of length
 ``total_ut_steps`` whose body holds the layers (a Python loop: each layer
 has its own weights) and the pass's exit terms. The weights are the scan's
 constants, so each one's gradient is summed over the passes by the scan's
-transpose, the program holds one copy of a layer's code, and the
-activations kept for the backward pass are each layer application's input
-(``remat``: a layer is recomputed in the backward pass). Under the scan a
+transpose, and the program holds one copy of a layer's code. With
+``remat`` the activations kept for the backward pass are each layer
+application's input and, by name (:data:`KEPT`), its flash kernel's output
+and log-sum-exp: the backward pass recomputes a layer but its attention
+kernel, whose recomputed launch nothing reads any more. Under the scan a
 pass has one name scope, ``pass``, for all its rounds.
 """
 
@@ -51,6 +53,7 @@ from jax import lax
 
 from dml_cnn_cifar10_tpu.config import DataConfig, ModelConfig
 from dml_cnn_cifar10_tpu.ops import attention as attention_lib
+from dml_cnn_cifar10_tpu.ops import kernel_paths
 from dml_cnn_cifar10_tpu.ops.layers import mixed_matmul, rms_norm, rotary
 from dml_cnn_cifar10_tpu.train import loss as loss_lib
 
@@ -61,6 +64,12 @@ SMALL: Dict[str, Any] = {
     "head_dim": 16, "intermediate_size": 128, "num_hidden_layers": 2,
     "vocab_size": 96, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
     "total_ut_steps": 4, "exit_entropy_beta": 0.1}
+
+#: What a layer application keeps across its recomputation beside its
+#: input, by the names ``ops/flash_attention.py`` gives the flash kernel's
+#: residuals: its output and log-sum-exp, so that nothing in the backward
+#: pass reads a recomputed forward kernel and the compiler drops it.
+KEPT = ("flash_out", "flash_lse")
 
 #: Tokens whose logits the loss holds at once, at most.
 LOSS_BLOCK_TOKENS = 1024
@@ -189,7 +198,10 @@ def exit_terms(params, rows, cfg: ModelConfig, mesh=None, passes=None,
         return _layer(h, p, sz, cfg, mesh)
 
     if cfg.remat:
-        one_layer = jax.checkpoint(one_layer)
+        kernel_paths.note("remat", "layer, keeps " + " ".join(KEPT))
+        one_layer = jax.checkpoint(
+            one_layer,
+            policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
 
     def one_pass(h, _):
         with jax.named_scope("pass"):

@@ -50,6 +50,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from dml_cnn_cifar10_tpu.utils import platform as platform_lib
@@ -944,6 +945,13 @@ def _flash_fwd_rule(q, k, v, segment_ids, scale, block_q, block_k,
     out, lse = _fwd_call(q, k, v, scale, block_q, block_k, interpret,
                          causal, mode="lse", segment_ids=segment_ids,
                          window=window)
+    # Named for a caller's ``jax.checkpoint`` policy: one that keeps both
+    # (``models/looped_decoder.py``) leaves nothing that reads a recomputed
+    # forward kernel, which is then dropped as dead code. Where no policy
+    # asks for a name it is an identity. The named output is also the
+    # primal one, so that whoever reads it reads the kept array.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, segment_ids, out, lse)
 
 

@@ -504,7 +504,8 @@ def _announced(fn, phase: str, mesh: Optional[Mesh]):
         line = (f"[step] {phase} on {mesh.size if mesh is not None else 1}"
                 f" device(s): update={rec.get('update', 'none')} "
                 f"attention={rec.get('attention', 'none')} "
-                f"pool={rec.get('pool', 'none')}")
+                f"pool={rec.get('pool', 'none')}"
+                + (f" remat={rec['remat']}" if "remat" in rec else ""))
         if line not in said:
             said.add(line)
             print(line, flush=True)

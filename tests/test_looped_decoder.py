@@ -4,6 +4,7 @@ loop's shared weights, the loss in blocks, the exit distribution, flash
 attention at the published head size, the counts from shapes, and the
 name scopes of its lowered step."""
 
+import dataclasses
 import json
 import os
 
@@ -370,3 +371,119 @@ def test_the_cli_takes_sizes_from_a_file(tmp_path, monkeypatch):
     small = config_from_args(build_parser().parse_args(
         ["--model", "looped_decoder", "--dataset", "tokens_synth"]))
     assert small.data.num_classes == VOCAB
+
+
+# --- what a layer keeps across its recomputation ---------------------------
+
+FLASH_S = 128          # the fewest tokens the dispatch hands to the kernels
+FLASH_CFG = ModelConfig(name="looped_decoder", compute_dtype="float32",
+                        use_pallas_attention=True)
+
+
+def _kernel_scopes(jaxpr, outer=""):
+    """The name-scope path of every ``pallas_call`` in ``jaxpr`` and in the
+    jaxprs its equations hold (scan, checkpoint, jit, ``custom_vjp``)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        path = f"{outer}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            found.append(path)
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _kernel_scopes(sub, path)
+    return found
+
+
+def _launches(paths, kernel, within=""):
+    return sum(p.endswith("/" + kernel) and within in p for p in paths)
+
+
+@pytest.fixture(scope="module")
+def flash_rows():
+    return jax.random.randint(jax.random.key(2), (2, FLASH_S + 1), 0, VOCAB)
+
+
+def test_a_recomputed_layer_runs_no_forward_kernel_again(params, flash_rows,
+                                                         monkeypatch):
+    """In the gradient's jaxpr: the flash forward kernel once a layer with
+    ``remat`` as without it (the scan holds one copy of a layer), and the
+    two backward kernels once each. With a policy that keeps nothing, as
+    the bare ``jax.checkpoint`` did, the forward kernel is there twice:
+    that is what the kept output and log-sum-exp take away."""
+    def scopes(cfg):
+        return _kernel_scopes(jax.make_jaxpr(jax.grad(
+            lambda p: m.loss(p, flash_rows, cfg)[0]))(params).jaxpr)
+
+    for remat in (False, True):
+        paths = scopes(dataclasses.replace(FLASH_CFG, remat=remat))
+        for i in range(m.SMALL["num_hidden_layers"]):
+            for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+                assert _launches(paths, kernel, f"/layer{i}/") == 1, \
+                    (remat, i, kernel, paths)
+        assert len(paths) == 3 * m.SMALL["num_hidden_layers"]
+    monkeypatch.setattr(m, "KEPT", ())
+    paths = scopes(dataclasses.replace(FLASH_CFG, remat=True))
+    assert _launches(paths, "flash_fwd") \
+        == 2 * m.SMALL["num_hidden_layers"], paths
+
+
+def test_the_kept_arrays_are_the_recomputed_ones(params, flash_rows):
+    """Loss and every gradient leaf with ``remat`` against without: the
+    kept output is what the recomputation would have written, so what is
+    left is how the CPU compiler fuses the two programs."""
+    def value_and_grads(remat):
+        cfg = dataclasses.replace(FLASH_CFG, remat=remat)
+        return jax.jit(jax.value_and_grad(
+            lambda p: m.loss(p, flash_rows, cfg)[0]))(params)
+
+    with jax.default_matmul_precision("highest"):
+        plain, g_plain = value_and_grads(False)
+        kept, g_kept = value_and_grads(True)
+    assert float(kept) == pytest.approx(float(plain), rel=2e-6)
+    for (name, a), (_, b) in zip(_leaves(g_kept), _leaves(g_plain)):
+        assert float(jnp.max(jnp.abs(b))) > 0, name
+        assert float(jnp.max(jnp.abs(a - b))) \
+            <= 2e-5 * float(jnp.max(jnp.abs(b))), name
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_names_change_nothing_under_a_bare_checkpoint(remat,
+                                                          monkeypatch):
+    """The image transformer's block (``models/vit.py``, recomputed under
+    a ``jax.checkpoint`` without a policy) through ``dispatch_attention``:
+    its gradient with the residuals named is the gradient with the names
+    taken out again, bit for bit, and the same kernels are launched (under
+    the bare checkpoint the forward kernel twice)."""
+    from dml_cnn_cifar10_tpu.models import vit
+    heads, dim = 2, 32
+    bp = vit._init_block(jax.random.key(6), dim, jnp.float32)
+    x = jax.random.normal(jax.random.key(7), (1, FLASH_S, dim))
+
+    def block(h, bp):
+        return vit._block(h, bp, heads, True, 1.0, causal=True)[0]
+
+    unnamed = []
+
+    def grads():
+        # jax keeps the rule's trace (and `flash_attention` is a jit of its
+        # own): without this the second run is handed the first one's
+        jax.clear_caches()
+        fn = jax.checkpoint(block) if remat else block
+        grad = jax.grad(lambda h, bp: jnp.sum(fn(h, bp) ** 2), (0, 1))
+        return (jax.jit(grad)(x, bp),
+                _kernel_scopes(jax.make_jaxpr(grad)(x, bp).jaxpr))
+
+    named, named_paths = grads()
+    monkeypatch.setattr(fa, "checkpoint_name",
+                        lambda value, name: unnamed.append(name) or value)
+    bare, bare_paths = grads()
+    jax.clear_caches()
+    assert set(unnamed) == {"flash_out", "flash_lse"}
+    assert [p.rsplit("/", 1)[-1] for p in named_paths] \
+        == [p.rsplit("/", 1)[-1] for p in bare_paths]
+    assert _launches(named_paths, "flash_fwd") == 1 + remat
+    for a, b in zip(jax.tree.leaves(named), jax.tree.leaves(bare)):
+        np.testing.assert_array_equal(a, b)
