@@ -378,7 +378,7 @@ class PeerReplicaStore:
                               ignore_errors=True)
 
     def flush(self, timeout_s: float = 10.0) -> None:
-        """Drain pending pushes (tests; never on the step path)."""
+        """Drain pending pushes (tests, lockstep sim; off the step path)."""
         deadline = time.time() + timeout_s
         with self._cv:
             while (self._queue or self._inflight) \

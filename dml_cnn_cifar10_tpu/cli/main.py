@@ -710,11 +710,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "into the metrics JSONL at the existing "
                         "boundaries (zero extra device fetches; see "
                         "docs/OBSERVABILITY.md)")
-    p.add_argument("--trace_events_path", type=str, default=None,
-                   help="write the host-loop spans as a Chrome "
-                        "trace-event JSON file (Perfetto-loadable next "
-                        "to the --profile_dir XLA trace); needs "
-                        "--telemetry true")
     p.add_argument("--health_metrics", type="bool", default=False,
                    help="compile global grad-norm / param-norm / "
                         "update-ratio scalars into the train step; they "
@@ -753,7 +748,6 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
         stats_port=args.stats_port,
         alert_rules=args.alert_rules,
         telemetry=args.telemetry,
-        trace_events_path=args.trace_events_path,
         health_metrics=args.health_metrics,
         peak_tflops=args.peak_tflops,
         preempt_sync_every=args.preempt_sync_every,
@@ -878,7 +872,7 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
     if args.pipe_schedule != "1f1b" and args.pipe_axis <= 1:
         # Mirror the --pipe_microbatches guard: without a pipe axis the
         # sequential fast path runs and a requested gpipe schedule would
-        # be silently ignored — reject instead of mislabeling a bench.
+        # be silently ignored — reject instead of mislabeling a run.
         raise SystemExit(
             f"--pipe_schedule={args.pipe_schedule} requires --pipe_axis "
             f"> 1 (got {args.pipe_axis}); without a pipe axis there is "
@@ -907,7 +901,7 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
     if args.optimizer_sharding == "zero1":
         # Mirror the builder-level checks with CLI-shaped errors (the
         # same trap the --fsdp guard above closes): a silently ignored
-        # sharding mode would mislabel every bench that rides it.
+        # sharding mode would mislabel every run that rides it.
         if args.fsdp:
             raise SystemExit(
                 "--optimizer_sharding zero1 does not compose with "
@@ -1019,10 +1013,7 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    args, unparsed = build_parser().parse_known_args(argv)
-    if unparsed:
-        print(f"[cli] ignoring unrecognized args: {unparsed}",
-              file=sys.stderr)
+    args = build_parser().parse_args(argv)
 
     # Before anything compiles: jax opens its persistent compilation
     # cache (the warm start, executable swapping being off) once, at

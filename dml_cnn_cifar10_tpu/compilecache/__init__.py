@@ -1,7 +1,7 @@
 """Persistent compilation cache + AOT warm-start (docs/COMPILECACHE.md).
 
 Every compile seam in the framework — the train step/chunk, state init,
-the eval steps, the serving buckets, the bench/FLOPs probes — can route
+the eval steps, the serving buckets, the FLOPs probes — can route
 through one disk-backed, fail-open executable cache, so supervisor
 restarts, elastic world-shrink re-entries, and serve bucket warmups pay
 XLA's retrace+compile cost once per program instead of once per process.

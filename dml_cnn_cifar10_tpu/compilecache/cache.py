@@ -6,7 +6,7 @@ MonitoredTrainingSession); under JAX every process restart — a
 supervisor recovery, an elastic world-shrink re-entry, a serve bucket
 warmup — pays a full retrace + XLA compile on entry, and the AOT
 ``lower().compile()`` path the FLOPs probes use doesn't even share the
-in-process executable cache (``bench.py``'s long-standing caveat). This
+in-process executable cache. This
 module makes the amortization an explicit, observable subsystem:
 
 - **Keying** (:meth:`CompileCache.fingerprint`): sha256 over the lowered
@@ -104,7 +104,7 @@ EXECUTABLE_BACKENDS = tuple(
 #: Fixed because the directory is part of what jax keys an entry on — a
 #: cache under a temporary name, a pid or the time never hits — and
 #: inside the checkout because that is the one place every entry point
-#: (CLI, bench scripts, ``chip_smoke.py``) agrees on without a flag.
+#: (CLI, the benchmark, ``chip_smoke.py``) agrees on without a flag.
 NATIVE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
@@ -131,8 +131,7 @@ def _native_cache_platform_ok() -> bool:
 def arm_native_cache() -> Optional[str]:
     """Place jax's persistent compilation cache and return its
     directory (``None`` when it stays off). The one resolver every
-    entry point calls — ``cli/main.py``, ``bench.py``,
-    ``tools/bench_resnet.py``, ``tools/bench_moe.py``,
+    entry point calls — ``cli/main.py``, ``benchmark/lib/driver.py``,
     ``chip_smoke.py`` — straight after flag parsing:
 
     - ``JAX_COMPILATION_CACHE_DIR`` set: jax has already taken it from

@@ -192,10 +192,10 @@ class ModelConfig:
     # divisible by data_axis * M.
     pipe_microbatches: int = 0
     # Pipeline schedule: "1f1b" (default — bubbles skipped, recompute
-    # backward: 3F+1B, minimal O(P·microbatch) memory; measured faster
-    # than the ring at every benched geometry), "1f1b_ring" (2F+1B
+    # backward: 3F+1B, minimal O(P·microbatch) memory; not measured
+    # against the ring on the chip), "1f1b_ring" (2F+1B
     # residual-ring backward — opt-in; see parallel/pipeline.py's
-    # measured verdict), or "gpipe" (the round-2 baseline: always-on
+    # docstring), or "gpipe" (the round-2 baseline: always-on
     # stage compute, autodiff through the scan; kept for comparison
     # benches).
     pipe_schedule: str = "1f1b"
@@ -796,10 +796,6 @@ class TrainConfig:
     # device fetches. Off by default: the span context managers then
     # reduce to a shared no-op.
     telemetry: bool = False
-    # Chrome trace-event file of the host-loop spans (Perfetto-loadable
-    # next to the XLA trace from profile_dir). Needs telemetry=True;
-    # non-chief processes write <path>.task<N>.
-    trace_events_path: Optional[str] = None
     # Training-health scalars compiled INTO the step (parallel/step.py):
     # global grad norm, param norm, update ratio — they ride the fused
     # boundary fetch (no extra round trips) into the train JSONL records.

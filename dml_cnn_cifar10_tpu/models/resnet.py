@@ -20,12 +20,10 @@ No reference counterpart (the reference model is the 5-layer CNN,
 - ``cfg.resnet_norm="nf"`` swaps every BN for scaled weight
   standardization (per-kernel fan-in standardize + learnable gain —
   weight bytes only) + per-conv biases + a SkipInit residual scalar
-  (init 0 — identity start, like the gamma-zero BN). The round-4
-  roofline measured 76.5% of the ResNet-50 step bandwidth-bound with
-  BN's stats/normalize passes among the top byte movers; nf removes
+  (init 0 — identity start, like the gamma-zero BN); nf removes
   every activation-sized stats read/write. Different training semantics
   (the NFNet line of work shows the class reaches BN-level accuracy
-  with care); the byte-reduction rung (tools/bench_resnet.py; not
+  with care); the byte-reduction rung (not
   measured on the current chip).
 """
 

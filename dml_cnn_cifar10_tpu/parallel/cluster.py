@@ -689,6 +689,10 @@ class ClusterMonitor:
         ``PeerLostError``, an eviction raises ``EvictedError``."""
         if not self.lockstep:
             return
+        if self.peer_store is not None:
+            # The sim's clock is the step: a boundary's replica push is
+            # committed (so the next beat advertises it) before the barrier.
+            self.peer_store.flush()
         attempt = 0
         while True:
             self._raise_if_dead(step)

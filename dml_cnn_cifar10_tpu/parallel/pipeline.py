@@ -46,20 +46,9 @@ default; ``"1f1b_ring"`` opts into the residual ring):
   backward. Total 2 forwards + 1 backward, memory 2P slots × the
   per-microbatch activation-residual set (still flat in M).
 
-**Measured verdict (tools/bench_pp.py, 8-virtual-CPU substrate,
-round 5): the ring LOSES to recompute at every geometry tried** —
-dim 64: 180 vs 126 ms (M=P), 213 vs 173 (M=4P); dim 256 batch 64:
-3167 vs 2830 (M=P), 3385 vs 2733 (M=4P) — so recompute stays the
-default and the ring ships opt-in. Mechanism: a transformer block's
-residual set is ~10 activation-sized tensors per microbatch, so the
-ring's store+load traffic exceeds the replay's FLOP cost until the
-stage's arithmetic intensity is much higher (replay FLOPs grow
-O(dim²·tokens), ring bytes O(dim·tokens) — the crossover sits at
-dim ≈ thousands on real TPU ratios, and this substrate never reached
-it). The negative result is recorded here the same way the maxpool-bwd
-and block-512 rejections are (ops/layers.py, ops/flash_attention.py),
-so it isn't silently retried; geometry where the ring should win can
-be re-checked any time with ``bench_pp.py --dim``.
+Which flavor is faster is not measured on the chip (``ROADMAP.md``
+Queue 3): the ring trades the replay's FLOPs, O(dim²·tokens), for
+store+load bytes, O(dim·tokens); recompute is the default.
 
 Composition: ``pipe`` composes with ``data`` (batch stays sharded
 outside). Tensor/sequence axes inside a pipelined stack would need
@@ -111,10 +100,9 @@ def pipeline_blocks(
 
     Returns the global ``[B, S, D]`` output (same sharding as ``x``).
     ``schedule``: ``"1f1b"`` (no bubble compute, recompute backward —
-    3F+1B, minimal O(P·microbatch) memory; the measured default),
-    ``"1f1b_ring"`` (residual-ring backward — 2F+1B, measured slower
-    here; see module docstring), or ``"gpipe"`` (round-2 baseline, kept
-    for comparison benches).
+    3F+1B, minimal O(P·microbatch) memory; the default), ``"1f1b_ring"``
+    (residual-ring backward — 2F+1B; see module docstring), or
+    ``"gpipe"`` (round-2 baseline, the tests' reference).
     """
     if schedule not in SCHEDULES:
         raise ValueError(f"unknown pipeline schedule {schedule!r}; "

@@ -798,9 +798,7 @@ def make_train_chunk_resident(
     def _cached(jitted, donate):
         # Wrapped BEFORE the dataset-binding partial: the cache key then
         # covers the dataset avals too (a different split size is a
-        # different program). ``fn.cached`` exposes the wrapper so
-        # bench.py can read the timed artifact's cost analysis and
-        # hit/compile_s record without a second compile.
+        # different program).
         return _cc_wrap(jitted, compile_cache, "train_chunk_resident",
                         mesh_context(mesh, donate=(donate,),
                                      compute_dtype=model_cfg.compute_dtype,
@@ -843,8 +841,7 @@ def make_train_chunk_resident(
                                     *abs_args)
 
         fn.lower = lower_dev
-        fn.cached = jitted_dev if compile_cache is not None else None
-        if fn.cached is not None:
+        if compile_cache is not None:
             def flops_dev(abs_args):
                 from dml_cnn_cifar10_tpu.utils.profiling import abstractify
                 return jitted_dev.cached_flops(
@@ -883,8 +880,7 @@ def make_train_chunk_resident(
                                           dataset_labels)), *abs_args)
 
     fn.lower = lower
-    fn.cached = jitted if compile_cache is not None else None
-    if fn.cached is not None:
+    if compile_cache is not None:
         def flops_idx(abs_args):
             from dml_cnn_cifar10_tpu.utils.profiling import abstractify
             return jitted.cached_flops(

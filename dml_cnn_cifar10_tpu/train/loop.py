@@ -1288,9 +1288,8 @@ class Trainer:
                 ckpt_mgr.close()
                 prefetch.close()
                 # A capture window the run ended (or crashed) inside still
-                # stops, parses, and emits its devtime records — like the
-                # Chrome trace below, the runs that die mid-window are
-                # exactly the ones worth attributing.
+                # stops, parses, and emits its devtime records: the runs that
+                # die mid-window are exactly the ones worth attributing.
                 if devwin is not None:
                     devwin.close(global_step)
                 # A supervisor-owned monitor must keep its threads (and
@@ -1298,14 +1297,6 @@ class Trainer:
                 # this Trainer built for itself dies with the fit.
                 if self._owns_cluster and self.cluster is not None:
                     self.cluster.close()
-            # The Chrome trace exports from the finally block so a
-            # crashed/preempted run still leaves its host-loop timeline —
-            # exactly the runs worth opening in Perfetto.
-            if tracer.enabled and cfg.trace_events_path:
-                path = cfg.trace_events_path
-                if self.task_index:
-                    path += f".task{self.task_index}"
-                tracer.export_chrome_trace(path, pid=self.task_index)
             self.logger.flush()
         # Release the fit-scoped resident closures — their partials pin
         # the train/test splits in HBM.

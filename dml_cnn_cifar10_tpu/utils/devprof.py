@@ -3,9 +3,7 @@ windows and a zero-fetch device step-time estimator.
 
 The PR-1 telemetry layer (``utils/telemetry.py``) times the HOST loop —
 it can say the run spent 95% of wall-clock "training" and still not know
-where the device spent that time (the headline bench sat at ~27% MFU for
-five rounds with nothing pointing at the other 73%). This module closes
-that gap from two directions, both honoring the loop's round-trip budget
+where the device spent that time. This module closes that gap from two directions, both honoring the loop's round-trip budget
 (zero extra device fetches — ``tests/test_telemetry.py`` pins it):
 
 - :class:`ProfileWindow` — ``--profile_at_steps N:K`` arms a

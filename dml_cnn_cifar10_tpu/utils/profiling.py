@@ -21,7 +21,7 @@ class DrainMeter:
     """Drain-anchored throughput meter.
 
     Dispatches are async: host loop intervals measure ENQUEUE rate, not
-    execution (the ``block_until_ready`` hazard ``bench.py`` documents).
+    execution.
     Every device fetch is a true drain, so the exact training rate is
     (steps between drains) / (wall time between drains) — provided the
     window holds only training dispatches. Protocol: call :meth:`rate`

@@ -16,12 +16,11 @@ profiler attached. Three coordinated pieces:
   Depth is kept per thread; a record names its thread where that is not
   the loop's. Near-zero overhead when disabled — ``span()`` returns a
   shared no-op context manager, no allocation, no clock read, no
-  profiler annotation, no collector hook. Finished spans export three
+  profiler annotation, no collector hook. Finished spans export two
   ways: JSONL ``span`` records through the existing ``MetricsLogger``
-  (:func:`flush_boundary`; microsecond resolution), a
+  (:func:`flush_boundary`; microsecond resolution) and a
   ``jax.profiler.TraceAnnotation`` while the span is open (the host
-  phases appear in any profiler capture, on the profiler's clock), and
-  a Chrome trace-event file (:meth:`SpanTracer.export_chrome_trace`).
+  phases appear in any profiler capture, on the profiler's clock).
 - Goodput accounting: top-level spans carry a category
   (``compile`` / ``data`` / ``eval`` / ``checkpoint`` / ``sync``);
   :meth:`SpanTracer.goodput` reports the fraction of wall-clock since
@@ -47,8 +46,6 @@ from __future__ import annotations
 
 import collections
 import gc
-import json
-import os
 import threading
 import time
 from typing import Optional
@@ -119,8 +116,7 @@ class SpanTracer:
     span. Only DEPTH-0 spans with a category count toward goodput —
     nested sub-spans are trace detail, not wall-clock attribution (a
     category on a nested span would double-count its parent's time).
-    The ring keeps the most recent ``max_spans`` finished spans for the
-    Chrome export; ``drain()`` hands out (and forgets) the spans finished
+    ``drain()`` hands out (and forgets) the spans finished
     since the last drain so boundary flushes are incremental. Overflow is
     counted (``dropped``), never silent.
 
@@ -138,13 +134,10 @@ class SpanTracer:
         self.dropped = 0
         self._local = threading.local()
         self._home = threading.get_ident()
-        # (name, cat, start_s, dur_s, depth, thread) tuples; _ring feeds
-        # the Chrome export, _pending feeds the incremental JSONL flush.
-        self._ring = collections.deque(maxlen=max_spans)
+        # (name, cat, start_s, dur_s, depth, thread), for the JSONL flush.
         self._pending = collections.deque(maxlen=max_spans)
         self._cat_secs = dict.fromkeys(GOODPUT_CATEGORIES, 0.0)
         self._epoch = self._goodput_epoch = time.perf_counter()
-        self._wall_epoch = time.time()
         # (logger, step) once the owning fit has made its last flush:
         # a span that finishes later is logged by whoever finishes it.
         self._sink = None
@@ -168,17 +161,15 @@ class SpanTracer:
         return _Span(self, name, cat)
 
     def _record(self, name, cat, t0, dur, depth) -> None:
-        if len(self._ring) == self.max_spans \
-                or len(self._pending) == self.max_spans:
-            self.dropped += 1
         thread = None if threading.get_ident() == self._home \
             else threading.current_thread().name
         rec = (name, cat, t0 - self._epoch, dur, depth, thread)
-        self._ring.append(rec)
         if depth == 0 and cat is not None:
             self._cat_secs[cat] = self._cat_secs.get(cat, 0.0) + dur
         sink = self._sink
         if sink is None:
+            if len(self._pending) == self.max_spans:
+                self.dropped += 1
             self._pending.append(rec)
         else:
             _log_span(sink[0], sink[1], rec)
@@ -259,30 +250,6 @@ class SpanTracer:
             out[f"{cat}_frac"] = round(secs / total, 6)
         out["train_frac"] = round((total - attributed) / total, 6)
         return out
-
-    def export_chrome_trace(self, path: str, pid: int = 0) -> None:
-        """Write the retained spans as a Chrome trace-event JSON file.
-
-        Load in Perfetto (ui.perfetto.dev) or chrome://tracing — ``ts``
-        is microseconds since the tracer epoch, so the host-loop lane
-        lines up with an XLA trace captured over the same run. A lane
-        (``tid``) is a depth of the loop's thread; another thread's
-        spans are left out (they reach a profiler capture as
-        ``TraceAnnotation``s).
-        """
-        events = [{"name": name, "ph": "X",
-                   "ts": round(start * 1e6, 1),
-                   "dur": round(dur * 1e6, 1),
-                   "pid": pid, "tid": depth,
-                   **({"cat": cat} if cat else {})}
-                  for name, cat, start, dur, depth, thread in self._ring
-                  if thread is None]
-        doc = {"traceEvents": events, "displayTimeUnit": "ms",
-               "otherData": {"epoch_unix_s": round(self._wall_epoch, 3),
-                             "dropped_spans": self.dropped}}
-        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(doc, f)
 
 
 def _log_span(logger, step: int, rec) -> None:

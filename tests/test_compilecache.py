@@ -196,8 +196,8 @@ def test_wrap_without_cache_is_identity():
 
 
 def test_cached_flops_served_from_entry(tmp_path):
-    """The bench/loop FLOPs probes read the cached artifact's cost
-    analysis instead of recompiling (the old bench.py:173 caveat)."""
+    """The loop's FLOPs probe reads the cached artifact's cost
+    analysis instead of recompiling."""
     from dml_cnn_cifar10_tpu.utils.profiling import compiled_flops
 
     cache = CompileCache(str(tmp_path))

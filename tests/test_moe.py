@@ -326,28 +326,6 @@ def test_moe_stats_match_hand_count():
                                [1.0, 0.0, 0.0, 0.0])
 
 
-def test_drop_table_matches_layer_stats():
-    """bench_moe.drop_table must report the layer's own stats — pin one
-    cell against a direct moe_mlp call on identical inputs."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
-                                    "tools"))
-    try:
-        import bench_moe
-    finally:
-        sys.path.pop(0)
-
-    rows = bench_moe.drop_table([4], [1.0], tokens=256, dim=16)
-    params = moe.init_moe_params(jax.random.PRNGKey(4 * 31 + 1), 16, 64, 4)
-    x = jax.random.normal(jax.random.PRNGKey(7), (8, 32, 16), jnp.float32)
-    _, stats = moe.moe_mlp(x, params, capacity_factor=1.0, top_k=1)
-    assert rows[0]["dropped_frac"] == pytest.approx(
-        float(stats["dropped_frac"]), abs=1e-4)
-    assert rows[0]["max_expert_load"] == pytest.approx(
-        float(jnp.max(stats["expert_load"])), abs=1e-4)
-
-
 @pytest.mark.slow
 def test_moe_stats_reach_step_metrics(rng):
     """A vit_moe train step publishes moe_aux_loss / moe_dropped_frac /

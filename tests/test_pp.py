@@ -144,7 +144,7 @@ def test_pp_and_sp_both_raise(rng):
 
 @pytest.mark.slow
 def test_pp_more_microbatches_matches_dp(rng):
-    """M > P (the bubble-amortizing schedule, tools/bench_pp.py): same
+    """M > P (the bubble-amortizing schedule): same
     math as dp, with the microbatch count actually threaded through."""
     cfg = dataclasses.replace(VIT_PP, pipe_microbatches=8)
     images = rng.normal(0.5, 0.25, (16, 24, 24, 3)).astype(np.float32)

@@ -40,9 +40,8 @@ def test_span_nesting_and_drain(monkeypatch):
     name, cat, start, dur, depth, thread = spans[1]
     assert cat == "eval" and start == 0.0 and dur == pytest.approx(2.0)
     assert thread is None      # the tracer's own thread is not named
-    # drain() forgets — a second drain is empty; the ring retains.
+    # drain() forgets — a second drain is empty.
     assert tr.drain() == []
-    assert len(tr._ring) == 2
 
 
 def test_disabled_tracer_is_noop():
@@ -86,22 +85,17 @@ def test_goodput_fractions_sum_to_one(monkeypatch):
     assert tr._cat_secs["eval"] == pytest.approx(0.5 + 1.0)
 
 
-def test_chrome_trace_export_and_ring_overflow(tmp_path):
+def test_span_ring_overflow_counts_dropped_and_drains_newest():
     tr = SpanTracer(enabled=True, max_spans=4)
     for i in range(6):
         with tr.span(f"s{i}", cat="data"):
             pass
-    assert tr.dropped == 2 and len(tr._ring) == 4
-    path = str(tmp_path / "trace.json")
-    tr.export_chrome_trace(path, pid=3)
-    with open(path) as f:
-        doc = json.load(f)
-    events = doc["traceEvents"]
-    assert [e["name"] for e in events] == ["s2", "s3", "s4", "s5"]
-    for e in events:
-        assert e["ph"] == "X" and e["pid"] == 3
-        assert isinstance(e["ts"], float) and isinstance(e["dur"], float)
-    assert doc["otherData"]["dropped_spans"] == 2
+    assert tr.dropped == 2
+    assert [s[0] for s in tr.drain()] == ["s2", "s3", "s4", "s5"]
+    # A drained ring has room again: nothing more is dropped.
+    with tr.span("s6", cat="data"):
+        pass
+    assert tr.dropped == 2 and [s[0] for s in tr.drain()] == ["s6"]
 
 
 def test_hbm_stats_shape():
@@ -191,14 +185,13 @@ def test_telemetry_run_and_fetch_parity(data_cfg, tmp_path, monkeypatch):
 
     monkeypatch.setattr(jax, "device_get", counting_get)
 
-    def run(sub, telemetry, health, trace=None):
+    def run(sub, telemetry, health):
         cfg = tiny_train_cfg(data_cfg, str(tmp_path / sub), total_steps=20,
                              output_every=5, eval_every=10,
                              checkpoint_every=10)
         cfg.telemetry = telemetry
         cfg.health_metrics = health
         cfg.metrics_jsonl = os.path.join(str(tmp_path / sub), "m.jsonl")
-        cfg.trace_events_path = trace
         counts["n"] = 0
         t0 = time.perf_counter()
         result = Trainer(cfg).fit()
@@ -207,9 +200,7 @@ def test_telemetry_run_and_fetch_parity(data_cfg, tmp_path, monkeypatch):
         return counts["n"], cfg, wall
 
     fetches_off, _, _ = run("off", telemetry=False, health=False)
-    trace_path = str(tmp_path / "on" / "host_trace.json")
-    fetches_on, cfg, wall = run("on", telemetry=True, health=True,
-                                trace=trace_path)
+    fetches_on, cfg, wall = run("on", telemetry=True, health=True)
     assert fetches_on == fetches_off, \
         "telemetry/health must not add device fetches"
 
@@ -255,25 +246,18 @@ def test_telemetry_run_and_fetch_parity(data_cfg, tmp_path, monkeypatch):
     # hbm records carry the full schema even on CPU.
     assert by_kind["hbm"][-1]["available"] in (True, False)
 
-    # Chrome trace-event file: valid JSON, Perfetto-loadable shape,
-    # and WELL-FORMED spans — complete events with non-negative
-    # durations that, within one lane (pid, tid=depth), are monotone
-    # and non-overlapping (the host loop's same-depth spans are
-    # sequential context managers; an overlap would mean the exporter
-    # scrambled ts/dur and Perfetto would render garbage).
-    with open(trace_path) as f:
-        doc = json.load(f)
-    events = doc["traceEvents"]
-    assert events and all(e["ph"] == "X" for e in events)
+    # WELL-FORMED spans: within one lane (thread, depth) the records
+    # are non-negative, monotone and non-overlapping (a thread's
+    # same-depth spans are sequential context managers).
     lanes = {}
-    for e in events:
-        assert e["dur"] >= 0 and e["ts"] >= 0
-        lanes.setdefault((e["pid"], e["tid"]), []).append(e)
+    for r in by_kind["span"]:
+        assert r["dur_s"] >= 0 and r["start_s"] >= 0
+        lanes.setdefault((r.get("thread"), r["depth"]), []).append(r)
     for lane in lanes.values():
-        lane.sort(key=lambda e: e["ts"])
+        lane.sort(key=lambda r: r["start_s"])
         for a, b in zip(lane, lane[1:]):
-            # 0.2 us slack: ts/dur round to 0.1 us on export.
-            assert b["ts"] >= a["ts"] + a["dur"] - 0.2, \
+            # 2 us slack: start_s/dur_s round to 1 us in the record.
+            assert b["start_s"] >= a["start_s"] + a["dur_s"] - 2e-6, \
                 (a, b, "same-depth spans must not overlap")
 
     # The stream passes the documented-schema lint (wired into tier 1).

@@ -122,7 +122,7 @@ def test_aggregate_unaligned_stream_flagged(tmp_path, two_streams):
     assert "UNALIGNED" in agg_lib.render(agg)
 
 
-def test_merged_trace_document(two_streams, tmp_path):
+def test_merged_trace_document(two_streams):
     pa, pb = two_streams
     doc = agg_lib.build_merged_trace([pa, pb])
     evs = doc["traceEvents"]
@@ -138,19 +138,6 @@ def test_merged_trace_document(two_streams, tmp_path):
     def span_ts(pid):
         return min(e["ts"] for e in span_x if e["pid"] == pid)
     assert span_ts(1) - span_ts(0) == pytest.approx(0.25e6, rel=0.05)
-
-    # A real Chrome trace file merges in, shifted by its epoch.
-    host_trace = tmp_path / "host0_trace.json"
-    host_trace.write_text(json.dumps({
-        "traceEvents": [{"ph": "X", "name": "eval", "pid": 0, "tid": 0,
-                         "ts": 100.0, "dur": 50.0}],
-        "otherData": {"epoch_unix_s": 1001.0}}))
-    doc = agg_lib.build_merged_trace([pa, pb], [str(host_trace)])
-    merged = [e for e in doc["traceEvents"]
-              if e.get("name") == "eval"]
-    assert merged and merged[0]["pid"] == 1000
-    # wall0 is host 1's 995.0 → the 1001.0 epoch shifts by 6 s.
-    assert merged[0]["ts"] == pytest.approx(6.0e6 + 100.0, rel=1e-3)
 
 
 def test_cli_main(two_streams, tmp_path, capsys):

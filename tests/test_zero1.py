@@ -505,30 +505,3 @@ def test_profile_window_feeds_optimizer_step_ms(tmp_path, monkeypatch):
     assert sink and sink[0]["kind"] == "devtime"
     assert sink[0]["optimizer_ms"] == 4.0
 
-
-def test_bench_gate_fp32_zero1_row():
-    """The zero1 bench row joins the perf gate with its own tolerance
-    entry: a within-tolerance candidate passes, a regressed one fails,
-    and baselines that predate the row skip it (never fail)."""
-    from tools import bench_gate
-
-    assert "fp32_zero1" in bench_gate.ROW_KEYS
-    assert "fp32_zero1" in bench_gate.ROW_TOLERANCES
-
-    def report(z_ips=None):
-        doc = {"metric": "train_throughput", "value": 1000.0,
-               "fp32": {"images_per_sec_per_chip": 1000.0}}
-        if z_ips is not None:
-            doc["fp32_zero1"] = {"images_per_sec_per_chip": z_ips,
-                                 "optimizer_ms": 0.01}
-        return doc
-
-    baselines = [report(900.0), report(910.0), report(905.0)]
-    ok = bench_gate.gate(report(880.0), baselines)       # -2.8% < 8%
-    assert all(c["ok"] for c in ok)
-    bad = bench_gate.gate(report(700.0), baselines)      # -22.7%
-    assert any(not c["ok"] and c["row"] == "fp32_zero1" for c in bad)
-    # Old baselines without the row: the candidate's row is unjudged on
-    # throughput-vs-median (no medians) — nothing fails.
-    legacy = [report(), report(), report()]
-    assert all(c["ok"] for c in bench_gate.gate(report(500.0), legacy))

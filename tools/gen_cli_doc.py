@@ -25,7 +25,7 @@ def render() -> str:
         "`tests/test_cli_doc.py` enforces freshness).",
         "",
         "The observability flags (`--metrics_jsonl`, `--telemetry`,",
-        "`--trace_events_path`, `--health_metrics`, `--tensorboard_dir`,",
+        "`--health_metrics`, `--tensorboard_dir`,",
         "`--profile_dir`) are documented in depth in",
         "[OBSERVABILITY.md](OBSERVABILITY.md) (JSONL schema, goodput",
         "accounting, Perfetto workflow).",

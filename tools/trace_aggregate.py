@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""Merge the per-process JSONL streams (and Chrome traces) of a
+"""Merge the per-process JSONL streams of a
 multi-host run into ONE run-level timeline.
 
 Cluster simulations (``parallel/cluster.py``), real multi-host jobs, and
 serving fleets (``fleet/``) each scatter one ``--metrics_jsonl`` stream
-(plus optional ``--trace_events_path`` Chrome traces) per process, every
+per process, every
 stream with its OWN clock zero (``t`` is seconds since that logger
 started). Post-mortems of cross-host behavior — who stalled, who
 restarted whom, how far the hosts' steps skewed — need those streams on
@@ -27,14 +27,11 @@ one clock. This tool:
   instant events for the notable kinds, counter tracks for
   ``images_per_sec`` / ``device_step_ms``, request-tracing hop lanes
   rebuilt from ``rspan`` records with one Chrome flow arrow per
-  ``trace_id`` linking a request's hops across processes — and, via
-  ``--traces``, any per-process Chrome trace files shifted onto the
-  same clock using their recorded ``epoch_unix_s``.
+  ``trace_id`` linking a request's hops across processes.
 
 Usage:
   python tools/trace_aggregate.py logs_0/m.jsonl logs_1/m.jsonl \\
-      [--out merged_trace.json] [--traces host0.json host1.json.task1] \\
-      [--format text|json]
+      [--out merged_trace.json] [--format text|json]
 
 ``tests/test_cluster.py`` runs this over the 2-process lockstep sim's
 streams in tier-1 and pins that the merged per-host step counts match
@@ -233,11 +230,9 @@ def _span_epoch_t(records: List[dict]) -> Optional[float]:
     return min(cands) if cands else None
 
 
-def build_merged_trace(paths: List[str],
-                       trace_paths: Optional[List[str]] = None) -> dict:
+def build_merged_trace(paths: List[str]) -> dict:
     """One Chrome/Perfetto document: per-process lanes rebuilt from the
-    JSONL streams, plus (optionally) real per-process Chrome trace files
-    shifted onto the shared clock via their ``epoch_unix_s``."""
+    JSONL streams."""
     streams = {p: load_stream(p) for p in paths}
     offsets = {p: clock_offset(recs) for p, recs in streams.items()}
     known = [v for v in offsets.values() if v is not None]
@@ -331,31 +326,9 @@ def build_merged_trace(paths: List[str],
             if ph == "f":
                 ev["bp"] = "e"
             events.append(ev)
-    for idx, tpath in enumerate(trace_paths or []):
-        try:
-            with open(tpath) as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"[aggregate] skipping trace {tpath}: {e}",
-                  file=sys.stderr)
-            continue
-        epoch = ((doc.get("otherData") or {}).get("epoch_unix_s"))
-        shift_us = ((epoch - wall0) * 1e6
-                    if isinstance(epoch, (int, float)) and known else 0.0)
-        pid_base = 1000 * (idx + 1)
-        for e in doc.get("traceEvents") or []:
-            e = dict(e)
-            e["pid"] = pid_base + int(e.get("pid") or 0)
-            if isinstance(e.get("ts"), (int, float)):
-                e["ts"] = round(e["ts"] + shift_us, 1)
-            events.append(e)
-        events.append({"ph": "M", "name": "process_name",
-                       "pid": pid_base,
-                       "args": {"name": f"trace {os.path.basename(tpath)}"}})
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"wall0_unix_s": wall0 or None,
-                          "sources": list(paths)
-                          + list(trace_paths or [])}}
+                          "sources": list(paths)}}
 
 
 # ---------------------------------------------------------------------------
@@ -412,15 +385,11 @@ def render(agg: dict) -> str:
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
-        description="merge per-process metrics JSONL streams (and "
-                    "Chrome traces) into one run-level timeline")
+        description="merge per-process metrics JSONL streams into "
+                    "one run-level timeline")
     p.add_argument("streams", nargs="+", help="metrics JSONL files")
     p.add_argument("--out", default=None,
                    help="write the merged Perfetto/Chrome trace here")
-    p.add_argument("--traces", nargs="*", default=None,
-                   help="per-process Chrome trace files "
-                        "(--trace_events_path outputs) to shift onto "
-                        "the shared clock and merge into --out")
     p.add_argument("--format", choices=("text", "json"), default="text")
     args = p.parse_args(argv)
     agg = aggregate(args.streams)
@@ -429,7 +398,7 @@ def main(argv=None) -> int:
     else:
         print(render(agg))
     if args.out:
-        doc = build_merged_trace(args.streams, args.traces)
+        doc = build_merged_trace(args.streams)
         os.makedirs(os.path.dirname(os.path.abspath(args.out)),
                     exist_ok=True)
         with open(args.out, "w") as f:
