@@ -74,15 +74,18 @@ def apply(params: Params, images: jax.Array, cfg: ModelConfig,
     # utils/devprof.scope_map reads a compiled instruction's layer from.
     # A convolution's bias and ReLU are with the pool behind it
     # (bias_relu_max_pool shares one backward pass between the three), a
-    # dense layer's with it.
+    # dense layer's with it. Every reader of a pool's output and of its
+    # input's gradient is one of the products below, so the pool's
+    # kernels may store both as what a product rounds them to.
+    store = L.product_operand_dtype(cdt)
     with jax.named_scope("conv1"):
         x = L.conv2d(x, p["conv1"]["kernel"])
     with jax.named_scope("pool1"):
-        x = bias_relu_max_pool(x, p["conv1"]["bias"], mesh)
+        x = bias_relu_max_pool(x, p["conv1"]["bias"], mesh, store)
     with jax.named_scope("conv2"):
         x = L.conv2d(x, p["conv2"]["kernel"])
     with jax.named_scope("pool2"):
-        x = bias_relu_max_pool(x, p["conv2"]["bias"], mesh)
+        x = bias_relu_max_pool(x, p["conv2"]["bias"], mesh, store)
     x = x.reshape(x.shape[0], -1)
     with jax.named_scope("fc1"):
         x = jax.nn.relu(L.dense(x, p["full1"]["kernel"], p["full1"]["bias"]))

@@ -22,6 +22,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
+#: Settings of ``jax_default_matmul_precision`` under which a TPU
+#: multiplies float32 operands in one bfloat16 pass.
+_ONE_BF16_PASS = (None, "default", "bfloat16", "BF16_BF16_F32")
+
 
 def truncated_normal_init(key, shape, stddev: float = 0.05,
                           dtype=jnp.float32) -> jax.Array:
@@ -43,6 +49,21 @@ def conv2d(x: jax.Array, kernel: jax.Array, stride: int = 1,
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
+
+
+def product_operand_dtype(dtype) -> jnp.dtype:
+    """What :func:`conv2d`, :func:`dense` and their gradients round a
+    ``dtype`` operand to before they multiply, on this backend at the
+    precision in force: bfloat16 for float32 on a TPU at the default
+    precision, else ``dtype`` itself (the CPU multiplies float32 as it
+    is; so does a TPU under ``jax.default_matmul_precision("highest")``).
+    An array that only such products read holds the same values for them
+    stored in this dtype (``ops/relu_pool.py``)."""
+    dtype = jnp.dtype(dtype)
+    if (dtype == jnp.float32 and platform_lib.on_tpu()
+            and jax.config.jax_default_matmul_precision in _ONE_BF16_PASS):
+        return jnp.dtype(jnp.bfloat16)
+    return dtype
 
 
 def max_pool(x: jax.Array, window: int = 3, stride: int = 2,
@@ -73,6 +94,13 @@ def max_pool(x: jax.Array, window: int = 3, stride: int = 2,
       where was not taken apart (at batch 256 the kernels' batch-in-the-
       lanes view need not be that model's layout). The stem keeps this
       function.
+    - PR 31, the CNN's pairs again: the kernels write the pooled output
+      and the input's gradient as bfloat16 where the model is float32 on
+      a TPU at the default precision (:func:`product_operand_dtype`):
+      every reader is a product that rounds them so anyway. 35.14 ->
+      29.57 ms a step. The convolution's output in front of the bias
+      stays float32: no product reads it, and rounding it is another
+      result. PERF.md, Findings, PR 31.
     """
     return lax.reduce_window(
         x,

@@ -24,12 +24,32 @@ view is a bitcast of the convolution's own layout, H and W are untiled
 leading dimensions, and a window's taps and the interleave are plain
 addressing.
 
+What is stored in which type. The kernels compute in float32 whatever
+the data's type: the bias is added and the sum rounded as the input's
+dtype rounds it, the taps are compared in float32, the backward kernel
+reads the incoming gradient ``g`` as it comes and sums its (at most four)
+terms and the bias's partial sums in float32. The two arrays a kernel
+writes for somebody else, the pooled ``y`` and the input's gradient
+``dz``, are written as ``store``: by default the input's dtype. A float32
+model on a TPU asks for bfloat16 (``ops/layers.product_operand_dtype``),
+because every reader of the two is a product at the default precision,
+which rounds a float32 operand to bfloat16 before it multiplies, or the
+backward kernel's own ``y > 0``: stored as bfloat16 they hold the values
+every reader sees anyway, and a step of the CNN at batch 16,384 moves
+4.8 GB less through HBM (PERF.md, Findings, PR 31). The op still hands
+``y`` and ``dz`` on in the input's dtype; the compiler folds that
+widening into the convolution or the dot that reads it, and no float32
+copy exists. The convolution's own output ``z`` is NOT such an array (an
+add, a ReLU and a compare read it: rounding it is a rounding that no
+product makes), nor is ``g``.
+
 Who takes the kernels: a TPU backend, even H and W, a float dtype, a
 batch that fills the lanes and channels that fill an int8 tile; one
 device, or a mesh whose ``data`` axis alone splits the batch (the kernels
 then run under a ``shard_map``, as the fused update does). Everything
 else, and every caller that asks for no gradient (eval, serving, the
-boundary's accuracy pass), runs ``max_pool(relu(z + bias))`` itself.
+boundary's accuracy pass), runs ``max_pool(relu(z + bias))`` itself, in
+the input's dtype.
 """
 
 from __future__ import annotations
@@ -66,45 +86,54 @@ def fits_kernels(shape, dtype) -> bool:
             and c % _SUBLANES == 0)
 
 
-def bias_relu_max_pool(z: jax.Array, bias: jax.Array, mesh=None
-                       ) -> jax.Array:
+def bias_relu_max_pool(z: jax.Array, bias: jax.Array, mesh=None,
+                       store=None) -> jax.Array:
     """``max_pool(relu(z + bias))`` for NHWC ``z`` and a bias a channel
     (window 3, stride 2, SAME).
 
     ``mesh`` is the mesh of the enclosing GSPMD program, if any. The
-    choice of path reads the platform, the shape and the mesh only."""
+    choice of path reads the platform, the shape and the mesh only.
+    ``store`` is the dtype in which the kernels write the pooled output
+    and the input's gradient (module docstring): the dtype to which the
+    caller's products round them anyway, by default the input's. The XLA
+    expression has no such store and ignores it."""
     if not (platform_lib.on_tpu() and fits_kernels(z.shape, z.dtype)):
         kernel_paths.note("pool", "xla")
         return _plain(z, bias)
+    store = jnp.dtype(z.dtype if store is None else store)
+    stores = "" if store == z.dtype else f", stores {store.name}"
     if mesh is None or mesh.size == 1:
-        kernel_paths.note("pool", "pallas")
-        return fused_bias_relu_max_pool(z, bias)
+        kernel_paths.note("pool", "pallas" + stores)
+        return fused_bias_relu_max_pool(z, bias, False, store)
     ndata = mesh.shape["data"]
     if mesh.size != ndata or z.shape[0] % (ndata * _LANES):
         # H over ``seq`` (spatial partitioning) needs halo exchanges the
         # kernels do not make; GSPMD's pool does.
         kernel_paths.note("pool", "xla (mesh)")
         return _plain(z, bias)
-    kernel_paths.note("pool", f"pallas/shard_map[batch/data x{ndata}]")
-    return over_data(mesh)(z, bias)
+    kernel_paths.note("pool",
+                      f"pallas/shard_map[batch/data x{ndata}]" + stores)
+    return over_data(mesh, store=store)(z, bias)
 
 
-def over_data(mesh, interpret: bool = False):
+def over_data(mesh, interpret: bool = False, store=None):
     """The kernel path with the batch split over ``data``: each device
     runs the kernels on its own images (a bare ``pallas_call`` cannot be
     partitioned by GSPMD); the bias is replicated, and its gradient is
     summed over the devices by ``shard_map``'s own transpose."""
     return jax.shard_map(
-        lambda z, bias: fused_bias_relu_max_pool(z, bias, interpret),
+        lambda z, bias: fused_bias_relu_max_pool(z, bias, interpret, store),
         mesh=mesh, in_specs=(P("data", None, None, None), P()),
         out_specs=P("data", None, None, None), check_vma=False)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def fused_bias_relu_max_pool(z, bias, interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def fused_bias_relu_max_pool(z, bias, interpret=False, store=None):
     """The kernel path itself; ``interpret`` runs the kernels in the
-    Pallas interpreter (tests, off TPU)."""
-    del interpret
+    Pallas interpreter (tests, off TPU); ``store`` as in
+    :func:`bias_relu_max_pool`. Called for no gradient it is the plain
+    expression, unrounded: its readers round it as they read."""
+    del interpret, store
     return _plain(z, bias)
 
 
@@ -122,15 +151,20 @@ def _from_kernel(xt):
         return jnp.transpose(xt, (3, 0, 1, 2))
 
 
-def _fused_fwd(z, bias, interpret):
-    yt, idx = _pool_fwd(_to_kernel(z), bias, interpret)
-    return _from_kernel(yt), (idx, yt, bias)
+def _fused_fwd(z, bias, interpret, store):
+    yt, idx = _pool_fwd(_to_kernel(z), bias, interpret,
+                        jnp.dtype(z.dtype if store is None else store))
+    # Widened for a consumer that takes one dtype; on the chip the
+    # convert folds into the product that reads it (module docstring).
+    return _from_kernel(yt).astype(z.dtype), (idx, yt, bias)
 
 
-def _fused_bwd(interpret, res, g):
+def _fused_bwd(interpret, store, res, g):
     idx, yt, bias = res
+    del store        # ``yt`` carries it
     dzt, dbias = _pool_bwd(idx, yt, _to_kernel(g), interpret)
-    return _from_kernel(dzt), dbias.sum(axis=(0, 2)).astype(bias.dtype)
+    return (_from_kernel(dzt).astype(g.dtype),
+            dbias.sum(axis=(0, 2)).astype(bias.dtype))
 
 
 fused_bias_relu_max_pool.defvjp(_fused_fwd, _fused_bwd)
@@ -212,10 +246,10 @@ def _fwd_kernel(*refs, rows: int, wo: int, halo: bool):
     jax.lax.fori_loop(0, rows, window_row, None)
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _pool_fwd(zt, bias, interpret):
-    """``zt`` [H, W, C, B], ``bias`` [C] -> (pooled [Ho, Wo, C, B], tap
-    int8 alike)."""
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _pool_fwd(zt, bias, interpret, store):
+    """``zt`` [H, W, C, B], ``bias`` [C] -> (pooled [Ho, Wo, C, B] as
+    ``store``, tap int8 alike)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -240,7 +274,7 @@ def _pool_fwd(zt, bias, interpret):
         functools.partial(_fwd_kernel, rows=rows, wo=wo, halo=halo),
         grid=(b // bl, c // cb, ho // rows),
         in_specs=in_specs, out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((ho, wo, c, b), zt.dtype),
+        out_shape=[jax.ShapeDtypeStruct((ho, wo, c, b), store),
                    jax.ShapeDtypeStruct((ho, wo, c, b), jnp.int8)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
@@ -320,8 +354,9 @@ def _bwd_kernel(*refs, rows: int, wo: int, halo: bool):
 
 @functools.partial(jax.jit, static_argnums=3)
 def _pool_bwd(idx, yt, gt, interpret):
-    """Residuals and ``gt`` [Ho, Wo, C, B] -> (``dz`` [H, W, C, B], the
-    bias's gradient in float32 partial sums [row blocks, C, B])."""
+    """Residuals and ``gt`` [Ho, Wo, C, B] -> (``dz`` [H, W, C, B] in the
+    dtype ``yt`` was stored in, the bias's gradient in float32 partial
+    sums [row blocks, C, B])."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -345,7 +380,7 @@ def _pool_bwd(idx, yt, gt, interpret):
                                 lambda bi, ci, hi: (hi, 0, ci, bi)),
                    pl.BlockSpec((1, cb, bl),
                                 lambda bi, ci, hi: (hi, ci, bi))],
-        out_shape=[jax.ShapeDtypeStruct((2 * ho, 2 * wo, c, b), gt.dtype),
+        out_shape=[jax.ShapeDtypeStruct((2 * ho, 2 * wo, c, b), yt.dtype),
                    jax.ShapeDtypeStruct((ho // rows, c, b), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),

@@ -97,17 +97,25 @@ def _plain_pool(z, bias):
 POOL_SIZES = [(24, None), (12, None), (32, 10_000), (112, 10_000)]
 
 
+def _bf16_once(a):
+    return np.asarray(jnp.asarray(a, jnp.float32).astype(jnp.bfloat16),
+                      np.float32)
+
+
 @pytest.mark.parametrize("ties", [False, True], ids=["random", "ties"])
 @pytest.mark.parametrize("size,block_bytes", POOL_SIZES,
                          ids=[f"{s}" for s, _ in POOL_SIZES])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-def test_pool_kernels_match_max_pool_of_relu(dtype, size, block_bytes, ties,
-                                             monkeypatch):
+@pytest.mark.parametrize("dtype,store", [
+    (jnp.float32, None), (jnp.bfloat16, None), (jnp.float32, jnp.bfloat16)],
+    ids=["float32", "bfloat16", "float32_stores_bfloat16"])
+def test_pool_kernels_match_max_pool_of_relu(dtype, store, size, block_bytes,
+                                             ties, monkeypatch):
     """The kernels (in the Pallas interpreter) against ``jax.vjp`` of
     ``max_pool(relu(z + bias))``: forward bit-equal, gradients equal up to
     the order in which the (at most four) windows that share an input
-    position, and the windows of a channel, are summed."""
+    position, and the windows of a channel, are summed. With a narrower
+    ``store`` the pooled output and the input's gradient are those same
+    float32 values rounded once, and nothing else changes."""
     from dml_cnn_cifar10_tpu.ops import relu_pool
 
     if block_bytes:
@@ -119,29 +127,46 @@ def test_pool_kernels_match_max_pool_of_relu(dtype, size, block_bytes, ties,
     z, bias = _pool_input(shape, dtype, ties)
     want, ref_vjp = jax.vjp(_plain_pool, z, bias)
     got, vjp = jax.vjp(
-        lambda z, b: relu_pool.fused_bias_relu_max_pool(z, b, True), z, bias)
-    assert got.dtype == want.dtype
+        lambda z, b: relu_pool.fused_bias_relu_max_pool(z, b, True, store),
+        z, bias)
+    stored = jnp.dtype(store or dtype)
+    assert got.dtype == want.dtype      # handed on in the input's dtype
     np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                  np.asarray(want, np.float32))
+                                  np.asarray(want.astype(stored), np.float32))
     ct = jnp.asarray(np.random.default_rng(1).normal(size=want.shape), dtype)
     eps = float(jnp.finfo(dtype).eps)
     (dz, dbias), (dz_want, dbias_want) = [
         [np.asarray(t, np.float32) for t in f(ct)] for f in (vjp, ref_vjp)]
     assert np.abs(dz_want).max() > 1.0          # the comparison is not empty
-    np.testing.assert_allclose(dz, dz_want, rtol=0,
-                               atol=4 * eps * np.abs(dz_want).max())
+    if store is None:
+        np.testing.assert_allclose(dz, dz_want, rtol=0,
+                                   atol=4 * eps * np.abs(dz_want).max())
+    else:
+        # the float32 sum rounded ONCE: a stored value, and the reference's
+        # rounded alike but where the order of the float32 terms moved a
+        # sum across a rounding boundary (then one step of bfloat16 away)
+        np.testing.assert_array_equal(dz, _bf16_once(dz))
+        off = dz != _bf16_once(dz_want)
+        assert off.mean() < 1e-3
+        np.testing.assert_allclose(dz[off], dz_want[off], rtol=2.0 ** -7)
+        # the bias's gradient never passes through the store
+        dbias_f32 = jax.vjp(lambda z, b: relu_pool.fused_bias_relu_max_pool(
+            z, b, True), z, bias)[1](ct)[1]
+        np.testing.assert_array_equal(dbias, dbias_f32)
     # a sum over every window of a channel: a rounding a term at most
     terms = np.abs(np.asarray(ct, np.float32)).sum(axis=(0, 1, 2)).max()
     np.testing.assert_allclose(dbias, dbias_want, rtol=0, atol=eps * terms)
     # What the forward pass leaves for the backward pass: the winning tap
-    # and the pooled output (and the bias, for its dtype).
+    # and the pooled output as stored (and the bias, for its dtype).
     carried = sorted((str(l.dtype), l.size) for l in jax.tree.leaves(vjp))
     assert carried == sorted([("int8", want.size),
-                              (str(jnp.dtype(dtype)), want.size),
+                              (str(stored), want.size),
                               (str(jnp.dtype(dtype)), bias.size)])
 
 
-def test_pool_kernels_over_data_sum_the_bias_gradient():
+@pytest.mark.parametrize("store", [None, jnp.bfloat16],
+                         ids=["float32", "stores_bfloat16"])
+def test_pool_kernels_over_data_sum_the_bias_gradient(store):
     """On a mesh the kernels run a device, each on its own images; the
     bias is replicated, so its gradient is the sum over the devices."""
     from dml_cnn_cifar10_tpu.config import ParallelConfig
@@ -151,12 +176,17 @@ def test_pool_kernels_over_data_sum_the_bias_gradient():
     mesh = mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:4])
     z, bias = _pool_input((8, 12, 12, 4), jnp.float32, True)
     want, ref_vjp = jax.vjp(_plain_pool, z, bias)
-    got, vjp = jax.vjp(jax.jit(relu_pool.over_data(mesh, interpret=True)),
-                       z, bias)
-    np.testing.assert_array_equal(got, want)
+    got, vjp = jax.vjp(
+        jax.jit(relu_pool.over_data(mesh, interpret=True, store=store)),
+        z, bias)
+    np.testing.assert_array_equal(got, want)    # halves: exact in bfloat16
     assert got.sharding.spec[0] == "data"
-    for a, b in zip(vjp(want), ref_vjp(want)):
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
+    (dz, dbias), (dz_want, dbias_want) = vjp(want), ref_vjp(want)
+    np.testing.assert_allclose(
+        dz, _bf16_once(dz_want) if store else dz_want, rtol=0, atol=1e-5)
+    np.testing.assert_allclose(dbias, dbias_want, rtol=0, atol=1e-5)
+    kept = {str(l.dtype) for l in jax.tree.leaves(vjp) if l.size == got.size}
+    assert kept == {"int8", str(jnp.dtype(store or jnp.float32))}
 
 
 @pytest.mark.parametrize("shape,fits", [
@@ -193,6 +223,135 @@ def test_pool_path_is_chosen_by_shape(shape, fits, monkeypatch):
         np.testing.assert_array_equal(got, want)
         for a, b in zip(vjp(want), ref_vjp(want)):
             np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("tpu,dtype,precision,path,stored", [
+    (True, "float32", None, "pallas, stores bfloat16", "bfloat16"),
+    (True, "float32", "bfloat16", "pallas, stores bfloat16", "bfloat16"),
+    (True, "float32", "highest", "pallas", "float32"),
+    (True, "float32", "float32", "pallas", "float32"),
+    (True, "bfloat16", None, "pallas", "bfloat16"),
+    (False, "float32", None, "xla", None),
+])
+def test_pool_store_is_chosen_by_dtype_and_precision(
+        tpu, dtype, precision, path, stored, monkeypatch):
+    """The narrow store engages where a product rounds what the model
+    computes in: float32 on a TPU at a one-pass bfloat16 precision. It is
+    read from the input and the backend, as ``models/cnn.py`` reads it;
+    the op's own default is the input's dtype."""
+    from dml_cnn_cifar10_tpu.ops import kernel_paths, relu_pool
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: tpu)
+    shape = (128, 24, 24, 64)
+    args = (jax.ShapeDtypeStruct(shape, dtype),
+            jax.ShapeDtypeStruct(shape[-1:], dtype))
+
+    def carried(as_the_model_does):
+        def f(z, b):
+            store = (L.product_operand_dtype(z.dtype)
+                     if as_the_model_does else None)
+            return relu_pool.bias_relu_max_pool(z, b, store=store)
+        with kernel_paths.recording() as rec:
+            out, vjp = jax.eval_shape(lambda z, b: jax.vjp(f, z, b), *args)
+        assert out.dtype == jnp.dtype(dtype) and out.shape == (128, 12, 12,
+                                                               64)
+        return rec["pool"], {str(l.dtype) for l in jax.tree.leaves(vjp)
+                             if l.shape == (12, 12, 64, 128)}
+
+    with jax.default_matmul_precision(precision):
+        got = carried(True)
+        default = carried(False)
+    assert got == (path, {"int8", stored} if stored else set())
+    assert default == (("pallas", {"int8", dtype}) if tpu
+                       else ("xla", set()))
+
+
+def _rounding_products(monkeypatch):
+    """The TPU's float32 products on the CPU: ``L.conv2d`` and ``L.dense``
+    with every operand rounded to bfloat16, forward and backward, summed
+    and kept in float32 (what the configuration's ``matmul_precision``
+    states). The CPU multiplies float32 as it is, so without this the
+    test below could not tell a rounding that every product makes anyway
+    from one that nobody makes."""
+    def rounds(f):
+        r = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+
+        @jax.custom_vjp
+        def product(x, w):
+            return f(r(x), r(w))
+
+        def fwd(x, w):
+            return product(x, w), (r(x), r(w))
+
+        def bwd(res, g):
+            return jax.vjp(f, *res)[1](r(g))
+
+        product.defvjp(fwd, bwd)
+        return product
+
+    conv, dot = rounds(L.conv2d), rounds(jnp.dot)
+    monkeypatch.setattr(L, "conv2d", conv)
+    monkeypatch.setattr(L, "dense", lambda x, w, b: dot(x, w) + b)
+
+
+def test_narrow_store_is_the_rounding_the_products_make(monkeypatch):
+    """The loss gradient of ``models/cnn.apply`` through the kernels with
+    the bfloat16 store equals, leaf for leaf within float32 summation
+    order, that of the plain model with everything stored in float32,
+    once every product rounds its operands as the TPU's do: the store
+    rounds nothing that is not rounded anyway. Rounding conv1's output
+    (which an add, a ReLU and a compare read, not a product) as well is
+    another gradient."""
+    from dml_cnn_cifar10_tpu.ops import relu_pool
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+
+    cfg = ModelConfig(logit_relu=False)
+    data = DataConfig(crop_height=8, crop_width=8)
+    params = cnn.init_params(jax.random.key(0), cfg, data)
+    rng = np.random.default_rng(0)
+    images = jnp.asarray(rng.normal(size=(128, 8, 8, 3)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 10, size=128))
+    _rounding_products(monkeypatch)
+
+    def grad(apply):
+        def loss(p):
+            lp = jax.nn.log_softmax(apply(p, images, cfg))
+            return -jnp.take_along_axis(lp, labels[:, None], 1).mean()
+        return jax.tree.leaves(jax.jit(jax.grad(loss))(params))
+
+    plain = grad(cnn.apply)                  # the CPU: XLA's pool, float32
+
+    def z1_rounded_too(p, x, cfg):
+        conv = L.conv2d
+        monkeypatch.setattr(L, "conv2d", lambda x, w: (
+            conv(x, w) if w.shape[2] != 3 else
+            conv(x, w).astype(jnp.bfloat16).astype(jnp.float32)))
+        try:
+            return cnn.apply(p, x, cfg)
+        finally:
+            monkeypatch.setattr(L, "conv2d", conv)
+
+    other = grad(z1_rounded_too)
+
+    # the chip's path: the kernels (here in the interpreter), and the
+    # store that models/cnn.py reads from the backend
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: True)
+    fused = relu_pool.fused_bias_relu_max_pool
+    stores = []
+    monkeypatch.setattr(
+        relu_pool, "fused_bias_relu_max_pool",
+        lambda z, b, interpret, store: (
+            stores.append(store), fused(z, b, True, store))[1])
+    narrow = grad(cnn.apply)
+    assert stores == [jnp.bfloat16, jnp.bfloat16]
+
+    for a, b in zip(narrow, plain):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=0, atol=5e-6 * scale)
+    moved = [float(jnp.abs(c - b).max() / jnp.abs(b).max())
+             for b, c in zip(plain, other)]
+    assert min(moved) > 2e-4 and max(moved) > 1e-3, moved
 
 
 def test_conv2d_matches_manual_nhwc():

@@ -107,7 +107,9 @@ def test_cnn_step_pools_are_the_kernels(chip_branch, ndev, capsys):
     """At a batch that fills the lanes of every device, each of the CNN's
     bias + ReLU + pool pairs is two kernels (forward, backward) beside
     the update's one a leaf, on four devices under a ``shard_map`` over
-    ``data``; no select-and-scatter is left in the step."""
+    ``data``; no select-and-scatter is left in the step. The model is
+    float32 and the backend (as patched) a TPU at the default precision,
+    so the kernels store bfloat16 and the step's line says so."""
     model_def = get_model("cnn")
     model_cfg, data_cfg = ModelConfig(), DataConfig()
     optim_cfg = OptimConfig()
@@ -120,7 +122,10 @@ def test_cnn_step_pools_are_the_kernels(chip_branch, ndev, capsys):
     assert text.count("tpu_custom_call") == 4 + len(jax.tree.leaves(
         state.params))
     want = "pallas" + (f"/shard_map[batch/data x{ndev}]" if ndev > 1 else "")
-    assert f" pool={want}\n" in capsys.readouterr().out
+    assert f" pool={want}, stores bfloat16\n" in capsys.readouterr().out
+    # pool1's output and its input's gradient, a device's share of each
+    assert "tensor<12x12x64x128xbf16>" in text
+    assert "tensor<24x24x64x128xbf16>" in text
     # The same builder at a batch that leaves lanes empty keeps XLA's pool.
     step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
                                     state_sharding=sh)
@@ -131,9 +136,12 @@ def test_cnn_step_pools_are_the_kernels(chip_branch, ndev, capsys):
 
 def test_cnn_pools_carry_no_activation_to_the_backward_pass(chip_branch):
     """What the CNN's forward pass keeps for its backward pass at 24x24:
-    of the pools the winning tap (int8) and the pooled output, both at the
-    pool's output size, and nothing of the size of a convolution's
-    activation (XLA's ReLU and max-pool keep it, twice)."""
+    of the pools the winning tap (int8) and the pooled output as stored
+    (bfloat16: a float32 model on a TPU at the default precision), both
+    at the pool's output size, and nothing of the size of a convolution's
+    activation (XLA's ReLU and max-pool keep it, twice). conv2's input is
+    float32 as traced; the chip's compiler reads it from the bfloat16
+    array (PERF.md, Findings, PR 31)."""
     from dml_cnn_cifar10_tpu.models import cnn
 
     cfg, data_cfg, batch = ModelConfig(logit_relu=False), DataConfig(), 128
@@ -146,9 +154,9 @@ def test_cnn_pools_carry_no_activation_to_the_backward_pass(chip_branch):
     carried = sorted((str(l.dtype), l.shape) for l in jax.tree.leaves(vjp)
                      if l.ndim == 4 and l.size >= batch * 6 * 6 * 64)
     assert carried == sorted([
-        ("int8", (12, 12, 64, batch)), ("float32", (12, 12, 64, batch)),
+        ("int8", (12, 12, 64, batch)), ("bfloat16", (12, 12, 64, batch)),
         ("float32", (batch, 12, 12, 64)),          # conv2's input
-        ("int8", (6, 6, 64, batch)), ("float32", (6, 6, 64, batch))])
+        ("int8", (6, 6, 64, batch)), ("bfloat16", (6, 6, 64, batch))])
 
 
 def test_sharded_update_operands_keep_the_xla_expression(chip_branch,
