@@ -55,15 +55,29 @@ def build_parser() -> argparse.ArgumentParser:
     # --- framework flags ---
     p.add_argument("--model", type=str, default="cnn",
                    choices=["cnn", "resnet18", "resnet50", "vit_tiny",
-                            "vit_moe", "looped_decoder"],
+                            "vit_moe", "looped_decoder", "hybrid_decoder"],
                    help="looped_decoder: a causal decoder over tokens whose "
-                        "layers run several times on the same weights "
-                        "(needs --dataset tokens_synth)")
+                        "layers run several times on the same weights; "
+                        "hybrid_decoder: one whose layers differ by a list "
+                        "(gated short convolution or grouped-head "
+                        "attention, then a dense MLP or this chip's share "
+                        "of sigmoid-routed experts), tied embedding "
+                        "(both need --dataset tokens_synth)")
     p.add_argument("--model_config_file", type=str, default=None,
                    help="sizes of a model that reads them from a file in "
                         "the shape of a published config.json "
-                        "(looped_decoder; default: its small built-in "
-                        "sizes)")
+                        "(looped_decoder, hybrid_decoder; default: the "
+                        "model's small built-in sizes). hybrid_decoder "
+                        "reads hidden_size, num_attention_heads, "
+                        "num_key_value_heads, head_dim (optional), "
+                        "intermediate_size, moe_intermediate_size, "
+                        "num_hidden_layers, layer_types (conv | "
+                        "full_attention), num_dense_layers, num_experts "
+                        "(held here), expert_first_id, router_num_experts, "
+                        "num_experts_per_tok, norm_topk_prob, "
+                        "routed_scaling_factor, use_expert_bias, "
+                        "vocab_size, norm_eps, rope_theta, conv_L_cache, "
+                        "conv_bias")
     p.add_argument("--dataset", type=str, default="cifar10",
                    choices=["cifar10", "cifar100", "synthetic",
                             "imagenet_synth", "tokens_synth"],
@@ -789,21 +803,25 @@ def config_from_args(args: argparse.Namespace) -> config_lib.TrainConfig:
     cfg.model.compute_dtype = args.compute_dtype
     cfg.model.config_file = args.model_config_file
     cfg.data.sequence_length = args.sequence_length
-    if (args.dataset == "tokens_synth") != (args.model == "looped_decoder"):
+    over_tokens = args.model in ("looped_decoder", "hybrid_decoder")
+    if (args.dataset == "tokens_synth") != over_tokens:
         raise SystemExit(
-            f"--dataset tokens_synth and --model looped_decoder go "
-            f"together (got {args.dataset} with {args.model}): a model "
-            f"over tokens reads token rows and nothing else does")
-    if args.model == "looped_decoder" and args.mode not in ("train", "eval"):
+            f"--dataset tokens_synth and a model over tokens "
+            f"(looped_decoder, hybrid_decoder) go together (got "
+            f"{args.dataset} with {args.model}): a model over tokens reads "
+            f"token rows and nothing else does")
+    if over_tokens and args.mode not in ("train", "eval"):
         raise SystemExit(
-            f"--model looped_decoder trains and evaluates; --mode "
+            f"--model {args.model} trains and evaluates; --mode "
             f"{args.mode} takes an image classifier (the serving stack "
             f"has no token requests and no KV cache)")
-    if args.dataset == "tokens_synth":
+    if over_tokens:
         # the generated ids cover the model's whole vocabulary
-        from dml_cnn_cifar10_tpu.models import looped_decoder
+        from dml_cnn_cifar10_tpu.models import hybrid_decoder, looped_decoder
+        module = {"looped_decoder": looped_decoder,
+                  "hybrid_decoder": hybrid_decoder}[args.model]
         cfg.data.num_classes = cfg.model.num_classes = \
-            looped_decoder.sizes(cfg.model)["vocab_size"]
+            module.sizes(cfg.model)["vocab_size"]
     cfg.optim.adam_b1 = args.adam_b1
     cfg.optim.adam_b2 = args.adam_b2
     cfg.optim.adam_eps = args.adam_eps

@@ -119,8 +119,8 @@ class ModelConfig:
     name: str = "cnn"                     # cnn | resnet18 | resnet50 | vit_tiny
     num_classes: int = 10
     # A file of sizes in the shape of a published ``config.json``, for a
-    # model that reads one (models/looped_decoder.py) in place of a flat
-    # family of fields here.
+    # model that reads one (models/looped_decoder.py,
+    # models/hybrid_decoder.py) in place of a flat family of fields here.
     config_file: Optional[str] = None
     # Reference applies ReLU to the final logits (cifar10cnn.py:145). Faithful
     # mode keeps it; fixed mode emits raw logits.
@@ -214,7 +214,11 @@ class ModelConfig:
     # test_scatter_dispatch_matches_einsum — reduction orders differ,
     # so outputs are close, not bit-identical).
     moe_dispatch: str = "einsum"
-    moe_top_k: int = 1                    # 1 = Switch, 2 = GShard routing
+    # 1 = Switch, 2 = GShard routing: ``ops.moe.moe_mlp``'s two, with its
+    # static capacity. (``ops.moe.routed_experts`` takes any number of
+    # experts a token and drops none; its sizes come from the model's
+    # ``config_file``, not from these fields.)
+    moe_top_k: int = 1
     moe_capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01            # load-balance loss weight
 

@@ -1,0 +1,390 @@
+"""A causal decoder over tokens whose layers differ by a list: an operator
+of one of two kinds (a gated short convolution, or rotary attention with
+grouped key/value heads and a norm on each head's query and key), then a
+feed-forward of one of two kinds (a dense gated SiLU MLP in the leading
+layers, sigmoid-routed experts after them), a tied embedding and head.
+
+With tokens ``x [B, S]``: ``h = E[x]``. Every layer ``i``: ``a = rms(h;
+g_op_i)``; by ``layer_types[i]``
+
+- ``conv``: ``[Bg, Cg, X] = split3(a W_in)``, ``u = Bg * X``, ``c[t] =
+  sum_j w[:, j] u[t - (L - 1) + j]`` (depthwise, causal), ``o = (Cg * c)
+  W_out`` (``ops.layers.gated_short_conv`` between the two products);
+- ``full_attention``: ``ops.attention.causal_self_attention``, the
+  sublayer the looped decoder runs too;
+
+``h = h + o``; ``m = rms(h; g_ffn_i)``; for ``i < num_dense_layers`` ``f =
+(silu(m W1) * (m W3)) W2``, else the experts' layer: ``s = sigmoid(m
+Wr)`` over ALL ``router_num_experts`` experts, a token takes the
+``num_experts_per_tok`` with the largest ``s + b`` and weighs them by
+their ``s`` over its sum, and the ``num_experts`` experts held here (ids
+``expert_first_id ..``) add their part (``ops.moe.routed_experts``: no
+capacity, nothing dropped, nothing stands in for the experts that live
+elsewhere); ``h = h + f``. The bias ``b`` (``use_expert_bias``) is a
+buffer of the model's state, zero at the start, that no gradient reaches:
+every training step moves each expert's by ``expert_bias_update_rate``
+toward an even load over all of the router's experts
+(``ops.moe.balanced_bias``). ``logits = rms(h; g_f) E^T``; the loss is the
+mean next-token cross-entropy, over the ``vocab_size`` rows held here.
+
+Sizes come from a JSON file in the shape of a published ``config.json``
+(``--model_config_file``, the keys of :data:`SMALL`); without one,
+:data:`SMALL`. The model states its own loss (``ModelDef.loss``): a batch
+is ``[B, S+1]`` int32 rows of a token dataset, and no ``[tokens,
+vocabulary]`` array is ever held.
+
+Numerics: parameters and the residual stream float32; every product (the
+grouped ones too) of operands rounded to ``compute_dtype`` and summed in
+float32; the router's product, sigmoid, choice and weights, the norms,
+rotary, softmax, the short convolution's taps and gates and the loss
+float32.
+
+Memory: the sublayers that treat each sequence alone (both operators, the
+dense MLP) take :data:`OP_CHUNK_TOKENS` tokens at a time, one group of
+sequences after the other; the experts take all of a step's tokens, their
+rows a block at a time. With ``remat`` the backward pass recomputes an
+operator but its flash kernel (:data:`KEPT`), a group's dense MLP, and a
+block of the experts' rows; what is kept is each sublayer's input.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from dml_cnn_cifar10_tpu.config import DataConfig, ModelConfig
+from dml_cnn_cifar10_tpu.models import looped_decoder
+from dml_cnn_cifar10_tpu.ops import attention as attention_lib
+from dml_cnn_cifar10_tpu.ops import kernel_paths
+from dml_cnn_cifar10_tpu.ops import moe as moe_lib
+from dml_cnn_cifar10_tpu.ops.layers import (gated_short_conv, mixed_matmul,
+                                            rms_norm)
+from dml_cnn_cifar10_tpu.train import loss as loss_lib
+
+#: The sizes of a run that names no file: what the tests and the chip's
+#: smoke run use. Half of the router's experts are held.
+SMALL: Dict[str, Any] = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_hidden_layers": 3,
+    "layer_types": ["conv", "full_attention", "conv"],
+    "num_dense_layers": 1, "num_experts": 4, "router_num_experts": 8,
+    "expert_first_id": 0, "num_experts_per_tok": 2, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "use_expert_bias": True,
+    "expert_bias_update_rate": 0.001, "vocab_size": 96,
+    "norm_eps": 1e-5, "rope_theta": 1000000, "conv_L_cache": 3,
+    "conv_bias": False}
+
+KEPT = looped_decoder.KEPT
+
+#: Tokens that a sublayer which treats each sequence alone takes at once,
+#: at most (whole sequences: one where a sequence is longer).
+OP_CHUNK_TOKENS = 8192
+#: Tokens of the dense MLP, which treats each token alone, at once.
+MLP_CHUNK_TOKENS = 4096
+#: Rows of the experts' buffer: the load expected under uniform routing
+#: (what the experts' bias steers toward) with a sixteenth of slack, in as
+#: many blocks of about this many rows as that takes, each a multiple of
+#: :data:`ROW_TILE`. Further blocks, up to the worst case, are visited
+#: only when routing is that skewed.
+EXPERT_BLOCK_ROWS = 8192
+ROW_TILE = 512
+
+
+@functools.lru_cache(maxsize=None)
+def _read_sizes(path: str) -> Dict[str, Any]:
+    spec = looped_decoder.read_config_file(path)
+    missing = sorted(set(SMALL) - set(spec))
+    if missing:
+        raise ValueError(f"{path} lacks {missing}")
+    sz = {k: spec[k] for k in SMALL}
+    sz["head_dim"] = spec.get("head_dim") or \
+        sz["hidden_size"] // sz["num_attention_heads"]
+    return _checked(sz, path)
+
+
+def _checked(sz: Dict[str, Any], where: str) -> Dict[str, Any]:
+    kinds = set(sz["layer_types"])
+    if len(sz["layer_types"]) != sz["num_hidden_layers"] \
+            or not kinds <= {"conv", "full_attention"}:
+        raise ValueError(f"{where}: layer_types has to name conv or "
+                         f"full_attention for each of num_hidden_layers")
+    if sz["conv_bias"]:
+        raise NotImplementedError(f"{where}: conv_bias is not built")
+    last = sz["expert_first_id"] + sz["num_experts"]
+    if not 0 <= sz["expert_first_id"] < last <= sz["router_num_experts"]:
+        raise ValueError(f"{where}: experts {sz['expert_first_id']}..{last} "
+                         f"are not among the router's "
+                         f"{sz['router_num_experts']}")
+    if sz["num_attention_heads"] % sz["num_key_value_heads"]:
+        raise ValueError(f"{where}: the key/value heads do not divide the "
+                         f"query heads")
+    return sz
+
+
+def sizes(cfg: ModelConfig) -> Dict[str, Any]:
+    """The model's sizes: the file ``cfg.config_file`` names, else
+    :data:`SMALL`; ``head_dim`` where the file has none is ``hidden_size /
+    num_attention_heads``."""
+    if not cfg.config_file:
+        return _checked({**SMALL, "head_dim": 16}, "SMALL")
+    return _read_sizes(looped_decoder.config_path(cfg.config_file))
+
+
+def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
+    """Normal weights of variance 1 / fan-in (the embedding: 1 / hidden;
+    the filter: 1 / taps), norm scales 1."""
+    del data_cfg
+    sz = sizes(cfg)
+    d, f, hm = sz["hidden_size"], sz["intermediate_size"], \
+        sz["moe_intermediate_size"]
+    dh = sz["head_dim"]
+    a, kv = sz["num_attention_heads"] * dh, sz["num_key_value_heads"] * dh
+    e, e_all = sz["num_experts"], sz["router_num_experts"]
+    dtype = jnp.dtype(cfg.dtype)
+    keys = iter(jax.random.split(key, 2 + 9 * sz["num_hidden_layers"]))
+
+    def matrix(fan_in, *shape):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                / np.sqrt(fan_in)).astype(dtype)
+
+    def scale(n=d):
+        return {"scale": jnp.ones((n,), dtype)}
+
+    def layer(i, kind):
+        p = {"op_norm": scale(), "ffn_norm": scale()}
+        if kind == "conv":
+            p["conv"] = {"w_in": matrix(d, d, 3 * d),
+                         "w": matrix(sz["conv_L_cache"], d,
+                                     sz["conv_L_cache"]),
+                         "w_out": matrix(d, d, d)}
+        else:
+            p["attn"] = {"wq": matrix(d, d, a), "wk": matrix(d, d, kv),
+                         "wv": matrix(d, d, kv), "wo": matrix(a, a, d),
+                         "q_norm": scale(dh), "k_norm": scale(dh)}
+        if i < sz["num_dense_layers"]:
+            p["mlp"] = {"w1": matrix(d, d, f), "w3": matrix(d, d, f),
+                        "w2": matrix(f, f, d)}
+        else:
+            p["moe"] = {"router": matrix(d, d, e_all),
+                        "w1": matrix(d, e, d, hm), "w3": matrix(d, e, d, hm),
+                        "w2": matrix(hm, e, hm, d)}
+        return p
+
+    return {"embed": matrix(d, sz["vocab_size"], d),
+            "layers": [layer(i, kind)
+                       for i, kind in enumerate(sz["layer_types"])],
+            "final_norm": scale()}
+
+
+def init_state(params):
+    """The model's state: for each layer that has experts their bias,
+    zero (``{}`` for a layer without). :func:`loss` reads it only where
+    the file says ``use_expert_bias``."""
+    return {"layers": [
+        {"expert_bias": jnp.zeros((p["moe"]["router"].shape[1],),
+                                  jnp.float32)} if "moe" in p else {}
+        for p in params["layers"]]}
+
+
+def _in_groups(fn, h, rows: int, most: int):
+    """``fn`` on ``h``, whose ``rows`` leading rows go in equal groups of at
+    most ``most`` (at least one row), one group after the other; a plain
+    call where one group is all of them."""
+    per = max(1, min(most, rows))
+    while rows % per:
+        per -= 1
+    if per == rows:
+        return fn(h)
+    grouped = h.reshape(rows // per, per, *h.shape[1:])
+    return lax.map(fn, grouped).reshape(h.shape)
+
+
+def _over_sequences(fn, h):
+    """``fn`` on ``h [B, S, D]``, as many whole sequences at a time as hold
+    at most :data:`OP_CHUNK_TOKENS` tokens."""
+    return _in_groups(fn, h, h.shape[0], OP_CHUNK_TOKENS // h.shape[1])
+
+
+def _over_tokens(fn, h):
+    """``fn``, which treats each token alone, on ``h [B, S, D]``,
+    :data:`MLP_CHUNK_TOKENS` tokens at a time."""
+    b, s, d = h.shape
+    return _in_groups(fn, h.reshape(b * s, d), b * s,
+                      MLP_CHUNK_TOKENS).reshape(b, s, d)
+
+
+def expert_block_rows(slots: int, expected: float) -> int:
+    """Rows of one block of the experts' buffer (see
+    :data:`EXPERT_BLOCK_ROWS`), at most all ``slots``."""
+    blocks = max(1, round(expected / EXPERT_BLOCK_ROWS))
+    rows = -(-int(1.0625 * expected / blocks) // ROW_TILE) * ROW_TILE
+    return min(slots, max(rows, ROW_TILE))
+
+
+def _operator(h, p, kind: str, sz, cfg: ModelConfig, mesh):
+    """``h + operator(rms(h))`` on ``h [B, S, D]``."""
+    eps, low = sz["norm_eps"], jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("op_norm"):
+        a = rms_norm(h, p["op_norm"]["scale"], eps)
+    if kind == "conv":
+        # not `conv`: that scope is the image models' convolutions
+        with jax.named_scope("short_conv"):
+            with jax.named_scope("in"):
+                bcx = mixed_matmul(a, p["conv"]["w_in"], low)
+            with jax.named_scope("gate_conv"):
+                gated = gated_short_conv(bcx, p["conv"]["w"])
+            with jax.named_scope("out"):
+                return h + mixed_matmul(gated, p["conv"]["w_out"], low)
+    with jax.named_scope("attn"):
+        return h + attention_lib.causal_self_attention(
+            a, p["attn"], heads=sz["num_attention_heads"],
+            kv_heads=sz["num_key_value_heads"], head_dim=sz["head_dim"],
+            rope_theta=sz["rope_theta"], low=low,
+            use_pallas=cfg.use_pallas_attention, mesh=mesh, norm_eps=eps)
+
+
+def _dense_ffn(h, p, sz, cfg: ModelConfig):
+    """``h + mlp(rms(h))``."""
+    low = jnp.dtype(cfg.compute_dtype)
+    with jax.named_scope("ffn_norm"):
+        m = rms_norm(h, p["ffn_norm"]["scale"], sz["norm_eps"])
+    with jax.named_scope("mlp"):
+        return h + mixed_matmul(
+            jax.nn.silu(mixed_matmul(m, p["mlp"]["w1"], low))
+            * mixed_matmul(m, p["mlp"]["w3"], low), p["mlp"]["w2"], low)
+
+
+def _expert_ffn(h, p, bias, sz, cfg: ModelConfig):
+    """``h + experts(rms(h))`` on all of a step's tokens, and the layer's
+    counters."""
+    b, s, d = h.shape
+    k, e_all = sz["num_experts_per_tok"], sz["router_num_experts"]
+    slots = b * s * k
+    # the layer's norm is formed where the router and the experts read it
+    # (scope `ffn_norm` is theirs to open: kind `route` / `expert` decide)
+    with jax.named_scope("moe"):
+        f, stats = moe_lib.routed_experts(
+            h.reshape(b * s, d), p["moe"],
+            first_expert=sz["expert_first_id"], top_k=k,
+            dtype=jnp.dtype(cfg.compute_dtype), bias=bias,
+            norm_topk=sz["norm_topk_prob"],
+            scaling=sz["routed_scaling_factor"],
+            block_rows=expert_block_rows(
+                slots, slots * sz["num_experts"] / e_all),
+            norm_scale=p["ffn_norm"]["scale"], norm_eps=sz["norm_eps"])
+    return h + f.reshape(b, s, d), stats
+
+
+def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
+         model_state=None, loss_blocks=None):
+    """The model's own loss over a batch of token rows ``[B, S+1]`` ->
+    ``(mean next-token cross-entropy, stats, new model state)``. ``stats``:
+    ``accuracy``, the share of next tokens whose logit is the largest,
+    and, the mean over the experts' layers, ``moe_rows_here_frac`` and
+    ``moe_load_max_over_mean`` (``ops.moe.routed_experts``). The state is
+    :func:`init_state`'s (None: as at the start); in training each
+    experts' layer's bias comes back moved one step toward an even load.
+    ``loss_blocks`` overrides the number of blocks the loss is taken in."""
+    sz = sizes(cfg)
+    if model_state is None:
+        model_state = init_state(params)
+    rate = sz["expert_bias_update_rate"] if train else 0.0
+    low = jnp.dtype(cfg.compute_dtype)
+    inputs, targets = rows[:, :-1], rows[:, 1:].reshape(-1)
+    n = targets.shape[0]
+
+    def remat(fn, **kwargs):
+        return jax.checkpoint(fn, **kwargs) if cfg.remat else fn
+
+    if cfg.remat:
+        kernel_paths.note("remat", "sublayer, keeps " + " ".join(KEPT))
+    with jax.named_scope("embed"):
+        h = params["embed"][inputs].astype(jnp.float32)
+    moe_stats, new_state = [], {"layers": []}
+    for i, (kind, p) in enumerate(zip(sz["layer_types"], params["layers"])):
+        state = model_state["layers"][i]
+        with jax.named_scope(f"layer{i}"):
+            operator = remat(
+                lambda x, p=p, kind=kind: _operator(x, p, kind, sz, cfg,
+                                                    mesh),
+                policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+            h = _over_sequences(operator, h)
+            if "mlp" in p:
+                h = _over_tokens(
+                    remat(lambda x, p=p: _dense_ffn(x, p, sz, cfg)), h)
+            else:
+                bias = state["expert_bias"] if sz["use_expert_bias"] \
+                    else None
+                h, stats = _expert_ffn(h, p, bias, sz, cfg)
+                if bias is not None:
+                    state = {"expert_bias": moe_lib.balanced_bias(
+                        bias, stats.pop("expert_load"), rate)}
+                moe_stats.append(stats)
+        new_state["layers"].append(state)
+    with jax.named_scope("final_norm"):
+        h = rms_norm(h, params["final_norm"]["scale"], sz["norm_eps"])
+    with jax.named_scope("head"):
+        ce, hit = loss_lib.blockwise_cross_entropy(
+            h.reshape(n, h.shape[-1]), params["embed"].T, targets,
+            loss_blocks or looped_decoder.token_blocks(n), low)
+    with jax.named_scope("loss"):
+        value = jnp.mean(ce)
+    stats = {"accuracy": lax.stop_gradient(jnp.mean(hit))}
+    for name in ("rows_here_frac", "load_max_over_mean"):
+        if moe_stats:
+            stats["moe_" + name] = sum(s[name] for s in moe_stats) \
+                / len(moe_stats)
+    return value, stats, jax.tree.map(lax.stop_gradient, new_state)
+
+
+def batch_shape(cfg: ModelConfig, data_cfg: DataConfig, batch: int):
+    """What a batch of this model is: rows of the token dataset."""
+    del cfg
+    return jax.ShapeDtypeStruct((batch, data_cfg.sequence_length + 1),
+                                jnp.int32)
+
+
+def param_count(cfg: ModelConfig) -> int:
+    """Every number the model holds: its parameters and the buffers of
+    its state (the experts' bias, which a published count has)."""
+    def build():
+        params = init_params(jax.random.key(0), cfg, DataConfig())
+        return params, init_state(params) \
+            if sizes(cfg)["use_expert_bias"] else {}
+    return sum(int(np.prod(x.shape))
+               for x in jax.tree.leaves(jax.eval_shape(build)))
+
+
+def step_flops(cfg: ModelConfig, data_cfg: DataConfig, batch: int) -> float:
+    """Operations of one training step on ``batch`` sequences, from the
+    shapes: three times the forward's multiply-adds, two operations each.
+    The experts under uniform routing: of a token's ``num_experts_per_tok``
+    slots the share ``num_experts / router_num_experts`` falls on an
+    expert held here. Causal attention as the half square it is, forward
+    once and backward two and a half times. Not counted: the embedding's
+    gather, the filter's taps and gates, norms, softmax, and what the
+    backward pass computes a second time."""
+    sz = sizes(cfg)
+    s, d = data_cfg.sequence_length, sz["hidden_size"]
+    a = sz["num_attention_heads"] * sz["head_dim"]
+    kv = sz["num_key_value_heads"] * sz["head_dim"]
+    kinds = sz["layer_types"]
+    conv, attn = kinds.count("conv"), kinds.count("full_attention")
+    dense = sz["num_dense_layers"]
+    here = sz["num_experts_per_tok"] * sz["num_experts"] \
+        / sz["router_num_experts"]
+    per_token = conv * 4 * d * d + attn * 2 * d * (a + kv) \
+        + dense * 3 * d * sz["intermediate_size"] \
+        + (len(kinds) - dense) * (d * sz["router_num_experts"]
+                                  + here * 3 * d
+                                  * sz["moe_intermediate_size"]) \
+        + d * sz["vocab_size"]
+    pairs = s * (s + 1) // 2
+    attention = attn * 2 * a * pairs
+    return float(batch * (6 * s * per_token + 2 * 3.5 * attention))

@@ -54,7 +54,7 @@ from jax import lax
 from dml_cnn_cifar10_tpu.config import DataConfig, ModelConfig
 from dml_cnn_cifar10_tpu.ops import attention as attention_lib
 from dml_cnn_cifar10_tpu.ops import kernel_paths
-from dml_cnn_cifar10_tpu.ops.layers import mixed_matmul, rms_norm, rotary
+from dml_cnn_cifar10_tpu.ops.layers import mixed_matmul, rms_norm
 from dml_cnn_cifar10_tpu.train import loss as loss_lib
 
 #: The sizes of a run that names no file: what the tests and the chip's
@@ -78,30 +78,34 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+def config_path(path: str) -> str:
+    """Where a ``--model_config_file`` is: looked for from the working
+    directory, then from the repository's root."""
+    if not os.path.isabs(path) and not os.path.isfile(path):
+        path = os.path.join(_REPO, path)
+    return os.path.abspath(path)
+
+
+def read_config_file(path: str) -> Dict[str, Any]:
+    with open(path) as f:
+        return json.load(f)
+
+
 @functools.lru_cache(maxsize=None)
 def _read_sizes(path: str) -> Dict[str, Any]:
-    with open(path) as f:
-        spec = json.load(f)
+    spec = read_config_file(path)
     missing = sorted(set(SMALL) - set(spec))
     if missing:
         raise ValueError(f"{path} lacks {missing}")
-    if spec["num_key_value_heads"] != spec["num_attention_heads"]:
-        raise NotImplementedError(
-            "grouped key/value heads are not built: num_key_value_heads "
-            "must equal num_attention_heads")
     return {k: spec[k] for k in SMALL}
 
 
 def sizes(cfg: ModelConfig) -> Dict[str, Any]:
-    """The model's sizes: the file ``cfg.config_file`` names (looked for
-    from the working directory, then from the repository's root), else
-    :data:`SMALL`."""
+    """The model's sizes: the file ``cfg.config_file`` names
+    (:func:`config_path`), else :data:`SMALL`."""
     if not cfg.config_file:
         return SMALL
-    path = cfg.config_file
-    if not os.path.isabs(path) and not os.path.isfile(path):
-        path = os.path.join(_REPO, path)
-    return _read_sizes(os.path.abspath(path))
+    return _read_sizes(config_path(cfg.config_file))
 
 
 def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
@@ -111,6 +115,7 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
     sz = sizes(cfg)
     d, f, v = sz["hidden_size"], sz["intermediate_size"], sz["vocab_size"]
     a = sz["num_attention_heads"] * sz["head_dim"]
+    kv = sz["num_key_value_heads"] * sz["head_dim"]
     dtype = jnp.dtype(cfg.dtype)
     keys = iter(jax.random.split(key, 3 + 7 * sz["num_hidden_layers"]))
 
@@ -123,7 +128,7 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
 
     def layer():
         return {"attn_norm": scale(), "wq": matrix(d, d, a),
-                "wk": matrix(d, d, a), "wv": matrix(d, d, a),
+                "wk": matrix(d, d, kv), "wv": matrix(d, d, kv),
                 "wo": matrix(a, a, d), "attn_post_norm": scale(),
                 "mlp_norm": scale(), "gate": matrix(d, d, f),
                 "up": matrix(d, d, f), "down": matrix(f, f, d),
@@ -138,24 +143,15 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
 
 def _layer(h, p, sz, cfg: ModelConfig, mesh):
     """One decoder layer on ``h [B, S, D]`` (float32)."""
-    b, s, _ = h.shape
-    heads, dh = sz["num_attention_heads"], sz["head_dim"]
     eps, low = sz["rms_norm_eps"], jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("attn_norm"):
         a = rms_norm(h, p["attn_norm"]["scale"], eps)
     with jax.named_scope("attn"):
-        with jax.named_scope("qkv"):
-            q, k, v = (mixed_matmul(a, p[w], low).reshape(b, s, heads, dh)
-                       for w in ("wq", "wk", "wv"))
-        with jax.named_scope("rotary"):
-            q, k = (rotary(t, sz["rope_theta"]).astype(low) for t in (q, k))
-        with jax.named_scope("flash"):
-            o = attention_lib.dispatch_attention(
-                q, k, v.astype(low), use_pallas=cfg.use_pallas_attention,
-                causal=True, mesh=mesh)
-        with jax.named_scope("out"):
-            o = mixed_matmul(o.reshape(b, s, heads * dh).astype(jnp.float32),
-                             p["wo"], low)
+        o = attention_lib.causal_self_attention(
+            a, p, heads=sz["num_attention_heads"],
+            kv_heads=sz["num_key_value_heads"], head_dim=sz["head_dim"],
+            rope_theta=sz["rope_theta"], low=low,
+            use_pallas=cfg.use_pallas_attention, mesh=mesh)
     with jax.named_scope("attn_post_norm"):
         h = h + rms_norm(o, p["attn_post_norm"]["scale"], eps)
     with jax.named_scope("mlp_norm"):
@@ -167,7 +163,7 @@ def _layer(h, p, sz, cfg: ModelConfig, mesh):
         return h + rms_norm(f, p["mlp_post_norm"]["scale"], eps)
 
 
-def _loss_blocks(tokens: int) -> int:
+def token_blocks(tokens: int) -> int:
     """The least number of equal blocks of at most
     :data:`LOSS_BLOCK_TOKENS` tokens."""
     blocks = -(-tokens // LOSS_BLOCK_TOKENS)
@@ -192,7 +188,7 @@ def exit_terms(params, rows, cfg: ModelConfig, mesh=None, passes=None,
     passes = passes or sz["total_ut_steps"]
     inputs, targets = rows[:, :-1], rows[:, 1:].reshape(-1)
     n = targets.shape[0]
-    blocks = loss_blocks or _loss_blocks(n)
+    blocks = loss_blocks or token_blocks(n)
 
     def one_layer(h, p):
         return _layer(h, p, sz, cfg, mesh)
@@ -266,8 +262,10 @@ def step_flops(cfg: ModelConfig, data_cfg: DataConfig, batch: int) -> float:
     sz = sizes(cfg)
     s, d = data_cfg.sequence_length, sz["hidden_size"]
     a = sz["num_attention_heads"] * sz["head_dim"]
+    kv = sz["num_key_value_heads"] * sz["head_dim"]
     layers = sz["num_hidden_layers"]
-    per_token = layers * (4 * d * a + 3 * d * sz["intermediate_size"]) \
+    per_token = layers * (2 * d * (a + kv)
+                          + 3 * d * sz["intermediate_size"]) \
         + d * sz["vocab_size"]
     attention = layers * 2 * a * (s * (s + 1) // 2)
     return float(batch * 6 * sz["total_ut_steps"]

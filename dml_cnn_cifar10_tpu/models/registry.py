@@ -32,6 +32,8 @@ class ModelDef(NamedTuple):
     stack_probe: Callable | None = None
     # A model that is no image classifier states its own loss:
     # ``loss(params, batch, model_cfg, train, mesh=None) -> (loss, stats)``
+    # (with ``has_state``: ``..., model_state=state) -> (loss, stats,
+    # new_state)``)
     # with ``stats["accuracy"]`` in place of the argmax over logits that
     # nothing holds (parallel/step.py asks for it before ``apply``, which
     # such a model leaves None). Its batch is what ``batch_shape(model_cfg,
@@ -101,6 +103,13 @@ def _looped_decoder() -> ModelDef:
                     batch_ndim=2, step_flops=m.step_flops)
 
 
+def _hybrid_decoder() -> ModelDef:
+    from dml_cnn_cifar10_tpu.models import hybrid_decoder as m
+    return ModelDef(m.init_params, None, m.init_state, True,
+                    wants_mesh=True, loss=m.loss, batch_shape=m.batch_shape,
+                    batch_ndim=2, step_flops=m.step_flops)
+
+
 MODELS = {
     "cnn": _cnn,
     "resnet18": _resnet(18),
@@ -108,6 +117,7 @@ MODELS = {
     "vit_tiny": _vit,
     "vit_moe": _vit_moe,
     "looped_decoder": _looped_decoder,
+    "hybrid_decoder": _hybrid_decoder,
 }
 
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dml_cnn_cifar10_tpu.ops import kernel_paths
+from dml_cnn_cifar10_tpu.ops.layers import mixed_matmul, rms_norm, rotary
 from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 
@@ -150,3 +151,41 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         lambda q, k, v, seg: flash(q, k, v, segment_ids=seg),
         mesh=mesh, in_specs=(qkv, qkv, qkv, P(bax, None)),
         out_specs=qkv, check_vma=False)(q, k, v, segment_ids)
+
+
+def causal_self_attention(a: jax.Array, p, *, heads: int, kv_heads: int,
+                          head_dim: int, rope_theta: float, low,
+                          use_pallas: bool, mesh=None,
+                          norm_eps: float | None = None) -> jax.Array:
+    """The attention sublayer of a decoder over tokens, between its norm
+    and its residual add: ``a [B, S, D]`` (float32, normed) -> ``[B, S,
+    D]``. ``p`` holds ``wq [D, heads * head_dim]``, ``wk`` and ``wv`` ``[D,
+    kv_heads * head_dim]``, ``wo``, and, where the model norms each head's
+    query and key, ``q_norm`` / ``k_norm`` (one ``scale [head_dim]`` for
+    all heads). Query head ``j`` reads key/value head ``j // (heads /
+    kv_heads)``: the key/value heads are repeated before the kernel, and
+    the repeat's transpose sums each group's gradient. Products of
+    operands rounded to ``low``, summed in float32; norms and rotary
+    float32. One scope a step, under the caller's."""
+    b, s, _ = a.shape
+    with jax.named_scope("qkv"):
+        q = mixed_matmul(a, p["wq"], low).reshape(b, s, heads, head_dim)
+        k, v = (mixed_matmul(a, p[w], low).reshape(b, s, kv_heads, head_dim)
+                for w in ("wk", "wv"))
+    if "q_norm" in p:
+        with jax.named_scope("qk_norm"):
+            q = rms_norm(q, p["q_norm"]["scale"], norm_eps)
+            k = rms_norm(k, p["k_norm"]["scale"], norm_eps)
+    with jax.named_scope("rotary"):
+        q, k = (rotary(t, rope_theta).astype(low) for t in (q, k))
+    with jax.named_scope("flash"):
+        v = v.astype(low)
+        if kv_heads != heads:
+            k, v = (jnp.repeat(t, heads // kv_heads, axis=2)
+                    for t in (k, v))
+        o = dispatch_attention(q, k, v, use_pallas=use_pallas, causal=True,
+                               mesh=mesh)
+    with jax.named_scope("out"):
+        return mixed_matmul(
+            o.reshape(b, s, heads * head_dim).astype(jnp.float32), p["wo"],
+            low)

@@ -203,7 +203,7 @@ def pooled_hw(h: int, w: int, n_pools: int, window: int = 3,
     return h, w
 
 
-# --- decoder primitives (models/looped_decoder.py) ---------------------------
+# --- decoder primitives (models/looped_decoder.py, hybrid_decoder.py) --------
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the last dim, float32."""
@@ -262,3 +262,67 @@ def _mixed_matmul_bwd(dtype, res, g):
 
 
 mixed_matmul.defvjp(_mixed_matmul_fwd, _mixed_matmul_bwd)
+
+
+def gated_short_conv(bcx: jax.Array, w: jax.Array) -> jax.Array:
+    """The gated short convolution between its two projections. ``bcx
+    [..., S, 3 C]`` holds the gates ``B`` and ``C`` and the values ``X``
+    (that order); ``w [C, L]`` is a depthwise causal filter of ``L`` taps:
+    ``c[t] = sum_j w[:, j] * (B * X)[t - (L - 1) + j]``, zeros before the
+    sequence's start; the result is ``C * c``. float32."""
+    b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    u = b * x
+    taps, s = w.shape[-1], u.shape[-2]
+    lead = [(0, 0)] * (u.ndim - 2)
+    padded = jnp.pad(u, lead + [(taps - 1, 0), (0, 0)])
+    conv = sum(lax.slice_in_dim(padded, j, j + s, axis=-2) * w[:, j]
+               for j in range(taps))
+    return c * conv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                   dtype) -> jax.Array:
+    """:func:`mixed_matmul` with a matrix a group of rows: the first
+    ``group_sizes[0]`` rows of ``x [M, K]`` meet ``w[0]`` of ``w [G, K,
+    N]``, the next ``group_sizes[1]`` rows ``w[1]``, and so on; rows past
+    the groups' sum belong to no group, cost no product, come back zero
+    and take a zero gradient. Operands rounded to ``dtype``, sums and
+    result float32, forward and in both backward products
+    (``lax.ragged_dot``, which the TPU's compiler makes one kernel each;
+    that kernel leaves the rows past the groups unwritten, so they are
+    cleared here, forward and in the gradient of ``x``)."""
+    return _grouped_matmul_fwd(x, w, group_sizes, dtype)[0]
+
+
+def _clear_past_groups(y, group_sizes):
+    live = jnp.arange(y.shape[0]) < jnp.sum(group_sizes)
+    return jnp.where(live[:, None], y, 0.0)
+
+
+def _grouped_matmul_fwd(x, w, group_sizes, dtype):
+    x_low, w_low = x.astype(dtype), w.astype(dtype)
+    y = lax.ragged_dot(x_low, w_low, group_sizes,
+                       preferred_element_type=jnp.float32)
+    return _clear_past_groups(y, group_sizes), (x_low, w, group_sizes)
+
+
+_RAGGED_CONTRACTION = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_matmul_bwd(dtype, res, g):
+    x_low, w, group_sizes = res
+    g_low = g.astype(dtype)
+    dx = _clear_past_groups(
+        lax.ragged_dot(g_low, jnp.swapaxes(w.astype(dtype), 1, 2),
+                       group_sizes, preferred_element_type=jnp.float32),
+        group_sizes)
+    dw = lax.ragged_dot_general(x_low, g_low, group_sizes,
+                                _RAGGED_CONTRACTION,
+                                preferred_element_type=jnp.float32)
+    return dx, dw.astype(w.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

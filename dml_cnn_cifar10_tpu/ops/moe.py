@@ -24,11 +24,15 @@ with the residual connection around the layer they pass through unchanged.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+from dml_cnn_cifar10_tpu.ops.layers import grouped_matmul, rms_norm
 
 Params = Dict[str, Any]
 
@@ -189,3 +193,211 @@ def moe_mlp(x: jax.Array, params: Params, capacity_factor: float,
         "expert_load": jax.lax.stop_gradient(f),
     }
     return y.reshape(b, s, d), stats
+
+
+# --- one chip's share of an expert layer that drops nothing ------------------
+
+def route_top_k(x: jax.Array, router: jax.Array, bias, top_k: int,
+                norm_topk: bool = True, scaling: float = 1.0
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid routing over ALL of the layer's experts: ``x [T, D]``,
+    ``router [D, E_all]`` -> ``(chosen [T, k] int32, weights [T, k])``.
+    ``s = sigmoid(x router)``; a token takes the ``k`` experts with the
+    largest ``s + bias`` (``bias [E_all]`` or None: it decides the choice
+    and nothing else, and no gradient reaches it); its weights are the
+    chosen experts' ``s``, over their sum plus 1e-6 where ``norm_topk``,
+    times ``scaling``. All float32, the product at the highest precision:
+    the choice is a function of these numbers alone, so a recomputation
+    chooses as the forward pass did."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    ranked = s if bias is None else s + bias.astype(jnp.float32)
+    _, chosen = lax.top_k(lax.stop_gradient(ranked), top_k)
+    weights = jnp.take_along_axis(s, chosen, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+    return chosen, weights * scaling
+
+
+def _gated_rows(rows, w1, w3, w2, sizes, dtype):
+    """The experts' gated SiLU MLP on ``rows [R, D]`` in the order of their
+    experts, ``sizes [E]`` of them to each expert from the front."""
+    hidden = jax.nn.silu(grouped_matmul(rows, w1, sizes, dtype)) \
+        * grouped_matmul(rows, w3, sizes, dtype)
+    return grouped_matmul(hidden, w2, sizes, dtype)
+
+
+def _block(blocks_in, j):
+    return tuple(lax.dynamic_index_in_dim(a, j, 0, keepdims=False)
+                 for a in blocks_in)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _expert_blocks(static, m, w1, w3, w2, here, blocks_in):
+    """What the rows routed here add to their tokens: ``m [T, D]`` (in the
+    products' dtype) -> ``[T, D]`` float32. ``blocks_in`` holds, a block of
+    rows a leading index, each row's slot (token * top_k + choice; the
+    rows in the order of their experts), how many rows each expert takes
+    from the block's front, and each row's weight (0 past the ``here``
+    rows). The blocks that hold a row are visited one after the other (a
+    loop of as many rounds as ``here`` needs: an empty block costs
+    nothing), each gathering its rows, forming the three products and
+    adding the weighted results to their tokens in place. The backward
+    pass is written out as a second such loop that forms a block again and
+    takes its gradient, so that one block's rows and hidden activations
+    are held at a time in both passes, whatever the worst case is."""
+    top_k, dtype = static
+    slot_of, sizes_of, weight_of = blocks_in
+    rows = slot_of.shape[1]
+
+    def add_block(j, y):
+        slot, sizes, weight = _block(blocks_in, j)
+        with jax.named_scope("dispatch"):
+            token = slot // top_k
+            taken = m[token]
+        with jax.named_scope("experts"):
+            out = _gated_rows(taken, w1, w3, w2, sizes, dtype)
+        with jax.named_scope("combine"):
+            # (rows past the groups' sum belong to no expert: zero, weight 0)
+            return y.at[token].add(out * weight[:, None])
+
+    return lax.fori_loop(0, (here + rows - 1) // rows, add_block,
+                         jnp.zeros(m.shape, jnp.float32))
+
+
+def _expert_blocks_fwd(static, m, w1, w3, w2, here, blocks_in):
+    return _expert_blocks(static, m, w1, w3, w2, here, blocks_in), \
+        (m, w1, w3, w2, here, blocks_in)
+
+
+def _expert_blocks_bwd(static, res, g):
+    top_k, dtype = static
+    m, w1, w3, w2, here, blocks_in = res
+    rows = blocks_in[0].shape[1]
+
+    def add_block(j, sums):
+        dm, dws, dweight_of = sums
+        slot, sizes, weight = _block(blocks_in, j)
+        with jax.named_scope("dispatch"):
+            token = slot // top_k
+            taken = m[token]
+        with jax.named_scope("combine"):
+            g_rows = g[token]
+        with jax.named_scope("experts"):
+            out, vjp = jax.vjp(
+                lambda taken, w1, w3, w2: _gated_rows(taken, w1, w3, w2,
+                                                      sizes, dtype),
+                taken, w1, w3, w2)
+            dtaken, *dw = vjp(g_rows * weight[:, None])
+            dws = jax.tree.map(jnp.add, dws, tuple(dw))
+        with jax.named_scope("combine"):
+            dweight_of = lax.dynamic_update_index_in_dim(
+                dweight_of, jnp.sum(out * g_rows, -1), j, 0)
+        with jax.named_scope("dispatch"):
+            dm = dm.at[token].add(dtaken.astype(jnp.float32))
+        return dm, dws, dweight_of
+
+    dm, dws, dweight_of = lax.fori_loop(
+        0, (here + rows - 1) // rows, add_block,
+        (jnp.zeros(m.shape, jnp.float32),
+         tuple(jnp.zeros_like(w) for w in (w1, w3, w2)),
+         jnp.zeros_like(blocks_in[2])))
+    return (dm.astype(m.dtype), *dws, None, (None, None, dweight_of))
+
+
+_expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
+
+
+def balanced_bias(bias: jax.Array, load: jax.Array, rate: float
+                  ) -> jax.Array:
+    """The experts' bias after one step of balancing without a loss term:
+    up by ``rate`` for an expert that got fewer slots than the mean over
+    all of the router's experts (``load [E_all]``), down by ``rate`` for
+    one that got more. It moves the choice of experts toward an even load
+    and touches nothing else (:func:`route_top_k`)."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load)
+
+
+def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
+                   top_k: int, dtype, bias=None, norm_topk: bool = True,
+                   scaling: float = 1.0, block_rows: int | None = None,
+                   norm_scale=None, norm_eps: float = 1e-5
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """What the experts held here add to every token: ``x [T, D]``
+    (float32) -> ``([T, D] float32, stats)``.
+
+    ``params``: ``router [D, E_all]`` over all of the layer's experts and
+    the ``E`` experts with the contiguous ids ``first_expert ..
+    first_expert + E - 1``, expert-major: ``w1``, ``w3`` ``[E, D, H]``,
+    ``w2`` ``[E, H, D]``, each a gated SiLU MLP ``(silu(m w1) * (m w3))
+    w2``; ``bias [E_all]`` (a buffer, not a parameter) or None. With
+    ``norm_scale`` the router and the experts read ``m = rms(x;
+    norm_scale)`` (the layer's norm, formed again in the backward pass, so
+    that ``x`` alone is kept), else ``x``. Every token is routed over all
+    ``E_all`` experts (:func:`route_top_k`); of its ``top_k`` slots those
+    whose expert lives here are kept, put in the order of their experts,
+    and the three products run over exactly those rows
+    (``ops.layers.grouped_matmul``); each row's result, times its weight,
+    is added back to its token. A token none of whose experts is here
+    gets zero. **No token is dropped and there is no capacity**: the rows
+    are taken ``block_rows`` at a time (all ``T * top_k`` where None), one
+    block after the other, as many as hold a row when the program runs:
+    the worst case costs no memory beyond a block's, and neither an empty
+    block nor the padding of the last one costs a product. On one chip
+    nothing is exchanged; what the absent experts would add is left out.
+
+    ``stats``: ``rows_here_frac``, the slots on experts held here over ``T
+    * top_k``; ``load_max_over_mean``, the fullest held expert's rows
+    over the mean's; and ``expert_load [E_all]``, the slots each of the
+    router's experts got, held here or not (:func:`balanced_bias`)."""
+    t, _ = x.shape
+    e = params["w1"].shape[0]
+    slots = t * top_k
+    rows = min(block_rows or slots, slots)
+    blocks = -(-slots // rows)
+
+    @jax.checkpoint
+    def normed(x, scale):
+        with jax.named_scope("ffn_norm"):
+            return x if scale is None else rms_norm(x, scale, norm_eps)
+
+    @jax.checkpoint
+    def route(m, router, bias):
+        with jax.named_scope("route"):
+            return route_top_k(m, router, bias, top_k, norm_topk, scaling)
+
+    m = normed(x, norm_scale)
+    chosen, weights = route(m, params["router"], bias)
+    with jax.named_scope("dispatch"):
+        local = chosen.reshape(slots) - first_expert
+        local = jnp.where((local >= 0) & (local < e), local, e)
+        # slots in the order of their experts, those of absent experts last
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        counts = jnp.sum(local[:, None] == jnp.arange(e)[None, :], axis=0,
+                         dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        here = ends[-1]
+        lows = jnp.arange(blocks, dtype=jnp.int32) * rows
+        slot_of = jnp.pad(order, (0, blocks * rows - slots)).reshape(
+            blocks, rows)
+        # of each expert's rows, those that fall into each block
+        sizes_of = jnp.clip(ends[None, :], lows[:, None],
+                            lows[:, None] + rows) \
+            - jnp.clip((ends - counts)[None, :], lows[:, None],
+                       lows[:, None] + rows)
+        weight_of = jnp.where(
+            lows[:, None] + jnp.arange(rows)[None, :] < here,
+            weights.reshape(slots)[slot_of], 0.0)
+    y = _expert_blocks((top_k, jnp.dtype(dtype)), m.astype(dtype),
+                       params["w1"], params["w3"], params["w2"], here,
+                       (slot_of, sizes_of, weight_of))
+    mean = jnp.maximum(here, 1).astype(jnp.float32) / e
+    e_all = params["router"].shape[1]
+    stats = {"rows_here_frac": here.astype(jnp.float32) / slots,
+             "load_max_over_mean": jnp.max(counts).astype(jnp.float32) / mean,
+             "expert_load": jnp.sum(
+                 chosen.reshape(slots)[:, None] == jnp.arange(e_all)[None, :],
+                 axis=0, dtype=jnp.int32)}
+    return y, jax.tree.map(lax.stop_gradient, stats)

@@ -181,8 +181,13 @@ def _forward_loss(model_def: ModelDef, model_cfg: ModelConfig,
     if model_def.loss is not None:
         def own_loss_fn(params, model_state, batch, labels):
             del labels
-            loss, stats = model_def.loss(params, batch, model_cfg,
-                                         train=True, **mesh_kwargs)
+            if model_def.has_state:
+                loss, stats, model_state = model_def.loss(
+                    params, batch, model_cfg, train=True,
+                    model_state=model_state, **mesh_kwargs)
+            else:
+                loss, stats = model_def.loss(params, batch, model_cfg,
+                                             train=True, **mesh_kwargs)
             return loss, (None, model_state, stats)
 
         return own_loss_fn
@@ -903,8 +908,12 @@ def _eval_accuracy_fn(model_def: ModelDef, model_cfg: ModelConfig, mesh):
     def accuracy(state: TrainState, batch, labels):
         del labels
         params = state.opt.get("ema", state.params)
+        kwargs = dict(mesh_kwargs)
+        if model_def.has_state:
+            kwargs["model_state"] = state.opt.get("ema_mstate",
+                                                  state.model_state)
         return model_def.loss(params, batch, model_cfg, train=False,
-                              **mesh_kwargs)[1]["accuracy"]
+                              **kwargs)[1]["accuracy"]
 
     return accuracy
 

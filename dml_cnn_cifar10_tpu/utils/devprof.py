@@ -364,9 +364,17 @@ LAYER_KINDS = (
     ("attention", re.compile(r"^attn$")),
     ("mlp", re.compile(r"^mlp$")),
     ("norm", re.compile(r"^(attn_norm|attn_post_norm|mlp_norm|"
-                        r"mlp_post_norm|norm)$")),
+                        r"mlp_post_norm|norm|op_norm|ffn_norm|"
+                        r"final_norm)$")),
     ("embed", re.compile(r"^embed$")),
     ("exit_head", re.compile(r"^(exit|head|gate)$")),
+    # a decoder whose layers differ by a list (models/hybrid_decoder.py):
+    # the gated short convolution with its two projections (not `conv`:
+    # that is the image models'), the router with the ordering and
+    # gathering of rows and the adding back, the experts' grouped products
+    ("short_conv", re.compile(r"^short_conv$")),
+    ("route", re.compile(r"^(route|dispatch|combine)$")),
+    ("expert", re.compile(r"^experts$")),
 )
 PASSES = ("forward", "backward", "update", "other")
 
@@ -550,7 +558,10 @@ def scope_map_of_text(text: str):
 
     def inherit(comp: str) -> None:
         """An unnamed copy takes the layer of what consumes it, through
-        other unnamed movers, a few hops down the same computation."""
+        other unnamed movers, a few hops down the same computation. So
+        does a kernel the compiler made of an operation and named anew
+        (a grouped product becomes the custom call ``ragged-dot-none``,
+        whose metadata holds that name and no scope)."""
         users: dict = {}
         opcode = {}
         for ins in comps.get(comp, ()):
@@ -558,7 +569,8 @@ def scope_map_of_text(text: str):
             for ref in ins.refs:
                 users.setdefault(ref, []).append(ins.name)
         for ins in comps.get(comp, ()):
-            if ins.opcode not in _COPIES or out[ins.name].kind != "none":
+            if ins.opcode not in _COPIES | {"custom-call"} \
+                    or out[ins.name].kind != "none":
                 continue
             front, seen = [ins.name], {ins.name}
             for _ in range(4):

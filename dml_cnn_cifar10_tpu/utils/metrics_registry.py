@@ -359,6 +359,15 @@ def _observe_record(kind: str, f: dict, reg: MetricsRegistry) -> None:
                   ).set(f.get("drain_wait_ms"))
         reg.counter("dml_train_boundaries_total",
                     "Metrics boundaries flushed").inc()
+        # one chip's share of an expert layer (ops/moe.routed_experts)
+        for key, help_text in (
+                ("moe_rows_here_frac",
+                 "Expert slots routed to experts held here, over tokens x "
+                 "experts a token, mean over the expert layers"),
+                ("moe_load_max_over_mean",
+                 "Rows of the fullest expert held here over the mean's")):
+            if f.get(key) is not None:
+                reg.gauge("dml_" + key, help_text).set(f[key])
     elif kind == "goodput":
         g = reg.gauge("dml_goodput_fraction",
                       "Cumulative goodput fraction by category",
