@@ -42,7 +42,9 @@ float32.
 Memory: the sublayers that treat each sequence alone (both operators, the
 dense MLP) take :data:`OP_CHUNK_TOKENS` tokens at a time, one group of
 sequences after the other; the experts take all of a step's tokens, their
-rows a block at a time. With ``remat`` the backward pass recomputes an
+rows a block at a time, and hold the blocks' float32 results (an even
+load's worth, :func:`expert_block_rows`) until each token has summed its
+own. With ``remat`` the backward pass recomputes an
 operator but its flash kernel (:data:`KEPT`), a group's dense MLP, and a
 block of the experts' rows; what is kept is each sublayer's input.
 """
@@ -91,7 +93,8 @@ MLP_CHUNK_TOKENS = 4096
 #: (what the experts' bias steers toward) with a sixteenth of slack, in as
 #: many blocks of about this many rows as that takes, each a multiple of
 #: :data:`ROW_TILE`. Further blocks, up to the worst case, are visited
-#: only when routing is that skewed.
+#: only when routing is that skewed, and fill the buffer again (a round
+#: more of ``ops.moe.routed_experts``).
 EXPERT_BLOCK_ROWS = 8192
 ROW_TILE = 512
 
@@ -260,7 +263,7 @@ def _dense_ffn(h, p, sz, cfg: ModelConfig):
             * mixed_matmul(m, p["mlp"]["w3"], low), p["mlp"]["w2"], low)
 
 
-def _expert_ffn(h, p, bias, sz, cfg: ModelConfig):
+def _expert_ffn(h, p, bias, sz, cfg: ModelConfig, mesh):
     """``h + experts(rms(h))`` on all of a step's tokens, and the layer's
     counters."""
     b, s, d = h.shape
@@ -277,7 +280,8 @@ def _expert_ffn(h, p, bias, sz, cfg: ModelConfig):
             scaling=sz["routed_scaling_factor"],
             block_rows=expert_block_rows(
                 slots, slots * sz["num_experts"] / e_all),
-            norm_scale=p["ffn_norm"]["scale"], norm_eps=sz["norm_eps"])
+            norm_scale=p["ffn_norm"]["scale"], norm_eps=sz["norm_eps"],
+            mesh=mesh)
     return h + f.reshape(b, s, d), stats
 
 
@@ -286,8 +290,9 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
     """The model's own loss over a batch of token rows ``[B, S+1]`` ->
     ``(mean next-token cross-entropy, stats, new model state)``. ``stats``:
     ``accuracy``, the share of next tokens whose logit is the largest,
-    and, the mean over the experts' layers, ``moe_rows_here_frac`` and
-    ``moe_load_max_over_mean`` (``ops.moe.routed_experts``). The state is
+    and, the mean over the experts' layers, ``moe_rows_here_frac``,
+    ``moe_load_max_over_mean`` and ``moe_buffer_rounds``
+    (``ops.moe.routed_experts``). The state is
     :func:`init_state`'s (None: as at the start); in training each
     experts' layer's bias comes back moved one step toward an even load.
     ``loss_blocks`` overrides the number of blocks the loss is taken in."""
@@ -321,7 +326,7 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
             else:
                 bias = state["expert_bias"] if sz["use_expert_bias"] \
                     else None
-                h, stats = _expert_ffn(h, p, bias, sz, cfg)
+                h, stats = _expert_ffn(h, p, bias, sz, cfg, mesh)
                 if bias is not None:
                     state = {"expert_bias": moe_lib.balanced_bias(
                         bias, stats.pop("expert_load"), rate)}
@@ -336,7 +341,7 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
     with jax.named_scope("loss"):
         value = jnp.mean(ce)
     stats = {"accuracy": lax.stop_gradient(jnp.mean(hit))}
-    for name in ("rows_here_frac", "load_max_over_mean"):
+    for name in ("rows_here_frac", "load_max_over_mean", "buffer_rounds"):
         if moe_stats:
             stats["moe_" + name] = sum(s[name] for s in moe_stats) \
                 / len(moe_stats)

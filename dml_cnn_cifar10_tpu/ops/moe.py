@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dml_cnn_cifar10_tpu.ops import sum_rows
 from dml_cnn_cifar10_tpu.ops.layers import grouped_matmul, rms_norm
 
 Params = Dict[str, Any]
@@ -214,10 +215,28 @@ def route_top_k(x: jax.Array, router: jax.Array, bias, top_k: int,
                                precision=lax.Precision.HIGHEST))
     ranked = s if bias is None else s + bias.astype(jnp.float32)
     _, chosen = lax.top_k(lax.stop_gradient(ranked), top_k)
-    weights = jnp.take_along_axis(s, chosen, axis=-1)
+    # the chosen experts' scores, picked out by comparison: a gather of
+    # single numbers, and the scatter-add that is its gradient, cost the
+    # TPU 7 ns a number (PERF.md, Findings, PR 33)
+    picked = chosen[..., None] == jnp.arange(s.shape[-1])
+    weights = jnp.sum(jnp.where(picked, s[:, None, :], 0.0), -1)
     if norm_topk:
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
     return chosen, weights * scaling
+
+
+@jax.custom_vjp
+def _moved(values, to, back):
+    """``values [N]`` with ``values[i]`` moved to place ``to[i]``: ``out[j]
+    = values[back[j]]``, ``to`` and ``back`` a permutation and its inverse.
+    By a sort on ``to``, forward, and on ``back`` for the gradient: a
+    gather or a scatter-add of single numbers costs the TPU several times
+    a sort of as many."""
+    return lax.sort((to, values), num_keys=1)[1]
+
+
+_moved.defvjp(lambda values, to, back: (_moved(values, to, back), (to, back)),
+              lambda res, g: (_moved(g, res[1], res[0]), None, None))
 
 
 def _gated_rows(rows, w1, w3, w2, sizes, dtype):
@@ -233,51 +252,110 @@ def _block(blocks_in, j):
                  for a in blocks_in)
 
 
+def buffer_rounds(here, held: int, blocks: int, rows: int):
+    """Times a buffer of ``held`` of the ``blocks`` blocks of ``rows`` rows
+    is filled to take ``here`` rows in, at least once; the integer 1 where
+    it holds them all."""
+    if held >= blocks:
+        return 1
+    return jnp.maximum(1, (here + held * rows - 1) // (held * rows))
+
+
+def _in_rounds(rounds, one_round, carry):
+    """``one_round(r, carry) -> (what round r adds to each token, carry)``
+    for ``r = 0 .. rounds - 1`` -> ``(the rounds' sum, carry)``. The first
+    round runs whatever ``rounds`` says and its sum is taken as it comes:
+    the one round of an even load adds nothing to anything."""
+    total, carry = one_round(0, carry)
+    if isinstance(rounds, int):     # the buffer holds every block: 1
+        return total, carry
+
+    def another(r, state):
+        total, carry = state
+        more, carry = one_round(r, carry)
+        return total + more, carry
+
+    return lax.fori_loop(1, rounds, another, (total, carry))
+
+
+def _round_of_blocks(static, rows, here, r, store_block, carry):
+    """Round ``r`` of the buffer: ``store_block(j, at, (buffer, *carry))``
+    for each block ``j`` of the round that holds a row, ``at`` the block's
+    first row in the buffer -> ``(buffer, *carry)``, the rows' range ``lo,
+    hi`` in the experts' order."""
+    _, _, held, row = static
+    room = held * rows
+    # never cleared: a row is stored before the range that holds it is read
+    buffer = lax.empty((room, *row), jnp.float32)
+    state = lax.fori_loop(
+        r * held, jnp.minimum((here + rows - 1) // rows, (r + 1) * held),
+        lambda j, state: store_block(j, (j - r * held) * rows, state),
+        (buffer, *carry))
+    return state, r * room, jnp.minimum(here, (r + 1) * room)
+
+
+def _store(buffer, block, at):
+    """``block [rows, D]`` into the buffer's rows from ``at``, in the shape
+    the buffer holds a row in."""
+    return lax.dynamic_update_slice_in_dim(
+        buffer, block.reshape(block.shape[0], *buffer.shape[1:]), at, 0)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _expert_blocks(static, m, w1, w3, w2, here, blocks_in):
+def _expert_blocks(static, m, w1, w3, w2, here, blocks_in, pos):
     """What the rows routed here add to their tokens: ``m [T, D]`` (in the
     products' dtype) -> ``[T, D]`` float32. ``blocks_in`` holds, a block of
     rows a leading index, each row's slot (token * top_k + choice; the
     rows in the order of their experts), how many rows each expert takes
     from the block's front, and each row's weight (0 past the ``here``
-    rows). The blocks that hold a row are visited one after the other (a
-    loop of as many rounds as ``here`` needs: an empty block costs
-    nothing), each gathering its rows, forming the three products and
-    adding the weighted results to their tokens in place. The backward
-    pass is written out as a second such loop that forms a block again and
-    takes its gradient, so that one block's rows and hidden activations
-    are held at a time in both passes, whatever the worst case is."""
-    top_k, dtype = static
-    slot_of, sizes_of, weight_of = blocks_in
-    rows = slot_of.shape[1]
+    rows); ``pos [T, top_k]`` each slot's row in that order. The blocks
+    that hold a row are visited one after the other (a loop of as many
+    rounds as ``here`` needs: an empty block costs nothing), each gathering
+    its rows, forming the three products and storing the weighted results
+    at its place in a buffer of ``held`` blocks; when the buffer is full
+    or the rows are at an end each token fetches and sums the rows it has
+    there (``ops.sum_rows``), and a load that exceeds the buffer fills it
+    again. The backward pass is written out as a second such loop that
+    forms a block again, takes its gradient and sends the rows' gradient
+    to their tokens the same way, so that one block's rows and hidden
+    activations are held at a time in both passes, whatever the worst case
+    is. ``static``: ``(top_k, dtype, held, the shape of a buffer's row)``."""
+    top_k, dtype, held, _ = static
+    rows = blocks_in[0].shape[1]
 
-    def add_block(j, y):
+    def store_block(j, at, state):
+        buffer, = state
         slot, sizes, weight = _block(blocks_in, j)
         with jax.named_scope("dispatch"):
-            token = slot // top_k
-            taken = m[token]
+            taken = m[slot // top_k]
         with jax.named_scope("experts"):
             out = _gated_rows(taken, w1, w3, w2, sizes, dtype)
         with jax.named_scope("combine"):
             # (rows past the groups' sum belong to no expert: zero, weight 0)
-            return y.at[token].add(out * weight[:, None])
+            return _store(buffer, out * weight[:, None], at),
 
-    return lax.fori_loop(0, (here + rows - 1) // rows, add_block,
-                         jnp.zeros(m.shape, jnp.float32))
+    def one_round(r, carry):
+        (buffer,), lo, hi = _round_of_blocks(static, rows, here, r,
+                                             store_block, carry)
+        with jax.named_scope("combine"):
+            return sum_rows.sum_rows_by_token(buffer, pos, lo, hi), carry
+
+    return _in_rounds(buffer_rounds(here, held, *blocks_in[0].shape),
+                      one_round, ())[0]
 
 
-def _expert_blocks_fwd(static, m, w1, w3, w2, here, blocks_in):
-    return _expert_blocks(static, m, w1, w3, w2, here, blocks_in), \
-        (m, w1, w3, w2, here, blocks_in)
+def _expert_blocks_fwd(static, m, w1, w3, w2, here, blocks_in, pos):
+    return _expert_blocks(static, m, w1, w3, w2, here, blocks_in, pos), \
+        (m, w1, w3, w2, here, blocks_in, pos)
 
 
 def _expert_blocks_bwd(static, res, g):
-    top_k, dtype = static
-    m, w1, w3, w2, here, blocks_in = res
+    top_k, dtype, held, _ = static
+    m, w1, w3, w2, here, blocks_in, pos = res
     rows = blocks_in[0].shape[1]
 
-    def add_block(j, sums):
-        dm, dws, dweight_of = sums
+    def store_block(j, at, state):
+        buffer, dws, dweight_of = state
         slot, sizes, weight = _block(blocks_in, j)
         with jax.named_scope("dispatch"):
             token = slot // top_k
@@ -295,15 +373,20 @@ def _expert_blocks_bwd(static, res, g):
             dweight_of = lax.dynamic_update_index_in_dim(
                 dweight_of, jnp.sum(out * g_rows, -1), j, 0)
         with jax.named_scope("dispatch"):
-            dm = dm.at[token].add(dtaken.astype(jnp.float32))
-        return dm, dws, dweight_of
+            buffer = _store(buffer, dtaken.astype(jnp.float32), at)
+        return buffer, dws, dweight_of
 
-    dm, dws, dweight_of = lax.fori_loop(
-        0, (here + rows - 1) // rows, add_block,
-        (jnp.zeros(m.shape, jnp.float32),
-         tuple(jnp.zeros_like(w) for w in (w1, w3, w2)),
+    def one_round(r, carry):
+        (buffer, *carry), lo, hi = _round_of_blocks(static, rows, here, r,
+                                                    store_block, carry)
+        with jax.named_scope("dispatch"):
+            return sum_rows.sum_rows_by_token(buffer, pos, lo, hi), tuple(carry)
+
+    dm, (dws, dweight_of) = _in_rounds(
+        buffer_rounds(here, held, *blocks_in[0].shape), one_round,
+        (tuple(jnp.zeros_like(w) for w in (w1, w3, w2)),
          jnp.zeros_like(blocks_in[2])))
-    return (dm.astype(m.dtype), *dws, None, (None, None, dweight_of))
+    return (dm.astype(m.dtype), *dws, None, (None, None, dweight_of), None)
 
 
 _expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
@@ -323,7 +406,7 @@ def balanced_bias(bias: jax.Array, load: jax.Array, rate: float
 def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
                    top_k: int, dtype, bias=None, norm_topk: bool = True,
                    scaling: float = 1.0, block_rows: int | None = None,
-                   norm_scale=None, norm_eps: float = 1e-5
+                   norm_scale=None, norm_eps: float = 1e-5, mesh=None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """What the experts held here add to every token: ``x [T, D]``
     (float32) -> ``([T, D] float32, stats)``.
@@ -345,18 +428,33 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
     are taken ``block_rows`` at a time (all ``T * top_k`` where None), one
     block after the other, as many as hold a row when the program runs:
     the worst case costs no memory beyond a block's, and neither an empty
-    block nor the padding of the last one costs a product. On one chip
-    nothing is exchanged; what the absent experts would add is left out.
+    block nor the padding of the last one costs a product. The blocks'
+    results are not scattered to their tokens: they are stored, in the
+    experts' order, in a float32 buffer of as many blocks as the load
+    takes when every expert of the router gets the same (``T * top_k * E /
+    E_all`` rows), and each token then fetches the rows it has there and
+    sums them in the order of its choices (``ops.sum_rows``; ``mesh``, the
+    mesh of the enclosing GSPMD program if any, is read only to decide
+    between that op's kernel and its XLA expression). **A round** is one
+    filling of that buffer with the sum by token that empties it. A load
+    within the buffer takes one; a load that exceeds it, however skewed
+    the routing, takes another round for each further bufferful: a pass
+    more over the tokens, and no more memory. On one chip nothing is
+    exchanged; what the absent experts would add is left out.
 
     ``stats``: ``rows_here_frac``, the slots on experts held here over ``T
     * top_k``; ``load_max_over_mean``, the fullest held expert's rows
-    over the mean's; and ``expert_load [E_all]``, the slots each of the
-    router's experts got, held here or not (:func:`balanced_bias`)."""
-    t, _ = x.shape
-    e = params["w1"].shape[0]
+    over the mean's; ``buffer_rounds``, the rounds the layer took (1 when
+    the load is within the buffer); and ``expert_load [E_all]``, the slots
+    each of the router's experts got, held here or not
+    (:func:`balanced_bias`)."""
+    t, d = x.shape
+    e, e_all = params["w1"].shape[0], params["router"].shape[1]
     slots = t * top_k
     rows = min(block_rows or slots, slots)
     blocks = -(-slots // rows)
+    # blocks of the buffer: the load when every expert gets the same
+    held = max(1, min(blocks, -(-(slots * e) // (e_all * rows))))
 
     @jax.checkpoint
     def normed(x, scale):
@@ -387,16 +485,23 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
                             lows[:, None] + rows) \
             - jnp.clip((ends - counts)[None, :], lows[:, None],
                        lows[:, None] + rows)
+        # each slot's row in the experts' order: the inverse of `order`
+        pos = jnp.argsort(order).astype(jnp.int32)
         weight_of = jnp.where(
             lows[:, None] + jnp.arange(rows)[None, :] < here,
-            weights.reshape(slots)[slot_of], 0.0)
-    y = _expert_blocks((top_k, jnp.dtype(dtype)), m.astype(dtype),
-                       params["w1"], params["w3"], params["w2"], here,
-                       (slot_of, sizes_of, weight_of))
+            jnp.pad(_moved(weights.reshape(slots), pos, order),
+                    (0, blocks * rows - slots)).reshape(blocks, rows), 0.0)
+        pos = pos.reshape(t, top_k)
+    y = _expert_blocks(
+        (top_k, jnp.dtype(dtype), held,
+         sum_rows.row_shape(t, top_k, d, mesh)),
+        m.astype(dtype), params["w1"], params["w3"], params["w2"], here,
+        (slot_of, sizes_of, weight_of), pos)
     mean = jnp.maximum(here, 1).astype(jnp.float32) / e
-    e_all = params["router"].shape[1]
     stats = {"rows_here_frac": here.astype(jnp.float32) / slots,
              "load_max_over_mean": jnp.max(counts).astype(jnp.float32) / mean,
+             "buffer_rounds": jnp.float32(
+                 buffer_rounds(here, held, blocks, rows)),
              "expert_load": jnp.sum(
                  chosen.reshape(slots)[:, None] == jnp.arange(e_all)[None, :],
                  axis=0, dtype=jnp.int32)}

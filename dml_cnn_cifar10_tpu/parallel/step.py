@@ -493,7 +493,8 @@ def _pallas_veto(state_sharding: Optional[TrainState]):
 
 def _announced(fn, phase: str, mesh: Optional[Mesh]):
     """``fn``, saying once which update, attention and pool path it
-    compiled.
+    compiled (and, where the model has them, what a recomputed layer keeps
+    and how the experts' rows reach their tokens).
 
     The choice is made where the shapes are known, inside the trace
     (``ops/kernel_paths.py``), so the line prints when the step is
@@ -510,7 +511,8 @@ def _announced(fn, phase: str, mesh: Optional[Mesh]):
                 f" device(s): update={rec.get('update', 'none')} "
                 f"attention={rec.get('attention', 'none')} "
                 f"pool={rec.get('pool', 'none')}"
-                + (f" remat={rec['remat']}" if "remat" in rec else ""))
+                + "".join(f" {kind}={rec[kind]}"
+                          for kind in ("remat", "experts") if kind in rec))
         if line not in said:
             said.add(line)
             print(line, flush=True)

@@ -365,7 +365,11 @@ def _observe_record(kind: str, f: dict, reg: MetricsRegistry) -> None:
                  "Expert slots routed to experts held here, over tokens x "
                  "experts a token, mean over the expert layers"),
                 ("moe_load_max_over_mean",
-                 "Rows of the fullest expert held here over the mean's")):
+                 "Rows of the fullest expert held here over the mean's"),
+                ("moe_buffer_rounds",
+                 "Times an expert layer filled its buffer of rows and "
+                 "summed it by token, mean over the expert layers (1: "
+                 "the load was within the buffer)")):
             if f.get(key) is not None:
                 reg.gauge("dml_" + key, help_text).set(f[key])
     elif kind == "goodput":

@@ -21,6 +21,7 @@ from dml_cnn_cifar10_tpu.models.registry import get_model
 from dml_cnn_cifar10_tpu.ops import attention as attention_lib
 from dml_cnn_cifar10_tpu.ops import flash_attention as fa
 from dml_cnn_cifar10_tpu.ops import moe
+from dml_cnn_cifar10_tpu.ops import sum_rows
 from dml_cnn_cifar10_tpu.ops.layers import (gated_short_conv, grouped_matmul,
                                             mixed_matmul)
 from dml_cnn_cifar10_tpu.parallel import mesh as mesh_lib
@@ -226,6 +227,110 @@ def test_routing_so_skewed_that_every_token_chooses_the_same_experts(
     assert float(jnp.min(jnp.max(jnp.abs(first), -1))) > 0   # every token
     np.testing.assert_array_equal(none, jnp.zeros_like(none))
     assert float(none_stats["rows_here_frac"]) == 0.0
+
+
+@pytest.mark.parametrize("block_rows,rounds", [(40, 2), (16, 3)])
+def test_a_load_that_exceeds_the_buffer_takes_further_rounds(
+        ref, expert_layer, block_rows, rounds):
+    """The same skew with the rows a block at a time: the buffer holds the
+    30 rows of an even load (one block of 40, two of 16) and 80 come, so
+    the share that holds experts 0-1 fills it twice or three times and
+    says so; value and every gradient are the uncut reference layer's."""
+    x, p = expert_layer
+    p = {**p, "bias": jnp.array([9., 9., 9., 0, 0, 0, 0, 0])}
+
+    def shares(x, p):
+        return sum(_share(x, p, f, 2, block_rows)[0] for f in (0, 2, 4, 6))
+
+    with jax.default_matmul_precision("highest"):
+        stats = [_share(x, p, f, 2, block_rows)[1] for f in (0, 2, 4, 6)]
+        want, _ = _uncut(ref, x, p)
+        np.testing.assert_allclose(shares(x, p), want, rtol=1e-4, atol=1e-5)
+        g = jax.random.normal(jax.random.key(3), want.shape)
+        got = jax.grad(lambda x, p: jnp.sum(g * shares(x, p)), (0, 1))(x, p)
+        wanted = jax.grad(lambda x, p: jnp.sum(g * _uncut(ref, x, p)[0]),
+                          (0, 1))(x, p)
+    assert [float(s["buffer_rounds"]) for s in stats] \
+        == [rounds, {40: 1, 16: 2}[block_rows], 1, 1]
+    assert float(stats[0]["rows_here_frac"]) == pytest.approx(2 / 3)
+    for (name, a), (_, b) in zip(_leaves(got), _leaves(wanted)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+# --- each token's sum of the rows it has in a buffer -------------------------
+
+def _rows_of_tokens(tokens, k, seed):
+    """``pos [tokens, k]``, a permutation of the slots in which token ``t``
+    has ``t % (k + 1)`` of its choices among the first ``here`` rows, and
+    ``here``."""
+    rng = np.random.default_rng(seed)
+    mine = np.zeros((tokens, k), bool)
+    for t in range(tokens):
+        mine[t, rng.permutation(k)[:t % (k + 1)]] = True
+    here = int(mine.sum())
+    pos = np.empty((tokens, k), np.int32)
+    pos[mine] = rng.permutation(here)
+    pos[~mine] = here + rng.permutation(tokens * k - here)
+    return jnp.asarray(pos), here
+
+
+@pytest.mark.parametrize("width", [256, 2048])
+@pytest.mark.parametrize("cut", ["whole", "cuts_a_tokens_rows", "empty",
+                                 "second_round"])
+def test_each_token_sums_the_rows_it_has_in_the_buffer(width, cut):
+    """The kernel in the Pallas interpreter, bit for bit the XLA expression
+    (the same float32 terms in the same order), and both
+    ``jax.ops.segment_sum`` of the rows by their tokens: tokens with 0 to
+    4 rows here, a range that cuts a token's rows, an empty one, and one
+    that starts past the buffer's first row."""
+    tokens, k = 40, 4
+    pos, here = _rows_of_tokens(tokens, k, seed=width)
+    lo, hi = {"whole": (0, here), "cuts_a_tokens_rows": (0, here - 9),
+              "empty": (0, 0), "second_round": (32, here)}[cut]
+    room = here - lo
+    buffer = jax.random.normal(jax.random.key(4), (room, width))
+    expression = sum_rows.sum_rows_xla(buffer, pos, lo, hi)
+    kernel = sum_rows.sum_rows_pallas(
+        buffer.reshape(room, width // 128, 128),
+        sum_rows._in_range(pos, lo, hi), True)
+    np.testing.assert_array_equal(kernel, expression)
+    flat = np.asarray(pos).reshape(-1)
+    slots = np.nonzero((flat >= lo) & (flat < hi))[0]
+    want = jax.ops.segment_sum(buffer[flat[slots] - lo], slots // k,
+                               num_segments=tokens)
+    np.testing.assert_allclose(expression, want, rtol=1e-6, atol=1e-6)
+    rows_of = np.bincount(slots // k, minlength=tokens)
+    if cut == "whole":
+        assert set(rows_of) == {0, 1, 2, 3, 4}
+    else:       # some token has rows on both sides of the cut
+        assert np.any((rows_of > 0) | (cut == "empty"))
+        assert np.any(rows_of < np.arange(tokens) % (k + 1))
+    np.testing.assert_array_equal(
+        np.asarray(expression)[rows_of == 0], 0.0)
+
+
+def test_who_takes_the_kernel(monkeypatch):
+    """A TPU backend, one device, a width of whole tiles; the buffer's
+    shape carries the choice to the sum."""
+    from dml_cnn_cifar10_tpu.ops import kernel_paths
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+    four = mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:4])
+    one = mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:1])
+    with kernel_paths.recording() as rec:
+        assert sum_rows.row_shape(64, 4, 2048) == (2048,)
+        assert rec == {"experts": "xla"}
+        monkeypatch.setattr(platform_lib, "on_tpu", lambda: True)
+        assert sum_rows.row_shape(64, 4, 2048, one) == (16, 128)
+        assert rec == {"experts": "pallas sum-by-token"}
+        assert sum_rows.row_shape(64, 4, 2048, four) == (2048,)
+        assert rec == {"experts": "xla (mesh)"}
+        assert sum_rows.row_shape(64, 4, 2048 + 128) == (2048 + 128,)
+        assert sum_rows.row_shape(60, 4, 2048) == (2048,)
+        # 8,200 tokens of 3 choices: no tile of them is a block of scalars
+        assert sum_rows._tile(8200, 3, 2048) is None
+        assert sum_rows.row_shape(8200, 3, 2048) == (2048,)
+        assert sum_rows._tile(32768, 4, 2048) == 256
+        assert rec == {"experts": "xla"}
 
 
 def test_the_bias_changes_the_choice_and_not_the_weights(expert_layer):
@@ -547,7 +652,7 @@ def test_the_cli_trains_it_and_the_records_carry_the_counters(tmp_path):
     """``python cifar10cnn.py --model hybrid_decoder ...``: the flag
     parser, ``Trainer.fit``, the resident K-step dispatch with the
     on-device index stream, AdamW; the loss falls, and each ``train``
-    record and the registry carry the experts' two counters."""
+    record and the registry carry the experts' three counters."""
     from dml_cnn_cifar10_tpu.cli.main import main
     from dml_cnn_cifar10_tpu.utils import metrics_registry
     out = tmp_path / "m.jsonl"
@@ -567,8 +672,10 @@ def test_the_cli_trains_it_and_the_records_carry_the_counters(tmp_path):
     for r in train:
         assert 0.0 < r["moe_rows_here_frac"] < 1.0
         assert r["moe_load_max_over_mean"] >= 1.0
+        assert r["moe_buffer_rounds"] >= 1.0
     reg = metrics_registry.default_registry()
-    for name in ("dml_moe_rows_here_frac", "dml_moe_load_max_over_mean"):
+    for name in ("dml_moe_rows_here_frac", "dml_moe_load_max_over_mean",
+                 "dml_moe_buffer_rounds"):
         assert list(reg.get(name).values().values()) \
             == [train[-1][name[len("dml_"):]]]
 

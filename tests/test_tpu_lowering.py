@@ -202,3 +202,60 @@ def test_vit_step_keeps_flash_and_update_kernels(chip_branch, ndev, attn,
     assert "attention=flash (257 tokens)" in said
     if ndev > 1:
         assert "shard_map[batch/data, heads/model]" in said
+
+
+def _float_scatters(jaxpr, under=""):
+    """The scopes of every scatter of floats in a jaxpr and the jaxprs it
+    holds (a loop's body names its scopes from the loop's own on)."""
+    from jax._src import core
+    found = []
+    for eqn in jaxpr.eqns:
+        scope = f"{under}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name.startswith("scatter") and jnp.issubdtype(
+                eqn.outvars[0].aval.dtype, jnp.floating):
+            found.append(scope)
+        for sub in core.jaxprs_in_params(eqn.params):
+            found += _float_scatters(sub, scope)
+    return found
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_hybrid_decoder_step_sums_the_experts_rows_by_token(
+        chip_branch, ndev, capsys, tmp_path):
+    """At a width of whole tiles on one device the experts' rows reach
+    their tokens through the sum-by-token kernel, forward and backward,
+    once an expert layer (the buffer holds every block at this size, so
+    no further round is built); on a mesh of four the same step keeps the
+    XLA expression. Either way no scatter of floats is left under
+    ``moe/``: the embedding's gradient is the step's only one."""
+    import json
+
+    from dml_cnn_cifar10_tpu.models import hybrid_decoder
+    path = tmp_path / "sizes.json"
+    path.write_text(json.dumps({**hybrid_decoder.SMALL, "hidden_size": 1024,
+                                "head_dim": 128}))
+    model_def = get_model("hybrid_decoder")
+    model_cfg = ModelConfig(name="hybrid_decoder", remat=True,
+                            config_file=str(path))
+    data_cfg = DataConfig(dataset="tokens_synth", sequence_length=32)
+    optim_cfg = OptimConfig(optimizer="adamw")
+    mesh = _mesh(ndev)
+    sh, state = _state_abs(model_def, model_cfg, data_cfg, optim_cfg, mesh)
+    step = step_lib.make_train_step(model_def, model_cfg, optim_cfg, mesh,
+                                    state_sharding=sh)
+    batch = (model_def.batch_shape(model_cfg, data_cfg, 4 * ndev),
+             jax.ShapeDtypeStruct((4 * ndev,), jnp.int32))
+    traced = step.trace(state, *batch)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    layers = hybrid_decoder.SMALL["num_hidden_layers"] \
+        - hybrid_decoder.SMALL["num_dense_layers"]
+    want = "pallas sum-by-token" if ndev == 1 else "xla (mesh)"
+    assert f" experts={want}\n" in capsys.readouterr().out
+    # (the jitted launcher is one function a distinct trace, called from
+    # each place that takes it)
+    assert ("tpu_custom_call" in text) == (ndev == 1)
+    assert text.count("call @sum_rows_pallas") \
+        == (2 * layers if ndev == 1 else 0)
+    scatters = _float_scatters(traced.jaxpr.jaxpr)
+    assert len(scatters) == 1 and "embed" in scatters[0], scatters
+    assert not [s for s in scatters if "moe" in s]
