@@ -59,9 +59,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="looped_decoder: a causal decoder over tokens whose "
                         "layers run several times on the same weights; "
                         "hybrid_decoder: one whose layers differ by a list "
-                        "(gated short convolution or grouped-head "
-                        "attention, then a dense MLP or this chip's share "
-                        "of sigmoid-routed experts), tied embedding "
+                        "(gated short convolution, grouped-head attention "
+                        "or the same under a sliding window, then a dense "
+                        "MLP or this chip's share of routed experts), "
+                        "head tied to the embedding or its own "
                         "(both need --dataset tokens_synth)")
     p.add_argument("--model_config_file", type=str, default=None,
                    help="sizes of a model that reads them from a file in "
@@ -72,12 +73,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "num_key_value_heads, head_dim (optional), "
                         "intermediate_size, moe_intermediate_size, "
                         "num_hidden_layers, layer_types (conv | "
-                        "full_attention), num_dense_layers, num_experts "
+                        "full_attention | sliding_attention), "
+                        "num_dense_layers, num_experts "
                         "(held here), expert_first_id, router_num_experts, "
                         "num_experts_per_tok, norm_topk_prob, "
-                        "routed_scaling_factor, use_expert_bias, "
-                        "vocab_size, norm_eps, rope_theta, conv_L_cache, "
-                        "conv_bias")
+                        "routed_scaling_factor, use_expert_bias (with "
+                        "expert_bias_update_rate), "
+                        "vocab_size, norm_eps (or rms_norm_eps), rope_theta "
+                        "or rope_parameters (a rule a kind of layer: "
+                        "rope_type default | yarn), conv_L_cache and "
+                        "conv_bias (conv layers), and where present "
+                        "sliding_window, tie_word_embeddings (true), "
+                        "router_score (sigmoid | softmax), qk_norm (true)")
     p.add_argument("--dataset", type=str, default="cifar10",
                    choices=["cifar10", "cifar100", "synthetic",
                             "imagenet_synth", "tokens_synth"],

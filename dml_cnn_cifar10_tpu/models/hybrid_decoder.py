@@ -1,8 +1,9 @@
 """A causal decoder over tokens whose layers differ by a list: an operator
-of one of two kinds (a gated short convolution, or rotary attention with
-grouped key/value heads and a norm on each head's query and key), then a
-feed-forward of one of two kinds (a dense gated SiLU MLP in the leading
-layers, sigmoid-routed experts after them), a tied embedding and head.
+of one of three kinds (a gated short convolution, rotary attention with
+grouped key/value heads over the whole sequence, or the same over a
+sliding window), then a feed-forward of one of two kinds (a dense gated
+SiLU MLP in the leading layers, routed experts after them), and a head
+that is the embedding's transpose or a matrix of its own.
 
 With tokens ``x [B, S]``: ``h = E[x]``. Every layer ``i``: ``a = rms(h;
 g_op_i)``; by ``layer_types[i]``
@@ -11,35 +12,52 @@ g_op_i)``; by ``layer_types[i]``
   sum_j w[:, j] u[t - (L - 1) + j]`` (depthwise, causal), ``o = (Cg * c)
   W_out`` (``ops.layers.gated_short_conv`` between the two products);
 - ``full_attention``: ``ops.attention.causal_self_attention``, the
-  sublayer the looped decoder runs too;
+  sublayer the looped decoder runs too: ``q = a Wq``, ``k = a Wk``, ``v =
+  a Wv``, an RMS norm on each head's query and key where ``qk_norm``,
+  rotary by the rule of the layer's kind, query head ``j`` on key/value
+  head ``j // (heads / kv_heads)``, scores ``q k^T / sqrt(head_dim)``
+  under ``col <= row``, softmax in float32, ``o = concat(heads) Wo``;
+- ``sliding_attention``: the same with the mask also ``col > row -
+  sliding_window`` (a token sees itself and the ``sliding_window - 1``
+  before it) and the rotary rule of its own kind;
 
-``h = h + o``; ``m = rms(h; g_ffn_i)``; for ``i < num_dense_layers`` ``f =
-(silu(m W1) * (m W3)) W2``, else the experts' layer: ``s = sigmoid(m
-Wr)`` over ALL ``router_num_experts`` experts, a token takes the
-``num_experts_per_tok`` with the largest ``s + b`` and weighs them by
-their ``s`` over its sum, and the ``num_experts`` experts held here (ids
+``h = h + o``. The rotary rule is ``rope_theta`` for every layer, or
+``rope_parameters``, a rule a kind of layer (``rope_type`` ``default``:
+``inv_freq_j = theta ** (-2 j / head_dim)``; ``yarn``: the slow pairs'
+frequencies divided by ``factor`` over a ramp and cos and sin times
+``attention_factor``: ``ops.layers.rope_frequencies``).
+
+``m = rms(h; g_ffn_i)``; for ``i < num_dense_layers`` ``f = (silu(m W1) *
+(m W3)) W2``, else the experts' layer: ``s = sigmoid(m Wr)`` or, with
+``router_score`` ``softmax``, ``s = softmax(m Wr)`` over ALL
+``router_num_experts`` experts, a token takes the ``num_experts_per_tok``
+with the largest ``s + b`` and weighs them by their ``s`` over its sum
+(``norm_topk_prob``), and the ``num_experts`` experts held here (ids
 ``expert_first_id ..``) add their part (``ops.moe.routed_experts``: no
 capacity, nothing dropped, nothing stands in for the experts that live
-elsewhere); ``h = h + f``. The bias ``b`` (``use_expert_bias``) is a
+elsewhere); ``h = h + f``. The bias ``b`` (``use_expert_bias``; without
+it the choice is by ``s`` alone and the model has no state) is a
 buffer of the model's state, zero at the start, that no gradient reaches:
 every training step moves each expert's by ``expert_bias_update_rate``
 toward an even load over all of the router's experts
-(``ops.moe.balanced_bias``). ``logits = rms(h; g_f) E^T``; the loss is the
-mean next-token cross-entropy, over the ``vocab_size`` rows held here.
+(``ops.moe.balanced_bias``). ``logits = rms(h; g_f) W_head`` with ``W_head
+= E^T`` where ``tie_word_embeddings``, else a parameter ``head [D, V]``
+of its own; the loss is the mean next-token cross-entropy, over the
+``vocab_size`` rows held here.
 
 Sizes come from a JSON file in the shape of a published ``config.json``
-(``--model_config_file``, the keys of :data:`SMALL`); without one,
-:data:`SMALL`. The model states its own loss (``ModelDef.loss``): a batch
-is ``[B, S+1]`` int32 rows of a token dataset, and no ``[tokens,
-vocabulary]`` array is ever held.
+(``--model_config_file``, the keys of :data:`SMALL` and :data:`OPTIONAL`);
+without one, :data:`SMALL`. The model states its own loss
+(``ModelDef.loss``): a batch is ``[B, S+1]`` int32 rows of a token
+dataset, and no ``[tokens, vocabulary]`` array is ever held.
 
 Numerics: parameters and the residual stream float32; every product (the
 grouped ones too) of operands rounded to ``compute_dtype`` and summed in
-float32; the router's product, sigmoid, choice and weights, the norms,
+float32; the router's product, score, choice and weights, the norms,
 rotary, softmax, the short convolution's taps and gates and the loss
 float32.
 
-Memory: the sublayers that treat each sequence alone (both operators, the
+Memory: the sublayers that treat each sequence alone (the operators, the
 dense MLP) take :data:`OP_CHUNK_TOKENS` tokens at a time, one group of
 sequences after the other; the experts take all of a step's tokens, their
 rows a block at a time, and hold the blocks' float32 results (an even
@@ -62,6 +80,7 @@ from jax import lax
 from dml_cnn_cifar10_tpu.config import DataConfig, ModelConfig
 from dml_cnn_cifar10_tpu.models import looped_decoder
 from dml_cnn_cifar10_tpu.ops import attention as attention_lib
+from dml_cnn_cifar10_tpu.ops import flash_attention as flash_lib
 from dml_cnn_cifar10_tpu.ops import kernel_paths
 from dml_cnn_cifar10_tpu.ops import moe as moe_lib
 from dml_cnn_cifar10_tpu.ops.layers import (gated_short_conv, mixed_matmul,
@@ -82,6 +101,17 @@ SMALL: Dict[str, Any] = {
     "norm_eps": 1e-5, "rope_theta": 1000000, "conv_L_cache": 3,
     "conv_bias": False}
 
+#: Keys a file may leave out, with what their absence means: no window
+#: layer, one rotary rule (``rope_theta``) for every layer, a head tied to
+#: the embedding, sigmoid scores, a norm on each head's query and key.
+OPTIONAL: Dict[str, Any] = {
+    "sliding_window": None, "rope_parameters": None,
+    "tie_word_embeddings": True, "router_score": "sigmoid", "qk_norm": True}
+
+#: The operator kinds of ``layer_types``; the two of attention are told
+#: apart by their scope (``attn`` / ``attn_window``).
+KINDS = ("conv", "full_attention", "sliding_attention")
+
 KEPT = looped_decoder.KEPT
 
 #: Tokens that a sublayer which treats each sequence alone takes at once,
@@ -99,13 +129,29 @@ EXPERT_BLOCK_ROWS = 8192
 ROW_TILE = 512
 
 
+def _unused(sz: Dict[str, Any]) -> set:
+    """Keys of :data:`SMALL` that these sizes never read: a file may leave
+    them out."""
+    out = set()
+    if "conv" not in sz.get("layer_types", ("conv",)):
+        out |= {"conv_L_cache", "conv_bias"}
+    if sz.get("rope_parameters") is not None:
+        out.add("rope_theta")
+    if not sz.get("use_expert_bias", True):
+        out.add("expert_bias_update_rate")
+    return out
+
+
 @functools.lru_cache(maxsize=None)
 def _read_sizes(path: str) -> Dict[str, Any]:
     spec = looped_decoder.read_config_file(path)
-    missing = sorted(set(SMALL) - set(spec))
+    if "norm_eps" not in spec and "rms_norm_eps" in spec:
+        spec["norm_eps"] = spec["rms_norm_eps"]     # the key's other name
+    sz = {**OPTIONAL,
+          **{k: spec[k] for k in (*SMALL, *OPTIONAL) if k in spec}}
+    missing = sorted(set(SMALL) - set(sz) - _unused(sz))
     if missing:
         raise ValueError(f"{path} lacks {missing}")
-    sz = {k: spec[k] for k in SMALL}
     sz["head_dim"] = spec.get("head_dim") or \
         sz["hidden_size"] // sz["num_attention_heads"]
     return _checked(sz, path)
@@ -114,11 +160,29 @@ def _read_sizes(path: str) -> Dict[str, Any]:
 def _checked(sz: Dict[str, Any], where: str) -> Dict[str, Any]:
     kinds = set(sz["layer_types"])
     if len(sz["layer_types"]) != sz["num_hidden_layers"] \
-            or not kinds <= {"conv", "full_attention"}:
-        raise ValueError(f"{where}: layer_types has to name conv or "
-                         f"full_attention for each of num_hidden_layers")
-    if sz["conv_bias"]:
+            or not kinds <= set(KINDS):
+        raise ValueError(f"{where}: layer_types has to name one of "
+                         f"{', '.join(KINDS)} for each of "
+                         f"num_hidden_layers")
+    if sz.get("conv_bias"):
         raise NotImplementedError(f"{where}: conv_bias is not built")
+    window = sz["sliding_window"]
+    if "sliding_attention" in kinds and not (window and window >= 1):
+        raise ValueError(f"{where}: a sliding_attention layer needs a "
+                         f"sliding_window of at least 1")
+    rules = sz["rope_parameters"]
+    for kind in sorted(kinds - {"conv"}) if rules is not None else ():
+        if kind not in rules:
+            raise ValueError(f"{where}: rope_parameters lacks a rule for "
+                             f"{kind}, which layer_types uses")
+        if rules[kind].get("rope_type", "default") not in ("default",
+                                                           "yarn"):
+            raise ValueError(f"{where}: rope_type "
+                             f"{rules[kind]['rope_type']!r} of {kind} is "
+                             f"not default or yarn")
+    if sz["router_score"] not in ("sigmoid", "softmax"):
+        raise ValueError(f"{where}: router_score {sz['router_score']!r} is "
+                         f"not sigmoid or softmax")
     last = sz["expert_first_id"] + sz["num_experts"]
     if not 0 <= sz["expert_first_id"] < last <= sz["router_num_experts"]:
         raise ValueError(f"{where}: experts {sz['expert_first_id']}..{last} "
@@ -132,11 +196,19 @@ def _checked(sz: Dict[str, Any], where: str) -> Dict[str, Any]:
 
 def sizes(cfg: ModelConfig) -> Dict[str, Any]:
     """The model's sizes: the file ``cfg.config_file`` names, else
-    :data:`SMALL`; ``head_dim`` where the file has none is ``hidden_size /
+    :data:`SMALL`; a key of :data:`OPTIONAL` the file lacks has its value
+    there, and ``head_dim`` where the file has none is ``hidden_size /
     num_attention_heads``."""
     if not cfg.config_file:
-        return _checked({**SMALL, "head_dim": 16}, "SMALL")
+        return _checked({**OPTIONAL, **SMALL, "head_dim": 16}, "SMALL")
     return _read_sizes(looped_decoder.config_path(cfg.config_file))
+
+
+def rope_rule(sz: Dict[str, Any], kind: str):
+    """The rotary rule of a layer of ``kind``: its entry of
+    ``rope_parameters``, else the one ``rope_theta``."""
+    rules = sz["rope_parameters"]
+    return sz["rope_theta"] if rules is None else rules[kind]
 
 
 def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
@@ -168,8 +240,9 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
                          "w_out": matrix(d, d, d)}
         else:
             p["attn"] = {"wq": matrix(d, d, a), "wk": matrix(d, d, kv),
-                         "wv": matrix(d, d, kv), "wo": matrix(a, a, d),
-                         "q_norm": scale(dh), "k_norm": scale(dh)}
+                         "wv": matrix(d, d, kv), "wo": matrix(a, a, d)}
+            if sz["qk_norm"]:
+                p["attn"].update(q_norm=scale(dh), k_norm=scale(dh))
         if i < sz["num_dense_layers"]:
             p["mlp"] = {"w1": matrix(d, d, f), "w3": matrix(d, d, f),
                         "w2": matrix(f, f, d)}
@@ -179,19 +252,24 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
                         "w2": matrix(hm, e, hm, d)}
         return p
 
-    return {"embed": matrix(d, sz["vocab_size"], d),
-            "layers": [layer(i, kind)
-                       for i, kind in enumerate(sz["layer_types"])],
-            "final_norm": scale()}
+    params = {"embed": matrix(d, sz["vocab_size"], d),
+              "layers": [layer(i, kind)
+                         for i, kind in enumerate(sz["layer_types"])],
+              "final_norm": scale()}
+    if not sz["tie_word_embeddings"]:
+        params["head"] = matrix(d, d, sz["vocab_size"])
+    return params
 
 
-def init_state(params):
+def init_state(params, cfg: ModelConfig):
     """The model's state: for each layer that has experts their bias,
-    zero (``{}`` for a layer without). :func:`loss` reads it only where
-    the file says ``use_expert_bias``."""
+    zero (``{}`` for a layer without, and for every layer where the file
+    says no ``use_expert_bias``: the state then holds nothing)."""
+    biased = sizes(cfg)["use_expert_bias"]
     return {"layers": [
         {"expert_bias": jnp.zeros((p["moe"]["router"].shape[1],),
-                                  jnp.float32)} if "moe" in p else {}
+                                  jnp.float32)}
+        if biased and "moe" in p else {}
         for p in params["layers"]]}
 
 
@@ -244,12 +322,16 @@ def _operator(h, p, kind: str, sz, cfg: ModelConfig, mesh):
                 gated = gated_short_conv(bcx, p["conv"]["w"])
             with jax.named_scope("out"):
                 return h + mixed_matmul(gated, p["conv"]["w_out"], low)
-    with jax.named_scope("attn"):
+    windowed = kind == "sliding_attention"
+    # a scope of its own for the window sublayer: the innermost scope that
+    # names a kind decides (utils/devprof.py), so the two are told apart
+    with jax.named_scope("attn_window" if windowed else "attn"):
         return h + attention_lib.causal_self_attention(
             a, p["attn"], heads=sz["num_attention_heads"],
             kv_heads=sz["num_key_value_heads"], head_dim=sz["head_dim"],
-            rope_theta=sz["rope_theta"], low=low,
-            use_pallas=cfg.use_pallas_attention, mesh=mesh, norm_eps=eps)
+            rope=rope_rule(sz, kind), low=low,
+            use_pallas=cfg.use_pallas_attention, mesh=mesh, norm_eps=eps,
+            window=sz["sliding_window"] if windowed else None)
 
 
 def _dense_ffn(h, p, sz, cfg: ModelConfig):
@@ -281,8 +363,37 @@ def _expert_ffn(h, p, bias, sz, cfg: ModelConfig, mesh):
             block_rows=expert_block_rows(
                 slots, slots * sz["num_experts"] / e_all),
             norm_scale=p["ffn_norm"]["scale"], norm_eps=sz["norm_eps"],
-            mesh=mesh)
+            mesh=mesh, score=sz["router_score"])
     return h + f.reshape(b, s, d), stats
+
+
+def window_blocks_frac(sz: Dict[str, Any], seq: int, low) -> float:
+    """Block pairs the window layers' flash schedule visits over those the
+    causal schedule visits, at the blocks a call of ``seq`` tokens in
+    ``low`` runs at (``ops.flash_attention.band_blocks_frac``)."""
+    block = flash_lib.auto_block(seq, sz["head_dim"] * low.itemsize)
+    return flash_lib.band_blocks_frac(seq, sz["sliding_window"], block)
+
+
+def _note_paths(sz: Dict[str, Any]) -> None:
+    """Adds to the step's line what only the model knows: in how many
+    layers the attention the dispatch noted runs under a window, and a
+    router that is not the sigmoid with a balancing bias."""
+    kinds = sz["layer_types"]
+    windowed = kinds.count("sliding_attention")
+    path = kernel_paths.noted("attention")
+    if windowed and path:
+        path = path.replace(f", window {sz['sliding_window']}", "")
+        kernel_paths.note(
+            "attention", f"{path}, window {sz['sliding_window']} in "
+                         f"{windowed} of {len(kinds)} layers")
+    path = kernel_paths.noted("experts")
+    if path:
+        if sz["router_score"] != "sigmoid":
+            path += f", {sz['router_score']} router"
+        if not sz["use_expert_bias"]:
+            path += ", no bias"
+        kernel_paths.note("experts", path)
 
 
 def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
@@ -290,16 +401,17 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
     """The model's own loss over a batch of token rows ``[B, S+1]`` ->
     ``(mean next-token cross-entropy, stats, new model state)``. ``stats``:
     ``accuracy``, the share of next tokens whose logit is the largest,
-    and, the mean over the experts' layers, ``moe_rows_here_frac``,
+    the mean over the experts' layers of ``moe_rows_here_frac``,
     ``moe_load_max_over_mean`` and ``moe_buffer_rounds``
-    (``ops.moe.routed_experts``). The state is
+    (``ops.moe.routed_experts``), and, where a layer has a window,
+    ``attn_window_blocks_frac`` (:func:`window_blocks_frac`). The state is
     :func:`init_state`'s (None: as at the start); in training each
     experts' layer's bias comes back moved one step toward an even load.
     ``loss_blocks`` overrides the number of blocks the loss is taken in."""
     sz = sizes(cfg)
     if model_state is None:
-        model_state = init_state(params)
-    rate = sz["expert_bias_update_rate"] if train else 0.0
+        model_state = init_state(params, cfg)
+    rate = sz.get("expert_bias_update_rate", 0.0) if train else 0.0
     low = jnp.dtype(cfg.compute_dtype)
     inputs, targets = rows[:, :-1], rows[:, 1:].reshape(-1)
     n = targets.shape[0]
@@ -324,8 +436,7 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
                 h = _over_tokens(
                     remat(lambda x, p=p: _dense_ffn(x, p, sz, cfg)), h)
             else:
-                bias = state["expert_bias"] if sz["use_expert_bias"] \
-                    else None
+                bias = state.get("expert_bias")
                 h, stats = _expert_ffn(h, p, bias, sz, cfg, mesh)
                 if bias is not None:
                     state = {"expert_bias": moe_lib.balanced_bias(
@@ -335,16 +446,22 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"]["scale"], sz["norm_eps"])
     with jax.named_scope("head"):
+        head = params["embed"].T if sz["tie_word_embeddings"] \
+            else params["head"]
         ce, hit = loss_lib.blockwise_cross_entropy(
-            h.reshape(n, h.shape[-1]), params["embed"].T, targets,
+            h.reshape(n, h.shape[-1]), head, targets,
             loss_blocks or looped_decoder.token_blocks(n), low)
     with jax.named_scope("loss"):
         value = jnp.mean(ce)
+    _note_paths(sz)
     stats = {"accuracy": lax.stop_gradient(jnp.mean(hit))}
     for name in ("rows_here_frac", "load_max_over_mean", "buffer_rounds"):
         if moe_stats:
             stats["moe_" + name] = sum(s[name] for s in moe_stats) \
                 / len(moe_stats)
+    if "sliding_attention" in sz["layer_types"]:
+        stats["attn_window_blocks_frac"] = jnp.float32(
+            window_blocks_frac(sz, inputs.shape[1], low))
     return value, stats, jax.tree.map(lax.stop_gradient, new_state)
 
 
@@ -360,8 +477,7 @@ def param_count(cfg: ModelConfig) -> int:
     its state (the experts' bias, which a published count has)."""
     def build():
         params = init_params(jax.random.key(0), cfg, DataConfig())
-        return params, init_state(params) \
-            if sizes(cfg)["use_expert_bias"] else {}
+        return params, init_state(params, cfg)
     return sum(int(np.prod(x.shape))
                for x in jax.tree.leaves(jax.eval_shape(build)))
 
@@ -371,25 +487,30 @@ def step_flops(cfg: ModelConfig, data_cfg: DataConfig, batch: int) -> float:
     shapes: three times the forward's multiply-adds, two operations each.
     The experts under uniform routing: of a token's ``num_experts_per_tok``
     slots the share ``num_experts / router_num_experts`` falls on an
-    expert held here. Causal attention as the half square it is, forward
-    once and backward two and a half times. Not counted: the embedding's
-    gather, the filter's taps and gates, norms, softmax, and what the
-    backward pass computes a second time."""
+    expert held here. Causal attention as the pairs it has: the half
+    square, ``S (S + 1) / 2``, in a full layer and the band, ``W (W + 1) /
+    2 + (S - W) W``, in a layer with a window ``W < S``; forward once and
+    backward two and a half times. The head once, tied or not. Not
+    counted: the embedding's gather, the filter's taps and gates, norms,
+    softmax, and what the backward pass computes a second time."""
     sz = sizes(cfg)
     s, d = data_cfg.sequence_length, sz["hidden_size"]
     a = sz["num_attention_heads"] * sz["head_dim"]
     kv = sz["num_key_value_heads"] * sz["head_dim"]
     kinds = sz["layer_types"]
-    conv, attn = kinds.count("conv"), kinds.count("full_attention")
+    conv, full = kinds.count("conv"), kinds.count("full_attention")
+    windowed = kinds.count("sliding_attention")
     dense = sz["num_dense_layers"]
     here = sz["num_experts_per_tok"] * sz["num_experts"] \
         / sz["router_num_experts"]
-    per_token = conv * 4 * d * d + attn * 2 * d * (a + kv) \
+    per_token = conv * 4 * d * d + (full + windowed) * 2 * d * (a + kv) \
         + dense * 3 * d * sz["intermediate_size"] \
         + (len(kinds) - dense) * (d * sz["router_num_experts"]
                                   + here * 3 * d
                                   * sz["moe_intermediate_size"]) \
         + d * sz["vocab_size"]
-    pairs = s * (s + 1) // 2
-    attention = attn * 2 * a * pairs
+    w = min(sz["sliding_window"] or s, s)
+    pairs = full * (s * (s + 1) // 2) \
+        + windowed * (w * (w + 1) // 2 + (s - w) * w)
+    attention = 2 * a * pairs
     return float(batch * (6 * s * per_token + 2 * 3.5 * attention))

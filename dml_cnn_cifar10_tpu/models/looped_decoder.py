@@ -150,7 +150,7 @@ def _layer(h, p, sz, cfg: ModelConfig, mesh):
         o = attention_lib.causal_self_attention(
             a, p, heads=sz["num_attention_heads"],
             kv_heads=sz["num_key_value_heads"], head_dim=sz["head_dim"],
-            rope_theta=sz["rope_theta"], low=low,
+            rope=sz["rope_theta"], low=low,
             use_pallas=cfg.use_pallas_attention, mesh=mesh)
     with jax.named_scope("attn_post_norm"):
         h = h + rms_norm(o, p["attn_post_norm"]["scale"], eps)

@@ -10,7 +10,8 @@ class ModelDef(NamedTuple):
     apply: Callable         # stateless: (params, images, cfg, train) -> logits
                             # stateful: (params, state, images, cfg, train)
                             #           -> (logits, new_state)
-    init_state: Callable    # (params) -> mutable state pytree ({} if none)
+    init_state: Callable    # (params, model_cfg) -> mutable state pytree
+                            # ({} if none)
     has_state: bool
     # apply accepts a ``mesh=`` kwarg: the ViTs route sequence-parallel
     # (ring) attention by it when the mesh's ``seq`` axis is >1, and a
@@ -50,7 +51,7 @@ class ModelDef(NamedTuple):
 
 def _cnn() -> ModelDef:
     from dml_cnn_cifar10_tpu.models import cnn
-    return ModelDef(cnn.init_params, cnn.apply, lambda p: {}, False,
+    return ModelDef(cnn.init_params, cnn.apply, lambda p, c: {}, False,
                     wants_mesh=True, spatial=True)
 
 
@@ -60,7 +61,7 @@ def _resnet(depth: int) -> Callable[[], ModelDef]:
         return ModelDef(
             lambda k, m, d: resnet.init_params(k, m, d, depth=depth),
             resnet.apply,
-            resnet.init_state,
+            lambda p, c: resnet.init_state(p),
             True,
             spatial=True,
         )
@@ -77,7 +78,7 @@ def _vit() -> ModelDef:
                 "name 'vit_moe' (its aux loss and expert sharding rules)")
         return vit.init_params(key, model_cfg, data_cfg)
 
-    return ModelDef(init, vit.apply, lambda p: {}, False, wants_mesh=True,
+    return ModelDef(init, vit.apply, lambda p, c: {}, False, wants_mesh=True,
                     stack_probe=vit.block_flops_probe)
 
 
@@ -91,14 +92,14 @@ def _vit_moe() -> ModelDef:
                 f"(got {model_cfg.moe_experts}); set ModelConfig.moe_experts")
         return vit.init_params(key, model_cfg, data_cfg)
 
-    return ModelDef(init, vit.apply_with_aux, lambda p: {}, False,
+    return ModelDef(init, vit.apply_with_aux, lambda p, c: {}, False,
                     wants_mesh=True, has_aux=True,
                     stack_probe=vit.block_flops_probe)
 
 
 def _looped_decoder() -> ModelDef:
     from dml_cnn_cifar10_tpu.models import looped_decoder as m
-    return ModelDef(m.init_params, None, lambda p: {}, False,
+    return ModelDef(m.init_params, None, lambda p, c: {}, False,
                     wants_mesh=True, loss=m.loss, batch_shape=m.batch_shape,
                     batch_ndim=2, step_flops=m.step_flops)
 

@@ -126,8 +126,9 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     its own slice with no collective. A dim its axis does not divide is
     replicated instead (and the printed path says so)."""
     b, seq, h, _ = q.shape
+    size = f"({seq} tokens)" + (f", window {window}" if window else "")
     if not (use_pallas and seq >= 128):
-        kernel_paths.note("attention", f"xla ({seq} tokens)")
+        kernel_paths.note("attention", f"xla {size}")
         return xla_attention(q, k, v, scale=scale, causal=causal,
                              segment_ids=segment_ids, window=window)
     from dml_cnn_cifar10_tpu.ops import flash_attention as fa
@@ -138,7 +139,7 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     flash = functools.partial(fa.flash_attention, scale=scale,
                               causal=causal, window=window,
                               interpret=interpret)
-    path = f"flash{'-interpret' if interpret else ''} ({seq} tokens)"
+    path = f"flash{'-interpret' if interpret else ''} {size}"
     if mesh is None or mesh.size == 1:
         kernel_paths.note("attention", path)
         return flash(q, k, v, segment_ids=segment_ids)
@@ -154,19 +155,25 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def causal_self_attention(a: jax.Array, p, *, heads: int, kv_heads: int,
-                          head_dim: int, rope_theta: float, low,
-                          use_pallas: bool, mesh=None,
-                          norm_eps: float | None = None) -> jax.Array:
+                          head_dim: int, rope, low, use_pallas: bool,
+                          mesh=None, norm_eps: float | None = None,
+                          window: int | None = None) -> jax.Array:
     """The attention sublayer of a decoder over tokens, between its norm
     and its residual add: ``a [B, S, D]`` (float32, normed) -> ``[B, S,
     D]``. ``p`` holds ``wq [D, heads * head_dim]``, ``wk`` and ``wv`` ``[D,
     kv_heads * head_dim]``, ``wo``, and, where the model norms each head's
     query and key, ``q_norm`` / ``k_norm`` (one ``scale [head_dim]`` for
-    all heads). Query head ``j`` reads key/value head ``j // (heads /
-    kv_heads)``: the key/value heads are repeated before the kernel, and
-    the repeat's transpose sums each group's gradient. Products of
-    operands rounded to ``low``, summed in float32; norms and rotary
-    float32. One scope a step, under the caller's."""
+    all heads). ``q = a wq``, ``k = a wk``, ``v = a wv``, no bias; rotary
+    positions on ``q`` and ``k`` by the rule ``rope`` (a number, theta, or
+    a mapping: ``ops.layers.rope_frequencies``, plain or YaRN); query head
+    ``j`` reads key/value head ``j // (heads / kv_heads)``: the key/value
+    heads are repeated before the kernel, and the repeat's transpose sums
+    each group's gradient; scores ``q k^T / sqrt(head_dim)`` under the mask
+    ``col <= row`` and, with ``window``, also ``col > row - window`` (a
+    token sees itself and the ``window - 1`` before it); softmax in
+    float32; ``concat(heads) wo``. Products of operands rounded to ``low``,
+    summed in float32; norms and rotary float32. One scope a step, under
+    the caller's."""
     b, s, _ = a.shape
     with jax.named_scope("qkv"):
         q = mixed_matmul(a, p["wq"], low).reshape(b, s, heads, head_dim)
@@ -177,14 +184,14 @@ def causal_self_attention(a: jax.Array, p, *, heads: int, kv_heads: int,
             q = rms_norm(q, p["q_norm"]["scale"], norm_eps)
             k = rms_norm(k, p["k_norm"]["scale"], norm_eps)
     with jax.named_scope("rotary"):
-        q, k = (rotary(t, rope_theta).astype(low) for t in (q, k))
+        q, k = (rotary(t, rope).astype(low) for t in (q, k))
     with jax.named_scope("flash"):
         v = v.astype(low)
         if kv_heads != heads:
             k, v = (jnp.repeat(t, heads // kv_heads, axis=2)
                     for t in (k, v))
         o = dispatch_attention(q, k, v, use_pallas=use_pallas, causal=True,
-                               mesh=mesh)
+                               window=window, mesh=mesh)
     with jax.named_scope("out"):
         return mixed_matmul(
             o.reshape(b, s, heads * head_dim).astype(jnp.float32), p["wo"],

@@ -38,3 +38,11 @@ def note(kind: str, path: str) -> None:
     rec = getattr(_local, "rec", None)
     if rec is not None:
         rec[kind] = path
+
+
+def noted(kind: str):
+    """What :func:`note` last recorded for ``kind`` in the live recording,
+    or None: for a caller that adds to a chooser's note what only it knows
+    (a model: in how many of its layers)."""
+    rec = getattr(_local, "rec", None)
+    return None if rec is None else rec.get(kind)

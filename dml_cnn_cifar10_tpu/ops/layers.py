@@ -15,7 +15,7 @@ Parity notes (all against ``/root/reference/cifar10cnn.py``):
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Mapping, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -212,16 +212,63 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
         * scale
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
+def rope_frequencies(rope, head_dim: int):
+    """A rotary rule -> ``(inv_freq [head_dim / 2] float64, factor)``: the
+    angle a position turns pair ``j`` by, and what cos and sin are both
+    multiplied by. ``rope`` is a number, theta, or a mapping with
+    ``rope_theta`` and ``rope_type``:
+
+    - ``default`` (or a number): ``inv_freq_j = theta ** (-2 j /
+      head_dim)``, factor 1;
+    - ``yarn`` (as the ``transformers`` library computes it; keys
+      ``factor``, ``original_max_position_embeddings``, ``beta_fast`` 32,
+      ``beta_slow`` 1, ``attention_factor`` ``0.1 ln(factor) + 1``): with
+      ``dim(n) = head_dim ln(original / (2 pi n)) / (2 ln theta)``, ``low =
+      floor(dim(beta_fast))`` and ``high = ceil(dim(beta_slow))`` (clipped
+      to ``0 .. head_dim - 1``), ``ramp_j = clip((j - low) / (high - low),
+      0, 1)`` and ``inv_freq_j = (1 - ramp_j) theta ** (-2 j / head_dim) +
+      ramp_j theta ** (-2 j / head_dim) / factor``: the fast pairs turn as
+      they were trained, the slow ones ``factor`` times slower; the factor
+      is ``attention_factor`` at every length."""
+    if not isinstance(rope, Mapping):
+        rope = {"rope_type": "default", "rope_theta": rope}
+    kind = rope.get("rope_type", "default")
+    theta = rope["rope_theta"]
+    plain = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    if kind == "default":
+        return plain, 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r} is not default or yarn")
+    scale, original = rope["factor"], rope["original_max_position_embeddings"]
+
+    def dim(turns):
+        return head_dim * np.log(original / (2 * np.pi * turns)) \
+            / (2 * np.log(theta))
+
+    low = max(int(np.floor(dim(rope.get("beta_fast", 32)))), 0)
+    high = min(int(np.ceil(dim(rope.get("beta_slow", 1)))), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    factor = rope.get("attention_factor")
+    if factor is None:
+        factor = 0.1 * np.log(scale) + 1.0
+    return (1 - ramp) * plain + ramp * plain / scale, float(factor)
+
+
+def rotary(x: jax.Array, rope) -> jax.Array:
     """Rotary positions on ``x [B, S, H, Dh]``, rotate-half over the whole
     head dimension: the pair ``(x[i], x[i + Dh/2])`` turns by ``position *
-    theta ** (-2 i / Dh)``, positions ``0..S-1``. float32."""
+    inv_freq_i``, positions ``0..S-1``, cos and sin times the rule's
+    factor (:func:`rope_frequencies`; a number is theta of the plain
+    rule). float32."""
     s, dh = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-np.arange(0, dh, 2, dtype=np.float64) / dh)
+    inv_freq, factor = rope_frequencies(rope, dh)
     angle = jnp.asarray(np.arange(s)[:, None] * inv_freq[None, :],
                         jnp.float32)
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x = x.astype(jnp.float32)
     half = jnp.concatenate([-x[..., dh // 2:], x[..., :dh // 2]], -1)
     return x * cos + half * sin

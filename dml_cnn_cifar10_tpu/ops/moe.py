@@ -199,20 +199,26 @@ def moe_mlp(x: jax.Array, params: Params, capacity_factor: float,
 # --- one chip's share of an expert layer that drops nothing ------------------
 
 def route_top_k(x: jax.Array, router: jax.Array, bias, top_k: int,
-                norm_topk: bool = True, scaling: float = 1.0
-                ) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid routing over ALL of the layer's experts: ``x [T, D]``,
-    ``router [D, E_all]`` -> ``(chosen [T, k] int32, weights [T, k])``.
-    ``s = sigmoid(x router)``; a token takes the ``k`` experts with the
-    largest ``s + bias`` (``bias [E_all]`` or None: it decides the choice
-    and nothing else, and no gradient reaches it); its weights are the
-    chosen experts' ``s``, over their sum plus 1e-6 where ``norm_topk``,
-    times ``scaling``. All float32, the product at the highest precision:
-    the choice is a function of these numbers alone, so a recomputation
-    chooses as the forward pass did."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
+                norm_topk: bool = True, scaling: float = 1.0,
+                score: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
+    """Routing over ALL of the layer's experts: ``x [T, D]``, ``router [D,
+    E_all]`` -> ``(chosen [T, k] int32, weights [T, k])``. ``s = sigmoid(x
+    router)``, each expert's score alone, or with ``score="softmax"`` ``s =
+    softmax(x router)`` over all ``E_all`` logits; a token takes the ``k``
+    experts with the largest ``s + bias`` (``bias [E_all]`` or None: it
+    decides the choice and nothing else, and no gradient reaches it); its
+    weights are the chosen experts' ``s``, where ``norm_topk`` over their
+    sum (plus 1e-6 for sigmoid scores, whose sum has no floor; a softmax's
+    ``k`` largest of ``E_all`` sum to ``k / E_all`` at least and take
+    none), times ``scaling``. All float32, the product at the highest
+    precision: the choice is a function of these numbers alone, so a
+    recomputation chooses as the forward pass did."""
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"router score {score!r} is not sigmoid or softmax")
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     ranked = s if bias is None else s + bias.astype(jnp.float32)
     _, chosen = lax.top_k(lax.stop_gradient(ranked), top_k)
     # the chosen experts' scores, picked out by comparison: a gather of
@@ -221,7 +227,8 @@ def route_top_k(x: jax.Array, router: jax.Array, bias, top_k: int,
     picked = chosen[..., None] == jnp.arange(s.shape[-1])
     weights = jnp.sum(jnp.where(picked, s[:, None, :], 0.0), -1)
     if norm_topk:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-6)
+        total = jnp.sum(weights, -1, keepdims=True)
+        weights = weights / (total + 1e-6 if score == "sigmoid" else total)
     return chosen, weights * scaling
 
 
@@ -406,7 +413,8 @@ def balanced_bias(bias: jax.Array, load: jax.Array, rate: float
 def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
                    top_k: int, dtype, bias=None, norm_topk: bool = True,
                    scaling: float = 1.0, block_rows: int | None = None,
-                   norm_scale=None, norm_eps: float = 1e-5, mesh=None
+                   norm_scale=None, norm_eps: float = 1e-5, mesh=None,
+                   score: str = "sigmoid"
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """What the experts held here add to every token: ``x [T, D]``
     (float32) -> ``([T, D] float32, stats)``.
@@ -419,7 +427,8 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
     ``norm_scale`` the router and the experts read ``m = rms(x;
     norm_scale)`` (the layer's norm, formed again in the backward pass, so
     that ``x`` alone is kept), else ``x``. Every token is routed over all
-    ``E_all`` experts (:func:`route_top_k`); of its ``top_k`` slots those
+    ``E_all`` experts (:func:`route_top_k`, by sigmoid scores or, ``score``,
+    a softmax's); of its ``top_k`` slots those
     whose expert lives here are kept, put in the order of their experts,
     and the three products run over exactly those rows
     (``ops.layers.grouped_matmul``); each row's result, times its weight,
@@ -464,7 +473,8 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
     @jax.checkpoint
     def route(m, router, bias):
         with jax.named_scope("route"):
-            return route_top_k(m, router, bias, top_k, norm_topk, scaling)
+            return route_top_k(m, router, bias, top_k, norm_topk, scaling,
+                               score)
 
     m = normed(x, norm_scale)
     chosen, weights = route(m, params["router"], bias)

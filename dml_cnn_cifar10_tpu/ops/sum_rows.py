@@ -25,9 +25,10 @@ keeps a row, and that shape is what :func:`sum_rows_by_token` reads the
 path from. Neither path reads a row outside ``lo .. hi`` into its sums, so
 the buffer's other rows may hold anything.
 
-Who takes the kernel: a TPU backend, one device, a width that fills whole
-tiles (a multiple of 1,024) and tokens that fill the sublanes (a multiple
-of 8) and divide into tiles. Everything else runs ``k`` masked gathers summed in the same order,
+Who takes the kernel: a TPU backend, one device, a width of whole lanes
+(a multiple of 128; a row whose ``D / 128`` is no multiple of 8 is padded
+to whole tiles by the buffer's layout) and tokens that fill the sublanes (a
+multiple of 8) and divide into tiles. Everything else runs ``k`` masked gathers summed in the same order,
 which is also the kernel's test oracle.
 """
 
@@ -44,8 +45,13 @@ from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 _LANES = 128
 _SUBLANES = 8
-#: Budget of a tile's fetched rows in vector memory (k x tile x D float32).
-_ROWS_BYTES = 8 << 20
+#: Budget of a tile's fetched rows in vector memory (k x tile x D float32):
+#: with the sums and the output's blocks a quarter of the limit asked for.
+_ROWS_BYTES = 12 << 20
+#: A tile's slots in scalar memory are one block of a 1-D int32 array, and
+#: XLA lays such an array out in tiles of 1,024 (``T(1024)``): Mosaic
+#: refuses a block of 512 of it for the described v5e.
+_SCALAR_BLOCK = 1024
 _VMEM_LIMIT = 64 << 20
 #: Tokens whose sums leave for the output block at once.
 _CHUNK = 64
@@ -58,7 +64,7 @@ def row_shape(tokens: int, k: int, width: int, mesh=None) -> tuple:
     ``(width,)``. ``mesh`` is the mesh of the enclosing GSPMD program, if
     any; the choice reads the platform, the shapes and the mesh only, and
     is noted for the step's line."""
-    if not (platform_lib.on_tpu() and width % (_SUBLANES * _LANES) == 0
+    if not (platform_lib.on_tpu() and width % _LANES == 0
             and tokens % _SUBLANES == 0 and _tile(tokens, k, width)):
         kernel_paths.note("experts", "xla")
         return (width,)
@@ -99,7 +105,7 @@ def _tile(tokens: int, k: int, width: int):
     """Tokens a grid step: all of them where their rows fit the budget,
     else the largest power of two times 8 that divides ``tokens``, keeps
     the fetched rows inside the budget and a tile's ``k`` slots a token a
-    multiple of 128 (a block of scalar memory); None where there is none."""
+    multiple of :data:`_SCALAR_BLOCK`; None where there is none."""
     def fits(tile):
         return tile * k * width * 4 <= _ROWS_BYTES
 
@@ -107,7 +113,7 @@ def _tile(tokens: int, k: int, width: int):
         return tokens
     best, tile = None, _SUBLANES
     while tokens % tile == 0 and fits(tile):
-        if tile * k % _LANES == 0:
+        if tile * k % _SCALAR_BLOCK == 0:
             best = tile
         tile *= 2
     return best

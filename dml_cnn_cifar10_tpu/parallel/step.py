@@ -98,7 +98,7 @@ def init_train_state(
     def build(key):
         params = model_def.init(key, model_cfg, data_cfg)
         opt = optim_lib.sgd_init(params, optim_cfg)
-        model_state = model_def.init_state(params)
+        model_state = model_def.init_state(params, model_cfg)
         if optim_cfg.ema_decay and model_def.has_state and model_state:
             # BatchNorm running stats track the RAW param trajectory; eval
             # with EMA params needs matching averaged stats, so the EMA

@@ -1060,7 +1060,8 @@ class Trainer:
                             # fetch would drain the device queue twice).
                             fused_keys = sorted(
                                 mk for mk in metrics
-                                if mk.startswith(("moe_", "health_")))
+                                if mk.startswith(("moe_", "attn_",
+                                                  "health_")))
                             parts = [jnp.reshape(metrics["loss"], (1,)),
                                      jnp.reshape(
                                          jnp.asarray(acc_arr, jnp.float32),

@@ -375,6 +375,9 @@ LAYER_KINDS = (
     ("short_conv", re.compile(r"^short_conv$")),
     ("route", re.compile(r"^(route|dispatch|combine)$")),
     ("expert", re.compile(r"^experts$")),
+    # attention under a sliding window, where a stack has both kinds (its
+    # norm stays `norm`; the sublayer's scopes are `attn`'s)
+    ("window_attention", re.compile(r"^attn_window$")),
 )
 PASSES = ("forward", "backward", "update", "other")
 

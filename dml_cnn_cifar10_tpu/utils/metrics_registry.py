@@ -369,7 +369,12 @@ def _observe_record(kind: str, f: dict, reg: MetricsRegistry) -> None:
                 ("moe_buffer_rounds",
                  "Times an expert layer filled its buffer of rows and "
                  "summed it by token, mean over the expert layers (1: "
-                 "the load was within the buffer)")):
+                 "the load was within the buffer)"),
+                # a stack with window layers (models/hybrid_decoder.py)
+                ("attn_window_blocks_frac",
+                 "Block pairs the window layers' flash schedule visits "
+                 "over those the causal schedule visits at the same "
+                 "sizes")):
             if f.get(key) is not None:
                 reg.gauge("dml_" + key, help_text).set(f[key])
     elif kind == "goodput":

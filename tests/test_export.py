@@ -60,7 +60,7 @@ def test_export_resnet_with_bn_state(rng):
     model_cfg = ModelConfig(name="resnet18", logit_relu=False)
     data_cfg = DataConfig(normalize="scale")
     params = model_def.init(jax.random.key(0), model_cfg, data_cfg)
-    mstate = model_def.init_state(params)
+    mstate = model_def.init_state(params, model_cfg)
     blob = export_lib.export_forward(model_def, model_cfg, data_cfg, params,
                                      model_state=mstate)
     served = export_lib.load_exported_bytes(blob)

@@ -62,7 +62,7 @@ def _leaves(tree):
 
 def _state(params, seed=11):
     """A state whose biases are away from their initial zero."""
-    state = m.init_state(params)
+    state = m.init_state(params, CFG)
     keys = iter(jax.random.split(jax.random.key(seed), 8))
     return jax.tree.map(
         lambda b: 0.05 * jax.random.normal(next(keys), b.shape), state)
@@ -274,7 +274,7 @@ def _rows_of_tokens(tokens, k, seed):
     return jnp.asarray(pos), here
 
 
-@pytest.mark.parametrize("width", [256, 2048])
+@pytest.mark.parametrize("width", [256, 2048, 2304])
 @pytest.mark.parametrize("cut", ["whole", "cuts_a_tokens_rows", "empty",
                                  "second_round"])
 def test_each_token_sums_the_rows_it_has_in_the_buffer(width, cut):
@@ -310,7 +310,7 @@ def test_each_token_sums_the_rows_it_has_in_the_buffer(width, cut):
 
 
 def test_who_takes_the_kernel(monkeypatch):
-    """A TPU backend, one device, a width of whole tiles; the buffer's
+    """A TPU backend, one device, a width of whole lanes; the buffer's
     shape carries the choice to the sum."""
     from dml_cnn_cifar10_tpu.ops import kernel_paths
     from dml_cnn_cifar10_tpu.utils import platform as platform_lib
@@ -324,12 +324,16 @@ def test_who_takes_the_kernel(monkeypatch):
         assert rec == {"experts": "pallas sum-by-token"}
         assert sum_rows.row_shape(64, 4, 2048, four) == (2048,)
         assert rec == {"experts": "xla (mesh)"}
-        assert sum_rows.row_shape(64, 4, 2048 + 128) == (2048 + 128,)
+        # 2,304 = 18 lanes: a row of two tiles and a quarter
+        assert sum_rows.row_shape(64, 4, 2304, one) == (18, 128)
+        assert sum_rows.row_shape(64, 4, 2048 + 64) == (2048 + 64,)
         assert sum_rows.row_shape(60, 4, 2048) == (2048,)
         # 8,200 tokens of 3 choices: no tile of them is a block of scalars
         assert sum_rows._tile(8200, 3, 2048) is None
         assert sum_rows.row_shape(8200, 3, 2048) == (2048,)
         assert sum_rows._tile(32768, 4, 2048) == 256
+        # a tile's slots are a block of 1,024 scalars, whatever k is
+        assert sum_rows._tile(32768, 8, 2304) == 128
         assert rec == {"experts": "xla"}
 
 
@@ -430,7 +434,7 @@ def test_the_short_convolution_is_causal_and_a_plain_depthwise_one():
 
 def _attention(a, p, heads, kv_heads, use_pallas=False):
     return attention_lib.causal_self_attention(
-        a, p, heads=heads, kv_heads=kv_heads, head_dim=16, rope_theta=1e6,
+        a, p, heads=heads, kv_heads=kv_heads, head_dim=16, rope=1e6,
         low=jnp.float32, use_pallas=use_pallas, norm_eps=1e-5)
 
 
