@@ -117,6 +117,13 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Both paths differentiate (the flash path via its custom_vjp backward
     kernels) and both honor ``causal``.
 
+    ``k`` and ``v`` may hold fewer heads than ``q``, ``[B, S, Hk, D]`` with
+    ``H % Hk == 0``: query head ``j`` reads key/value head ``j // (H //
+    Hk)``. The flash kernels find it in their index maps and sum a group's
+    key/value gradients in float32 before their one store; the XLA path
+    repeats the heads, which is the definition the kernels are tested
+    against (the repeat's transpose sums the group's gradients).
+
     ``mesh`` is the mesh of the enclosing GSPMD program, if any (callers
     already inside a ``shard_map`` pass none). A compiled ``pallas_call``
     cannot sit bare in a program partitioned over more than one device,
@@ -124,11 +131,16 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     mesh: batch over ``data``, heads over ``model`` — attention is
     independent per (batch, head), so each device runs the kernel on
     its own slice with no collective. A dim its axis does not divide is
-    replicated instead (and the printed path says so)."""
+    replicated instead (and the printed path says so); the heads' axis
+    has to divide the key/value heads as well as the query heads."""
     b, seq, h, _ = q.shape
+    hk = k.shape[2]
+    group = h // hk
     size = f"({seq} tokens)" + (f", window {window}" if window else "")
     if not (use_pallas and seq >= 128):
         kernel_paths.note("attention", f"xla {size}")
+        if group > 1:
+            k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         return xla_attention(q, k, v, scale=scale, causal=causal,
                              segment_ids=segment_ids, window=window)
     from dml_cnn_cifar10_tpu.ops import flash_attention as fa
@@ -139,12 +151,14 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     flash = functools.partial(fa.flash_attention, scale=scale,
                               causal=causal, window=window,
                               interpret=interpret)
-    path = f"flash{'-interpret' if interpret else ''} {size}"
+    path = f"flash{'-interpret' if interpret else ''} {size}" + (
+        f", {group} query heads a key/value head" if group > 1 else "")
     if mesh is None or mesh.size == 1:
         kernel_paths.note("attention", path)
         return flash(q, k, v, segment_ids=segment_ids)
     bax = "data" if b % mesh.shape["data"] == 0 else None
-    hax = "model" if h % mesh.shape["model"] == 0 else None
+    heads_axis = mesh.shape["model"]
+    hax = "model" if h % heads_axis == 0 and hk % heads_axis == 0 else None
     kernel_paths.note(
         "attention", f"{path}/shard_map[batch/{bax}, heads/{hax}]")
     qkv = P(bax, None, hax, None)
@@ -166,9 +180,11 @@ def causal_self_attention(a: jax.Array, p, *, heads: int, kv_heads: int,
     all heads). ``q = a wq``, ``k = a wk``, ``v = a wv``, no bias; rotary
     positions on ``q`` and ``k`` by the rule ``rope`` (a number, theta, or
     a mapping: ``ops.layers.rope_frequencies``, plain or YaRN); query head
-    ``j`` reads key/value head ``j // (heads / kv_heads)``: the key/value
-    heads are repeated before the kernel, and the repeat's transpose sums
-    each group's gradient; scores ``q k^T / sqrt(head_dim)`` under the mask
+    ``j`` reads key/value head ``j // (heads / kv_heads)``: ``k`` and ``v``
+    go to :func:`dispatch_attention` at their own head count (the flash
+    kernels find a query head's key/value head in their index maps and sum
+    each group's gradient in float32 inside the dK/dV kernel; only the XLA
+    path repeats the heads); scores ``q k^T / sqrt(head_dim)`` under the mask
     ``col <= row`` and, with ``window``, also ``col > row - window`` (a
     token sees itself and the ``window - 1`` before it); softmax in
     float32; ``concat(heads) wo``. Products of operands rounded to ``low``,
@@ -186,12 +202,8 @@ def causal_self_attention(a: jax.Array, p, *, heads: int, kv_heads: int,
     with jax.named_scope("rotary"):
         q, k = (rotary(t, rope).astype(low) for t in (q, k))
     with jax.named_scope("flash"):
-        v = v.astype(low)
-        if kv_heads != heads:
-            k, v = (jnp.repeat(t, heads // kv_heads, axis=2)
-                    for t in (k, v))
-        o = dispatch_attention(q, k, v, use_pallas=use_pallas, causal=True,
-                               window=window, mesh=mesh)
+        o = dispatch_attention(q, k, v.astype(low), use_pallas=use_pallas,
+                               causal=True, window=window, mesh=mesh)
     with jax.named_scope("out"):
         return mixed_matmul(
             o.reshape(b, s, heads * head_dim).astype(jnp.float32), p["wo"],

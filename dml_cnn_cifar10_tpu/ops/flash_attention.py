@@ -18,6 +18,15 @@ materializes in HBM in either direction:
   * dK/dV kernel — grid (b·h, k_blocks, q_blocks): accumulates
     ``dV_j = Σ_i P_ijᵀ dO_i`` and ``dK_j = Σ_i dS_ijᵀ Q_i · scale``.
 
+Grouped key/value heads: ``k`` and ``v`` may come with fewer heads than
+``q``, ``[B, S, Hk, D]`` with ``H % Hk == 0``; query head ``j`` reads
+key/value head ``j // (H // Hk)``. Nothing is repeated: the forward and
+dQ passes find the key/value head in their index maps, and the dK/dV
+pass runs over the ``B·Hk`` key/value heads, each key block visiting the
+whole group's query blocks before its one store, so a group's gradients
+are summed in the float32 scratch. Equal head counts are the same program
+as before the kernels took groups.
+
 ``flash_attention`` carries a ``jax.custom_vjp`` wiring the three kernels
 together, so the whole long-context stack (ViT blocks, Ulysses all-to-all
 attention, ring attention's per-block engine) differentiates. The
@@ -122,14 +131,39 @@ def _static_kv_start(kv_start):
     return int(kv_start)
 
 
-def _to_bh(x, block):
-    """[B, S, H, D] → [B·H, S_padded, D], S padded to a ``block`` multiple."""
+def _group(q, k):
+    """``H // Hk`` of ``q [B, S, H, D]`` over ``k [B, Skv, Hk, D]``: query
+    head ``j`` reads key/value head ``j // group``. A fact of the shapes,
+    not an argument; 1 is plain multi-head attention."""
+    h, hk = q.shape[2], k.shape[2]
+    if hk < 1 or h % hk:
+        raise ValueError(
+            f"{h} query heads do not divide into groups over {hk} "
+            f"key/value heads: the first must be a multiple of the second")
+    return h // hk
+
+
+def _to_bh(x, block, group=1):
+    """[B, S, H, D] → [B·H, S_padded, D], S padded to a ``block`` multiple.
+    The key/value side of a call with grouped heads (``group`` > 1) keeps
+    batch and heads apart, ``[B, Hk, S_padded, D]``: every 3-D
+    ``[rows, S, D]`` operand or result of a kernel is then a query-side
+    one, ``rows = B·H``, which is what a reader of the kernel's trace event
+    takes for the work done."""
     b, s, h, d = x.shape
-    x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, s, d)
+    x = jnp.transpose(x, (0, 2, 1, 3))
+    if group == 1:
+        x = x.reshape(b * h, s, d)
     pad = (-s) % block
     if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 2) + ((0, pad), (0, 0)))
     return x
+
+
+def _kv_block(bk, d, group):
+    """Block shape of one key/value head's ``[bk, D]`` tile in
+    :func:`_to_bh`'s layout; the kernels see ``[1, bk, D]`` either way."""
+    return (1, bk, d) if group == 1 else (None, 1, bk, d)
 
 
 def _from_bh(x, b, s, h):
@@ -137,6 +171,13 @@ def _from_bh(x, b, s, h):
     x = x[:, :s]
     x = x.reshape(b, h, s, *x.shape[2:])
     return jnp.swapaxes(x, 1, 2)
+
+
+def _kv_from_bh(x, b, s, hk):
+    """:func:`_to_bh`'s key/value layout → [B, S, Hk, D]."""
+    if x.ndim == 3:
+        return _from_bh(x, b, s, hk)
+    return jnp.swapaxes(x[:, :, :s], 1, 2)
 
 
 def _stat_to_tile(x, block):
@@ -444,11 +485,20 @@ import numpy as _np
 
 
 @functools.lru_cache(maxsize=256)
-def _fold_schedule(nq, nk, bq, bk, causal, window, major="q", kv_start=0):
+def _fold_schedule(nq, nk, bq, bk, causal, window, major="q", kv_start=0,
+                   group=1):
     """The folded (live-blocks-only) grid schedule → int32 ``[4, T]``
     rows ``(outer_block, inner_block, is_first, is_last)`` — or ``None``
     when nothing can be skipped (full attention runs the plain
     rectangular grid: no SMEM prefetch needed).
+
+    ``group`` > 1 is the dK/dV pass over grouped heads: under each outer
+    (key) block the live inner blocks come ``group`` times over, once for
+    each query head that reads this key/value head, ``is_first`` only on
+    the first tick of member 0 and ``is_last`` only on the last of member
+    ``group - 1``, so the accumulators sum the whole group before their
+    one store. A fifth row names the member, and full attention gets a
+    schedule too (every block live): ``[5, T · group]``, never ``None``.
 
     Instead of walking the full ``outer × inner`` rectangle and
     ``pl.when``-skipping dead band blocks (which still pay per-grid-step
@@ -464,7 +514,7 @@ def _fold_schedule(nq, nk, bq, bk, causal, window, major="q", kv_start=0):
     finalizes as dead (zero output, LARGE lse). ``kv_start`` shifts
     the K/V columns' global coordinates (ring window steps attend a
     neighbor shard whose columns sit ``±S_local`` away)."""
-    if not causal and window is None:
+    if not causal and window is None and group == 1:
         return None
     ticks = []
     n_outer, n_inner = (nq, nk) if major == "q" else (nk, nq)
@@ -472,15 +522,18 @@ def _fold_schedule(nq, nk, bq, bk, causal, window, major="q", kv_start=0):
         cols = []
         for c in range(n_inner):
             i, j = (r, c) if major == "q" else (c, r)
-            if bool(_band_live(i * bq, bq, kv_start + j * bk, bk, causal,
-                               window)):
+            live = _band_live(i * bq, bq, kv_start + j * bk, bk, causal,
+                              window)
+            if live is None or bool(live):
                 cols.append(c)
         if not cols:
             cols = [0]
-        for n, c in enumerate(cols):
-            ticks.append((r, c, 1 if n == 0 else 0,
-                          1 if n == len(cols) - 1 else 0))
-    return _np.asarray(ticks, _np.int32).T.copy()
+        last = (group - 1, len(cols) - 1)
+        for member in range(group):
+            for n, c in enumerate(cols):
+                ticks.append((r, c, int((member, n) == (0, 0)),
+                              int((member, n) == last), member))
+    return _np.asarray(ticks, _np.int32).T[:4 if group == 1 else 5].copy()
 
 
 def _band_live(row0, rows, col0, cols, causal, window):
@@ -530,7 +583,8 @@ def _norm_segments(segment_ids):
     return seg, seg
 
 
-def _index_maps(folded: bool, h: int, q_major: bool = True):
+def _index_maps(folded: bool, h: int, q_major: bool = True,
+                group: int = 1):
     """The four pallas index maps (q-side, kv-side, and their segment-id
     variants) for one kernel pass — ONE definition so the folded/rect and
     q-major/k-major variants cannot drift (round-4 review finding).
@@ -540,23 +594,34 @@ def _index_maps(folded: bool, h: int, q_major: bool = True):
     inner — which is (q, k) for the q-major passes (forward, dQ) and
     (k, q) for the k-major dK/dV pass. Rect grids read the grid indices
     directly, whose order is (outer, inner) the same way. Segment maps
-    fold the head out of the batch·head grid axis (ids are per batch)."""
+    fold the head out of the batch·head grid axis (ids are per batch).
+
+    Grouped heads (``group`` > 1; ``h`` query heads, the key/value side
+    ``[B, Hk, S, D]``, see :func:`_to_bh`): the q-major passes' first grid
+    axis still runs over the ``B·H`` query heads ``g`` and only the
+    key/value side's map changes, to batch ``g // h`` and key/value head
+    ``g % h // group``. The k-major pass's first axis runs over the
+    ``B·Hk`` key/value heads ``g``; its schedule (always folded,
+    :func:`_fold_schedule`) names the group's member ``r`` in row 4 and
+    the query side reads head ``g · group + r``."""
     qrow, krow = (0, 1) if q_major else (1, 0)
     if folded:
-        qi = lambda g, t, info: (g, info[qrow, t], 0)         # noqa: E731
-        kj = lambda g, t, info: (g, info[krow, t], 0)         # noqa: E731
-        qi_seg = lambda g, t, info: (g // h, info[qrow, t], 0)  # noqa: E731
-        kj_seg = lambda g, t, info: (g // h, 0, info[krow, t])  # noqa: E731
-    elif q_major:
-        qi = lambda g, i, j: (g, i, 0)                        # noqa: E731
-        kj = lambda g, i, j: (g, j, 0)                        # noqa: E731
-        qi_seg = lambda g, i, j: (g // h, i, 0)               # noqa: E731
-        kj_seg = lambda g, i, j: (g // h, 0, j)               # noqa: E731
+        q_blk = lambda t, info: info[qrow, t]                 # noqa: E731
+        k_blk = lambda t, info: info[krow, t]                 # noqa: E731
     else:
-        qi = lambda g, j, i: (g, i, 0)                        # noqa: E731
-        kj = lambda g, j, i: (g, j, 0)                        # noqa: E731
-        qi_seg = lambda g, j, i: (g // h, i, 0)               # noqa: E731
-        kj_seg = lambda g, j, i: (g // h, 0, j)               # noqa: E731
+        q_blk = lambda *ij: ij[qrow]                          # noqa: E731
+        k_blk = lambda *ij: ij[krow]                          # noqa: E731
+    per_batch = h if q_major else h // group   # heads of axis 0 a batch
+    q_head = kv_head = lambda g, *a: (g,)                     # noqa: E731
+    if group > 1 and q_major:
+        kv_head = lambda g, *a: (g // h, g % h // group)      # noqa: E731
+    elif group > 1:
+        q_head = lambda g, t, info: (g * group + info[4, t],)  # noqa: E731
+        kv_head = lambda g, *a: (g // per_batch, g % per_batch)  # noqa: E731
+    qi = lambda g, *a: (*q_head(g, *a), q_blk(*a), 0)         # noqa: E731
+    kj = lambda g, *a: (*kv_head(g, *a), k_blk(*a), 0)        # noqa: E731
+    qi_seg = lambda g, *a: (g // per_batch, q_blk(*a), 0)     # noqa: E731
+    kj_seg = lambda g, *a: (g // per_batch, 0, k_blk(*a))     # noqa: E731
     return qi, kj, qi_seg, kj_seg
 
 
@@ -566,19 +631,22 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
 
     mode: "out" → out; "lse" → (out, lse [B,S,H]);
     "stats" → (acc, m, l) — the ring merge interface.
+    ``k, v`` [B, Skv, Hk, D] with ``H % Hk == 0``: only their index map
+    knows of the group (:func:`_index_maps`).
     ``segment_ids`` [B, S] int32 restricts attention to equal-id pairs
     (packed sequences).
     """
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
+    group = _group(q, k)
     kv_len = k.shape[1]
     bq, bk = min(block_q, s), min(block_k, kv_len)
 
     qb = _to_bh(q, bq)
-    kb_ = _to_bh(k, bk)
-    vb = _to_bh(v, bk)
-    spq, spk = qb.shape[1], kb_.shape[1]
+    kb_ = _to_bh(k, bk, group)
+    vb = _to_bh(v, bk, group)
+    spq, spk = qb.shape[1], kb_.shape[-2]
     nq, nk = spq // bq, spk // bk
     has_seg = segment_ids is not None
     sched = _fold_schedule(nq, nk, bq, bk, causal, window, "q",
@@ -588,11 +656,11 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
     kw = dict(scale=scale, kv_len=kv_len, q_len=s, block_q=bq, block_k=bk,
               causal=causal, window=window, kv_start=kv_start,
               has_segments=has_seg, folded=folded)
-    qi, kj, qi_seg, kj_seg = _index_maps(folded, h)
+    qi, kj, qi_seg, kj_seg = _index_maps(folded, h, group=group)
     in_specs = [
         pl.BlockSpec((1, bq, d), qi),
-        pl.BlockSpec((1, bk, d), kj),
-        pl.BlockSpec((1, bk, d), kj),
+        pl.BlockSpec(_kv_block(bk, d, group), kj),
+        pl.BlockSpec(_kv_block(bk, d, group), kj),
     ]
     inputs = [qb, kb_, vb]
     if has_seg:
@@ -823,9 +891,11 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                         segment_ids=None, window=None, kv_start: int = 0):
     """The flash backward as a standalone op: ``(dq, dk, dv)`` from saved
     forward state. ``lse``/``delta`` are [B, S, H] f32 — the row logsumexp
-    from the forward and ``rowsum(dO ∘ O)``. Exposed (not just wired into
-    the custom_vjp) because ring attention's backward reuses it per ring
-    step with the *global* lse/delta (parallel/ring_attention.py).
+    from the forward and ``rowsum(dO ∘ O)``. ``k, v`` and so ``dk, dv``
+    are ``[B, Skv, Hk, D]``, ``H % Hk == 0`` (see :func:`flash_attention`).
+    Exposed (not just wired into the custom_vjp) because ring attention's
+    backward reuses it per ring step with the *global* lse/delta
+    (parallel/ring_attention.py).
 
     ``out_dtype`` overrides the gradient dtype (default: match each
     input's). The ring backward passes f32 so its per-step partials are
@@ -837,6 +907,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
         q, scale, block_q, block_k, interpret)
     kv_start = _static_kv_start(kv_start)
     b, s, h, d = q.shape
+    group = _group(q, k)
+    hk = h // group
     kv_len = k.shape[1]
     bq, bk = min(block_q, s), min(block_k, kv_len)
     dq_dt = q.dtype if out_dtype is None else out_dtype
@@ -844,10 +916,10 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
     dv_dt = v.dtype if out_dtype is None else out_dtype
 
     qb, dob = _to_bh(q, bq), _to_bh(do, bq)
-    kb_, vb = _to_bh(k, bk), _to_bh(v, bk)
+    kb_, vb = _to_bh(k, bk, group), _to_bh(v, bk, group)
     lse_t = _stat_to_tile(lse.astype(jnp.float32), bq)
     delta_t = _stat_to_tile(delta.astype(jnp.float32), bq)
-    spq, spk = qb.shape[1], kb_.shape[1]
+    spq, spk = qb.shape[1], kb_.shape[-2]
     nq, nk = spq // bq, spk // bk
 
     has_seg = segment_ids is not None
@@ -859,9 +931,9 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
               has_segments=has_seg, folded=folded)
 
     # dQ pass: q-major — outer/inner = (q block i, k block j).
-    qi, kj, qi_seg, kj_seg = _index_maps(folded, h)
+    qi, kj, qi_seg, kj_seg = _index_maps(folded, h, group=group)
     q_spec_i = pl.BlockSpec((1, bq, d), qi)
-    kv_spec_j = pl.BlockSpec((1, bk, d), kj)
+    kv_spec_j = pl.BlockSpec(_kv_block(bk, d, group), kj)
     stat_spec_i = pl.BlockSpec((1, bq, 128), qi)
 
     in_specs = [q_spec_i, kv_spec_j, kv_spec_j, q_spec_i, stat_spec_i,
@@ -901,10 +973,24 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                 interpret=interpret,
             )(*inputs)
 
-    # dK/dV pass: k-major — outer/inner = (k block j, q block i).
-    qi2, kj2, qi2_seg, kj2_seg = _index_maps(folded, h, q_major=False)
+    # dK/dV pass: k-major — outer/inner = (k block j, q block i), the
+    # first grid axis over the B·Hk key/value heads. Grouped heads: each
+    # key block's inner ticks run over the group's members times the live
+    # query blocks (the schedule's job, so the kernel's body is the one of
+    # equal head counts), dk_scr/dv_scr sum the WHOLE GROUP in float32 and
+    # are rounded and stored once, [B, Hk, S, D] — where a repeat's
+    # transpose would sum `group` arrays already rounded to the operands'
+    # type. The LOAD-BEARING note in _fwd_call holds here as there: the
+    # scratch carries a key block's sums across its ticks only because
+    # the grid runs sequentially on one core.
+    sched_k = _fold_schedule(nq, nk, bq, bk, causal, window, "k",
+                             kv_start=kv_start, group=group)
+    folded_k = sched_k is not None      # full attention too, if grouped
+    kw = dict(kw, folded=folded_k)
+    qi2, kj2, qi2_seg, kj2_seg = _index_maps(folded_k, h, q_major=False,
+                                             group=group)
     q_spec = pl.BlockSpec((1, bq, d), qi2)
-    kv_spec = pl.BlockSpec((1, bk, d), kj2)
+    kv_spec = pl.BlockSpec(_kv_block(bk, d, group), kj2)
     stat_spec = pl.BlockSpec((1, bq, 128), qi2)
     in_specs2 = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
     if has_seg:
@@ -917,15 +1003,13 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
                    pltpu.VMEM((bk, d), jnp.float32)]
     with jax.named_scope(_kernel_name("bwd_dkv", window)):
-        if folded:
-            sched_k = _fold_schedule(nq, nk, bq, bk, causal, window, "k",
-                                     kv_start=kv_start)
+        if folded_k:
             dk, dv = pl.pallas_call(
                 functools.partial(_flash_bwd_dkv_kernel, **kw),
                 out_shape=dkv_shapes,
                 grid_spec=pltpu.PrefetchScalarGridSpec(
                     num_scalar_prefetch=1,
-                    grid=(b * h, sched_k.shape[1]),
+                    grid=(b * hk, sched_k.shape[1]),
                     in_specs=in_specs2,
                     out_specs=[kv_spec, kv_spec],
                     scratch_shapes=dkv_scratch),
@@ -942,8 +1026,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                 interpret=interpret,
             )(*inputs)
 
-    return (_from_bh(dq, b, s, h), _from_bh(dk, b, kv_len, h),
-            _from_bh(dv, b, kv_len, h))
+    return (_from_bh(dq, b, s, h), _kv_from_bh(dk, b, kv_len, hk),
+            _kv_from_bh(dv, b, kv_len, hk))
 
 
 def attention_delta(o, do):
@@ -1011,10 +1095,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
                     segment_ids: jax.Array | None = None,
                     window: int | None = None) -> jax.Array:
-    """FlashAttention over [B, S, H, D] tensors → [B, S, H, D].
+    """FlashAttention: ``q [B, S, H, D]``, ``k, v [B, Skv, Hk, D]`` →
+    ``[B, S, H, D]``, for any ``H % Hk == 0`` (the group ``H // Hk`` is
+    read from the shapes; query head ``j`` attends key/value head ``j //
+    group``; ``dk`` and ``dv`` come back ``[B, Skv, Hk, D]``, each group's
+    sum taken in float32 and rounded once).
 
-    Contract-identical to :func:`ops.attention.xla_attention` (including
-    under ``jax.grad`` — the custom_vjp runs the Pallas backward kernels);
+    At equal head counts contract-identical to
+    :func:`ops.attention.xla_attention` (including under ``jax.grad`` —
+    the custom_vjp runs the Pallas backward kernels), with fewer key/value
+    heads to the same on the heads repeated;
     tests assert numerical agreement of both values and gradients.
     Sequence lengths that aren't multiples of the block sizes are
     zero-padded and masked inside the kernels. ``causal=True`` masks above

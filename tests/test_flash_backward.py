@@ -414,3 +414,158 @@ def test_window_fully_dead_rows_are_finite_and_inert():
     g_ref = _grads(lambda q, k, v: attn.xla_attention(
         q, k, v, window=64)[:, :190], q, k, v)
     _assert_close(g_live, g_ref, atol=5e-6)
+
+
+# --- grouped key/value heads: k, v [B, S, Hk, D] under q [B, S, H, D] -------
+
+def _grouped(group, d, s=256, b=1, hk=2, seed=11, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, s, hk * group, d), dtype),
+            jax.random.normal(ks[1], (b, s, hk, d), dtype),
+            jax.random.normal(ks[2], (b, s, hk, d), dtype))
+
+
+def _assert_grouped_equals_repeated(q, k, v, **kw):
+    """The kernels on ``k, v`` at their own head count against the same
+    kernels on the heads repeated (the program before the kernels took
+    groups, and the XLA path's definition): values and all three gradients,
+    ``dk`` / ``dv`` the repeated heads' gradients summed over each group."""
+    b, s, hk, d = k.shape
+    group = q.shape[2] // hk
+    out = fa.flash_attention(q, k, v, **kw)
+    got = _grads(lambda *a: fa.flash_attention(*a, **kw), q, k, v)
+    assert got[1].shape == got[2].shape == (b, s, hk, d)
+    # the heads' own gradients, then the float32 sum over each group
+    repeated, per_head = jax.vjp(
+        lambda q, k, v: fa.flash_attention(q, k, v, **kw),
+        q, jnp.repeat(k, group, 2), jnp.repeat(v, group, 2))
+    np.testing.assert_allclose(out, repeated, atol=2e-6)
+    dq, dk, dv = per_head(jnp.cos(out))
+    want = (dq, *(t.reshape(b, s, hk, group, d).sum(3) for t in (dk, dv)))
+    _assert_close(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_grouped_heads_causal(group, d, window):
+    _assert_grouped_equals_repeated(*_grouped(group, d), causal=True,
+                                    window=window)
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("group", [4, 8])
+def test_grouped_heads_ragged_seq(group, window):
+    """200 tokens in blocks of 128: the padded rows and columns."""
+    _assert_grouped_equals_repeated(*_grouped(group, 64, s=200, b=2, hk=1),
+                                    causal=True, window=window)
+
+
+@pytest.mark.parametrize("group", [4, 8])
+def test_grouped_heads_full_attention(group):
+    """No mask: the forward and dQ passes walk the rectangular grid, the
+    dK/dV pass a schedule with every block live."""
+    _assert_grouped_equals_repeated(*_grouped(group, 64, b=2), causal=False)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_grouped_heads_segment_ids(causal):
+    q, k, v = _grouped(4, 64, b=2)
+    seg = jnp.stack([(jnp.arange(256) >= 100).astype(jnp.int32),
+                     (jnp.arange(256) >= 180).astype(jnp.int32)])
+    _assert_grouped_equals_repeated(q, k, v, causal=causal, segment_ids=seg)
+
+
+def test_grouped_heads_bf16_sum_is_rounded_once():
+    """bfloat16 operands: the group's ``dk`` / ``dv`` are one float32 sum
+    rounded once, so they lie at least as near the float32 gradients as
+    the repeat's transpose, a sum of eight bfloat16 arrays in bfloat16."""
+    q, k, v = _grouped(8, 64, hk=1, dtype=jnp.bfloat16)
+    do = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.bfloat16)
+    rep = [jnp.repeat(t, 8, 2) for t in (k, v)]
+    out, lse = fa.flash_attention_fwd_lse(q, k, v, causal=True)
+    delta = fa.attention_delta(out, do)
+    _, dk, dv = fa.flash_attention_bwd(q, k, v, do, lse, delta, causal=True)
+    assert dk.dtype == dv.dtype == jnp.bfloat16 and dk.shape == k.shape
+    heads32 = fa.flash_attention_bwd(q, *rep, do, lse, delta, causal=True,
+                                     out_dtype=jnp.float32)[1:]
+    heads16 = fa.flash_attention_bwd(q, *rep, do, lse, delta,
+                                     causal=True)[1:]
+    for got, h32, h16 in zip((dk, dv), heads32, heads16):
+        exact = h32.reshape(1, 256, 1, 8, 64).sum(3)
+        before = h16.reshape(1, 256, 1, 8, 64).sum(3)   # bfloat16 sum
+        err = float(jnp.linalg.norm(got.astype(jnp.float32) - exact))
+        err_before = float(jnp.linalg.norm(
+            before.astype(jnp.float32) - exact))
+        assert err <= err_before and err < 0.004 * float(
+            jnp.linalg.norm(exact))
+
+
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 300),
+                                           (False, 300), (False, None)])
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_dkv_schedule_visits_each_live_block_once_a_member(group, causal,
+                                                           window):
+    """Under every key block, exactly the live query blocks of equal head
+    counts, ``group`` times over in the members' order, with one
+    ``is_first`` (the first tick) and one ``is_last`` (the last)."""
+    nq = nk = 7
+    sched = fa._fold_schedule(nq, nk, 128, 128, causal, window, "k",
+                              group=group)
+    live = [[i for i in range(nq)
+             if fa._band_live(i * 128, 128, j * 128, 128, causal, window)
+             in (None, True)] for j in range(nk)]
+    assert all(live)
+    if group == 1:
+        base = fa._fold_schedule(nq, nk, 128, 128, causal, window, "k")
+        if not causal and window is None:
+            assert sched is None and base is None
+            return
+        np.testing.assert_array_equal(sched, base)
+        assert sched.shape[0] == 4
+    else:
+        assert sched.shape == (5, group * sum(map(len, live)))
+    ticks = sched.T.tolist()
+    for j in range(nk):
+        mine = [t for t in ticks if t[0] == j]
+        assert ticks.index(mine[0]) + len(mine) - 1 == ticks.index(mine[-1])
+        assert [t[1] for t in mine] == live[j] * group
+        if group > 1:
+            assert [t[4] for t in mine] == [
+                r for r in range(group) for _ in live[j]]
+        assert [t[2] for t in mine] == [1] + [0] * (len(mine) - 1)
+        assert [t[3] for t in mine] == [0] * (len(mine) - 1) + [1]
+
+
+@pytest.mark.parametrize("entry", ["flash_attention",
+                                   "flash_attention_fwd_lse",
+                                   "flash_attention_stats",
+                                   "flash_attention_bwd"])
+def test_head_counts_that_do_not_group_are_refused(entry):
+    q = jax.ShapeDtypeStruct((1, 256, 6, 64), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 256, 4, 64), jnp.float32)
+    stat = jax.ShapeDtypeStruct((1, 256, 6), jnp.float32)
+    args = (q, kv, kv) + ((q, stat, stat) if entry.endswith("bwd") else ())
+    with pytest.raises(ValueError, match=r"6 query heads.* 4 key/value"):
+        jax.eval_shape(getattr(fa, entry), *args)
+
+
+@pytest.mark.parametrize("hk,heads_axis", [(2, "model"), (1, None)])
+def test_dispatch_splits_grouped_heads_only_where_both_counts_divide(
+        hk, heads_axis):
+    """Under a mesh the heads' axis splits ``q`` and ``k, v`` alike, so it
+    has to divide the key/value heads too; else the heads are replicated,
+    the note says so, and the values are the same."""
+    from dml_cnn_cifar10_tpu.ops import kernel_paths
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    group = 4 // hk
+    q, k, v = _grouped(group, 16, s=128, b=2, hk=hk)
+    with kernel_paths.recording() as rec:
+        got = jax.jit(lambda q, k, v: attn.dispatch_attention(
+            q, k, v, use_pallas=True, causal=True, mesh=mesh))(q, k, v)
+    assert rec["attention"] == (
+        f"flash-interpret (128 tokens), {group} query heads a key/value "
+        f"head/shard_map[batch/data, heads/{heads_axis}]")
+    want = attn.xla_attention(q, jnp.repeat(k, group, 2),
+                              jnp.repeat(v, group, 2), causal=True)
+    np.testing.assert_allclose(got, want, atol=2e-5)

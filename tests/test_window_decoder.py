@@ -518,6 +518,24 @@ def test_the_dispatchs_note_says_the_window():
     assert kernel_paths.noted("attention") is None
 
 
+def test_the_flash_paths_note_names_the_grouping_and_keeps_its_rewrite(small):
+    """At 128 tokens through the kernels (the interpreter here): 4 query
+    heads over 2 key/value heads are named in the note, and the model's
+    rewrite of the window still finds its words in it."""
+    cfg, _ = small
+    q, kv = jnp.zeros((1, 128, 4, 16)), jnp.zeros((1, 128, 2, 16))
+    said = "flash-interpret (128 tokens), window 12, 2 query heads a " \
+        "key/value head"
+    with kernel_paths.recording() as rec:
+        attention_lib.dispatch_attention(q, kv, kv, use_pallas=True,
+                                         causal=True, window=WINDOW)
+        assert kernel_paths.noted("attention") == said
+        m._note_paths(m.sizes(cfg))
+    assert rec["attention"] == (
+        "flash-interpret (128 tokens), 2 query heads a key/value head, "
+        f"window {WINDOW} in 3 of 4 layers")
+
+
 def test_the_cli_trains_it_and_the_records_carry_the_gauge(small, tmp_path):
     """``python cifar10cnn.py --model hybrid_decoder --model_config_file
     ...`` through ``Trainer.fit`` on the resident K-step dispatch: the
