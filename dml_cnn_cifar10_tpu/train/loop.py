@@ -71,6 +71,22 @@ class Trainer:
                  fault_injector=None, cluster=None, alert_engine=None,
                  flight_recorder=None, logger=None, publish_hook=None,
                  autopilot=None):
+        # Host telemetry (utils/telemetry.py): ONE span stream for the
+        # object's life — its own making (`trainer_init`),
+        # `init_or_restore`, then every `fit` from set-up to teardown with
+        # the FLOP-probe thread and the garbage collector — emitted at the
+        # existing metrics boundaries with zero extra device fetches.
+        # Disabled, a span is a shared no-op context manager and nothing
+        # else exists.
+        self._tracer = telemetry_lib.SpanTracer(enabled=cfg.telemetry)
+        with self._tracer.span("trainer_init"):
+            self._build(cfg, mesh, task_index, fault_injector, cluster,
+                        alert_engine, flight_recorder, logger, publish_hook,
+                        autopilot)
+
+    def _build(self, cfg, mesh, task_index, fault_injector, cluster,
+               alert_engine, flight_recorder, logger, publish_hook,
+               autopilot):
         self.cfg = cfg
         self.task_index = task_index
         # Alert-driven remediation (autopilot/engine.py): injected by
@@ -140,8 +156,7 @@ class Trainer:
         # supervisor restart or elastic re-entry deserializes the
         # executables its predecessor compiled instead of recompiling.
         # The on_event hook feeds obtain-time into the goodput `compile`
-        # fraction (the tracer exists only while fit() runs).
-        self._tracer = None
+        # fraction (of the fit that is running: its clock starts anew).
         self.compile_cache = compilecache.CompileCache.from_config(
             cfg, logger=self.logger, on_event=self._note_compile_event)
         # One sharding tree, computed once, used everywhere state is placed
@@ -234,12 +249,10 @@ class Trainer:
 
     def _note_compile_event(self, ev: dict) -> None:
         """Compile-cache event hook: attribute obtain time (trace +
-        load-or-compile) to the goodput `compile` fraction. Only while a
-        fit()'s tracer is live — pre-loop compiles (init before the
-        tracer epoch) are logged as JSONL events but not attributed."""
-        tracer = self._tracer
-        if tracer is not None and tracer.enabled:
-            tracer.add_secs("compile", ev.get("compile_s") or 0.0)
+        load-or-compile) to the goodput `compile` fraction. Pre-loop
+        compiles (init, before a fit starts its goodput clock anew) are
+        logged as JSONL events but not attributed."""
+        self._tracer.add_secs("compile", ev.get("compile_s") or 0.0)
 
     def _register_scope_maps(self, compiled, state_abs, out_dir,
                              step: int) -> None:
@@ -265,6 +278,10 @@ class Trainer:
             print(f"[devprof] scope map not built: {e!r}", file=sys.stderr)
 
     def init_or_restore(self) -> step_lib.TrainState:
+        with self._tracer.span("init_or_restore"):
+            return self._init_or_restore()
+
+    def _init_or_restore(self) -> step_lib.TrainState:
         key = jax.random.key(self.cfg.seed)
         sharding = self.state_sharding if self.state_sharding is not None \
             else mesh_lib.replicated(self.mesh)
@@ -386,14 +403,8 @@ class Trainer:
 
     def fit(self, total_steps: Optional[int] = None,
             state: Optional[step_lib.TrainState] = None) -> TrainResult:
-        # Host telemetry (utils/telemetry.py): ONE span stream over the
-        # whole of fit — set-up, the loop, the FLOP-probe thread, the
-        # garbage collector, teardown — emitted at the existing metrics
-        # boundaries with zero extra device fetches. Disabled, a span is
-        # a shared no-op context manager and nothing else exists.
-        tracer = telemetry_lib.SpanTracer(enabled=self.cfg.telemetry)
-        self._tracer = tracer  # exposed for tests/diagnostics
-        tracer.watch_gc()
+        tracer = self._tracer
+        tracer.reopen()
         with contextlib.ExitStack() as setup:
             # `_fit` closes the stack where set-up ends; here it only
             # closes the span of a set-up that raised.

@@ -379,7 +379,7 @@ LAYER_KINDS = (
     # norm stays `norm`; the sublayer's scopes are `attn`'s)
     ("window_attention", re.compile(r"^attn_window$")),
 )
-PASSES = ("forward", "backward", "update", "other")
+PASSES = ("forward", "recompute", "backward", "update", "other")
 
 _HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
@@ -391,12 +391,18 @@ _HLO_CALLED = re.compile(
 _HLO_CALLED_LIST = re.compile(
     r"\b(branch_computations|called_computations)=\{([^}]*)\}")
 _HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_SAYS = re.compile(r'\b(?:custom_call_target="[^"]*"'
+                       r'|frontend_attributes=\{[^}]*\})')
 _TRANSFORM = re.compile(r"^(jvp|transpose|vmap|remat|checkpoint|"
                         r"custom_jvp|custom_vjp|shard_map)\((.*)\)$")
 _CALL = re.compile(r"^(jit|pjit|closed_call|core_call|custom_jvp_call|"
                    r"custom_vjp_call)(\(.*\))?$")
+#: What `jax.checkpoint` puts around the forward it forms again in the
+#: backward pass (jax 0.9.0); the transposed products beside it sit under
+#: `checkpoint` alone.
+_REMATTED = "rematted_computation"
 _PLUMBING = frozenset(("while", "body", "cond", "body_fun", "cond_fun",
-                       "branch", "scan", "checkpoint"))
+                       "branch", "scan", "checkpoint", _REMATTED))
 #: Instructions that move or name data and never decide what a fusion
 #: costs: left out when a fusion's layers are counted.
 _NO_WORK = frozenset((
@@ -412,7 +418,8 @@ class ScopeEntry(NamedTuple):
     pass_: str       # one of PASSES
     mixed: bool      # a fusion whose instructions come from several layers
     in_loop: bool    # an instruction of a `while` body or condition
-    inherited: bool = False   # an unnamed copy, named after its consumer
+    inherited: bool = False   # an unnamed copy, counted with its consumer
+    part: str = ""   # the scope after the one that decided the kind
 
 
 def _split_path(op_name: str) -> List[str]:
@@ -431,7 +438,18 @@ def _split_path(op_name: str) -> List[str]:
 
 
 def parse_op_name(op_name: str):
-    """An instruction's ``op_name`` -> ``(scope, kind, pass)``."""
+    """An instruction's ``op_name`` -> ``(scope, kind, pass, part)``.
+
+    ``part`` is the scope that follows the one that decided the kind
+    (``layer2/attn_window/rotary`` is kind ``window_attention``, part
+    ``rotary``; ``conv1`` has none). The pass is ``recompute`` where the
+    path lies under a ``transpose(`` and holds ``rematted_computation``: a
+    forward that ``jax.checkpoint`` forms again in the backward pass (the
+    optimized HLO of the three decoders' dispatches, compiled for a v5e,
+    names every product of a recomputed forward so, and the fusions around
+    them). What a ``custom_vjp`` forms again by its own backward rule (the
+    blockwise loss's logits, the experts' products in their written-out
+    backward loop) is under no ``jax.checkpoint`` and stays ``backward``."""
     # XLA joins the names of instructions it merged with ";".
     comps = _split_path(op_name.split(";", 1)[0])
     backward = any("transpose(" in c for c in comps)
@@ -439,30 +457,34 @@ def parse_op_name(op_name: str):
     if comps and not _TRANSFORM.match(comps[-1]):
         comps = comps[:-1]       # the primitive's own name
     scopes: List[str] = []
+    rematted = False
     for c in comps:
         m = _TRANSFORM.match(c)
         while m:
             c = m.group(2)
             m = _TRANSFORM.match(c)
-        for part in _split_path(c):
-            if not (_CALL.match(part) or part in _PLUMBING):
-                scopes.append(part)
-    kind = "none"
-    for part in reversed(scopes):
-        kind = next((k for k, pat in LAYER_KINDS if pat.match(part)), "none")
+        for piece in _split_path(c):
+            rematted = rematted or piece == _REMATTED
+            if not (_CALL.match(piece) or piece in _PLUMBING):
+                scopes.append(piece)
+    kind, part = "none", ""
+    for i in reversed(range(len(scopes))):
+        kind = next((k for k, pat in LAYER_KINDS if pat.match(scopes[i])),
+                    "none")
         if kind != "none":
+            part = scopes[i + 1] if i + 1 < len(scopes) else ""
             break
     if kind == "none" and relu:
         kind = "norm_act"        # a ReLU under no layer's scope
     if backward:
-        pass_ = "backward"
+        pass_ = "recompute" if rematted else "backward"
     elif "fwd_bwd" in scopes:
         pass_ = "forward"
     elif "optimizer" in scopes:
         pass_ = "update"
     else:
         pass_ = "other"
-    return "/".join(scopes), kind, pass_
+    return "/".join(scopes), kind, pass_, part
 
 
 class _Instr(NamedTuple):
@@ -472,6 +494,7 @@ class _Instr(NamedTuple):
     op_name: str
     called: Tuple[Tuple[str, str], ...]    # (attribute, computation)
     refs: Tuple[str, ...]                  # every %name the line mentions
+    says: str = ""     # a custom call's target and frontend attributes
 
 
 def _parse_hlo(text: str):
@@ -501,9 +524,12 @@ def _parse_hlo(text: str):
         for attr, names in _HLO_CALLED_LIST.findall(rest):
             called += [(attr, c.strip().lstrip("%"))
                        for c in names.split(",") if c.strip()]
-        cur.append(_Instr(m.group(2), op.group(1) if op else "",
+        opcode = op.group(1) if op else ""
+        cur.append(_Instr(m.group(2), opcode,
                           bool(m.group(1)), name.group(1) if name else "",
-                          tuple(called), tuple(_HLO_REF.findall(rest))))
+                          tuple(called), tuple(_HLO_REF.findall(rest)),
+                          " ".join(_HLO_SAYS.findall(rest))
+                          if opcode == "custom-call" else ""))
     return module, entry, comps
 
 
@@ -514,6 +540,16 @@ _COPIES = frozenset(("copy", "copy-start", "copy-done", "slice-start",
                      "slice-done"))
 _THROUGH = _COPIES | frozenset(("bitcast", "reshape", "tuple",
                                 "get-tuple-element", "custom-call"))
+
+#: Kernels the compiler makes of an operation and names anew: the custom
+#: call's metadata holds the new name and no scope, no source line, and its
+#: target is every Mosaic kernel's. Their kind, by what the instruction
+#: still says of itself (its name, its ``op_name``, its frontend
+#: attributes): ``lax.ragged_dot`` becomes ``ragged-dot-none.<n>`` with a
+#: ``ragged_dot_tiling`` and the scalar-core ``ragged-dot-metadata`` that
+#: feeds it, and ``ops/layers.grouped_matmul`` (the experts' grouped
+#: products) is its one caller.
+_RENAMED_KERNELS = ((re.compile(r"ragged[-_]dot"), "expert"),)
 
 #: Attributes whose computation runs as instructions of its own (events
 #: of the trace), against `calls=` of a fusion and the scalar `to_apply`
@@ -532,14 +568,16 @@ def scope_map_of_text(text: str):
     metadata and, where that names no layer, by its root's (then by the
     working instruction nearest the root that names one); one whose
     working instructions come from more than one layer is ``mixed``. A
-    copy without metadata (the compiler's prefetches and layout changes)
-    takes the layer of the instruction that consumes it."""
+    kernel the compiler named anew (``_RENAMED_KERNELS``) has the kind of
+    what it is and the pass its operands and users tell. A copy without
+    metadata (the compiler's prefetches and layout changes) takes the
+    layer of the instruction that consumes it: ``inherited``, a guess."""
     module, entry, comps = _parse_hlo(text)
 
     def layers_in(comp: str, found: list) -> None:
-        """``(scope, kind, pass)`` of the working instructions of a fused
-        computation that name a layer, nested fusions included, in the
-        order of the text (operands before their users)."""
+        """``(scope, kind, pass, part)`` of the working instructions of a
+        fused computation that name a layer, nested fusions included, in
+        the order of the text (operands before their users)."""
         for ins in comps.get(comp, ()):
             if ins.opcode == "fusion":
                 for attr, c in ins.called:
@@ -559,35 +597,56 @@ def scope_map_of_text(text: str):
                              if a == "calls"), "")
         return ""
 
-    def inherit(comp: str) -> None:
-        """An unnamed copy takes the layer of what consumes it, through
-        other unnamed movers, a few hops down the same computation. So
-        does a kernel the compiler made of an operation and named anew
-        (a grouped product becomes the custom call ``ragged-dot-none``,
-        whose metadata holds that name and no scope)."""
-        users: dict = {}
-        opcode = {}
+    def reached(start: str, edges: dict, opcode: dict, tells) -> list:
+        """The entries that ``tells`` holds for, nearest first, along
+        ``edges`` (users or operands) from ``start`` through unnamed
+        movers, a few hops within the same computation."""
+        told, front, seen = [], [start], {start}
+        for _ in range(4):
+            nxt = [n for f in front for n in edges.get(f, ())
+                   if n in out and n not in seen]
+            seen.update(nxt)
+            says = [n for n in nxt if tells(out[n])]
+            told += [out[n] for n in says]
+            front = [n for n in nxt if n not in says
+                     and out[n].kind == "none" and opcode.get(n) in _THROUGH]
+            if not front:
+                break
+        return told
+
+    def settle(comp: str, renamed: list) -> None:
+        """The passes of this computation's renamed kernels, then its
+        copies. A kernel's pass is what its operands and users tell: one
+        that a forward instruction reads is forward, one made from a
+        backward (or a recomputed) value is that, and where all that
+        tell anything agree it is theirs; else ``other``. An unnamed copy
+        takes the layer of the first named instruction that consumes it."""
+        users, operands, opcode = {}, {}, {}
         for ins in comps.get(comp, ()):
             opcode[ins.name] = ins.opcode
+            operands[ins.name] = ins.refs
             for ref in ins.refs:
                 users.setdefault(ref, []).append(ins.name)
+
+        def a_pass(e):
+            return e.pass_ != "other"
+
+        for name in renamed * 2:     # a kernel may feed another
+            made = {e.pass_ for e in reached(name, operands, opcode, a_pass)}
+            read = {e.pass_ for e in reached(name, users, opcode, a_pass)}
+            told = made | read
+            out[name] = out[name]._replace(pass_=(
+                "backward" if "backward" in made
+                else "recompute" if "recompute" in made
+                else "forward" if "forward" in read
+                else told.pop() if len(told) == 1 else "other"))
         for ins in comps.get(comp, ()):
-            if ins.opcode not in _COPIES | {"custom-call"} \
-                    or out[ins.name].kind != "none":
-                continue
-            front, seen = [ins.name], {ins.name}
-            for _ in range(4):
-                nxt = [u for n in front for u in users.get(n, ())
-                       if u in out and u not in seen]
-                named = [u for u in nxt if out[u].kind != "none"]
+            if ins.opcode in _COPIES and out[ins.name].kind == "none":
+                named = reached(ins.name, users, opcode,
+                                lambda e: e.kind != "none")
                 if named:
-                    e = out[named[0]]
-                    out[ins.name] = e._replace(mixed=False, inherited=True)
-                    break
-                seen.update(nxt)
-                front = [u for u in nxt if opcode.get(u) in _THROUGH]
-                if not front:
-                    break
+                    out[ins.name] = named[0]._replace(mixed=False,
+                                                      inherited=True)
 
     out: dict = {}
     todo, done = [(entry, False)], set()
@@ -596,13 +655,14 @@ def scope_map_of_text(text: str):
         if comp is None or comp in done:
             continue
         done.add(comp)
+        renamed = []
         for ins in comps.get(comp, ()):
-            scope, kind, pass_ = parse_op_name(ins.op_name)
+            scope, kind, pass_, part = parse_op_name(ins.op_name)
             mixed = False
             if ins.opcode == "fusion":
                 fused = [c for a, c in ins.called if a == "calls"]
                 if kind == "none" and fused:
-                    scope, kind, pass_ = parse_op_name(
+                    scope, kind, pass_, part = parse_op_name(
                         root_op_name(fused[0]) or ins.op_name)
                 found: list = []
                 for c in fused:
@@ -610,13 +670,20 @@ def scope_map_of_text(text: str):
                 if kind == "none" and found:
                     # a fusion the compiler made (a packed ReLU mask) whose
                     # root carries no name: the layer nearest the root
-                    scope, kind, pass_ = found[-1]
+                    scope, kind, pass_, part = found[-1]
                 mixed = len({f[0] for f in found}) > 1
-            out[ins.name] = ScopeEntry(scope, kind, pass_, mixed, in_loop)
+            elif ins.opcode == "custom-call" and kind == "none":
+                said = f"{ins.name} {ins.op_name} {ins.says}"
+                kind = next((k for pat, k in _RENAMED_KERNELS
+                             if pat.search(said)), "none")
+                if kind != "none":
+                    renamed.append(ins.name)
+            out[ins.name] = ScopeEntry(scope, kind, pass_, mixed, in_loop,
+                                       part=part)
             for attr, c in ins.called:
                 if attr in _RUNS.get(ins.opcode, ()):
                     todo.append((c, in_loop or ins.opcode == "while"))
-        inherit(comp)
+        settle(comp, renamed)
     return module, out
 
 
@@ -657,12 +724,14 @@ def register_scope_map(compiled, out_dir: Optional[str] = None,
         with open(path, "w") as f:
             json.dump({"module": module, "instructions": {
                 name: {"scope": e.scope, "kind": e.kind, "pass": e.pass_,
-                       "mixed": e.mixed, "in_loop": e.in_loop,
-                       "inherited": e.inherited}
+                       "part": e.part, "mixed": e.mixed,
+                       "in_loop": e.in_loop, "inherited": e.inherited}
                 for name, e in entries.items()}}, f)
     if logger is not None:
         logger.log("scopemap", step=step, module=module,
                    instructions=len(entries),
                    mapped=sum(e.kind != "none" for e in entries.values()),
-                   mixed=sum(e.mixed for e in entries.values()), path=path)
+                   mixed=sum(e.mixed for e in entries.values()),
+                   recompute=sum(e.pass_ == "recompute"
+                                 for e in entries.values()), path=path)
     return module

@@ -7,10 +7,12 @@ IS HEALTHY — the two questions a long multi-host job must answer without a
 profiler attached. Three coordinated pieces:
 
 - :class:`SpanTracer`: a ring-buffered context-manager tracer, ONE span
-  stream over the whole of ``Trainer.fit``: set-up (``fit_setup`` and its
-  children), the loop's phases (compile/first-dispatch, data wait,
-  dispatch enqueue, the boundary's accuracy dispatch / drain / logging,
-  eval, checkpoint, preemption allgather), the FLOP-probe thread, the
+  stream over a ``Trainer``'s life: its making (``trainer_init``),
+  ``init_or_restore``, and the whole of every ``fit``: set-up
+  (``fit_setup`` and its children), the loop's phases
+  (compile/first-dispatch, data wait, dispatch enqueue, the boundary's
+  accuracy dispatch / drain / logging, eval, checkpoint, preemption
+  allgather), the FLOP-probe thread, the
   collections of Python's garbage collector that can stall a loop
   (``gc_gen<n>``), teardown.
   Depth is kept per thread; a record names its thread where that is not
@@ -120,12 +122,14 @@ class SpanTracer:
     since the last drain so boundary flushes are incremental. Overflow is
     counted (``dropped``), never silent.
 
-    One tracer covers a whole ``fit``, its background threads included:
-    depth is kept per thread (a span of the FLOP-probe thread never shifts
-    the loop's nesting), and a record names its thread where that is not
-    the one the tracer was made on. Two clocks: span starts are relative
-    to ``_epoch`` (the tracer's creation, so set-up is inside it), the
-    goodput clock runs from :meth:`start` (where set-up ends).
+    One tracer covers a ``Trainer``'s life — its making, every ``fit``
+    (:meth:`reopen` .. :meth:`close`) and what runs between them — with
+    its background threads: depth is kept per thread (a span of the
+    FLOP-probe thread never shifts the loop's nesting), and a record names
+    its thread where that is not the one the tracer was made on. Two
+    clocks: span starts are relative to ``_epoch`` (the tracer's creation,
+    so set-up is inside it), the goodput clock runs from :meth:`start`
+    (where a fit's set-up ends).
     """
 
     def __init__(self, enabled: bool = True, max_spans: int = 65536):
@@ -187,6 +191,13 @@ class SpanTracer:
         if not self.enabled or secs <= 0:
             return
         self._cat_secs[cat] = self._cat_secs.get(cat, 0.0) + secs
+
+    def reopen(self) -> None:
+        """A ``fit`` begins (the first, or the next after a
+        :meth:`close`): finished spans wait for its boundary flushes again,
+        those from before it among them, and the collector is watched."""
+        self._sink = None
+        self.watch_gc()
 
     def watch_gc(self) -> None:
         """Record one span ``gc_gen<n>`` per collection of Python's

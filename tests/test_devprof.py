@@ -218,50 +218,98 @@ def test_profile_window_fail_open(tmp_path, capsys, monkeypatch):
 
 @pytest.mark.parametrize("op_name,want", [
     ("jit(chunk)/while/body/closed_call/fwd_bwd/jvp(conv1)/"
-     "conv_general_dilated", ("fwd_bwd/conv1", "conv", "forward")),
+     "conv_general_dilated", ("fwd_bwd/conv1", "conv", "forward", "")),
     ("jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(conv1))/"
-     "conv_general_dilated", ("fwd_bwd/conv1", "conv", "backward")),
+     "conv_general_dilated", ("fwd_bwd/conv1", "conv", "backward", "")),
     ("jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(pool1))/"
-     "select_and_scatter", ("fwd_bwd/pool1", "pool", "backward")),
+     "select_and_scatter", ("fwd_bwd/pool1", "pool", "backward", "")),
     ("jit(chunk)/while/body/closed_call/optimizer/sub",
-     ("optimizer", "optimizer", "update")),
+     ("optimizer", "optimizer", "update", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(stage3)/"
      "jvp(block2)/jvp(conv2)/conv_general_dilated",
-     ("fwd_bwd/stage3/block2/conv2", "conv", "forward")),
+     ("fwd_bwd/stage3/block2/conv2", "conv", "forward", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/"
      "transpose(jvp(stage1/block0))/transpose(jvp(shortcut))/"
      "transpose(jvp(bn))/mul",
-     ("fwd_bwd/stage1/block0/shortcut/bn", "norm_act", "backward")),
+     ("fwd_bwd/stage1/block0/shortcut/bn", "norm_act", "backward", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(stage1/block0)/"
      "jvp(shortcut)/add_any", ("fwd_bwd/stage1/block0/shortcut", "conv",
-                               "forward")),
+                               "forward", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(stem)/jvp(bn)/"
-     "jit(relu)/max", ("fwd_bwd/stem/bn", "norm_act", "forward")),
+     "jit(relu)/max", ("fwd_bwd/stem/bn", "norm_act", "forward", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(head)/jvp(fc)/"
-     "dot_general", ("fwd_bwd/head/fc", "dense", "forward")),
+     "dot_general", ("fwd_bwd/head/fc", "dense", "forward", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(head)/jvp(pool)/"
-     "reduce_sum", ("fwd_bwd/head/pool", "pool", "forward")),
+     "reduce_sum", ("fwd_bwd/head/pool", "pool", "forward", "")),
     ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(loss)/"
-     "jit(log_softmax)/reduce_max", ("fwd_bwd/loss", "dense", "forward")),
+     "jit(log_softmax)/reduce_max", ("fwd_bwd/loss", "dense", "forward", "")),
     ("jit(chunk_dev)/while/body/decode/_random_crop/nkw,nrwc->nrkc/"
      "dot_general", ("decode/_random_crop/nkw,nrwc->nrkc", "decode",
-                     "other")),
-    ("jit(chunk_dev)/index/while/body/xor", ("index", "decode", "other")),
-    ("jit(chunk_dev)/gather/gather", ("gather", "decode", "other")),
+                     "other", "_random_crop")),
+    ("jit(chunk_dev)/index/while/body/xor", ("index", "decode", "other", "")),
+    ("jit(chunk_dev)/gather/gather", ("gather", "decode", "other", "")),
     ("jit(ev)/train_acc/conv1/conv_general_dilated",
-     ("train_acc/conv1", "conv", "other")),
+     ("train_acc/conv1", "conv", "other", "")),
     # a ReLU under no layer's scope; an addition that is a primitive,
     # not the `add` scope; plumbing; nothing at all
     ("jit(chunk)/while/body/closed_call/fwd_bwd/jvp(jit(relu))/max",
-     ("fwd_bwd", "norm_act", "forward")),
+     ("fwd_bwd", "norm_act", "forward", "")),
     ("jit(chunk)/while/body/closed_call/fwd_bwd/add",
-     ("fwd_bwd", "none", "forward")),
-    ("jit(chunk)/while/body/dynamic_slice", ("", "none", "other")),
-    ("", ("", "none", "other")),
+     ("fwd_bwd", "none", "forward", "")),
+    ("jit(chunk)/while/body/dynamic_slice", ("", "none", "other", "")),
+    ("", ("", "none", "other", "")),
     # XLA joins the names of merged instructions with ";": the first
     ("jit(chunk)/while/body/closed_call/fwd_bwd/transpose(jvp(loss))/mul;"
      "fwd_bwd/transpose(jvp(loss))/broadcast_in_dim",
-     ("fwd_bwd/loss", "dense", "backward")),
+     ("fwd_bwd/loss", "dense", "backward", "")),
+    # recorded from the three decoders' dispatches, compiled for a v5e: a
+    # sublayer's part; a forward formed again under `jax.checkpoint` is its
+    # own pass and the marker is no scope; the transposed product beside it
+    # sits under `checkpoint` alone and stays backward
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer0)/while/body/"
+     "closed_call/attn_window/rotary/mul",
+     ("fwd_bwd/layer0/attn_window/rotary", "window_attention", "forward",
+      "rotary")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp())/while/"
+     "body/closed_call/pass/layer3/pass/layer3/checkpoint/"
+     "rematted_computation/attn/qkv/dot_general",
+     ("fwd_bwd/pass/layer3/pass/layer3/attn/qkv", "attention", "recompute",
+      "qkv")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp())/while/"
+     "body/closed_call/pass/layer3/pass/layer3/checkpoint/attn/qkv/"
+     "dot_general",
+     ("fwd_bwd/pass/layer3/pass/layer3/attn/qkv", "attention", "backward",
+      "qkv")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp(layer2))/"
+     "while/body/closed_call/checkpoint/rematted_computation/short_conv/in/"
+     "dot_general",
+     ("fwd_bwd/layer2/short_conv/in", "short_conv", "recompute", "in")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp(layer1))/"
+     "moe/fwd_bwd/jvp(layer1)/moe/checkpoint/rematted_computation/route/"
+     "top_k",
+     ("fwd_bwd/layer1/moe/fwd_bwd/layer1/moe/route", "route", "recompute",
+      "")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp())/while/"
+     "body/closed_call/pass/layer0/pass/layer0/checkpoint/attn/flash/"
+     "jit(flash_attention)/flash_bwd_dkv/pallas_call",
+     ("fwd_bwd/pass/layer0/pass/layer0/attn/flash/flash_bwd_dkv",
+      "attention", "backward", "flash")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer0)/moe/combine/"
+     "jit(sum_rows_pallas)/sum_rows_by_token/pallas_call",
+     ("fwd_bwd/layer0/moe/combine/sum_rows_by_token", "route", "forward",
+      "sum_rows_by_token")),
+    # what a `custom_vjp` forms again by its own backward rule (the
+    # experts' products in their written-out backward loop, the blockwise
+    # loss's logits) is under no `jax.checkpoint`: backward
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(fwd_bwd)/"
+     "jvp(layer4)/moe/while/body/experts/jvp()/convert_element_type",
+     ("fwd_bwd/fwd_bwd/layer4/moe/experts", "expert", "backward", "")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(fwd_bwd)/"
+     "jvp(head)/loss/while/body/closed_call/dot_general",
+     ("fwd_bwd/fwd_bwd/head/loss", "dense", "backward", "")),
+    # the marker outside any backward pass is plumbing and no more
+    ("jit(f)/fwd_bwd/jvp(layer0)/checkpoint/rematted_computation/mlp/tanh",
+     ("fwd_bwd/layer0/mlp", "mlp", "forward", "")),
 ])
 def test_parse_op_name(op_name, want):
     assert devprof.parse_op_name(op_name) == want
@@ -455,15 +503,151 @@ def test_register_scope_map_keeps_writes_and_announces(tmp_path):
     assert kind == "scopemap" and rec["step"] == 40
     assert (rec["module"], rec["instructions"], rec["mixed"]) \
         == ("jit_chunk", 21, 1)
-    assert rec["mapped"] == 9
+    assert rec["mapped"] == 9 and rec["recompute"] == 0
     assert rec["path"] == os.path.join(out, "scopemap_jit_chunk.json")
     with open(rec["path"]) as f:
         doc = json.load(f)
     assert doc["instructions"]["sas.3"] == {
         "scope": "fwd_bwd/pool1", "kind": "pool", "pass": "backward",
-        "mixed": False, "in_loop": True, "inherited": False}
+        "part": "", "mixed": False, "in_loop": True, "inherited": False}
     # no capture directory: kept and announced, nothing written
     devprof.register_scope_map(Compiled(), None, logger=Log(), step=41)
     assert seen[-1][1]["path"] is None
     devprof.clear_scope_maps()
     assert devprof.scope_maps() == {}
+
+
+# --- a sublayer's parts, the recomputed pass, a kernel named anew ----------
+
+@pytest.mark.parametrize("scope,kind,part", [
+    # the three decoders' kinds, each with the scope after the deciding one
+    ("pass/layer0/attn/qkv", "attention", "qkv"),
+    ("layer1/attn/qk_norm", "attention", "qk_norm"),
+    ("layer1/attn/rotary", "attention", "rotary"),
+    ("layer3/attn/flash/flash_fwd", "attention", "flash"),
+    ("layer1/attn/out", "attention", "out"),
+    ("layer2/attn_window/rotary", "window_attention", "rotary"),
+    ("layer2/attn_window/flash/flash_window_bwd_dq", "window_attention",
+     "flash"),
+    ("layer3/short_conv/in", "short_conv", "in"),
+    ("layer3/short_conv/gate_conv", "short_conv", "gate_conv"),
+    ("layer3/short_conv/out", "short_conv", "out"),
+    ("layer1/moe/combine/sum_rows_by_token", "route", "sum_rows_by_token"),
+    ("layer1/moe/dispatch", "route", ""), ("layer1/moe/route", "route", ""),
+    ("layer1/moe/experts", "expert", ""), ("layer0/mlp", "mlp", ""),
+    ("layer1/moe/ffn_norm", "norm", ""), ("exit/norm", "norm", ""),
+    ("embed", "embed", ""), ("exit/head", "exit_head", ""),
+    ("exit/gate", "exit_head", ""), ("exit/head/loss", "dense", ""),
+    # the image models' layers have no sublayer scopes
+    ("conv1", "conv", ""), ("pool1", "pool", ""), ("fc1", "dense", ""),
+    ("stage1/block0/shortcut/bn", "norm_act", ""),
+    ("stage3/block2/conv2", "conv", ""), ("head/fc", "dense", ""),
+])
+def test_the_part_is_the_scope_after_the_one_that_decided_the_kind(
+        scope, kind, part):
+    for path in (f"jit(step)/fwd_bwd/jvp({scope})/dot_general",
+                 f"jit(step)/fwd_bwd/transpose(jvp({scope}))/mul"):
+        assert devprof.parse_op_name(path)[1::2] == (kind, part), path
+
+
+def _toy_step(remat: bool):
+    import jax
+    import jax.numpy as jnp
+
+    def layer(p, x):
+        with jax.named_scope("mlp"):
+            return jnp.tanh(x @ p["a"]) @ p["b"]
+
+    def loss(p, x):
+        f = jax.checkpoint(layer) if remat else layer
+        for i in range(2):
+            with jax.named_scope(f"layer{i}"):
+                x = f(p, x)
+        return jnp.sum(x * x)
+
+    def step(p, x):
+        with jax.named_scope("fwd_bwd"):
+            return jax.grad(loss)(p, x)
+
+    f32 = jnp.float32
+    p = {"a": jax.ShapeDtypeStruct((16, 16), f32),
+         "b": jax.ShapeDtypeStruct((16, 16), f32)}
+    return jax.jit(step).lower(
+        p, jax.ShapeDtypeStruct((4, 16), f32)).compile()
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_only_a_checkpointed_step_has_recompute_instructions(remat):
+    m = devprof.scope_map(_toy_step(remat))
+    passes = {e.pass_ for e in m.values()}
+    assert {"forward", "backward"} <= passes <= set(devprof.PASSES)
+    again = [e for e in m.values() if e.pass_ == "recompute"]
+    assert bool(again) == remat
+    # the layer's own forward formed again: its kind, and no marker left
+    assert all(e.kind == "mlp" and "rematted" not in e.scope
+               and "checkpoint" not in e.scope for e in again)
+    assert not any("rematted" in e.scope for e in m.values())
+
+
+_HLO_EXPERTS = """HloModule jit_step, is_scheduled=true
+
+%fused_weigh (p0: f32[8,4]) -> f32[8,4] {
+  %p0 = f32[8,4]{1,0} parameter(0)
+  ROOT %mul.1 = f32[8,4]{1,0} multiply(%p0, %p0), metadata={op_name="jit(step)/fwd_bwd/transpose(fwd_bwd)/jvp(layer1)/moe/while/body/combine/mul"}
+}
+
+%fused_rows (p0.1: bf16[8,4]) -> bf16[8,4] {
+  %p0.1 = bf16[8,4]{1,0} parameter(0)
+  ROOT %gather.1 = bf16[8,4]{1,0} negate(%p0.1), metadata={op_name="jit(step)/fwd_bwd/transpose(fwd_bwd)/jvp(layer1)/moe/while/body/combine/gather"}
+}
+
+%fused_act (p0.2: f32[8,4]) -> bf16[8,4] {
+  %p0.2 = f32[8,4]{1,0} parameter(0)
+  ROOT %cv.1 = bf16[8,4]{1,0} convert(%p0.2), metadata={op_name="jit(step)/fwd_bwd/jvp(layer1)/moe/while/body/experts/convert_element_type"}
+}
+
+ENTRY %main (rows: bf16[8,4], w: bf16[2,4,4], sizes: s32[2]) -> (f32[8,4], bf16[8,4], f32[8,4]) {
+  %rows = bf16[8,4]{1,0} parameter(0)
+  %w = bf16[2,4,4]{2,1,0} parameter(1)
+  %sizes = s32[2]{0} parameter(2)
+  %ragged-dot-metadata = (s32[3]{0}, s32[1]{0}) custom-call(%sizes), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %gte.1 = s32[3]{0} get-tuple-element(%ragged-dot-metadata), index=0
+  %ragged-dot-none.4 = f32[8,4]{1,0} custom-call(%gte.1, %rows, %w), custom_call_target="tpu_custom_call", frontend_attributes={mosaic_fusion_entry_point="true",ragged_dot_tiling="512,512,256"}, metadata={op_name="ragged-dot-none"}
+  %act_fusion.1 = bf16[8,4]{1,0} fusion(%ragged-dot-none.4), kind=kLoop, calls=%fused_act, metadata={op_name="jit(step)/fwd_bwd/jvp(layer1)/moe/while/body/experts/convert_element_type"}
+  %rows_fusion.2 = bf16[8,4]{1,0} fusion(%rows), kind=kLoop, calls=%fused_rows, metadata={op_name="jit(step)/fwd_bwd/transpose(fwd_bwd)/jvp(layer1)/moe/while/body/combine/gather"}
+  %copy.7 = bf16[8,4]{1,0} copy(%rows_fusion.2)
+  %ragged-dot-none.3 = f32[8,4]{1,0} custom-call(%gte.1, %copy.7, %w), custom_call_target="tpu_custom_call", frontend_attributes={mosaic_fusion_entry_point="true",ragged_dot_tiling="512,512,256"}, metadata={op_name="ragged-dot-none"}
+  %copy.8 = f32[8,4]{1,0} copy(%ragged-dot-none.3)
+  %custom-call.9 = f32[8,4]{1,0} custom-call(), custom_call_target="AllocateBuffer"
+  %weigh_fusion.5 = f32[8,4]{1,0} fusion(%copy.8, %custom-call.9), kind=kLoop, calls=%fused_weigh, metadata={op_name="jit(step)/fwd_bwd/transpose(fwd_bwd)/jvp(layer1)/moe/while/body/combine/mul"}
+  %ragged-dot-none.6 = f32[8,4]{1,0} custom-call(%sizes, %rows, %w), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  ROOT %tuple.2 = (f32[8,4]{1,0}, bf16[8,4]{1,0}, f32[8,4]{1,0}) tuple(%weigh_fusion.5, %act_fusion.1, %ragged-dot-none.6)
+}
+"""
+
+
+def test_a_kernel_the_compiler_named_anew_keeps_its_own_kind():
+    """The experts' grouped products become ``ragged-dot-none.<n>`` with
+    no scope. One that feeds an instruction under ``moe/combine`` is an
+    expert's product of the backward pass (its rows come from there), not
+    the router's and no guess; an unnamed copy beside it still goes with
+    its consumer."""
+    _, m = devprof.scope_map_of_text(_HLO_EXPERTS)
+    assert m["weigh_fusion.5"][:3] == (
+        "fwd_bwd/fwd_bwd/layer1/moe/combine", "route", "backward")
+    dot = m["ragged-dot-none.3"]
+    assert (dot.scope, dot.kind, dot.pass_, dot.part) \
+        == ("", "expert", "backward", "")
+    assert not dot.inherited and not dot.mixed
+    # a forward instruction reads this one; the metadata's kernel feeds both
+    assert m["ragged-dot-none.4"][1:3] == ("expert", "forward")
+    assert m["ragged-dot-metadata"][1:3] == ("expert", "forward")
+    assert not m["ragged-dot-metadata"].inherited
+    # nothing around it tells a pass: the kind stands, the pass is open
+    assert m["ragged-dot-none.6"][1:3] == ("expert", "other")
+    # copies are counted with what consumes them, and say so
+    assert m["copy.8"] == m["weigh_fusion.5"]._replace(inherited=True)
+    assert m["copy.7"] == dot._replace(inherited=True)
+    # a custom call that is no kernel of the program's is nobody's
+    assert m["custom-call.9"][1:3] == ("none", "other")
+    assert not m["custom-call.9"].inherited

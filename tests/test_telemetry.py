@@ -451,6 +451,38 @@ def test_gc_hook_is_registered_only_while_an_enabled_tracer_watches():
     assert gc.callbacks == before
 
 
+def test_a_reopened_tracer_keeps_spans_for_the_next_flush_again():
+    """Between a fit's close and the next one's start whoever finishes a
+    span logs it; the next fit's spans wait for its boundaries."""
+    import gc
+
+    logged = []
+
+    class Log:
+        def log(self, kind, **fields):
+            logged.append(fields["name"])
+
+    before = list(gc.callbacks)
+    tr = SpanTracer(enabled=True)
+    with tr.span("trainer_init"):
+        pass
+    tr.reopen()
+    assert len(gc.callbacks) == len(before) + 1
+    with tr.span("dispatch"):
+        pass
+    assert logged == []
+    tr.close(Log())
+    assert logged == ["trainer_init", "dispatch"] and gc.callbacks == before
+    with tr.span("init_or_restore"):       # between two fits
+        pass
+    assert logged[-1] == "init_or_restore" and tr.drain() == []
+    tr.reopen()
+    with tr.span("dispatch"):
+        pass
+    assert len(logged) == 3 and [r[0] for r in tr.drain()] == ["dispatch"]
+    tr.close()
+
+
 def test_short_young_collections_leave_no_span(monkeypatch):
     """A fit makes a thousand collections of generation 0 in its set-up;
     only those long enough to stall a loop, and every full one, are
@@ -544,8 +576,15 @@ def test_fit_spans_cover_setup_probe_boundary_teardown_and_gc(
     assert any(n.startswith("gc_gen") for n in by_name)
     # set-up's children lie inside it, one level down; the boundary's
     # three are siblings at the loop's depth, in order
+    # the tracer is the Trainer's: its making comes first on its clock,
+    # and the fit's own initialisation lies inside the fit's set-up
+    (made,) = by_name["trainer_init"]
     (setup,) = by_name["fit_setup"]
-    assert setup["depth"] == 0 and setup["start_s"] < 0.01
+    (init,) = by_name["init_or_restore"]
+    assert made["depth"] == 0 and made["start_s"] < 0.01
+    assert setup["depth"] == 0 \
+        and setup["start_s"] >= made["start_s"] + made["dur_s"]
+    assert init["depth"] == 1 and setup["start_s"] <= init["start_s"]
     for child in ("build_iterators", "place_resident", "build_step"):
         (c,) = by_name[child]
         assert c["depth"] == 1 and c["start_s"] >= setup["start_s"]
@@ -572,7 +611,8 @@ def test_fit_spans_cover_setup_probe_boundary_teardown_and_gc(
         *(f"{c}_frac" for c in GOODPUT_CATEGORIES)}
     end = max(s["start_s"] + s["dur_s"] for s in spans
               if s["name"] == "checkpoint")
-    assert final[-1]["total_s"] <= end - setup["dur_s"] + 0.5
+    assert final[-1]["total_s"] \
+        <= end - setup["start_s"] - setup["dur_s"] + 0.5
     # the scope maps: one record a program, the maps kept by module
     maps = [r for r in recs if r["kind"] == "scopemap"]
     assert {m["module"] for m in maps} == set(devprof.scope_maps())
@@ -581,6 +621,54 @@ def test_fit_spans_cover_setup_probe_boundary_teardown_and_gc(
     from tools import check_jsonl_schema
     assert check_jsonl_schema.check_lines(
         [json.dumps(r) for r in recs], strict=True) == []
+
+
+def test_a_trainers_making_and_its_restore_are_spans_of_one_tracer(
+        data_cfg, tmp_path, monkeypatch):
+    """``trainer_init`` and ``init_or_restore`` are posted (records and the
+    registry's counters) by a fit that initialises, and by a ``Trainer``
+    that restores before it goes on; one tracer serves both of an
+    object's fits."""
+    import threading
+
+    from dml_cnn_cifar10_tpu.train.loop import Trainer
+    from dml_cnn_cifar10_tpu.utils import metrics_registry as mr
+
+    reg = mr.MetricsRegistry()
+    monkeypatch.setattr(mr, "_DEFAULT", reg)
+
+    def counted(name):
+        family = reg.get("dml_spans_total")
+        return 0 if family is None else family.values().get((name,), 0)
+
+    trainer, result, recs = _resident_fit(data_cfg, tmp_path, "on", True,
+                                          total_steps=4)
+    assert result.final_step == 4
+    assert counted("trainer_init") == counted("init_or_restore") == 1
+    assert reg.get("dml_span_seconds_total").values()[
+        ("trainer_init",)] > 0
+    # a second Trainer on the same directory restores step 4, outside any
+    # fit; the spans wait for its fit's first flush, and a second fit of
+    # the same object keeps the tracer
+    cfg = trainer.cfg
+    cfg.total_steps = 8
+    before = set(threading.enumerate())
+    again = Trainer(cfg)
+    tracer = again._tracer
+    state = again.init_or_restore()
+    assert int(state.step) == 4 and counted("init_or_restore") == 1
+    first = again.fit(total_steps=6, state=state)
+    assert counted("trainer_init") == counted("init_or_restore") == 2
+    second = again.fit(total_steps=8, state=first.state)
+    for t in set(threading.enumerate()) - before:
+        t.join(timeout=300)
+    again.logger.close()
+    assert second.final_step == 8 and again._tracer is tracer
+    assert counted("fit_setup") == 3 and counted("trainer_init") == 2
+    with open(cfg.metrics_jsonl) as f:
+        spans = [r for r in map(json.loads, f) if r["kind"] == "span"]
+    restored = [s for s in spans if s["name"] == "init_or_restore"][-1]
+    assert restored["depth"] == 0
 
 
 def test_telemetry_off_builds_and_registers_nothing(data_cfg, tmp_path,

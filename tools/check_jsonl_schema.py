@@ -142,7 +142,7 @@ KIND_KEYS = {
     # program; `path` is null where no profiler capture directory
     # exists to write scopemap_<module>.json beside.
     "scopemap": ("step", "module", "instructions", "mapped", "mixed",
-                 "path"),
+                 "recompute", "path"),
     "devtime": ("step", "device", "total_ms", "compute_ms",
                 "collective_ms", "infeed_ms", "optimizer_ms",
                 "window_ms", "top_ops"),
