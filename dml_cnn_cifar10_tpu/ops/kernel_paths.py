@@ -3,7 +3,8 @@ that chose it.
 
 The fused optimizer update (``ops/optimizer.py``), the attention
 dispatch (``ops/attention.py``), the CNN's bias + ReLU + pool
-(``ops/relu_pool.py``) and the experts' sum of rows by token
+(``ops/relu_pool.py``), the experts' grouped products
+(``ops/layers.grouped_matmul``) and their sum of rows by token
 (``ops/sum_rows.py``) each pick between a compiled Pallas kernel and
 an XLA expression from what they can see at trace time: the platform,
 the mesh, the token count, the shape. A step builder
@@ -34,7 +35,7 @@ def recording():
 
 def note(kind: str, path: str) -> None:
     """Record that ``kind`` ("update" | "attention" | "pool" | "remat" |
-    "experts") compiled ``path``."""
+    "grouped" | "experts") compiled ``path``."""
     rec = getattr(_local, "rec", None)
     if rec is not None:
         rec[kind] = path

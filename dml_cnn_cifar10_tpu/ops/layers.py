@@ -15,13 +15,15 @@ Parity notes (all against ``/root/reference/cifar10cnn.py``):
 from __future__ import annotations
 
 import functools
-from typing import Mapping, Tuple
+import importlib
+from typing import Mapping, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from dml_cnn_cifar10_tpu.ops import kernel_paths
 from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 #: Settings of ``jax_default_matmul_precision`` under which a TPU
@@ -327,19 +329,150 @@ def gated_short_conv(bcx: jax.Array, w: jax.Array) -> jax.Array:
     return c * conv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+#: Row tiles a grouped product's kernels may take, the largest first: a
+#: block of the experts' rows is a whole number of 512 (``models/
+#: hybrid_decoder.ROW_TILE``).
+_ROW_TILES = (512, 256, 128)
+_LANES = 128
+#: What a grid step of the grouped kernels may hold in vector memory by
+#: :func:`grouped_tiles`' arithmetic: 2 MiB under the 16 MiB of scoped
+#: memory Mosaic gives a kernel that asks for no more (megablox asks for
+#: no more), since the compiler took up to 0.94 MiB above the arithmetic
+#: at the cells' shapes for a described v5e (PERF.md, Findings, PR 37).
+_GMM_VMEM = 14 << 20
+#: The v5e a grid step is modelled on: bfloat16 operations a second,
+#: bytes a second from HBM, and the fixed cost of a step. The v5e is the
+#: one chip the repo measures and ``on_tpu`` cannot tell a generation: on
+#: another the model may rank the tiles otherwise, never let one past
+#: :data:`_GMM_VMEM`.
+_PEAK_FLOPS, _HBM_BYTES_S, _STEP_S = 197e12, 819e9, 0.35e-6
+
+
+class GroupedTiles(NamedTuple):
+    """``(tm, tk, tn)`` of each of :func:`grouped_matmul`'s three kernels,
+    in megablox's terms: ``fwd`` of ``gmm(x, w)``, ``dx`` of ``gmm(g, w,
+    transpose_rhs=True)`` (its ``k`` is the forward's ``n``), ``dw`` of
+    ``tgmm(x.T, g)`` (its output tile is ``(tk, tn)`` of ``w``)."""
+
+    fwd: Tuple[int, int, int]
+    dx: Tuple[int, int, int]
+    dw: Tuple[int, int, int]
+
+
+def _lane_divisors(size: int):
+    return [t for t in range(size, 0, -_LANES) if size % t == 0]
+
+
+def _kernel_tiles(tm, m, k, n, groups, itemsize, transposed):
+    """The ``(tk, tn)`` of least modelled time for one kernel (see
+    :func:`grouped_tiles`); None where no tile fits."""
+    best, least = None, None
+    for tk in _lane_divisors(k):
+        for tn in _lane_divisors(n):
+            out = (tk if transposed else tm) * tn
+            fetched = tm * tk + (tm if transposed else tk) * tn
+            if 2 * fetched * itemsize + 3 * 4 * out > _GMM_VMEM:
+                continue
+            steps = (m // tm) * (k // tk) * (n // tn)
+            stored = 4 * (groups * k * n if transposed else m * n)
+            seconds = steps * (_STEP_S + max(
+                2 * tm * tk * tn / _PEAK_FLOPS,
+                (fetched * itemsize + stored / steps) / _HBM_BYTES_S))
+            if least is None or seconds < least:
+                best, least = (tm, tk, tn), seconds
+    return best
+
+
+def grouped_tiles(m: int, k: int, n: int, groups: int, dtype,
+                  mesh=None):
+    """The tiles of :func:`grouped_matmul`'s kernels for ``x [m, k]`` and
+    ``w [groups, k, n]`` in ``dtype``, or None where the products stay
+    ``lax.ragged_dot``: off a TPU, under a mesh of more than one device (a
+    bare ``pallas_call`` cannot be partitioned by GSPMD), at a ``k`` or
+    ``n`` that is no whole number of 128 lanes, at ``m`` that is no whole
+    number of row tiles (:data:`_ROW_TILES`), or in another dtype than
+    bfloat16 (the kernels multiply float32 operands at a precision of
+    their own, which would be another result).
+
+    ``tm`` is the largest row tile that divides ``m``. Each kernel's ``tk``
+    and ``tn`` are the multiples of 128 that divide their dimensions and
+    take the least time by a model of a grid step: the longer of its
+    products at 197 TF/s and its bytes at 819 GB/s (the two operands'
+    blocks, and its share of the float32 output, which leaves once a row
+    tile in ``gmm`` and once a group in ``tgmm``), plus 0.35 us. Only tiles
+    whose blocks fit :data:`_GMM_VMEM` are looked at, by the arithmetic
+
+        gmm:  2 (tm tk + tk tn) s + 3 x 4 tm tn
+        tgmm: 2 (tm tk + tm tn) s + 3 x 4 tk tn
+
+    (``s`` the dtype's bytes; each operand's block double-buffered; the
+    float32 output block double-buffered and its accumulator). At the two
+    expert decoders' blocks of 8,704 rows, bfloat16:
+
+        (2048 -> 1792) fwd (512, 1024,  896): 5.5 + 5.25 = 10.75 MiB
+        (1792 -> 2048) fwd (512,  896, 1024): 5.25 + 6.0 = 11.25 MiB
+        (2304 ->  896) fwd (512, 1152,  896): 6.19 + 5.25 = 11.44 MiB
+        ( 896 -> 2304) fwd (512,  896, 1152): 5.69 + 6.75 = 12.44 MiB
+
+    and ``dx`` the other direction's forward tiles."""
+    if not (platform_lib.on_tpu() and (mesh is None or mesh.size == 1)
+            and jnp.dtype(dtype) == jnp.bfloat16
+            and k % _LANES == 0 and n % _LANES == 0):
+        return None
+    tm = next((t for t in _ROW_TILES if m % t == 0), None)
+    if tm is None:
+        return None
+    s = jnp.dtype(dtype).itemsize
+    tiles = GroupedTiles(_kernel_tiles(tm, m, k, n, groups, s, False),
+                         _kernel_tiles(tm, m, n, k, groups, s, False),
+                         _kernel_tiles(tm, m, k, n, groups, s, True))
+    return None if None in tiles else tiles
+
+
 def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
-                   dtype) -> jax.Array:
+                   dtype, mesh=None) -> jax.Array:
     """:func:`mixed_matmul` with a matrix a group of rows: the first
     ``group_sizes[0]`` rows of ``x [M, K]`` meet ``w[0]`` of ``w [G, K,
     N]``, the next ``group_sizes[1]`` rows ``w[1]``, and so on; rows past
     the groups' sum belong to no group, cost no product, come back zero
     and take a zero gradient. Operands rounded to ``dtype``, sums and
-    result float32, forward and in both backward products
-    (``lax.ragged_dot``, which the TPU's compiler makes one kernel each;
-    that kernel leaves the rows past the groups unwritten, so they are
-    cleared here, forward and in the gradient of ``x``)."""
-    return _grouped_matmul_fwd(x, w, group_sizes, dtype)[0]
+    result float32, forward and in both backward products. Where
+    :func:`grouped_tiles` gives tiles (one TPU device, whole lanes and row
+    tiles, bfloat16; ``mesh`` is the enclosing GSPMD program's, if any)
+    the three products are megablox's Pallas kernels ``gmm`` / ``tgmm`` at
+    those tiles, else ``lax.ragged_dot``, which the TPU's compiler makes
+    one kernel each at tiles of its own. Neither writes the rows past the
+    groups, so they are cleared here, forward and in the gradient of
+    ``x``. The path is noted for the step's line (``ops.kernel_paths``,
+    kind ``grouped``): ``ragged_dot``, or ``pallas gmm`` and each distinct
+    forward tile in the order the products were traced."""
+    tiles = grouped_tiles(x.shape[0], x.shape[1], w.shape[2], w.shape[0],
+                          dtype, mesh)
+    if tiles is None:
+        path = "ragged_dot"
+    else:
+        tile = "({},{},{})".format(*tiles.fwd)
+        said = kernel_paths.noted("grouped") or ""
+        path = said if tile in said else (
+            f"{said} {tile}" if said.startswith("pallas gmm")
+            else f"pallas gmm {tile}")
+    kernel_paths.note("grouped", path)
+    return grouped_matmul_tiled(x, w, group_sizes, dtype, tiles)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def grouped_matmul_tiled(x, w, group_sizes, dtype, tiles=None,
+                         interpret=False):
+    """:func:`grouped_matmul` on the path ``tiles`` names (None:
+    ``lax.ragged_dot``); ``interpret`` runs the kernels in the Pallas
+    interpreter (tests, off TPU)."""
+    return _grouped_matmul_fwd(x, w, group_sizes, dtype, tiles, interpret)[0]
+
+
+def _megablox():
+    # the module: the package's `gmm` is the custom-VJP'd function
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
 def _clear_past_groups(y, group_sizes):
@@ -347,10 +480,14 @@ def _clear_past_groups(y, group_sizes):
     return jnp.where(live[:, None], y, 0.0)
 
 
-def _grouped_matmul_fwd(x, w, group_sizes, dtype):
+def _grouped_matmul_fwd(x, w, group_sizes, dtype, tiles, interpret):
     x_low, w_low = x.astype(dtype), w.astype(dtype)
-    y = lax.ragged_dot(x_low, w_low, group_sizes,
-                       preferred_element_type=jnp.float32)
+    if tiles is None:
+        y = lax.ragged_dot(x_low, w_low, group_sizes,
+                           preferred_element_type=jnp.float32)
+    else:
+        y = _megablox().gmm(x_low, w_low, group_sizes, jnp.float32,
+                            tiles.fwd, interpret=interpret)
     return _clear_past_groups(y, group_sizes), (x_low, w, group_sizes)
 
 
@@ -359,17 +496,22 @@ _RAGGED_CONTRACTION = lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _grouped_matmul_bwd(dtype, res, g):
+def _grouped_matmul_bwd(dtype, tiles, interpret, res, g):
     x_low, w, group_sizes = res
     g_low = g.astype(dtype)
-    dx = _clear_past_groups(
-        lax.ragged_dot(g_low, jnp.swapaxes(w.astype(dtype), 1, 2),
-                       group_sizes, preferred_element_type=jnp.float32),
-        group_sizes)
-    dw = lax.ragged_dot_general(x_low, g_low, group_sizes,
-                                _RAGGED_CONTRACTION,
-                                preferred_element_type=jnp.float32)
-    return dx, dw.astype(w.dtype), None
+    if tiles is None:
+        dx = lax.ragged_dot(g_low, jnp.swapaxes(w.astype(dtype), 1, 2),
+                            group_sizes, preferred_element_type=jnp.float32)
+        dw = lax.ragged_dot_general(x_low, g_low, group_sizes,
+                                    _RAGGED_CONTRACTION,
+                                    preferred_element_type=jnp.float32)
+    else:
+        mb = _megablox()
+        dx = mb.gmm(g_low, w.astype(dtype), group_sizes, jnp.float32,
+                    tiles.dx, transpose_rhs=True, interpret=interpret)
+        dw = mb.tgmm(x_low.T, g_low, group_sizes, jnp.float32, tiles.dw,
+                     interpret=interpret)
+    return (_clear_past_groups(dx, group_sizes), dw.astype(w.dtype), None)
 
 
-grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+grouped_matmul_tiled.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

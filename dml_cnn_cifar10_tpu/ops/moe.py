@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dml_cnn_cifar10_tpu.ops import sum_rows
+from dml_cnn_cifar10_tpu.ops import kernel_paths, sum_rows
 from dml_cnn_cifar10_tpu.ops.layers import grouped_matmul, rms_norm
 
 Params = Dict[str, Any]
@@ -246,12 +246,12 @@ _moved.defvjp(lambda values, to, back: (_moved(values, to, back), (to, back)),
               lambda res, g: (_moved(g, res[1], res[0]), None, None))
 
 
-def _gated_rows(rows, w1, w3, w2, sizes, dtype):
+def _gated_rows(rows, w1, w3, w2, sizes, dtype, mesh):
     """The experts' gated SiLU MLP on ``rows [R, D]`` in the order of their
     experts, ``sizes [E]`` of them to each expert from the front."""
-    hidden = jax.nn.silu(grouped_matmul(rows, w1, sizes, dtype)) \
-        * grouped_matmul(rows, w3, sizes, dtype)
-    return grouped_matmul(hidden, w2, sizes, dtype)
+    hidden = jax.nn.silu(grouped_matmul(rows, w1, sizes, dtype, mesh)) \
+        * grouped_matmul(rows, w3, sizes, dtype, mesh)
+    return grouped_matmul(hidden, w2, sizes, dtype, mesh)
 
 
 def _block(blocks_in, j):
@@ -290,7 +290,7 @@ def _round_of_blocks(static, rows, here, r, store_block, carry):
     for each block ``j`` of the round that holds a row, ``at`` the block's
     first row in the buffer -> ``(buffer, *carry)``, the rows' range ``lo,
     hi`` in the experts' order."""
-    _, _, held, row = static
+    _, _, held, row, _ = static
     room = held * rows
     # never cleared: a row is stored before the range that holds it is read
     buffer = lax.empty((room, *row), jnp.float32)
@@ -326,8 +326,9 @@ def _expert_blocks(static, m, w1, w3, w2, here, blocks_in, pos):
     forms a block again, takes its gradient and sends the rows' gradient
     to their tokens the same way, so that one block's rows and hidden
     activations are held at a time in both passes, whatever the worst case
-    is. ``static``: ``(top_k, dtype, held, the shape of a buffer's row)``."""
-    top_k, dtype, held, _ = static
+    is. ``static``: ``(top_k, dtype, held, the shape of a buffer's row,
+    the enclosing program's mesh or None)``."""
+    top_k, dtype, held, _, mesh = static
     rows = blocks_in[0].shape[1]
 
     def store_block(j, at, state):
@@ -336,7 +337,7 @@ def _expert_blocks(static, m, w1, w3, w2, here, blocks_in, pos):
         with jax.named_scope("dispatch"):
             taken = m[slot // top_k]
         with jax.named_scope("experts"):
-            out = _gated_rows(taken, w1, w3, w2, sizes, dtype)
+            out = _gated_rows(taken, w1, w3, w2, sizes, dtype, mesh)
         with jax.named_scope("combine"):
             # (rows past the groups' sum belong to no expert: zero, weight 0)
             return _store(buffer, out * weight[:, None], at),
@@ -357,7 +358,7 @@ def _expert_blocks_fwd(static, m, w1, w3, w2, here, blocks_in, pos):
 
 
 def _expert_blocks_bwd(static, res, g):
-    top_k, dtype, held, _ = static
+    top_k, dtype, held, _, mesh = static
     m, w1, w3, w2, here, blocks_in, pos = res
     rows = blocks_in[0].shape[1]
 
@@ -372,7 +373,7 @@ def _expert_blocks_bwd(static, res, g):
         with jax.named_scope("experts"):
             out, vjp = jax.vjp(
                 lambda taken, w1, w3, w2: _gated_rows(taken, w1, w3, w2,
-                                                      sizes, dtype),
+                                                      sizes, dtype, mesh),
                 taken, w1, w3, w2)
             dtaken, *dw = vjp(g_rows * weight[:, None])
             dws = jax.tree.map(jnp.add, dws, tuple(dw))
@@ -444,7 +445,8 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
     E_all`` rows), and each token then fetches the rows it has there and
     sums them in the order of its choices (``ops.sum_rows``; ``mesh``, the
     mesh of the enclosing GSPMD program if any, is read only to decide
-    between that op's kernel and its XLA expression). **A round** is one
+    between that op's kernel and its XLA expression, and so between the
+    grouped products' kernels and ``lax.ragged_dot``). **A round** is one
     filling of that buffer with the sum by token that empties it. A load
     within the buffer takes one; a load that exceeds it, however skewed
     the routing, takes another round for each further bufferful: a pass
@@ -503,10 +505,14 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
                     (0, blocks * rows - slots)).reshape(blocks, rows), 0.0)
         pos = pos.reshape(t, top_k)
     y = _expert_blocks(
-        (top_k, jnp.dtype(dtype), held,
-         sum_rows.row_shape(t, top_k, d, mesh)),
+        (top_k, jnp.dtype(dtype), held, sum_rows.row_shape(t, top_k, d, mesh),
+         mesh),
         m.astype(dtype), params["w1"], params["w3"], params["w2"], here,
         (slot_of, sizes_of, weight_of), pos)
+    # the step's line: how the grouped products ran, then how the rows
+    # reached their tokens (each chooser noted its own)
+    kernel_paths.note("experts", f"{kernel_paths.noted('grouped')}, "
+                                 f"{kernel_paths.noted('experts')}")
     mean = jnp.maximum(here, 1).astype(jnp.float32) / e
     stats = {"rows_here_frac": here.astype(jnp.float32) / slots,
              "load_max_over_mean": jnp.max(counts).astype(jnp.float32) / mean,

@@ -626,6 +626,56 @@ ENTRY %main (rows: bf16[8,4], w: bf16[2,4,4], sizes: s32[2]) -> (f32[8,4], bf16[
 """
 
 
+_HLO_GMM = """HloModule jit_chunk_dev, is_scheduled=true
+
+%fwd_body (p.1: bf16[8,4]) -> f32[8,4] {
+  %p.1 = bf16[8,4]{1,0} parameter(0)
+  %sizes.1 = s32[9]{0} constant({0, 0, 0, 0, 0, 0, 0, 0, 0})
+  ROOT %gmm.3 = f32[8,4]{1,0} custom-call(%sizes.1, %p.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer1)/moe/while/body/experts/jit(gmm)/pallas_call"}
+}
+
+%fwd_cond (p.2: bf16[8,4]) -> pred[] {
+  %p.2 = bf16[8,4]{1,0} parameter(0)
+  ROOT %c.2 = pred[] constant(false)
+}
+
+%bwd_body (p.3: bf16[8,4]) -> f32[2,4,4] {
+  %p.3 = bf16[8,4]{1,0} parameter(0)
+  %sizes.3 = s32[9]{0} constant({0, 0, 0, 0, 0, 0, 0, 0, 0})
+  %jvp_jit_gmm__.7 = f32[8,4]{1,0} custom-call(%sizes.3, %p.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(fwd_bwd)/jvp(layer1)/moe/while/body/experts/jvp(jit(gmm))/pallas_call"}
+  %copy.4 = f32[8,4]{1,0} copy(%jvp_jit_gmm__.7)
+  ROOT %jvp_jit_tgmm__.9 = f32[2,4,4]{2,1,0} custom-call(%sizes.3, %p.3, %copy.4), custom_call_target="tpu_custom_call", metadata={op_name="jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(fwd_bwd)/jvp(layer1)/moe/while/body/experts/transpose(experts)/jvp(jit(tgmm))/pallas_call"}
+}
+
+ENTRY %main (rows: bf16[8,4]) -> (bf16[8,4], bf16[8,4]) {
+  %rows = bf16[8,4]{1,0} parameter(0)
+  %while.1 = bf16[8,4]{1,0} while(%rows), condition=%fwd_cond, body=%fwd_body
+  %while.2 = bf16[8,4]{1,0} while(%rows), condition=%fwd_cond, body=%bwd_body
+  ROOT %tuple.3 = (bf16[8,4]{1,0}, bf16[8,4]{1,0}) tuple(%while.1, %while.2)
+}
+"""
+
+
+def test_the_grouped_kernels_are_the_experts_by_their_own_names():
+    """megablox's ``gmm`` / ``tgmm`` keep the scope they were called under:
+    kind ``expert`` by their own ``op_name``, pass ``forward`` in the
+    experts' forward loop and ``backward`` in their written-out backward
+    loop (the forward formed again there, the input gradients and the
+    weight gradients alike), none of them a guess; the copy between two
+    of them goes with its consumer."""
+    _, m = devprof.scope_map_of_text(_HLO_GMM)
+    fwd = m["gmm.3"]
+    assert (fwd.scope, fwd.kind, fwd.pass_, fwd.part) \
+        == ("fwd_bwd/layer1/moe/experts", "expert", "forward", "")
+    assert fwd.in_loop and not fwd.inherited and not fwd.mixed
+    again, dw = m["jvp_jit_gmm__.7"], m["jvp_jit_tgmm__.9"]
+    assert again[:3] == ("fwd_bwd/fwd_bwd/layer1/moe/experts", "expert",
+                         "backward")
+    assert dw[:3] == ("fwd_bwd/fwd_bwd/layer1/moe/experts/experts",
+                      "expert", "backward")
+    assert not again.inherited and not dw.inherited
+    assert m["copy.4"] == dw._replace(inherited=True)
+
 def test_a_kernel_the_compiler_named_anew_keeps_its_own_kind():
     """The experts' grouped products become ``ragged-dot-none.<n>`` with
     no scope. One that feeds an instruction under ``moe/combine`` is an

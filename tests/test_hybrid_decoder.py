@@ -409,6 +409,136 @@ def test_rows_past_the_groups_cost_nothing_and_read_as_nothing():
         got[1][1]).any()
 
 
+
+#: Sizes a group in a block of 384 rows, in row tiles of 128: empty
+#: groups, a group over the first tile's end (rows 100-299 straddle tiles
+#: 0-2), and 34 rows past the groups' sum; then every row taken.
+_BLOCKS = {"empty_and_past": [100, 0, 200, 0, 50, 0, 0, 0],
+           "whole": [0, 128, 0, 0, 130, 0, 126, 0]}
+
+
+@pytest.mark.parametrize("widths", [(1024, 896), (2304, 896)],
+                         ids=["lfm2_8to7", "mellum2_18to7"])
+@pytest.mark.parametrize("block", sorted(_BLOCKS))
+def test_the_grouped_kernels_are_the_grouped_product(widths, block,
+                                                     monkeypatch):
+    """megablox's ``gmm`` / ``tgmm`` in the Pallas interpreter at the tiles
+    the rule chooses for the chip, forward and both gradients, against one
+    dense product a group and against ``lax.ragged_dot``, at the two
+    cells' ratios of widths in whole lanes (2,048 : 1,792 = 8 : 7 and
+    2,304 : 896 = 18 : 7): rows past the groups' sum come back zero and
+    take a zero gradient, an empty group's weights none."""
+    from dml_cnn_cifar10_tpu.ops import layers
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+    k, n = widths
+    sizes = jnp.array(_BLOCKS[block], jnp.int32)
+    m, live = 384, int(sizes.sum())
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: True)
+    tiles = layers.grouped_tiles(m, k, n, 8, jnp.bfloat16)
+    monkeypatch.undo()
+    assert tiles.fwd[0] == 128
+    x = jax.random.normal(jax.random.key(4), (m, k))
+    w = jax.random.normal(jax.random.key(5), (8, k, n)) / k ** 0.5
+    g = jax.random.normal(jax.random.key(6), (m, n))
+    group = jnp.repeat(jnp.arange(8), sizes, total_repeat_length=m)
+    low = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+
+    def dense(x, w):
+        with jax.default_matmul_precision("highest"):
+            y = jnp.einsum("mk,mkn->mn", low(x), low(w)[group])
+        return jnp.where((jnp.arange(m) < live)[:, None], y, 0.0)
+
+    def kernels(x, w):
+        return layers.grouped_matmul_tiled(x, w, sizes, jnp.bfloat16, tiles,
+                                           True)
+
+    def ragged(x, w):
+        return grouped_matmul(x, w, sizes, jnp.bfloat16)
+
+    def both(f):
+        y, vjp = jax.vjp(f, x, w)
+        return (y, *vjp(g))
+
+    got, xla = both(kernels), both(ragged)
+    y_want = dense(x, w)
+    np.testing.assert_allclose(got[0], y_want, rtol=1e-5, atol=1e-5)
+    with jax.default_matmul_precision("highest"):
+        dx_want = jnp.einsum("mn,mkn->mk", low(g), low(w)[group]) \
+            * (jnp.arange(m) < live)[:, None]
+        dw_want = jnp.zeros_like(w).at[group[:live]].add(jnp.einsum(
+            "mk,mn->mkn", low(x)[:live], low(g)[:live]))
+    np.testing.assert_allclose(got[1], dx_want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[2], dw_want, rtol=1e-5, atol=1e-4)
+    for a, b in zip(got, xla):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-4)
+    assert not np.asarray(got[0][live:]).any()
+    assert not np.asarray(got[1][live:]).any()
+    assert not np.asarray(got[2][np.asarray(sizes) == 0]).any()
+
+
+def test_who_takes_the_grouped_kernels_and_at_which_tiles(monkeypatch):
+    """The path and the tiles from what the chooser can see: a TPU, one
+    device, whole lanes, whole row tiles and bfloat16; the tiles at the
+    two cells' blocks of 8,704 rows, and their blocks within the scoped
+    vector memory by the docstring's arithmetic."""
+    from dml_cnn_cifar10_tpu.ops import layers
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+    one = mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:1])
+    four = mesh_lib.build_mesh(ParallelConfig(), devices=jax.devices()[:4])
+    bf16 = jnp.bfloat16
+    assert layers.grouped_tiles(8704, 2048, 1792, 8, bf16) is None   # CPU
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: True)
+    assert layers.grouped_tiles(8704, 2048, 1792, 8, bf16, one) is not None
+    assert layers.grouped_tiles(8704, 2048, 1792, 8, bf16, four) is None
+    assert layers.grouped_tiles(8704, 2048, 1792 + 64, 8, bf16) is None
+    assert layers.grouped_tiles(8704, 2048 + 64, 1792, 8, bf16) is None
+    assert layers.grouped_tiles(8704 + 64, 2048, 1792, 8, bf16) is None
+    assert layers.grouped_tiles(8704, 2048, 1792, 8, jnp.float32) is None
+    assert layers.grouped_tiles(8704 + 256, 2048, 1792, 8, bf16).fwd[0] \
+        == 256
+
+    def vmem(tiles, transposed):
+        tm, tk, tn = tiles
+        fetched = tm * tk + (tm if transposed else tk) * tn
+        return 2 * fetched * 2 + 3 * 4 * (tk if transposed else tm) * tn
+
+    want = {(2048, 1792): ((512, 1024, 896), (512, 896, 1024),
+                           (512, 512, 896)),
+            (1792, 2048): ((512, 896, 1024), (512, 1024, 896),
+                           (512, 896, 512)),
+            (2304, 896): ((512, 1152, 896), (512, 896, 1152),
+                          (512, 768, 896)),
+            (896, 2304): ((512, 896, 1152), (512, 1152, 896),
+                          (512, 896, 768))}
+    for (k, n), tiles in want.items():
+        got = layers.grouped_tiles(8704, k, n, 8, bf16)
+        assert tuple(got) == tiles, (k, n, got)
+        for (tm, tk, tn), (kk, nn) in zip(got, ((k, n), (n, k), (k, n))):
+            assert 8704 % tm == 0 and kk % tk == 0 and nn % tn == 0
+        assert max(vmem(got.fwd, False), vmem(got.dx, False),
+                   vmem(got.dw, True)) <= layers._GMM_VMEM < 16 << 20
+
+
+@pytest.mark.parametrize("chip, want", [
+    (True, "pallas gmm (512,1024,896) (512,896,1024)"),
+    (False, "ragged_dot")], ids=["tpu", "cpu"])
+def test_the_grouped_product_notes_its_own_path(chip, want, monkeypatch):
+    """The chooser notes the path it took for the step's line, each
+    distinct forward tile once, in the order the products were traced:
+    the gated MLP's two products into the hidden width, then the one out
+    of it."""
+    from dml_cnn_cifar10_tpu.ops import kernel_paths, layers
+    from dml_cnn_cifar10_tpu.utils import platform as platform_lib
+    monkeypatch.setattr(platform_lib, "on_tpu", lambda: chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32)
+    with kernel_paths.recording() as rec:
+        for k, n in ((2048, 1792), (2048, 1792), (1792, 2048)):
+            jax.eval_shape(
+                lambda x, w, s: layers.grouped_matmul(x, w, s, jnp.bfloat16),
+                jax.ShapeDtypeStruct((8704, k), jnp.float32),
+                jax.ShapeDtypeStruct((8, k, n), jnp.float32), sizes)
+    assert rec == {"grouped": want}
+
 # --- the two operators -------------------------------------------------------
 
 def test_the_short_convolution_is_causal_and_a_plain_depthwise_one():
@@ -568,7 +698,7 @@ def test_the_count_of_operations_against_xlas(ref, tmp_path, monkeypatch):
     assert m.expert_block_rows(batch * S * 2, batch * S * 2) == batch * S * 2
     monkeypatch.setattr(
         moe, "grouped_matmul",
-        lambda x, w, sizes, dtype: mixed_matmul(x, w[0], dtype))
+        lambda x, w, sizes, dtype, mesh: mixed_matmul(x, w[0], dtype))
 
     def grads(p, rows):
         return jax.grad(lambda p: m.loss(p, rows, cfg, loss_blocks=1)[0])(p)

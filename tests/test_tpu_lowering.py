@@ -204,9 +204,16 @@ def test_vit_step_keeps_flash_and_update_kernels(chip_branch, ndev, attn,
         assert "shard_map[batch/data, heads/model]" in said
 
 
+#: megablox's jitted launchers, whose own jaxprs are not looked into: they
+#: count their row tiles a group with a scatter of floats
+#: (``jnp.histogram``), a few numbers a call
+_MEGABLOX_LAUNCHERS = ("gmm", "tgmm")
+
+
 def _float_scatters(jaxpr, under=""):
     """The scopes of every scatter of floats in a jaxpr and the jaxprs it
-    holds (a loop's body names its scopes from the loop's own on)."""
+    holds (a loop's body names its scopes from the loop's own on), except
+    inside :data:`_MEGABLOX_LAUNCHERS`."""
     from jax._src import core
     found = []
     for eqn in jaxpr.eqns:
@@ -214,29 +221,55 @@ def _float_scatters(jaxpr, under=""):
         if eqn.primitive.name.startswith("scatter") and jnp.issubdtype(
                 eqn.outvars[0].aval.dtype, jnp.floating):
             found.append(scope)
+        if eqn.params.get("name") in _MEGABLOX_LAUNCHERS:
+            continue
         for sub in core.jaxprs_in_params(eqn.params):
             found += _float_scatters(sub, scope)
     return found
 
 
+@pytest.mark.parametrize("name, counted", [("gmm", 0), ("tgmm", 0),
+                                           ("load", 1)])
+def test_float_scatters_skip_only_megablox_launchers(name, counted):
+    """A scatter-add into a float vector is counted wherever it lies, a
+    per-expert load under ``moe/route`` as much as a row of ``moe/``,
+    unless it is inside a jitted function named as megablox's
+    launchers."""
+    def counts(slot, x):
+        return jnp.zeros(8, jnp.float32).at[slot].add(x)
+    counts.__name__ = name
+
+    def step(slot, x):
+        with jax.named_scope("moe"), jax.named_scope("route"):
+            return jax.jit(counts)(slot, x)
+    jaxpr = jax.make_jaxpr(step)(jnp.zeros(4, jnp.int32),
+                                 jnp.ones(4, jnp.float32))
+    found = _float_scatters(jaxpr.jaxpr)
+    assert len(found) == counted, found
+    assert all("moe/route" in s for s in found)
+
+
 @pytest.mark.parametrize("ndev", [1, 4])
 def test_hybrid_decoder_step_sums_the_experts_rows_by_token(
         chip_branch, ndev, capsys, tmp_path):
-    """At a width of whole tiles on one device the experts' rows reach
-    their tokens through the sum-by-token kernel, forward and backward,
-    once an expert layer (the buffer holds every block at this size, so
-    no further round is built); on a mesh of four the same step keeps the
-    XLA expression. Either way no scatter of floats is left under
-    ``moe/``: the embedding's gradient is the step's only one."""
+    """At widths of whole tiles on one device, in bfloat16, the experts'
+    three grouped products are megablox's ``gmm`` / ``tgmm`` kernels and
+    their rows reach their tokens through the sum-by-token kernel, forward
+    and backward, once an expert layer (the buffer holds every block at
+    this size, so no further round is built); on a mesh of four the same
+    step keeps ``lax.ragged_dot`` and the XLA expression. Either way no
+    scatter of floats is left under ``moe/``: the embedding's gradient is
+    the step's only one."""
     import json
 
     from dml_cnn_cifar10_tpu.models import hybrid_decoder
     path = tmp_path / "sizes.json"
     path.write_text(json.dumps({**hybrid_decoder.SMALL, "hidden_size": 1024,
-                                "head_dim": 128}))
+                                "head_dim": 128,
+                                "moe_intermediate_size": 256}))
     model_def = get_model("hybrid_decoder")
     model_cfg = ModelConfig(name="hybrid_decoder", remat=True,
-                            config_file=str(path))
+                            compute_dtype="bfloat16", config_file=str(path))
     data_cfg = DataConfig(dataset="tokens_synth", sequence_length=32)
     optim_cfg = OptimConfig(optimizer="adamw")
     mesh = _mesh(ndev)
@@ -249,13 +282,20 @@ def test_hybrid_decoder_step_sums_the_experts_rows_by_token(
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     layers = hybrid_decoder.SMALL["num_hidden_layers"] \
         - hybrid_decoder.SMALL["num_dense_layers"]
-    want = "pallas sum-by-token" if ndev == 1 else "xla (mesh)"
+    # 4 x 32 tokens of 2 choices: one block of 256 rows
+    want = "pallas gmm (256,1024,256) (256,256,1024), pallas sum-by-token" \
+        if ndev == 1 else "ragged_dot, xla (mesh)"
     assert f" experts={want}\n" in capsys.readouterr().out
     # (the jitted launcher is one function a distinct trace, called from
     # each place that takes it)
     assert ("tpu_custom_call" in text) == (ndev == 1)
     assert text.count("call @sum_rows_pallas") \
         == (2 * layers if ndev == 1 else 0)
+    # a layer's forward loop runs three products, its backward loop the
+    # three again, their three input gradients and three weight gradients
+    assert text.count("call @gmm") == (9 * layers if ndev == 1 else 0)
+    assert text.count("call @tgmm") == (3 * layers if ndev == 1 else 0)
+    assert ("ragged_dot" in text) == (ndev > 1)
     scatters = _float_scatters(traced.jaxpr.jaxpr)
     assert len(scatters) == 1 and "embed" in scatters[0], scatters
     assert not [s for s in scatters if "moe" in s]

@@ -417,7 +417,7 @@ def test_the_count_of_operations_against_xlas(ref, tmp_path, monkeypatch):
         lambda: m.init_params(jax.random.key(0), cfg, data))
     monkeypatch.setattr(
         moe, "grouped_matmul",
-        lambda x, w, sizes, dtype: mixed_matmul(x, w[0], dtype))
+        lambda x, w, sizes, dtype, mesh: mixed_matmul(x, w[0], dtype))
 
     def grads(p, rows):
         return jax.grad(lambda p: m.loss(p, rows, cfg, loss_blocks=1)[0])(p)
@@ -480,7 +480,8 @@ def test_the_lowered_step_holds_the_scopes_and_the_map_their_kinds(small,
     said = capsys.readouterr().out
     assert f"attention=xla ({S} tokens), window {WINDOW} in 3 of 4 layers " \
         in said
-    assert said.rstrip().endswith("experts=xla, softmax router, no bias")
+    assert said.rstrip().endswith(
+        "experts=ragged_dot, xla, softmax router, no bias")
     import re
     named = ["/" + n for n in set(re.findall(
         r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))]
