@@ -1,9 +1,10 @@
 """A causal decoder over tokens whose layers differ by a list: an operator
-of one of three kinds (a gated short convolution, rotary attention with
-grouped key/value heads over the whole sequence, or the same over a
-sliding window), then a feed-forward of one of two kinds (a dense gated
-SiLU MLP in the leading layers, routed experts after them), and a head
-that is the embedding's transpose or a matrix of its own.
+of one of four kinds (a gated short convolution, rotary attention with
+grouped key/value heads over the whole sequence, the same over a sliding
+window, or multi-head latent attention), then a feed-forward of one of two
+kinds (a dense gated SiLU MLP in the leading layers, routed experts after
+them, with or without shared experts), and a head that is the embedding's
+transpose or a matrix of its own.
 
 With tokens ``x [B, S]``: ``h = E[x]``. Every layer ``i``: ``a = rms(h;
 g_op_i)``; by ``layer_types[i]``
@@ -20,12 +21,20 @@ g_op_i)``; by ``layer_types[i]``
 - ``sliding_attention``: the same with the mask also ``col > row -
   sliding_window`` (a token sees itself and the ``sliding_window - 1``
   before it) and the rotary rule of its own kind;
+- ``latent_attention``: ``ops.attention.latent_attention`` (DeepSeek-V2's
+  MLA without a query latent): keys and values from one RMS-normed latent
+  of ``kv_lora_rank``, queries and keys of ``qk_nope_head_dim +
+  qk_rope_head_dim`` over values of ``v_head_dim``, rotary on the
+  ``qk_rope_head_dim`` slice alone with one rotary key for all heads, the
+  softmax scale times YaRN's ``mscale_all_dim`` factor squared;
 
 ``h = h + o``. The rotary rule is ``rope_theta`` for every layer, or
 ``rope_parameters``, a rule a kind of layer (``rope_type`` ``default``:
 ``inv_freq_j = theta ** (-2 j / head_dim)``; ``yarn``: the slow pairs'
 frequencies divided by ``factor`` over a ramp and cos and sin times
-``attention_factor``: ``ops.layers.rope_frequencies``).
+``attention_factor``: ``ops.layers.rope_frequencies``). A file in
+DeepSeek's shape names ``rope_scaling`` beside ``rope_theta`` instead: that
+rule, for every attention layer.
 
 ``m = rms(h; g_ffn_i)``; for ``i < num_dense_layers`` ``f = (silu(m W1) *
 (m W3)) W2``, else the experts' layer: ``s = sigmoid(m Wr)`` or, with
@@ -35,7 +44,10 @@ with the largest ``s + b`` and weighs them by their ``s`` over its sum
 (``norm_topk_prob``), and the ``num_experts`` experts held here (ids
 ``expert_first_id ..``) add their part (``ops.moe.routed_experts``: no
 capacity, nothing dropped, nothing stands in for the experts that live
-elsewhere); ``h = h + f``. The bias ``b`` (``use_expert_bias``; without
+elsewhere); with ``n_shared_experts`` every token also takes one gated
+SiLU MLP of ``n_shared_experts x moe_intermediate_size`` (the shared
+experts, counted once: every chip computes them for its own tokens);
+``h = h + f``. The bias ``b`` (``use_expert_bias``; without
 it the choice is by ``s`` alone and the model has no state) is a
 buffer of the model's state, zero at the start, that no gradient reaches:
 every training step moves each expert's by ``expert_bias_update_rate``
@@ -43,11 +55,15 @@ toward an even load over all of the router's experts
 (``ops.moe.balanced_bias``). ``logits = rms(h; g_f) W_head`` with ``W_head
 = E^T`` where ``tie_word_embeddings``, else a parameter ``head [D, V]``
 of its own; the loss is the mean next-token cross-entropy, over the
-``vocab_size`` rows held here.
+``vocab_size`` rows held here, and, where the file names an
+``aux_loss_alpha`` (with ``seq_aux``), in training each experts' layer's
+sequence-wise balance loss (``ops.moe.sequence_balance_loss``), summed
+over the layers as DeepSeek-V2 adds them.
 
 Sizes come from a JSON file in the shape of a published ``config.json``
-(``--model_config_file``, the keys of :data:`SMALL` and :data:`OPTIONAL`);
-without one, :data:`SMALL`. The model states its own loss
+(``--model_config_file``, the keys of :data:`SMALL` and :data:`OPTIONAL`,
+some under the published names of :data:`ALIASES`); without one,
+:data:`SMALL`. The model states its own loss
 (``ModelDef.loss``): a batch is ``[B, S+1]`` int32 rows of a token
 dataset, and no ``[tokens, vocabulary]`` array is ever held.
 
@@ -103,14 +119,34 @@ SMALL: Dict[str, Any] = {
 
 #: Keys a file may leave out, with what their absence means: no window
 #: layer, one rotary rule (``rope_theta``) for every layer, a head tied to
-#: the embedding, sigmoid scores, a norm on each head's query and key.
+#: the embedding, sigmoid scores, a norm on each head's query and key, no
+#: latent attention's sizes, no shared expert, no balance loss.
 OPTIONAL: Dict[str, Any] = {
     "sliding_window": None, "rope_parameters": None,
-    "tie_word_embeddings": True, "router_score": "sigmoid", "qk_norm": True}
+    "tie_word_embeddings": True, "router_score": "sigmoid", "qk_norm": True,
+    "kv_lora_rank": None, "qk_nope_head_dim": None,
+    "qk_rope_head_dim": None, "v_head_dim": None, "n_shared_experts": None,
+    "aux_loss_alpha": 0.0, "seq_aux": False}
 
-#: The operator kinds of ``layer_types``; the two of attention are told
-#: apart by their scope (``attn`` / ``attn_window``).
-KINDS = ("conv", "full_attention", "sliding_attention")
+#: Published keys that name one of :data:`SMALL`'s, as DeepSeek's config
+#: does.
+ALIASES = {"rms_norm_eps": "norm_eps", "n_routed_experts": "num_experts",
+           "first_k_dense_replace": "num_dense_layers",
+           "scoring_func": "router_score"}
+
+#: Published keys of which one value is built, and that value: a query
+#: latent, experts in some layers only, routing limited to groups of
+#: experts, other than the greedy top-k are not.
+ONLY: Dict[str, Any] = {"q_lora_rank": None, "moe_layer_freq": 1,
+                        "n_group": 1, "topk_group": 1,
+                        "topk_method": "greedy"}
+
+#: The operator kinds of ``layer_types``; each kind of attention is told
+#: apart by its scope (``attn`` / ``attn_window`` / ``mla``).
+KINDS = ("conv", "full_attention", "sliding_attention", "latent_attention")
+#: The sizes a ``latent_attention`` layer reads.
+LATENT = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+          "v_head_dim")
 
 KEPT = looped_decoder.KEPT
 
@@ -145,8 +181,21 @@ def _unused(sz: Dict[str, Any]) -> set:
 @functools.lru_cache(maxsize=None)
 def _read_sizes(path: str) -> Dict[str, Any]:
     spec = looped_decoder.read_config_file(path)
-    if "norm_eps" not in spec and "rms_norm_eps" in spec:
-        spec["norm_eps"] = spec["rms_norm_eps"]     # the key's other name
+    for published, key in ALIASES.items():
+        if key not in spec and published in spec:
+            spec[key] = spec[published]
+    for key, built in ONLY.items():
+        if spec.get(key, built) != built:
+            raise NotImplementedError(
+                f"{path}: {key} {spec[key]!r} is not built (only "
+                f"{built!r})")
+    if spec.get("rope_scaling") and spec.get("rope_parameters") is None:
+        # DeepSeek's one rule for every attention layer, `type` its kind
+        rule = dict(spec["rope_scaling"])
+        rule = {"rope_type": rule.pop("type", "default"), **rule,
+                "rope_theta": spec["rope_theta"]}
+        spec["rope_parameters"] = {kind: rule for kind in
+                                   set(spec.get("layer_types", ())) - {"conv"}}
     sz = {**OPTIONAL,
           **{k: spec[k] for k in (*SMALL, *OPTIONAL) if k in spec}}
     missing = sorted(set(SMALL) - set(sz) - _unused(sz))
@@ -191,6 +240,13 @@ def _checked(sz: Dict[str, Any], where: str) -> Dict[str, Any]:
     if sz["num_attention_heads"] % sz["num_key_value_heads"]:
         raise ValueError(f"{where}: the key/value heads do not divide the "
                          f"query heads")
+    if "latent_attention" in kinds and not all(
+            isinstance(sz[k], int) and sz[k] >= 1 for k in LATENT):
+        raise ValueError(f"{where}: a latent_attention layer needs "
+                         f"{', '.join(LATENT)}")
+    if sz["aux_loss_alpha"] and not sz["seq_aux"]:
+        raise NotImplementedError(f"{where}: only the sequence-wise balance "
+                                  f"loss (seq_aux) is built")
     return sz
 
 
@@ -218,11 +274,16 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
     sz = sizes(cfg)
     d, f, hm = sz["hidden_size"], sz["intermediate_size"], \
         sz["moe_intermediate_size"]
-    dh = sz["head_dim"]
-    a, kv = sz["num_attention_heads"] * dh, sz["num_key_value_heads"] * dh
+    dh, heads = sz["head_dim"], sz["num_attention_heads"]
+    a, kv = heads * dh, sz["num_key_value_heads"] * dh
     e, e_all = sz["num_experts"], sz["router_num_experts"]
+    hs = (sz["n_shared_experts"] or 0) * hm
     dtype = jnp.dtype(cfg.dtype)
-    keys = iter(jax.random.split(key, 2 + 9 * sz["num_hidden_layers"]))
+    # nine draws a layer were enough until latent attention and shared
+    # experts; the files without them draw as they did
+    per_layer = 12 if hs or "latent_attention" in sz["layer_types"] else 9
+    keys = iter(jax.random.split(key, 2 + per_layer
+                                 * sz["num_hidden_layers"]))
 
     def matrix(fan_in, *shape):
         return (jax.random.normal(next(keys), shape, jnp.float32)
@@ -238,6 +299,13 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
                          "w": matrix(sz["conv_L_cache"], d,
                                      sz["conv_L_cache"]),
                          "w_out": matrix(d, d, d)}
+        elif kind == "latent_attention":
+            rank, nope, rope, vd = (sz[k] for k in LATENT)
+            p["mla"] = {"wq": matrix(d, d, heads * (nope + rope)),
+                        "wkv_a": matrix(d, d, rank + rope),
+                        "kv_norm": scale(rank),
+                        "wkv_b": matrix(rank, rank, heads * (nope + vd)),
+                        "wo": matrix(heads * vd, heads * vd, d)}
         else:
             p["attn"] = {"wq": matrix(d, d, a), "wk": matrix(d, d, kv),
                          "wv": matrix(d, d, kv), "wo": matrix(a, a, d)}
@@ -250,6 +318,10 @@ def init_params(key: jax.Array, cfg: ModelConfig, data_cfg: DataConfig):
             p["moe"] = {"router": matrix(d, d, e_all),
                         "w1": matrix(d, e, d, hm), "w3": matrix(d, e, d, hm),
                         "w2": matrix(hm, e, hm, d)}
+            if hs:
+                p["moe"]["shared"] = {"w1": matrix(d, d, hs),
+                                      "w3": matrix(d, d, hs),
+                                      "w2": matrix(hs, hs, d)}
         return p
 
     params = {"embed": matrix(d, sz["vocab_size"], d),
@@ -322,6 +394,13 @@ def _operator(h, p, kind: str, sz, cfg: ModelConfig, mesh):
                 gated = gated_short_conv(bcx, p["conv"]["w"])
             with jax.named_scope("out"):
                 return h + mixed_matmul(gated, p["conv"]["w_out"], low)
+    if kind == "latent_attention":
+        rank, nope, rope, vd = (sz[k] for k in LATENT)
+        with jax.named_scope("mla"):
+            return h + attention_lib.latent_attention(
+                a, p["mla"], heads=sz["num_attention_heads"], nope_dim=nope,
+                rope_dim=rope, v_dim=vd, rope=rope_rule(sz, kind), low=low,
+                use_pallas=cfg.use_pallas_attention, mesh=mesh, norm_eps=eps)
     windowed = kind == "sliding_attention"
     # a scope of its own for the window sublayer: the innermost scope that
     # names a kind decides (utils/devprof.py), so the two are told apart
@@ -334,23 +413,38 @@ def _operator(h, p, kind: str, sz, cfg: ModelConfig, mesh):
             window=sz["sliding_window"] if windowed else None)
 
 
+def _gated_mlp(m, p, low):
+    return mixed_matmul(jax.nn.silu(mixed_matmul(m, p["w1"], low))
+                        * mixed_matmul(m, p["w3"], low), p["w2"], low)
+
+
 def _dense_ffn(h, p, sz, cfg: ModelConfig):
     """``h + mlp(rms(h))``."""
     low = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("ffn_norm"):
         m = rms_norm(h, p["ffn_norm"]["scale"], sz["norm_eps"])
     with jax.named_scope("mlp"):
-        return h + mixed_matmul(
-            jax.nn.silu(mixed_matmul(m, p["mlp"]["w1"], low))
-            * mixed_matmul(m, p["mlp"]["w3"], low), p["mlp"]["w2"], low)
+        return h + _gated_mlp(m, p["mlp"], low)
 
 
-def _expert_ffn(h, p, bias, sz, cfg: ModelConfig, mesh):
-    """``h + experts(rms(h))`` on all of a step's tokens, and the layer's
-    counters."""
+def _expert_ffn(h, p, bias, sz, cfg: ModelConfig, mesh, train: bool):
+    """``h + experts(rms(h))`` on all of a step's tokens (with the shared
+    experts, :data:`MLP_CHUNK_TOKENS` at a time, each chunk recomputed in
+    the backward pass under ``remat``), and the layer's counters; in
+    training with an ``aux_loss_alpha`` also its balance loss."""
     b, s, d = h.shape
     k, e_all = sz["num_experts_per_tok"], sz["router_num_experts"]
     slots = b * s * k
+    low = jnp.dtype(cfg.compute_dtype)
+    shared = None
+    if "shared" in p["moe"]:
+        def one(m):
+            return _gated_mlp(m, p["moe"]["shared"], low)
+
+        one = jax.checkpoint(one) if cfg.remat else one
+
+        def shared(m):
+            return _in_groups(one, m, b * s, MLP_CHUNK_TOKENS)
     # the layer's norm is formed where the router and the experts read it
     # (scope `ffn_norm` is theirs to open: kind `route` / `expert` decide)
     with jax.named_scope("moe"):
@@ -363,7 +457,9 @@ def _expert_ffn(h, p, bias, sz, cfg: ModelConfig, mesh):
             block_rows=expert_block_rows(
                 slots, slots * sz["num_experts"] / e_all),
             norm_scale=p["ffn_norm"]["scale"], norm_eps=sz["norm_eps"],
-            mesh=mesh, score=sz["router_score"])
+            mesh=mesh, score=sz["router_score"], shared=shared,
+            balance_alpha=sz["aux_loss_alpha"] if train else 0.0,
+            sequences=b)
     return h + f.reshape(b, s, d), stats
 
 
@@ -403,7 +499,10 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
     ``accuracy``, the share of next tokens whose logit is the largest,
     the mean over the experts' layers of ``moe_rows_here_frac``,
     ``moe_load_max_over_mean`` and ``moe_buffer_rounds``
-    (``ops.moe.routed_experts``), and, where a layer has a window,
+    (``ops.moe.routed_experts``) and, in training with an
+    ``aux_loss_alpha``, of ``moe_aux_loss`` (each layer's balance loss,
+    which the returned loss holds summed over the layers), and, where a
+    layer has a window,
     ``attn_window_blocks_frac`` (:func:`window_blocks_frac`). The state is
     :func:`init_state`'s (None: as at the start); in training each
     experts' layer's bias comes back moved one step toward an even load.
@@ -437,7 +536,7 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
                     remat(lambda x, p=p: _dense_ffn(x, p, sz, cfg)), h)
             else:
                 bias = state.get("expert_bias")
-                h, stats = _expert_ffn(h, p, bias, sz, cfg, mesh)
+                h, stats = _expert_ffn(h, p, bias, sz, cfg, mesh, train)
                 if bias is not None:
                     state = {"expert_bias": moe_lib.balanced_bias(
                         bias, stats.pop("expert_load"), rate)}
@@ -451,14 +550,21 @@ def loss(params, rows, cfg: ModelConfig, train: bool = True, mesh=None,
         ce, hit = loss_lib.blockwise_cross_entropy(
             h.reshape(n, h.shape[-1]), head, targets,
             loss_blocks or looped_decoder.token_blocks(n), low)
+    balance = [s.pop("balance_loss") for s in moe_stats
+               if "balance_loss" in s]
     with jax.named_scope("loss"):
         value = jnp.mean(ce)
+        if balance:
+            value = value + sum(balance)
     _note_paths(sz)
     stats = {"accuracy": lax.stop_gradient(jnp.mean(hit))}
     for name in ("rows_here_frac", "load_max_over_mean", "buffer_rounds"):
         if moe_stats:
             stats["moe_" + name] = sum(s[name] for s in moe_stats) \
                 / len(moe_stats)
+    if balance:
+        stats["moe_aux_loss"] = lax.stop_gradient(sum(balance)
+                                                  / len(balance))
     if "sliding_attention" in sz["layer_types"]:
         stats["attn_window_blocks_frac"] = jnp.float32(
             window_blocks_frac(sz, inputs.shape[1], low))
@@ -487,30 +593,44 @@ def step_flops(cfg: ModelConfig, data_cfg: DataConfig, batch: int) -> float:
     shapes: three times the forward's multiply-adds, two operations each.
     The experts under uniform routing: of a token's ``num_experts_per_tok``
     slots the share ``num_experts / router_num_experts`` falls on an
-    expert held here. Causal attention as the pairs it has: the half
-    square, ``S (S + 1) / 2``, in a full layer and the band, ``W (W + 1) /
-    2 + (S - W) W``, in a layer with a window ``W < S``; forward once and
-    backward two and a half times. The head once, tied or not. Not
-    counted: the embedding's gather, the filter's taps and gates, norms,
-    softmax, and what the backward pass computes a second time."""
+    expert held here; the shared experts on every token. Causal attention
+    as the pairs it has: the half square, ``S (S + 1) / 2``, in a full or
+    latent layer and the band, ``W (W + 1) / 2 + (S - W) W``, in a layer
+    with a window ``W < S``; a pair costs each head two products forward,
+    of the query/key width and of the value width, and five backward: the
+    score again, ``dQ`` and ``dK`` at the query/key width, ``dP`` and
+    ``dV`` at the value width (two and a half times the forward where the
+    widths are one). The head once, tied or not. Not counted: the
+    embedding's gather, the filter's taps and gates, norms, softmax, the
+    balance loss, and what the backward pass computes a second time."""
     sz = sizes(cfg)
     s, d = data_cfg.sequence_length, sz["hidden_size"]
-    a = sz["num_attention_heads"] * sz["head_dim"]
+    heads = sz["num_attention_heads"]
+    a = heads * sz["head_dim"]
     kv = sz["num_key_value_heads"] * sz["head_dim"]
     kinds = sz["layer_types"]
     conv, full = kinds.count("conv"), kinds.count("full_attention")
     windowed = kinds.count("sliding_attention")
+    latent = kinds.count("latent_attention")
     dense = sz["num_dense_layers"]
     here = sz["num_experts_per_tok"] * sz["num_experts"] \
         / sz["router_num_experts"]
+    hm = sz["moe_intermediate_size"]
     per_token = conv * 4 * d * d + (full + windowed) * 2 * d * (a + kv) \
         + dense * 3 * d * sz["intermediate_size"] \
-        + (len(kinds) - dense) * (d * sz["router_num_experts"]
-                                  + here * 3 * d
-                                  * sz["moe_intermediate_size"]) \
+        + (len(kinds) - dense) * (
+            d * sz["router_num_experts"]
+            + (here + (sz["n_shared_experts"] or 0)) * 3 * d * hm) \
         + d * sz["vocab_size"]
     w = min(sz["sliding_window"] or s, s)
-    pairs = full * (s * (s + 1) // 2) \
-        + windowed * (w * (w + 1) // 2 + (s - w) * w)
-    attention = 2 * a * pairs
-    return float(batch * (6 * s * per_token + 2 * 3.5 * attention))
+    half = s * (s + 1) // 2
+    pairs = full * half + windowed * (w * (w + 1) // 2 + (s - w) * w)
+    attention = 2 * 3.5 * 2 * a * pairs
+    if latent:
+        rank, nope, rope, vd = (sz[k] for k in LATENT)
+        qk = nope + rope
+        per_token += latent * (d * heads * qk + d * (rank + rope)
+                               + rank * heads * (nope + vd) + heads * vd * d)
+        attention += latent * 2 * heads * half * ((qk + vd)
+                                                  + (3 * qk + 2 * vd))
+    return float(batch * (6 * s * per_token + attention))

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dml_cnn_cifar10_tpu.ops import kernel_paths
-from dml_cnn_cifar10_tpu.ops.layers import mixed_matmul, rms_norm, rotary
+from dml_cnn_cifar10_tpu.ops.layers import (mixed_matmul, rms_norm,
+                                            rope_softmax_factor, rotary)
 from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 
@@ -122,7 +123,9 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Hk)``. The flash kernels find it in their index maps and sum a group's
     key/value gradients in float32 before their one store; the XLA path
     repeats the heads, which is the definition the kernels are tested
-    against (the repeat's transpose sums the group's gradients).
+    against (the repeat's transpose sums the group's gradients). ``v`` may
+    be of a head size of its own, and the output is then of that size
+    (the step's line says both: ``qk 192 v 128``).
 
     ``mesh`` is the mesh of the enclosing GSPMD program, if any (callers
     already inside a ``shard_map`` pass none). A compiled ``pallas_call``
@@ -133,10 +136,11 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     its own slice with no collective. A dim its axis does not divide is
     replicated instead (and the printed path says so); the heads' axis
     has to divide the key/value heads as well as the query heads."""
-    b, seq, h, _ = q.shape
+    b, seq, h, d = q.shape
     hk = k.shape[2]
     group = h // hk
-    size = f"({seq} tokens)" + (f", window {window}" if window else "")
+    size = f"({seq} tokens)" + (f", window {window}" if window else "") + (
+        f", qk {d} v {v.shape[-1]}" if v.shape[-1] != d else "")
     if not (use_pallas and seq >= 128):
         kernel_paths.note("attention", f"xla {size}")
         if group > 1:
@@ -208,3 +212,60 @@ def causal_self_attention(a: jax.Array, p, *, heads: int, kv_heads: int,
         return mixed_matmul(
             o.reshape(b, s, heads * head_dim).astype(jnp.float32), p["wo"],
             low)
+
+
+def latent_attention(a: jax.Array, p, *, heads: int, nope_dim: int,
+                     rope_dim: int, v_dim: int, rope, low, use_pallas: bool,
+                     mesh=None, norm_eps: float = 1e-6) -> jax.Array:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1) as
+    a decoder trains it, between its norm and its residual add: ``a [B,
+    S, D]`` (float32, normed) -> ``[B, S, D]``. No query latent. ``p``
+    holds ``wq [D, heads (nope_dim + rope_dim)]``, ``wkv_a [D, rank +
+    rope_dim]``, ``kv_norm`` (``scale [rank]``), ``wkv_b [rank, heads
+    (nope_dim + v_dim)]`` and ``wo [heads v_dim, D]``, no bias:
+
+    - ``q = a wq``, each head ``[q_nope (nope_dim), q_pe (rope_dim)]``;
+    - ``[c, k_pe] = a wkv_a``: the latent ``c`` shared by keys and values,
+      and ONE rotary key ``k_pe`` for all heads; ``c = rms(c)``;
+    - ``c wkv_b``, each head ``[k_nope (nope_dim), v (v_dim)]``;
+    - rotary by the rule ``rope`` on ``q_pe`` and ``k_pe`` alone
+      (rotate-half over the ``rope_dim`` slice, ``ops.layers.rotary``),
+      ``q = [q_nope, q_pe]``, ``k = [k_nope, k_pe]`` with ``k_pe``
+      broadcast to every head;
+    - causal softmax of ``q k^T`` times ``(nope_dim + rope_dim)^-0.5 x
+      ops.layers.rope_softmax_factor(rope)`` (YaRN's ``mscale_all_dim``),
+      in float32, times ``v``; ``o = concat(heads) wo``.
+
+    Queries and keys are ``nope_dim + rope_dim`` wide and values
+    ``v_dim``: :func:`dispatch_attention` takes them at their own widths
+    (the flash kernels too), and nothing is padded. Products of operands
+    rounded to ``low``, summed in float32; the norm and rotary float32.
+    Scopes ``q``, ``kv_down``, ``kv_norm``, ``kv_up``, ``rotary``,
+    ``flash``, ``out`` under the caller's."""
+    b, s, _ = a.shape
+    rank = p["kv_norm"]["scale"].shape[0]
+    with jax.named_scope("q"):
+        q = mixed_matmul(a, p["wq"], low).reshape(b, s, heads,
+                                                  nope_dim + rope_dim)
+    with jax.named_scope("kv_down"):
+        down = mixed_matmul(a, p["wkv_a"], low)
+    with jax.named_scope("kv_norm"):
+        c = rms_norm(down[..., :rank], p["kv_norm"]["scale"], norm_eps)
+    with jax.named_scope("kv_up"):
+        kv = mixed_matmul(c, p["wkv_b"], low).reshape(b, s, heads,
+                                                      nope_dim + v_dim)
+    with jax.named_scope("rotary"):
+        q_pe = rotary(q[..., nope_dim:], rope)
+        k_pe = rotary(down[..., None, rank:], rope)
+        q = jnp.concatenate([q[..., :nope_dim], q_pe], -1).astype(low)
+        k = jnp.concatenate(
+            [kv[..., :nope_dim],
+             jnp.broadcast_to(k_pe, (b, s, heads, rope_dim))], -1).astype(low)
+    scale = (nope_dim + rope_dim) ** -0.5 * rope_softmax_factor(rope)
+    with jax.named_scope("flash"):
+        o = dispatch_attention(q, k, kv[..., nope_dim:].astype(low),
+                               use_pallas=use_pallas, scale=scale,
+                               causal=True, mesh=mesh)
+    with jax.named_scope("out"):
+        return mixed_matmul(
+            o.reshape(b, s, heads * v_dim).astype(jnp.float32), p["wo"], low)

@@ -75,8 +75,9 @@ NEG_INF = -1e30  # not -inf: exp(-inf - -inf) would NaN the first block
 # ---------------------------------------------------------------------------
 
 
-def _resolve(q, scale, block_q, block_k, interpret):
-    """Fill in the static kernel parameters from the input shapes."""
+def _resolve(q, scale, block_q, block_k, interpret, v=None):
+    """Fill in the static kernel parameters from the input shapes (the
+    block size from the wider of a query's and a value's row)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
@@ -104,7 +105,8 @@ def _resolve(q, scale, block_q, block_k, interpret):
     # that asymmetric folds (bq != bk) and in-tile K-half gating lose to
     # symmetric blocks on the W=1024 causal band, were measured on an
     # earlier chip only: not measured on the current one.
-    auto = auto_block(s, q.shape[-1] * q.dtype.itemsize)
+    width = q.shape[-1] if v is None else max(q.shape[-1], v.shape[-1])
+    auto = auto_block(s, width * q.dtype.itemsize)
     block_q = auto if block_q is None else block_q
     block_k = auto if block_k is None else block_k
     return float(scale), block_q, block_k, interpret
@@ -632,13 +634,15 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
     mode: "out" → out; "lse" → (out, lse [B,S,H]);
     "stats" → (acc, m, l) — the ring merge interface.
     ``k, v`` [B, Skv, Hk, D] with ``H % Hk == 0``: only their index map
-    knows of the group (:func:`_index_maps`).
+    knows of the group (:func:`_index_maps`). ``v`` may be of a head size
+    of its own, ``Dv``, and so is the output.
     ``segment_ids`` [B, S] int32 restricts attention to equal-id pairs
     (packed sequences).
     """
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     group = _group(q, k)
     kv_len = k.shape[1]
     bq, bk = min(block_q, s), min(block_k, kv_len)
@@ -660,7 +664,7 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
     in_specs = [
         pl.BlockSpec((1, bq, d), qi),
         pl.BlockSpec(_kv_block(bk, d, group), kj),
-        pl.BlockSpec(_kv_block(bk, d, group), kj),
+        pl.BlockSpec(_kv_block(bk, dv, group), kj),
     ]
     inputs = [qb, kb_, vb]
     if has_seg:
@@ -673,26 +677,27 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
         ]
         inputs += [_seg_tile(q_seg, bq), _seg_lane(kv_seg, bk)]
 
-    o_spec = pl.BlockSpec((1, bq, d), qi)
+    o_spec = pl.BlockSpec((1, bq, dv), qi)
+    o_shape = (b * h, spq, dv)
     stat_spec = pl.BlockSpec((1, bq, 128), qi)
     stat_shape = jax.ShapeDtypeStruct((b * h, spq, 128), jnp.float32)
     if mode == "out":
         kernel, out_shape, out_specs = (
-            _flash_kernel, jax.ShapeDtypeStruct(qb.shape, q.dtype), o_spec)
+            _flash_kernel, jax.ShapeDtypeStruct(o_shape, q.dtype), o_spec)
     elif mode == "lse":
         kernel = _flash_fwd_kernel
-        out_shape = [jax.ShapeDtypeStruct(qb.shape, q.dtype), stat_shape]
+        out_shape = [jax.ShapeDtypeStruct(o_shape, q.dtype), stat_shape]
         out_specs = [o_spec, stat_spec]
     else:
         kernel = _flash_stats_kernel
-        out_shape = [jax.ShapeDtypeStruct(qb.shape, jnp.float32),
+        out_shape = [jax.ShapeDtypeStruct(o_shape, jnp.float32),
                      stat_shape, stat_shape]
         out_specs = [o_spec, stat_spec, stat_spec]
 
     scratch = [
         pltpu.VMEM((bq, 128), jnp.float32),   # m (col 0 used)
         pltpu.VMEM((bq, 128), jnp.float32),   # l (col 0 used)
-        pltpu.VMEM((bq, d), jnp.float32),     # acc
+        pltpu.VMEM((bq, dv), jnp.float32),    # acc
     ]
     # LOAD-BEARING: every grid below (incl. the b*h axis) must execute
     # SEQUENTIALLY on one core — _flash_update zeroes l/acc only at the
@@ -892,7 +897,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
     """The flash backward as a standalone op: ``(dq, dk, dv)`` from saved
     forward state. ``lse``/``delta`` are [B, S, H] f32 — the row logsumexp
     from the forward and ``rowsum(dO ∘ O)``. ``k, v`` and so ``dk, dv``
-    are ``[B, Skv, Hk, D]``, ``H % Hk == 0`` (see :func:`flash_attention`).
+    are ``[B, Skv, Hk, D]``, ``H % Hk == 0`` (see :func:`flash_attention`);
+    ``v``, ``do`` and so ``dv`` may be of a head size of their own.
     Exposed (not just wired into the custom_vjp) because ring attention's
     backward reuses it per ring step with the *global* lse/delta
     (parallel/ring_attention.py).
@@ -904,9 +910,10 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
     from jax.experimental.pallas import tpu as pltpu
 
     scale, block_q, block_k, interpret = _resolve(
-        q, scale, block_q, block_k, interpret)
+        q, scale, block_q, block_k, interpret, v)
     kv_start = _static_kv_start(kv_start)
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     group = _group(q, k)
     hk = h // group
     kv_len = k.shape[1]
@@ -934,9 +941,11 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
     qi, kj, qi_seg, kj_seg = _index_maps(folded, h, group=group)
     q_spec_i = pl.BlockSpec((1, bq, d), qi)
     kv_spec_j = pl.BlockSpec(_kv_block(bk, d, group), kj)
+    v_spec_j = pl.BlockSpec(_kv_block(bk, dv, group), kj)
+    do_spec_i = pl.BlockSpec((1, bq, dv), qi)
     stat_spec_i = pl.BlockSpec((1, bq, 128), qi)
 
-    in_specs = [q_spec_i, kv_spec_j, kv_spec_j, q_spec_i, stat_spec_i,
+    in_specs = [q_spec_i, kv_spec_j, v_spec_j, do_spec_i, stat_spec_i,
                 stat_spec_i]
     inputs = [qb, kb_, vb, dob, lse_t, delta_t]
     if has_seg:
@@ -991,8 +1000,10 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                                              group=group)
     q_spec = pl.BlockSpec((1, bq, d), qi2)
     kv_spec = pl.BlockSpec(_kv_block(bk, d, group), kj2)
+    v_spec = pl.BlockSpec(_kv_block(bk, dv, group), kj2)
+    do_spec = pl.BlockSpec((1, bq, dv), qi2)
     stat_spec = pl.BlockSpec((1, bq, 128), qi2)
-    in_specs2 = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
+    in_specs2 = [q_spec, kv_spec, v_spec, do_spec, stat_spec, stat_spec]
     if has_seg:
         in_specs2 += [
             pl.BlockSpec((1, bq, 128), qi2_seg),
@@ -1001,7 +1012,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
     dkv_shapes = [jax.ShapeDtypeStruct(kb_.shape, dk_dt),
                   jax.ShapeDtypeStruct(vb.shape, dv_dt)]
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
-                   pltpu.VMEM((bk, d), jnp.float32)]
+                   pltpu.VMEM((bk, dv), jnp.float32)]
     with jax.named_scope(_kernel_name("bwd_dkv", window)):
         if folded_k:
             dk, dv = pl.pallas_call(
@@ -1011,7 +1022,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                     num_scalar_prefetch=1,
                     grid=(b * hk, sched_k.shape[1]),
                     in_specs=in_specs2,
-                    out_specs=[kv_spec, kv_spec],
+                    out_specs=[kv_spec, v_spec],
                     scratch_shapes=dkv_scratch),
                 interpret=interpret,
             )(jnp.asarray(sched_k), *inputs)
@@ -1021,7 +1032,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, scale=None,
                 out_shape=dkv_shapes,
                 grid=(b * h, nk, nq),
                 in_specs=in_specs2,
-                out_specs=[kv_spec, kv_spec],
+                out_specs=[kv_spec, v_spec],
                 scratch_shapes=dkv_scratch,
                 interpret=interpret,
             )(*inputs)
@@ -1099,7 +1110,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``[B, S, H, D]``, for any ``H % Hk == 0`` (the group ``H // Hk`` is
     read from the shapes; query head ``j`` attends key/value head ``j //
     group``; ``dk`` and ``dv`` come back ``[B, Skv, Hk, D]``, each group's
-    sum taken in float32 and rounded once).
+    sum taken in float32 and rounded once). ``v`` may be of a head size
+    ``Dv`` of its own (latent attention's queries and keys of 192 over
+    values of 128): the output and ``dv`` are then ``Dv`` wide, ``q``,
+    ``k``, ``dq`` and ``dk`` ``D`` wide, and the blocks are sized by the
+    wider row. Equal sizes are the program of before.
 
     At equal head counts contract-identical to
     :func:`ops.attention.xla_attention` (including under ``jax.grad`` —
@@ -1119,7 +1134,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     fetch-free, so cost scales with W·S instead of S².
     """
     scale, block_q, block_k, interpret = _resolve(
-        q, scale, block_q, block_k, interpret)
+        q, scale, block_q, block_k, interpret, v)
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")
     return _flash(q, k, v, segment_ids, scale, block_q, block_k, interpret,
@@ -1150,7 +1165,7 @@ def flash_attention_fwd_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     :func:`flash_attention_bwd` in its backward ring.)
     """
     scale, block_q, block_k, interpret = _resolve(
-        q, scale, block_q, block_k, interpret)
+        q, scale, block_q, block_k, interpret, v)
     return _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
                      mode="lse", segment_ids=segment_ids, window=window,
                      kv_start=_static_kv_start(kv_start))
@@ -1179,7 +1194,7 @@ def flash_attention_stats(q: jax.Array, k: jax.Array, v: jax.Array,
     Pallas (:func:`parallel.ring_attention.ring_attention`).
     """
     scale, block_q, block_k, interpret = _resolve(
-        q, scale, block_q, block_k, interpret)
+        q, scale, block_q, block_k, interpret, v)
     return _fwd_call(q, k, v, scale, block_q, block_k, interpret, causal,
                      mode="stats", segment_ids=segment_ids, window=window,
                      kv_start=_static_kv_start(kv_start))

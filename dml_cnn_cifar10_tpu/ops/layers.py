@@ -214,6 +214,14 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
         * scale
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude rule: ``0.1 mscale ln(factor) + 1``, and 1 where
+    ``factor`` stretches nothing (1 or less)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
 def rope_frequencies(rope, head_dim: int):
     """A rotary rule -> ``(inv_freq [head_dim / 2] float64, factor)``: the
     angle a position turns pair ``j`` by, and what cos and sin are both
@@ -224,14 +232,20 @@ def rope_frequencies(rope, head_dim: int):
       head_dim)``, factor 1;
     - ``yarn`` (as the ``transformers`` library computes it; keys
       ``factor``, ``original_max_position_embeddings``, ``beta_fast`` 32,
-      ``beta_slow`` 1, ``attention_factor`` ``0.1 ln(factor) + 1``): with
-      ``dim(n) = head_dim ln(original / (2 pi n)) / (2 ln theta)``, ``low =
-      floor(dim(beta_fast))`` and ``high = ceil(dim(beta_slow))`` (clipped
-      to ``0 .. head_dim - 1``), ``ramp_j = clip((j - low) / (high - low),
-      0, 1)`` and ``inv_freq_j = (1 - ramp_j) theta ** (-2 j / head_dim) +
-      ramp_j theta ** (-2 j / head_dim) / factor``: the fast pairs turn as
-      they were trained, the slow ones ``factor`` times slower; the factor
-      is ``attention_factor`` at every length."""
+      ``beta_slow`` 1): with ``dim(n) = head_dim ln(original / (2 pi n)) /
+      (2 ln theta)``, ``low = floor(dim(beta_fast))`` and ``high =
+      ceil(dim(beta_slow))`` (clipped to ``0 .. head_dim - 1``), ``ramp_j =
+      clip((j - low) / (high - low), 0, 1)`` and ``inv_freq_j = (1 -
+      ramp_j) theta ** (-2 j / head_dim) + ramp_j theta ** (-2 j /
+      head_dim) / factor``: the fast pairs turn as they were trained, the
+      slow ones ``factor`` times slower. The factor of cos and sin, at
+      every length, is the rule's ``attention_factor``; where it names
+      none but names ``mscale`` or ``mscale_all_dim`` (DeepSeek's keys,
+      1 and 0 where absent) it is ``yarn_mscale(factor, mscale) /
+      yarn_mscale(factor, mscale_all_dim)``; where it names none of the
+      three it is ``yarn_mscale(factor) = 0.1 ln(factor) + 1``, the
+      library's default. ``head_dim`` is the width rotary turns: the rope
+      slice where only a slice turns (latent attention's 64)."""
     if not isinstance(rope, Mapping):
         rope = {"rope_type": "default", "rope_theta": rope}
     kind = rope.get("rope_type", "default")
@@ -252,9 +266,22 @@ def rope_frequencies(rope, head_dim: int):
     ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
                    / max(high - low, 0.001), 0.0, 1.0)
     factor = rope.get("attention_factor")
-    if factor is None:
-        factor = 0.1 * np.log(scale) + 1.0
+    if factor is None and ("mscale" in rope or "mscale_all_dim" in rope):
+        factor = yarn_mscale(scale, rope.get("mscale", 1.0)) \
+            / yarn_mscale(scale, rope.get("mscale_all_dim", 0.0))
+    elif factor is None:
+        factor = yarn_mscale(scale)
     return (1 - ramp) * plain + ramp * plain / scale, float(factor)
+
+
+def rope_softmax_factor(rope) -> float:
+    """What a YaRN rule multiplies attention's softmax scale by:
+    ``yarn_mscale(factor, mscale_all_dim) ** 2`` where the rule names
+    ``mscale_all_dim`` (DeepSeek's), else 1."""
+    if not isinstance(rope, Mapping) or rope.get("rope_type") != "yarn" \
+            or not rope.get("mscale_all_dim"):
+        return 1.0
+    return yarn_mscale(rope["factor"], rope["mscale_all_dim"]) ** 2
 
 
 def rotary(x: jax.Array, rope) -> jax.Array:

@@ -200,9 +200,10 @@ def moe_mlp(x: jax.Array, params: Params, capacity_factor: float,
 
 def route_top_k(x: jax.Array, router: jax.Array, bias, top_k: int,
                 norm_topk: bool = True, scaling: float = 1.0,
-                score: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
+                score: str = "sigmoid", with_scores: bool = False):
     """Routing over ALL of the layer's experts: ``x [T, D]``, ``router [D,
-    E_all]`` -> ``(chosen [T, k] int32, weights [T, k])``. ``s = sigmoid(x
+    E_all]`` -> ``(chosen [T, k] int32, weights [T, k])``, and ``s`` after
+    them where ``with_scores``. ``s = sigmoid(x
     router)``, each expert's score alone, or with ``score="softmax"`` ``s =
     softmax(x router)`` over all ``E_all`` logits; a token takes the ``k``
     experts with the largest ``s + bias`` (``bias [E_all]`` or None: it
@@ -229,7 +230,31 @@ def route_top_k(x: jax.Array, router: jax.Array, bias, top_k: int,
     if norm_topk:
         total = jnp.sum(weights, -1, keepdims=True)
         weights = weights / (total + 1e-6 if score == "sigmoid" else total)
+    if with_scores:
+        return chosen, weights * scaling, s
     return chosen, weights * scaling
+
+
+def sequence_balance_loss(s: jax.Array, chosen: jax.Array, sequences: int,
+                          alpha: float) -> jax.Array:
+    """DeepSeek-V2's sequence-wise balance loss (arXiv:2405.04434 §2.2.3,
+    ``seq_aux``) of one expert layer: ``s [T, E_all]`` the router's scores
+    of ``sequences`` sequences of ``T / sequences`` tokens each, in order,
+    ``chosen [T, k]`` each token's experts. ``alpha mean_b sum_i f_bi
+    P_bi`` with ``f_bi = E_all / (k S)`` times the slots of sequence ``b``
+    on expert ``i`` (no gradient) and ``P_bi`` its mean score of expert
+    ``i``: 1 alpha under an even load. Over ALL of the router's experts,
+    held here or not: every chip that holds a share of the layer computes
+    the same number from the same router."""
+    t, e_all = s.shape
+    k = chosen.shape[-1]
+    per = t // sequences
+    slots = chosen.reshape(sequences, per * k)
+    counts = jnp.sum(slots[..., None] == jnp.arange(e_all), axis=1,
+                     dtype=jnp.float32)
+    f = counts * (e_all / (k * per))
+    p = jnp.mean(s.reshape(sequences, per, e_all), axis=1)
+    return alpha * jnp.mean(jnp.sum(f * p, -1))
 
 
 @jax.custom_vjp
@@ -415,7 +440,8 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
                    top_k: int, dtype, bias=None, norm_topk: bool = True,
                    scaling: float = 1.0, block_rows: int | None = None,
                    norm_scale=None, norm_eps: float = 1e-5, mesh=None,
-                   score: str = "sigmoid"
+                   score: str = "sigmoid", shared=None,
+                   balance_alpha: float = 0.0, sequences: int = 1
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """What the experts held here add to every token: ``x [T, D]``
     (float32) -> ``([T, D] float32, stats)``.
@@ -453,12 +479,20 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
     more over the tokens, and no more memory. On one chip nothing is
     exchanged; what the absent experts would add is left out.
 
+    ``shared``, where given, is a function of ``m [T, D]`` that every token
+    takes (shared experts: every chip computes them for its own tokens),
+    added to the routed part under scope ``shared``. ``balance_alpha`` > 0
+    adds :func:`sequence_balance_loss` over ``sequences`` sequences of the
+    ``T`` tokens, from the router's own scores.
+
     ``stats``: ``rows_here_frac``, the slots on experts held here over ``T
     * top_k``; ``load_max_over_mean``, the fullest held expert's rows
     over the mean's; ``buffer_rounds``, the rounds the layer took (1 when
-    the load is within the buffer); and ``expert_load [E_all]``, the slots
+    the load is within the buffer); ``expert_load [E_all]``, the slots
     each of the router's experts got, held here or not
-    (:func:`balanced_bias`)."""
+    (:func:`balanced_bias`); none of them with a gradient. And, with
+    ``balance_alpha``, ``balance_loss``: the loss term, WITH its
+    gradient, for the caller to add to its loss."""
     t, d = x.shape
     e, e_all = params["w1"].shape[0], params["router"].shape[1]
     slots = t * top_k
@@ -476,10 +510,14 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
     def route(m, router, bias):
         with jax.named_scope("route"):
             return route_top_k(m, router, bias, top_k, norm_topk, scaling,
-                               score)
+                               score, with_scores=balance_alpha > 0)
 
     m = normed(x, norm_scale)
-    chosen, weights = route(m, params["router"], bias)
+    chosen, weights, *scores = route(m, params["router"], bias)
+    if scores:
+        with jax.named_scope("route"):
+            balance = sequence_balance_loss(scores[0], chosen, sequences,
+                                            balance_alpha)
     with jax.named_scope("dispatch"):
         local = chosen.reshape(slots) - first_expert
         local = jnp.where((local >= 0) & (local < e), local, e)
@@ -509,6 +547,9 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
          mesh),
         m.astype(dtype), params["w1"], params["w3"], params["w2"], here,
         (slot_of, sizes_of, weight_of), pos)
+    if shared is not None:
+        with jax.named_scope("shared"):
+            y = y + shared(m)
     # the step's line: how the grouped products ran, then how the rows
     # reached their tokens (each chooser noted its own)
     kernel_paths.note("experts", f"{kernel_paths.noted('grouped')}, "
@@ -521,4 +562,7 @@ def routed_experts(x: jax.Array, params: Params, *, first_expert: int,
              "expert_load": jnp.sum(
                  chosen.reshape(slots)[:, None] == jnp.arange(e_all)[None, :],
                  axis=0, dtype=jnp.int32)}
-    return y, jax.tree.map(lax.stop_gradient, stats)
+    stats = jax.tree.map(lax.stop_gradient, stats)
+    if scores:
+        stats["balance_loss"] = balance
+    return y, stats

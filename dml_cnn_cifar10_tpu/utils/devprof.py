@@ -378,6 +378,10 @@ LAYER_KINDS = (
     # attention under a sliding window, where a stack has both kinds (its
     # norm stays `norm`; the sublayer's scopes are `attn`'s)
     ("window_attention", re.compile(r"^attn_window$")),
+    # multi-head latent attention (its parts `q`, `kv_down`, `kv_norm`,
+    # `kv_up`, `rotary`, `flash`, `out`) and the experts every token takes
+    ("latent_attention", re.compile(r"^mla$")),
+    ("shared_expert", re.compile(r"^shared$")),
 )
 PASSES = ("forward", "recompute", "backward", "update", "other")
 

@@ -370,6 +370,10 @@ def _observe_record(kind: str, f: dict, reg: MetricsRegistry) -> None:
                  "Times an expert layer filled its buffer of rows and "
                  "summed it by token, mean over the expert layers (1: "
                  "the load was within the buffer)"),
+                ("moe_aux_loss",
+                 "Sequence-wise balance loss of an expert layer (alpha "
+                 "times the mean over sequences of sum_i f_i P_i), mean "
+                 "over the expert layers"),
                 # a stack with window layers (models/hybrid_decoder.py)
                 ("attn_window_blocks_frac",
                  "Block pairs the window layers' flash schedule visits "

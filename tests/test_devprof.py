@@ -550,6 +550,53 @@ def test_the_part_is_the_scope_after_the_one_that_decided_the_kind(
         assert devprof.parse_op_name(path)[1::2] == (kind, part), path
 
 
+@pytest.mark.parametrize("op_name,want", [
+    # multi-head latent attention: kind `latent_attention`, each of its
+    # parts (the latent's norm is the sublayer's, not kind `norm`)
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer2)/while/body/"
+     "closed_call/mla/q/dot_general",
+     ("fwd_bwd/layer2/mla/q", "latent_attention", "forward", "q")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer2)/while/body/"
+     "closed_call/mla/kv_down/dot_general",
+     ("fwd_bwd/layer2/mla/kv_down", "latent_attention", "forward",
+      "kv_down")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp(layer2))/"
+     "while/body/closed_call/checkpoint/rematted_computation/mla/kv_norm/"
+     "rsqrt",
+     ("fwd_bwd/layer2/mla/kv_norm", "latent_attention", "recompute",
+      "kv_norm")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp(layer2))/"
+     "while/body/closed_call/checkpoint/mla/kv_up/dot_general",
+     ("fwd_bwd/layer2/mla/kv_up", "latent_attention", "backward", "kv_up")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer0)/while/body/"
+     "closed_call/mla/rotary/concatenate",
+     ("fwd_bwd/layer0/mla/rotary", "latent_attention", "forward",
+      "rotary")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp(layer4))/"
+     "while/body/closed_call/checkpoint/mla/flash/jit(flash_attention)/"
+     "flash_bwd_dkv/pallas_call",
+     ("fwd_bwd/layer4/mla/flash/flash_bwd_dkv", "latent_attention",
+      "backward", "flash")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer1)/while/body/"
+     "closed_call/mla/out/dot_general",
+     ("fwd_bwd/layer1/mla/out", "latent_attention", "forward", "out")),
+    # the shared experts, chunk by chunk, each formed again under `remat`
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer1)/moe/shared/"
+     "while/body/closed_call/checkpoint/dot_general",
+     ("fwd_bwd/layer1/moe/shared", "shared_expert", "forward", "")),
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/transpose(jvp(layer1))/"
+     "moe/shared/while/body/closed_call/checkpoint/rematted_computation/"
+     "logistic",
+     ("fwd_bwd/layer1/moe/shared", "shared_expert", "recompute", "")),
+    # the balance loss is the router's
+    ("jit(chunk_dev)/while/body/closed_call/fwd_bwd/jvp(layer1)/moe/route/"
+     "reduce_sum", ("fwd_bwd/layer1/moe/route", "route", "forward", "")),
+])
+def test_the_latent_sublayer_and_the_shared_experts_have_kinds_of_their_own(
+        op_name, want):
+    assert devprof.parse_op_name(op_name) == want
+
+
 def _toy_step(remat: bool):
     import jax
     import jax.numpy as jnp

@@ -36,7 +36,9 @@ KIND_KEYS = {
     # (utils/devprof.py; null before the first complete window).
     # `optimizer_ms` is the per-step device time inside the step's
     # jax.named_scope("optimizer"), from the last --profile_at_steps
-    # capture window (null until one completes).
+    # capture window (null until one completes). Optional keys ride
+    # beside these and are not checked: `moe_*` (an expert decoder's
+    # counters, `moe_aux_loss` with a balance loss), `attn_*`, `health_*`.
     "train": ("step", "loss", "train_accuracy", "images_per_sec", "lr",
               "device_step_ms", "drain_wait_ms", "optimizer_ms"),
     "eval": ("step", "test_accuracy"),
