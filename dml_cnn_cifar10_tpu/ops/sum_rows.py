@@ -17,8 +17,12 @@ block, the buffer left in HBM and only the rows a token has in range
 fetched, one row a DMA, all of a tile's copies in flight before the first
 is waited for, then added to its token's sum in the order of the token's
 choices; the rows a tile has in range, each with its token, sit in scalar
-memory, put first in the tile by a sort outside the kernel. A DMA moves
-whole (8, 128) tiles, so the kernel's buffer holds a row as ``[D / 128,
+memory, put first in the tile by a sort outside the kernel. A token has
+there as many slots as the power of two at or above ``k`` (8 at ``k`` =
+6), the slots past its ``k`` choices absent, so that a tile's slots fill
+whole blocks of scalar memory for any ``k``: the sort puts them last with
+the other absent ones, and nothing fetches, waits for or adds them. A DMA
+moves whole (8, 128) tiles, so the kernel's buffer holds a row as ``[D / 128,
 128]``, tiles that lie together in HBM, and not as one sublane of ``[R,
 D]``'s tiles: :func:`row_shape` says in which of the two shapes the caller
 keeps a row, and that shape is what :func:`sum_rows_by_token` reads the
@@ -28,8 +32,10 @@ the buffer's other rows may hold anything.
 Who takes the kernel: a TPU backend, one device, a width of whole lanes
 (a multiple of 128; a row whose ``D / 128`` is no multiple of 8 is padded
 to whole tiles by the buffer's layout) and tokens that fill the sublanes (a
-multiple of 8) and divide into tiles. Everything else runs ``k`` masked gathers summed in the same order,
-which is also the kernel's test oracle.
+multiple of 8) and divide into tiles: a tile's padded slots a whole number
+of scalar blocks, the rows its ``k`` choices a token can fetch within the
+budget of vector memory (:func:`_tile`). Everything else runs ``k`` masked
+gathers summed in the same order, which is also the kernel's test oracle.
 """
 
 from __future__ import annotations
@@ -45,12 +51,14 @@ from dml_cnn_cifar10_tpu.utils import platform as platform_lib
 
 _LANES = 128
 _SUBLANES = 8
-#: Budget of a tile's fetched rows in vector memory (k x tile x D float32):
+#: Budget of a tile's fetched rows in vector memory, counted on the rows it
+#: can really fetch (k x tile x D float32, the padded slots fetch nothing):
 #: with the sums and the output's blocks a quarter of the limit asked for.
 _ROWS_BYTES = 12 << 20
-#: A tile's slots in scalar memory are one block of a 1-D int32 array, and
-#: XLA lays such an array out in tiles of 1,024 (``T(1024)``): Mosaic
-#: refuses a block of 512 of it for the described v5e.
+#: A tile's slots in scalar memory, counted padded (:func:`_slots` a
+#: token), are one block of a 1-D int32 array, and XLA lays such an array
+#: out in tiles of 1,024 (``T(1024)``): Mosaic refuses a block of 512 of it
+#: for the described v5e.
 _SCALAR_BLOCK = 1024
 _VMEM_LIMIT = 64 << 20
 #: Tokens whose sums leave for the output block at once.
@@ -73,7 +81,9 @@ def row_shape(tokens: int, k: int, width: int, mesh=None) -> tuple:
         # rows may lie on any device
         kernel_paths.note("experts", "xla (mesh)")
         return (width,)
-    kernel_paths.note("experts", "pallas sum-by-token")
+    wide = _slots(k)
+    kernel_paths.note("experts", "pallas sum-by-token" if wide == k else
+                      f"pallas sum-by-token ({k} of {wide} slots)")
     return (width // _LANES, _LANES)
 
 
@@ -101,11 +111,17 @@ def sum_rows_by_token(buffer: jax.Array, pos: jax.Array, lo, hi) -> jax.Array:
     return sum_rows_pallas(buffer, _in_range(pos, lo, hi), False)
 
 
+def _slots(k: int) -> int:
+    """A token's slots in the kernel: the power of two at or above ``k``."""
+    return 1 << (k - 1).bit_length()
+
+
 def _tile(tokens: int, k: int, width: int):
     """Tokens a grid step: all of them where their rows fit the budget,
     else the largest power of two times 8 that divides ``tokens``, keeps
-    the fetched rows inside the budget and a tile's ``k`` slots a token a
-    multiple of :data:`_SCALAR_BLOCK`; None where there is none."""
+    the rows of its ``k`` choices a token inside the budget and its
+    :func:`_slots` a token a multiple of :data:`_SCALAR_BLOCK`; None where
+    there is none."""
     def fits(tile):
         return tile * k * width * 4 <= _ROWS_BYTES
 
@@ -113,7 +129,7 @@ def _tile(tokens: int, k: int, width: int):
         return tokens
     best, tile = None, _SUBLANES
     while tokens % tile == 0 and fits(tile):
-        if tile * k % _SCALAR_BLOCK == 0:
+        if tile * _slots(k) % _SCALAR_BLOCK == 0:
             best = tile
         tile *= 2
     return best
@@ -132,12 +148,16 @@ def sum_rows_pallas(buffer, rel, interpret=False):
     _, parts, lanes = buffer.shape
     tile = _tile(tokens, k, parts * lanes)
     chunk = _CHUNK if tile % _CHUNK == 0 else _SUBLANES
+    wide = _slots(k)
+    if wide > k:
+        rel = jnp.pad(rel, ((0, 0), (0, wide - k)), constant_values=-1)
     # A tile's rows in range come first, in the order of their slots (a
     # token's choices stay in order), each with its token: the kernel's
-    # scalar loops then run over rows that exist and take no branch.
-    slots = rel.reshape(tokens // tile, tile * k)
-    token = jnp.broadcast_to(jnp.arange(tile * k, dtype=jnp.int32) // k,
-                             slots.shape)
+    # scalar loops then run over rows that exist and take no branch, and
+    # never reach the padded slots.
+    slots = rel.reshape(tokens // tile, tile * wide)
+    token = jnp.broadcast_to(
+        jnp.arange(tile * wide, dtype=jnp.int32) // wide, slots.shape)
     absent, rows_of, token_of = lax.sort((slots < 0, slots, token),
                                          dimension=1, num_keys=1)
     count = jnp.sum(~absent, 1, dtype=jnp.int32)
@@ -169,7 +189,7 @@ def sum_rows_pallas(buffer, rel, interpret=False):
 
         lax.fori_loop(0, tile // chunk, leave, None)
 
-    a_tiles_slots = pl.BlockSpec((tile * k,), lambda i, count: (i,),
+    a_tiles_slots = pl.BlockSpec((tile * wide,), lambda i, count: (i,),
                                  memory_space=pltpu.SMEM)
 
     return pl.pallas_call(

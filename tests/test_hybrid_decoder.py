@@ -274,16 +274,18 @@ def _rows_of_tokens(tokens, k, seed):
     return jnp.asarray(pos), here
 
 
+@pytest.mark.parametrize("k", [4, 6, 3])
 @pytest.mark.parametrize("width", [256, 2048, 2304])
 @pytest.mark.parametrize("cut", ["whole", "cuts_a_tokens_rows", "empty",
                                  "second_round"])
-def test_each_token_sums_the_rows_it_has_in_the_buffer(width, cut):
+def test_each_token_sums_the_rows_it_has_in_the_buffer(width, cut, k):
     """The kernel in the Pallas interpreter, bit for bit the XLA expression
     (the same float32 terms in the same order), and both
     ``jax.ops.segment_sum`` of the rows by their tokens: tokens with 0 to
-    4 rows here, a range that cuts a token's rows, an empty one, and one
-    that starts past the buffer's first row."""
-    tokens, k = 40, 4
+    ``k`` rows here, a range that cuts a token's rows, an empty one, and
+    one that starts past the buffer's first row; at 6 and 3 choices a
+    token the kernel pads its slots to 8 and 4."""
+    tokens = 40
     pos, here = _rows_of_tokens(tokens, k, seed=width)
     lo, hi = {"whole": (0, here), "cuts_a_tokens_rows": (0, here - 9),
               "empty": (0, 0), "second_round": (32, here)}[cut]
@@ -301,12 +303,29 @@ def test_each_token_sums_the_rows_it_has_in_the_buffer(width, cut):
     np.testing.assert_allclose(expression, want, rtol=1e-6, atol=1e-6)
     rows_of = np.bincount(slots // k, minlength=tokens)
     if cut == "whole":
-        assert set(rows_of) == {0, 1, 2, 3, 4}
+        assert set(rows_of) == set(range(k + 1))
     else:       # some token has rows on both sides of the cut
         assert np.any((rows_of > 0) | (cut == "empty"))
         assert np.any(rows_of < np.arange(tokens) % (k + 1))
     np.testing.assert_array_equal(
         np.asarray(expression)[rows_of == 0], 0.0)
+
+
+def test_tiles_of_padded_slots_sum_as_the_expression(monkeypatch):
+    """Where a tile holds fewer tokens than there are, at 6 choices a token
+    (8 slots, so a tile of 128 tokens is one block of 1,024 scalars): the
+    kernel in the interpreter over two tiles, bit for bit the XLA
+    expression."""
+    tokens, k, width = 256, 6, 256
+    monkeypatch.setattr(sum_rows, "_ROWS_BYTES", 128 * k * width * 4)
+    assert sum_rows._tile(tokens, k, width) == 128
+    pos, here = _rows_of_tokens(tokens, k, seed=6)
+    buffer = jax.random.normal(jax.random.key(6), (here, width))
+    rel = sum_rows._in_range(pos, 0, here)
+    kernel = sum_rows.sum_rows_pallas(
+        buffer.reshape(here, width // 128, 128), rel, True)
+    np.testing.assert_array_equal(
+        kernel, sum_rows.sum_rows_xla(buffer, pos, 0, here))
 
 
 def test_who_takes_the_kernel(monkeypatch):
@@ -335,6 +354,13 @@ def test_who_takes_the_kernel(monkeypatch):
         # a tile's slots are a block of 1,024 scalars, whatever k is
         assert sum_rows._tile(32768, 8, 2304) == 128
         assert rec == {"experts": "xla"}
+        # 6 choices a token take 8 slots: 2,048 slots a tile of 256, whose
+        # rows of 6 choices are the budget's 12 MiB to the byte
+        assert sum_rows._tile(32768, 6, 2048) == 256
+        assert sum_rows.row_shape(32768, 6, 2048, one) == (16, 128)
+        assert rec == {"experts": "pallas sum-by-token (6 of 8 slots)"}
+        assert sum_rows.row_shape(32768, 4, 2048, one) == (16, 128)
+        assert rec == {"experts": "pallas sum-by-token"}
 
 
 def test_the_bias_changes_the_choice_and_not_the_weights(expert_layer):

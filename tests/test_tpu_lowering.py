@@ -249,24 +249,28 @@ def test_float_scatters_skip_only_megablox_launchers(name, counted):
     assert all("moe/route" in s for s in found)
 
 
-@pytest.mark.parametrize("ndev", [1, 4])
+@pytest.mark.parametrize("ndev, top_k", [(1, 2), (4, 2), (1, 6), (4, 6)])
 def test_hybrid_decoder_step_sums_the_experts_rows_by_token(
-        chip_branch, ndev, capsys, tmp_path):
+        chip_branch, ndev, top_k, capsys, tmp_path):
     """At widths of whole tiles on one device, in bfloat16, the experts'
     three grouped products are megablox's ``gmm`` / ``tgmm`` kernels and
     their rows reach their tokens through the sum-by-token kernel, forward
     and backward, once an expert layer (the buffer holds every block at
-    this size, so no further round is built); on a mesh of four the same
-    step keeps ``lax.ragged_dot`` and the XLA expression. Either way no
-    scatter of floats is left under ``moe/``: the embedding's gradient is
-    the step's only one."""
+    this size, so no further round is built), at 6 choices a token on 8
+    slots; on a mesh of four the same step keeps ``lax.ragged_dot`` and
+    the XLA expression. Either way no scatter of floats is left under
+    ``moe/``: the embedding's gradient is the step's only one."""
     import json
 
     from dml_cnn_cifar10_tpu.models import hybrid_decoder
     path = tmp_path / "sizes.json"
+    # at 6 choices all 8 experts are held, so that the buffer holds every
+    # block of the 768 rows and one round is built
     path.write_text(json.dumps({**hybrid_decoder.SMALL, "hidden_size": 1024,
                                 "head_dim": 128,
-                                "moe_intermediate_size": 256}))
+                                "moe_intermediate_size": 256,
+                                "num_experts_per_tok": top_k,
+                                "num_experts": {2: 4, 6: 8}[top_k]}))
     model_def = get_model("hybrid_decoder")
     model_cfg = ModelConfig(name="hybrid_decoder", remat=True,
                             compute_dtype="bfloat16", config_file=str(path))
@@ -282,8 +286,9 @@ def test_hybrid_decoder_step_sums_the_experts_rows_by_token(
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     layers = hybrid_decoder.SMALL["num_hidden_layers"] \
         - hybrid_decoder.SMALL["num_dense_layers"]
-    # 4 x 32 tokens of 2 choices: one block of 256 rows
+    # 4 x 32 tokens of 2 choices: one block of 256 rows; of 6, three
     want = "pallas gmm (256,1024,256) (256,256,1024), pallas sum-by-token" \
+        + {2: "", 6: " (6 of 8 slots)"}[top_k] \
         if ndev == 1 else "ragged_dot, xla (mesh)"
     assert f" experts={want}\n" in capsys.readouterr().out
     # (the jitted launcher is one function a distinct trace, called from
