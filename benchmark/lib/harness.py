@@ -174,11 +174,13 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     # traffic's length; the older two names are what the readers know
     examples = steps * batch
     flops = cell.reference.train_flops_per_image(cell.config)
+    # ``config`` is the cell's configuration's file as it was run: a
+    # reader takes its sizes from there, never from a file it names
     ctx = {"window_s": w.t1 - w.t0, "steps": steps, "examples": examples,
            "images": examples, "chips": cell.chips, "peak": peak,
            "spans": w.spans, "trace": trace,
            "setup_s": w.t0 - t_process_start, "flops_per_example": flops,
-           "flops_per_image": flops}
+           "flops_per_image": flops, "config": cell.config}
     wanted = cell.per_layer if traced else cell.end_to_end
     metrics, silent = {}, []
     for m in wanted:

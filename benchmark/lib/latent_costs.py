@@ -27,13 +27,9 @@ way (the output leads a custom call's text, and it is ``d_v`` wide).
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Optional, Tuple
 
 from benchmark.lib import kernel_costs
-
-HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def flash_latent_fwd(b: int, s: int, heads: int, d_qk: int, d_v: int,
@@ -55,23 +51,22 @@ def flash_latent_bwd(b: int, s: int, heads: int, d_qk: int, d_v: int,
             "bytes": rows * (4 * d_qk + 4 * d_v) * itemsize + 2 * rows * 4}
 
 
-def config_widths(config: str) -> Tuple[int, int]:
-    """``(d_qk, d_v)`` of a configuration's file."""
-    with open(os.path.join(HERE, "configs", config + ".json")) as f:
-        spec = json.load(f)
+def config_widths(spec: dict) -> Tuple[int, int]:
+    """``(d_qk, d_v)`` of a configuration's file (the cell's own)."""
     return spec["qk_nope_head_dim"] + spec["qk_rope_head_dim"], \
         spec["v_head_dim"]
 
 
-def latent_roofline_pct(ctx, names: str, cost, widths: Tuple[int, int],
+def latent_roofline_pct(ctx, names: str, cost, spec: dict,
                         launches_a_pass: int = 1) -> Optional[float]:
     """``kernel_costs.kernel_roofline_pct`` for the kernels named
-    ``<one of names>.<n>``, each product at the widths ``(d_qk, d_v)``
-    and not at the width the event's first array has. Nothing where the
-    trace has no such kernel."""
-    d_qk, d_v = widths
+    ``<one of names>.<n>``, each product at the widths ``(d_qk, d_v)`` of
+    ``spec`` (the cell's configuration), read only once such a kernel is
+    found, and not at the width the event's first array has. Nothing where
+    the trace has no such kernel."""
     return kernel_costs.kernel_roofline_pct(
         ctx, names,
-        lambda b, s, heads, d, itemsize: cost(b, s, heads, d_qk, d_v,
+        lambda b, s, heads, d, itemsize: cost(b, s, heads,
+                                              *config_widths(spec),
                                               itemsize),
         launches_a_pass)

@@ -18,13 +18,9 @@ there are, not what a pair or a row costs.
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Optional
 
 from benchmark.lib import kernel_costs
-
-HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def band_pairs(s: int, window: Optional[int]) -> int:
@@ -53,19 +49,26 @@ def flash_window_bwd(b: int, s: int, heads: int, head_dim: int,
                    s, window)
 
 
-def config_window(config: str) -> int:
-    """``sliding_window`` of a configuration's file."""
-    with open(os.path.join(HERE, "configs", config + ".json")) as f:
-        return json.load(f)["sliding_window"]
+def config_window(spec: dict) -> int:
+    """``sliding_window`` of a configuration's file (the cell's own); a
+    ``ValueError`` where it states none, since the band of an unknown
+    window cannot be counted."""
+    window = spec.get("sliding_window")
+    if not window:
+        raise ValueError(f"the configuration states no sliding_window: "
+                         f"{window!r}")
+    return window
 
 
-def window_roofline_pct(ctx, names: str, cost, window: int,
+def window_roofline_pct(ctx, names: str, cost, spec: dict,
                         launches_a_pass: int = 1) -> Optional[float]:
     """``kernel_costs.kernel_roofline_pct`` for the kernels a call with a
-    window names (``flash_window_fwd.<n>`` etc.), their cost the band's.
-    Nothing where the trace has no such kernel."""
+    window names (``flash_window_fwd.<n>`` etc.), their cost the band's
+    at the window of ``spec`` (the cell's configuration), which is read
+    only once such a kernel is found. Nothing where the trace has no such
+    kernel."""
     return kernel_costs.kernel_roofline_pct(
         ctx, names,
-        lambda b, s, heads, d, itemsize: cost(b, s, heads, d, window,
-                                              itemsize),
+        lambda b, s, heads, d, itemsize: cost(b, s, heads, d,
+                                              config_window(spec), itemsize),
         launches_a_pass)

@@ -2,16 +2,13 @@
 roofline in the traced window: the BAND's operations over the bf16 peak
 (or the kernel's bytes over the memory's rate, whichever is larger) over
 the time the kernels named ``flash_window_fwd.<n>`` took
-(``benchmark/lib/window_costs.py``). The window is that of the one
-configuration whose cell lists this metric, the other shapes the event's
-own. Nothing where the program has no such kernel."""
+(``benchmark/lib/window_costs.py``). The window is that of the cell's own
+configuration, the other shapes the event's own. Nothing where the
+program has no such kernel."""
 
 from benchmark.lib import window_costs
-
-CONFIG = "mellum2_12b_a2p5b_l4_e8"
 
 
 def read(ctx):
     return window_costs.window_roofline_pct(
-        ctx, "flash_window_fwd", window_costs.flash_window_fwd,
-        window_costs.config_window(CONFIG))
+        ctx, "flash_window_fwd", window_costs.flash_window_fwd, ctx["config"])

@@ -17,14 +17,21 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 LINE = re.compile(r"^[^\t\n]{1,200}$")
 
 
-@pytest.fixture(scope="module", params=["committed", "with_pending"])
+@pytest.fixture(scope="module",
+                params=["committed", "with_pending", "appended"])
 def bench(request, tmp_path_factory):
-    """``BENCHMARK.json`` as committed, and as it will read once the
-    entries under ``benchmark/pending/`` have joined it. Gives the root to
-    load cells from, too."""
+    """``BENCHMARK.json`` as committed, as it will read once the entries
+    under ``benchmark/pending/`` have joined it, and as a configuration
+    that joins at the end leaves it. Gives the root to load cells from,
+    too."""
     if request.param == "committed":
         with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
             return dict(json.load(f), _root=ROOT)
+    if request.param == "appended":
+        root = bench_roots.make_root_with_one_appended(
+            str(tmp_path_factory.mktemp("appended_root")))
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            return dict(json.load(f), _root=root)
     pending = bench_roots.benchmark_with_pending()
     root = bench_roots.make_root(
         str(tmp_path_factory.mktemp("pending_root")), pending)

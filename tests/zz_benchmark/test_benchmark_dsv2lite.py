@@ -37,6 +37,9 @@ SMALL = {"hidden_size": 64, "num_attention_heads": 4,
          "aux_loss_alpha": 0.01, "sequence_length": 40}
 NEW = ["model.latent_attention_device_ms", "model.shared_expert_device_ms",
        "flash_latent_fwd_roofline", "flash_latent_bwd_roofline"]
+# entries by layer kind that list this cell beside the cells they began with
+BY_KIND = ["model.route_device_ms", "model.expert_device_ms",
+           "model.mlp_device_ms"]
 
 
 def published() -> dict:
@@ -178,28 +181,43 @@ def test_the_module_counts_the_published_model():
     assert cell.chips == 1 and harness.task_of(cell).grad_blocks == 1
 
 
-def test_the_cells_entries_and_those_that_wait():
-    """The configuration and the cell are the newest entries of their
-    lists; the four per-layer entries of the cell wait under
-    ``benchmark/pending/`` and the tests' roots lay them over
-    ``BENCHMARK.json``, listing this cell alone."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert bench["configs"][-1]["name"] == "deepseek_v2_lite_l5_e8"
-    assert bench["workloads"][-1] == {
+def check_the_cells_entries(bench: dict):
+    """In ``bench`` (``BENCHMARK.json``, or one to which a later
+    configuration has been appended), found by name: the configuration,
+    the cell, the cell's four entries of its own and the three by kind
+    that it joined. An entry's list of cells holds this one, and may hold
+    cells that join later. The causal kernels' two shares do not list it:
+    they would cost its value side at the query's width."""
+    configs = {c["name"]: c for c in bench["configs"]}
+    workloads = {w["name"]: w for w in bench["workloads"]}
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    assert "deepseek_v2_lite_l5_e8" in configs
+    assert workloads[CELL] == {
         "name": CELL, "config": "deepseek_v2_lite_l5_e8",
         "traffic": "b4_s8192_resident_k2", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    assert not set(NEW) & {m["name"] for m in bench["per_layer"]}
-    pending = {m["name"]: m for m in
-               bench_roots.benchmark_with_pending()["per_layer"]}
-    for name in NEW:
-        m = pending[name]
-        assert m["workloads"] == [CELL]
+        "why": workloads[CELL]["why"]}
+    assert set(NEW) | set(BY_KIND) <= set(listed)
+    for name in NEW + BY_KIND:
+        m = listed[name]
+        assert CELL in m["workloads"], name
         assert (m["better"], m["source"], m["moves"], m["layer"]) == (
             "higher" if name.endswith("_roofline") else "lower",
             "device_trace", "img_per_s_per_chip", "models and kernels")
         assert m["unit"] == ("%" if name.endswith("_roofline") else "ms")
+    for name in ("flash_fwd_roofline", "flash_bwd_roofline"):
+        assert CELL not in listed[name]["workloads"], name
+
+
+def test_the_cells_entries_and_those_that_wait():
+    """The configuration, the cell and its entries are in
+    ``BENCHMARK.json``; none of them waits under ``benchmark/pending/``
+    any longer, and the tests' roots lay nothing of this cell over it."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    check_the_cells_entries(bench)
+    check_the_cells_entries(bench_roots.benchmark_with_pending())
+    cell = cells.load_cell(ROOT, CELL)
+    assert set(NEW) | set(BY_KIND) <= {m["name"] for m in cell.per_layer}
 
 
 def test_the_latent_kernels_costs_by_hand():
@@ -227,7 +245,7 @@ def test_the_latent_kernels_costs_by_hand():
                                                               rel=1e-3)
     assert fwd["bytes"] / PEAK["hbm_bytes_per_s"] \
         == pytest.approx(0.822e-3, rel=1e-2)
-    assert latent_costs.config_widths("deepseek_v2_lite_l5_e8") == (192, 128)
+    assert latent_costs.config_widths(published()) == (192, 128)
 
 
 @pytest.mark.parametrize("name,names,passes", [
@@ -247,14 +265,39 @@ def test_a_latent_share_counts_each_product_at_its_width(name, names,
     ops = [xplane.Op(i * 2e7, i * 2e7 + 12e6, n, text, "XLA Ops")
            for i, n in enumerate(names)]
     ctx = {"trace": xplane.Trace([xplane.DevicePlane("/device:TPU:0", ops)]),
-           "peak": PEAK}
+           "peak": PEAK, "config": published()}
     least = 2 * 64 * kernel_costs.causal_pairs(8192) * passes / 197e12
     assert read(ctx) == pytest.approx(100 * least / (12e-3 * len(names)))
     assert 0 < read(ctx) < 100
     other = [xplane.Op(0, 1e6, "flash_window_fwd.1", text, "XLA Ops")]
     ctx["trace"] = xplane.Trace([xplane.DevicePlane("/device:TPU:0", other)])
     assert read(ctx) is None
-    assert read({"trace": None, "peak": PEAK}) is None
+    assert read({"trace": None, "peak": PEAK, "config": published()}) is None
+
+
+@pytest.mark.parametrize("name,names,passes", [
+    ("flash_latent_fwd_roofline", ("flash_fwd.3",), 128 + 64),
+    ("flash_latent_bwd_roofline", ("flash_bwd_dq.4", "flash_bwd_dkv.5"),
+     3 * 128 + 2 * 64)])
+def test_a_latent_share_takes_the_widths_of_the_cells_configuration(
+        name, names, passes):
+    """The same kernels read for a cell whose configuration states other
+    widths (queries and keys 96 + 32, values 64): the share costs the
+    products at those, not at this configuration's 192 and 128."""
+    read = cells.load_module(os.path.join(
+        ROOT, "benchmark", "metrics", name + ".py")).read
+    text = "%k = (bf16[64,8192,64]{2,1,0}, f32[64,8192,128]) custom-call(" \
+        "s32[4,136] %s, bf16[64,8192,128] %q, ...)"
+    ops = [xplane.Op(i * 2e7, i * 2e7 + 12e6, n, text, "XLA Ops")
+           for i, n in enumerate(names)]
+    other = {**published(), "qk_nope_head_dim": 96, "qk_rope_head_dim": 32,
+             "v_head_dim": 64}
+    ctx = {"trace": xplane.Trace([xplane.DevicePlane("/device:TPU:0", ops)]),
+           "peak": PEAK, "config": other}
+    assert latent_costs.config_widths(other) == (128, 64)
+    least = 2 * 64 * kernel_costs.causal_pairs(8192) * passes / 197e12
+    assert read(ctx) == pytest.approx(100 * least / (12e-3 * len(names)))
+    assert read({**ctx, "config": published()}) > read(ctx)
 
 
 @pytest.mark.parametrize("name,kind", [

@@ -171,7 +171,8 @@ def test_the_experts_share_is_read_from_the_programs_count(monkeypatch):
     from benchmark.lib import scopes
     spec = published()
     peak = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
-    ctx = {"trace": None, "peak": peak, "steps": 4, "examples": 16}
+    ctx = {"trace": None, "peak": peak, "steps": 4, "examples": 16,
+           "config": spec}
     read = cells.load_module(os.path.join(
         ROOT, "benchmark", "metrics", "expert_roofline.py")).read
     monkeypatch.setattr(scopes, "kind_ms_per_step",
@@ -185,6 +186,73 @@ def test_the_experts_share_is_read_from_the_programs_count(monkeypatch):
     monkeypatch.setattr(expert_costs, "rows_here_frac", lambda: 0.25)
     monkeypatch.setattr(scopes, "kind_ms_per_step", lambda ctx, kind: None)
     assert read(ctx) is None
+
+
+@pytest.mark.parametrize("config,rows,d,h", [
+    ("mellum2_12b_a2p5b_l4_e8", 32768 * 8, 2304, 896),
+    ("deepseek_v2_lite_l5_e8", 32768 * 6, 2048, 1408)])
+def test_the_experts_share_takes_the_cells_own_configuration(
+        config, rows, d, h, monkeypatch):
+    """The same trace and count read for a cell of another configuration:
+    its own four expert layers, experts a token and widths (Mellum2's 8
+    choices of 2,304 -> 896, DeepSeek-V2-Lite's 6 of 2,048 -> 1,408 after
+    its dense layer), not the expert decoder's."""
+    from benchmark.lib import scopes
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        spec = json.load(f)
+    peak = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+    ctx = {"trace": None, "peak": peak, "steps": 4, "examples": 16,
+           "config": spec}
+    read = cells.load_module(os.path.join(
+        ROOT, "benchmark", "metrics", "expert_roofline.py")).read
+    monkeypatch.setattr(scopes, "kind_ms_per_step",
+                        lambda ctx, kind: {"expert": 60.0}.get(kind))
+    monkeypatch.setattr(expert_costs, "rows_here_frac", lambda: 0.25)
+    least = 4 * 9 * 2 * (rows / 4) * d * h / 197e12
+    assert read(ctx) == pytest.approx(100 * least / 60e-3)
+    assert read(ctx) != read({**ctx, "config": published()})
+
+
+EXPERTS = {   # configuration: (layers, held, a token, D, H), or none
+    "cnn_cifar10": None, "resnet50_imagenet": None, "ouro_2p6b_l6": None,
+    "lfm2_8b_a1b_l5_e8": (4, 8, 4, 2048, 1792),
+    "mellum2_12b_a2p5b_l4_e8": (4, 8, 8, 2304, 896),
+    "deepseek_v2_lite_l5_e8": (4, 8, 6, 2048, 1408)}
+
+
+@pytest.mark.parametrize("config", sorted(EXPERTS))
+def test_the_expert_layers_of_each_configuration(config):
+    """Each configuration's expert layers by its own keys: LFM2's leading
+    dense layer, Mellum2's list of MLP kinds, DeepSeek-V2's first dense
+    layer and the frequency after it; none where a configuration states no
+    experts."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        spec = json.load(f)
+    got = expert_costs.expert_layers(spec)
+    assert (got if got is None else tuple(got)) == EXPERTS[config]
+
+
+@pytest.mark.parametrize("change,match", [
+    ({"num_dense_layers": None, "mlp_layer_types": None}, "expert layers"),
+    ({"num_dense_layers": 2}, "expert layers"),
+    ({"num_experts": None}, "experts held"),
+    ({"n_routed_experts": 16}, "experts held"),
+    ({"mlp_layer_types": ["sparse", "mixed", "sparse", "sparse"]},
+     "mlp_layer_types"),
+    ({"num_experts_per_tok": None}, "num_experts_per_tok")])
+def test_a_configuration_whose_experts_cannot_be_counted_is_refused(
+        change, match):
+    """A key left out, two keys that disagree, a kind of layer it does not
+    know: a ``ValueError``, never a guess."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "mellum2_12b_a2p5b_l4_e8.json")) as f:
+        spec = json.load(f)
+    spec.update(change)
+    spec = {k: v for k, v in spec.items() if v is not None}
+    with pytest.raises(ValueError, match=match):
+        expert_costs.expert_layers(spec)
 
 
 def test_the_gauge_is_the_programs_or_nothing():

@@ -18,7 +18,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 SEED = 2 ** 31 + 34          # the driver's seeds pass 32 signed bits
 CONFIG = os.path.join(ROOT, "benchmark", "configs", "mellum2_12b_a2p5b_l4_e8")
+CONFIG_NAME = "mellum2_12b_a2p5b_l4_e8"
 CELL = "mellum2_l4_e8_s8192_resident"
+# the entries that list this cell, in their order
+OWN = ["model.window_attention_device_ms", "flash_window_fwd_roofline",
+       "flash_window_bwd_roofline"]
 PEAK = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 SMALL = {"hidden_size": 64, "num_attention_heads": 4,
          "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
@@ -164,10 +168,21 @@ def test_the_module_counts_the_published_model():
     assert cell.traffic["flags"]["sequence_length"] \
         == spec["sequence_length"] == 8192
     assert cell.chips == 1 and harness.task_of(cell).grad_blocks == 1
-    assert [m["name"] for m in cell.per_layer
-            if cell.name in m.get("workloads", ())] == [
-        "model.window_attention_device_ms", "flash_window_fwd_roofline",
-        "flash_window_bwd_roofline"]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        check_the_cells_entries(json.load(f))
+
+
+def check_the_cells_entries(bench: dict):
+    """In ``bench`` (``BENCHMARK.json``, or one to which a later
+    configuration has been appended), found by name: the configuration,
+    the cell, and the three entries that list it, in their order. Entries
+    that list it may be added, and a later cell may join their lists."""
+    assert CONFIG_NAME in {c["name"] for c in bench["configs"]}
+    cell = {w["name"]: w for w in bench["workloads"]}[CELL]
+    assert (cell["config"], cell["chips"]) == (CONFIG_NAME, 1)
+    listing = [m["name"] for m in bench["per_layer"]
+               if CELL in m.get("workloads", ())]
+    assert [n for n in listing if n in OWN] == OWN
 
 
 def test_the_bands_pairs_and_costs_by_hand():
@@ -192,7 +207,7 @@ def test_the_bands_pairs_and_costs_by_hand():
                                                               rel=1e-3)
     assert fwd["bytes"] / PEAK["hbm_bytes_per_s"] \
         == pytest.approx(1.316e-3, rel=1e-2)
-    assert window_costs.config_window("mellum2_12b_a2p5b_l4_e8") == 1024
+    assert window_costs.config_window(published()) == 1024
 
 
 @pytest.mark.parametrize("name,names,passes", [
@@ -213,13 +228,38 @@ def test_a_windows_share_is_of_the_bands_pairs(name, names, passes):
             xplane.Op(10e7, 10.9e7, "flash_bwd_dq.2", text, "XLA Ops")]
     ctx = {"trace": xplane.Trace([xplane.DevicePlane("/device:TPU:0",
                                                      ops + full)]),
-           "peak": PEAK}
+           "peak": PEAK, "config": published()}
     least = passes * 4 * 128 * 128 * 7_864_832 / 197e12
     assert read(ctx) == pytest.approx(100 * least / (4e-3 * len(names)))
     assert 0 < read(ctx) < 100
     ctx["trace"] = xplane.Trace([xplane.DevicePlane("/device:TPU:0", full)])
     assert read(ctx) is None
-    assert read({"trace": None, "peak": PEAK}) is None
+    assert read({"trace": None, "peak": PEAK, "config": published()}) is None
+
+
+@pytest.mark.parametrize("name,names,passes", [
+    ("flash_window_fwd_roofline", ("flash_window_fwd.3",), 1.0),
+    ("flash_window_bwd_roofline", ("flash_window_bwd_dq.4",
+                                   "flash_window_bwd_dkv.5"), 2.5)])
+def test_a_windows_share_takes_the_window_of_the_cells_configuration(
+        name, names, passes):
+    """The same kernels read for a cell whose configuration states a
+    window of 512: the band of 512 (4,063,488 pairs a head), not this
+    configuration's 1,024; a configuration that states no window is
+    refused, not read as the half square."""
+    read = cells.load_module(os.path.join(
+        ROOT, "benchmark", "metrics", name + ".py")).read
+    text = "%k = bf16[128,8192,128]{2,1,0} custom-call(...)"
+    ops = [xplane.Op(i * 1e7, i * 1e7 + 4e6, n, text, "XLA Ops")
+           for i, n in enumerate(names)]
+    ctx = {"trace": xplane.Trace([xplane.DevicePlane("/device:TPU:0", ops)]),
+           "peak": PEAK, "config": {**published(), "sliding_window": 512}}
+    assert window_costs.band_pairs(8192, 512) == 4_063_488
+    least = passes * 4 * 128 * 128 * 4_063_488 / 197e12
+    assert read(ctx) == pytest.approx(100 * least / (4e-3 * len(names)))
+    assert read({**ctx, "config": published()}) > read(ctx)
+    with pytest.raises(ValueError, match="sliding_window"):
+        read({**ctx, "config": {**published(), "sliding_window": None}})
 
 
 def test_the_window_kinds_reader_reads_its_kind_or_nothing(monkeypatch):
